@@ -11,8 +11,6 @@ resolves to a `jax.Device`; scheduling/streams belong to XLA.
 """
 from __future__ import annotations
 
-import functools
-
 __all__ = [
     "Place", "CPUPlace", "XLAPlace", "TPUPlace", "CUDAPlace", "CUDAPinnedPlace",
     "get_device", "set_device", "is_compiled_with_cuda", "is_compiled_with_xpu",
@@ -36,7 +34,7 @@ class CPUPlace(Place):
 
     def jax_device(self):
         import jax
-        return _backend_devices("cpu")[0]
+        return jax.devices("cpu")[0]
 
 
 class XLAPlace(Place):
@@ -52,7 +50,11 @@ class XLAPlace(Place):
     def jax_device(self):
         import jax
         devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                f"{self!r}: this process has {len(devs)} "
+                f"{devs[0].platform} device(s)")
+        return devs[self.device_id]
 
 
 # TPUPlace is the user-facing alias; CUDAPlace is accepted for API parity with
@@ -68,15 +70,6 @@ class CUDAPlace(XLAPlace):
 class CUDAPinnedPlace(CPUPlace):
     def __repr__(self):
         return "CUDAPinnedPlace [-> CPUPlace]"
-
-
-@functools.lru_cache(maxsize=None)
-def _backend_devices(platform: str):
-    import jax
-    try:
-        return tuple(jax.devices(platform))
-    except RuntimeError:
-        return tuple()
 
 
 def _accelerator_platform() -> str | None:

@@ -426,9 +426,9 @@ class CompiledProgram:
                                            int(mesh.shape["dp"]))
 
         # pre-placed feeds (reader.Prefetcher via place_feed) pass through;
-        # host arrays take the synchronous conversion
-        feed_vals = {n: v if isinstance(v, jax.Array) else jnp.asarray(v)
-                     for n, v in feed.items()}
+        # host arrays go straight to the shards the step reads them from
+        feed_vals = self._place_host_feeds(
+            feed, self._feed_specs(program, mesh, sorted(feed)), mesh)
         state_names = [n for n in _persistable_names(program)
                        if scope.get(n) is not None]
         feed_sig = tuple(sorted((n, tuple(v.shape), str(v.dtype))
@@ -496,22 +496,102 @@ class CompiledProgram:
             pf = Prefetcher(batches, place_fn=compiled.place_feed)
             for feed in pf: exe.run(compiled, feed=feed, ...)
         """
-        from jax.sharding import NamedSharding
-        from ..reader.prefetcher import _canonical_array, _x64_enabled
         mesh = self._get_mesh()
         dp = mesh.shape["dp"]
+        specs = {n: P("dp") if np.ndim(v) >= 1 and np.shape(v)[0] % dp == 0
+                 else P() for n, v in feed.items()}
+        return self._place_host_feeds(feed, specs, mesh)
+
+    @staticmethod
+    def _feed_specs(program, mesh, feed_names):
+        """Per-dispatch feed in_specs — what `_compile` traces against
+        and what `_run` places host feeds by."""
+        if "sp" in mesh.axis_names:
+            # batch over dp, sequence (dim 1) over sp; rank-1 feeds
+            # (e.g. flat labels) shard batch only
+            block = program.global_block()
+            sp_deg = mesh.shape["sp"]
+            feed_specs = {}
+            for n in feed_names:
+                try:
+                    shape = tuple(block.var(n).shape or ())
+                except KeyError:
+                    shape = ()
+                # sequence dim (dim 1) rides sp only when it divides evenly
+                # ([-1, 1] label feeds and ragged dims shard batch only)
+                if len(shape) >= 2 and shape[1] is not None and \
+                        shape[1] > 1 and shape[1] % sp_deg == 0:
+                    feed_specs[n] = P("dp", "sp")
+                else:
+                    feed_specs[n] = P("dp")
+            return feed_specs
+        # the partition-spec engine: P("dp") batch split for training
+        # feeds (the historical default), dist_attr head-dim tp shards
+        # and replicated_feed P() for the tp-decode serving programs
+        from .partition_spec import feed_partition_specs
+        return feed_partition_specs(program, mesh, feed_names)
+
+    @staticmethod
+    def _steps_feed_specs(program, mesh, feed_shapes):
+        """Stacked ([K, per-step...]) feed in_specs for the scanned
+        paths, from the feeds' runtime shapes."""
+        dp = mesh.shape["dp"]
+        has_sp = "sp" in mesh.axis_names
+        sp_deg = mesh.shape["sp"] if has_sp else 1
+        block = program.global_block()
+        feed_specs = {}
+        for n, shape in feed_shapes.items():
+            # steps axis never shards; the per-step batch (axis 1)
+            # shards over dp like the looped path's P("dp").  A
+            # non-divisible batch must FAIL here like it does there —
+            # silently replicating it would run every rank over the
+            # full batch with a different summation order, breaking
+            # the bitwise-to-looped contract
+            if len(shape) >= 2:
+                if shape[1] % dp != 0:
+                    raise ValueError(
+                        f"run_steps feed {n!r} per-step batch "
+                        f"{shape[1]} does not divide the dp world "
+                        f"{dp} (stacked feeds shard axis 1 over dp, "
+                        "like run() shards axis 0)")
+                if has_sp:
+                    # mirror _feed_specs' sp heuristic one axis right:
+                    # the declared per-step dim 1 (sequence) is the
+                    # stacked axis 2
+                    try:
+                        gshape = tuple(block.var(n).shape or ())
+                    except KeyError:
+                        gshape = ()
+                    if len(gshape) >= 2 and gshape[1] is not None and \
+                            gshape[1] > 1 and gshape[1] % sp_deg == 0 \
+                            and len(shape) >= 3 and \
+                            shape[2] % sp_deg == 0:
+                        feed_specs[n] = P(None, "dp", "sp")
+                    else:
+                        feed_specs[n] = P(None, "dp")
+                else:
+                    feed_specs[n] = P(None, "dp")
+            else:
+                feed_specs[n] = P(None)  # [K] per-step scalars
+        return feed_specs
+
+    @staticmethod
+    def _place_host_feeds(feed, specs, mesh):
+        """Host arrays -> mesh arrays laid out as the compiled step's
+        in_specs read them: one host->device copy per shard.  A bare
+        `jnp.asarray` (what `specs=None` asks for) lands each batch whole
+        on device 0 and leaves the jit to scatter it from there.
+        `jax.Array`s pass through."""
+        from jax.sharding import NamedSharding
+        from ..reader.prefetcher import _canonical_array, _x64_enabled
         x64 = _x64_enabled()
         out = {}
         for n, v in feed.items():
-            if isinstance(v, jax.Array):
-                out[n] = v
-                continue
-            a = _canonical_array(v, x64)
-            if a.ndim >= 1 and a.shape[0] % dp == 0:
-                spec = P("dp")
-            else:
-                spec = P()
-            out[n] = jax.device_put(a, NamedSharding(mesh, spec))
+            if not isinstance(v, jax.Array):
+                v = _canonical_array(v, x64)
+                v = jnp.asarray(v) if specs is None else jax.device_put(
+                    v, NamedSharding(mesh, specs[n]))
+            out[n] = v
         return out
 
     def _run_steps(self, executor, feed, fetch_list, scope, return_numpy):
@@ -566,11 +646,9 @@ class CompiledProgram:
         if elastic is not None:
             micro_k = self._anchor_elastic(executor, scope, elastic,
                                            int(mesh.shape["dp"]))
-        feed_vals = {n: v if isinstance(v, jax.Array) else jnp.asarray(v)
-                     for n, v in feed.items()}
         k = None
-        for n, v in feed_vals.items():
-            shape = tuple(getattr(v, "shape", ()))
+        for n, v in feed.items():
+            shape = tuple(np.shape(v))
             if len(shape) == 0:
                 raise ValueError(
                     f"run_steps feed {n!r} is a scalar; every feed "
@@ -580,6 +658,14 @@ class CompiledProgram:
                 raise ValueError(
                     f"feed {n!r} leading (steps) dim {shape[0]} != {k}")
         k = int(k)
+        # a ragged per-step batch stays unplaced: the bucket lookup
+        # below pads it (or _compile_steps refuses it)
+        shapes = {n: tuple(np.shape(v)) for n, v in feed.items()}
+        dp = mesh.shape["dp"]
+        even = all(len(s) < 2 or s[1] % dp == 0 for s in shapes.values())
+        feed_vals = self._place_host_feeds(
+            feed, self._steps_feed_specs(program, mesh, shapes)
+            if even else None, mesh)
         state_names = [n for n in _persistable_names(program)
                        if scope.get(n) is not None]
 
@@ -677,12 +763,7 @@ class CompiledProgram:
         an inner scan of gm_k commit-free body steps followed by ONE
         commit-tail execution — the publish allgather and merged-grad
         allreduce run once per window instead of once per micro-step."""
-        from ..utils.shard_map_compat import shard_map_unchecked
         from .partition_spec import state_partition_specs
-        dp = mesh.shape["dp"]
-        has_sp = "sp" in mesh.axis_names
-        sp_deg = mesh.shape["sp"] if has_sp else 1
-        block = program.global_block()
 
         if split is not None:
             step = self._traced_step(split.body, state_names,
@@ -730,45 +811,12 @@ class CompiledProgram:
                 return fetches, new_state
 
         state_specs = state_partition_specs(program, mesh, state_names)
-        feed_specs = {}
-        for n, v in feed_vals.items():
-            shape = tuple(getattr(v, "shape", ()))
-            # steps axis never shards; the per-step batch (axis 1)
-            # shards over dp like the looped path's P("dp").  A
-            # non-divisible batch must FAIL here like it does there —
-            # silently replicating it would run every rank over the
-            # full batch with a different summation order, breaking
-            # the bitwise-to-looped contract
-            if len(shape) >= 2:
-                if shape[1] % dp != 0:
-                    raise ValueError(
-                        f"run_steps feed {n!r} per-step batch "
-                        f"{shape[1]} does not divide the dp world "
-                        f"{dp} (stacked feeds shard axis 1 over dp, "
-                        "like run() shards axis 0)")
-                if has_sp:
-                    # mirror _compile's sp heuristic one axis right:
-                    # the declared per-step dim 1 (sequence) is the
-                    # stacked axis 2
-                    try:
-                        gshape = tuple(block.var(n).shape or ())
-                    except KeyError:
-                        gshape = ()
-                    if len(gshape) >= 2 and gshape[1] is not None and \
-                            gshape[1] > 1 and gshape[1] % sp_deg == 0 \
-                            and len(shape) >= 3 and \
-                            shape[2] % sp_deg == 0:
-                        feed_specs[n] = P(None, "dp", "sp")
-                    else:
-                        feed_specs[n] = P(None, "dp")
-                else:
-                    feed_specs[n] = P(None, "dp")
-            else:
-                feed_specs[n] = P(None)  # [K] per-step scalars
+        feed_specs = self._steps_feed_specs(
+            program, mesh, {n: tuple(v.shape) for n, v in feed_vals.items()})
         fetch_specs = tuple(P() for _ in fetch_names)
-        sharded = shard_map_unchecked(
-            multi, mesh, in_specs=(state_specs, feed_specs, P()),
-            out_specs=(fetch_specs, state_specs))
+        sharded = jax.shard_map(
+            multi, mesh=mesh, in_specs=(state_specs, feed_specs, P()),
+            out_specs=(fetch_specs, state_specs), check_vma=False)
         return jax.jit(sharded, donate_argnums=(0,))
 
     def _window_split(self, program, fetch_names):
@@ -966,10 +1014,6 @@ class CompiledProgram:
         return step
 
     def _compile(self, program, state_names, feed_names, fetch_names, mesh):
-        from ..utils.shard_map_compat import shard_map_unchecked
-        block = program.global_block()
-        axes = tuple(mesh.axis_names)
-        has_sp = "sp" in axes
         step = self._traced_step(program, state_names, fetch_names, mesh)
 
         # ZeRO sharded buckets (distributed/sharding.py stages 1-3:
@@ -984,35 +1028,12 @@ class CompiledProgram:
         # dist_attr tp param sharding + accumulator inheritance live in
         # the engine too, so the per-dispatch and scanned compile paths
         # place identical 2-D layouts
-        from .partition_spec import (state_partition_specs,
-                                     feed_partition_specs)
+        from .partition_spec import state_partition_specs
         state_specs = state_partition_specs(program, mesh, state_names)
-        if has_sp:
-            # batch over dp, sequence (dim 1) over sp; rank-1 feeds
-            # (e.g. flat labels) shard batch only
-            sp_deg = mesh.shape["sp"]
-            feed_specs = {}
-            for n in feed_names:
-                try:
-                    shape = tuple(block.var(n).shape or ())
-                except KeyError:
-                    shape = ()
-                # sequence dim (dim 1) rides sp only when it divides evenly
-                # ([-1, 1] label feeds and ragged dims shard batch only)
-                if len(shape) >= 2 and shape[1] is not None and \
-                        shape[1] > 1 and shape[1] % sp_deg == 0:
-                    feed_specs[n] = P("dp", "sp")
-                else:
-                    feed_specs[n] = P("dp")
-        else:
-            # the partition-spec engine: P("dp") batch split for
-            # training feeds (the historical default), dist_attr
-            # head-dim tp shards and replicated_feed P() for the
-            # tp-decode serving programs
-            feed_specs = feed_partition_specs(program, mesh, feed_names)
+        feed_specs = self._feed_specs(program, mesh, feed_names)
         fetch_specs = tuple(P() for _ in fetch_names)
 
-        sharded = shard_map_unchecked(
-            step, mesh, in_specs=(state_specs, feed_specs, P()),
-            out_specs=(fetch_specs, state_specs))
+        sharded = jax.shard_map(
+            step, mesh=mesh, in_specs=(state_specs, feed_specs, P()),
+            out_specs=(fetch_specs, state_specs), check_vma=False)
         return jax.jit(sharded, donate_argnums=(0,))

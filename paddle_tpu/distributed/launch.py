@@ -9,6 +9,12 @@ TPU mapping: one worker process per host of the slice (`--nproc_per_node`
 defaults to 1 — a single jax client drives all local chips); `--ips` lists
 slice hosts; rank-0 endpoint doubles as the jax.distributed coordinator.
 
+The launcher itself stays OFF JAX: it imports the package (which
+initialises no backend) and never calls `jax.devices()` or runs a
+computation.  A chip belongs to one process at a time — a parent that had
+touched JAX would hold it, and the trainer it spawns would fail or hang.
+Keep it so (`tests/test_chip_smoke.py` checks the import stays cold).
+
 Supervision (docs/elastic.md): the launcher is a SUPERVISOR, not a
 passive poller.  A rank that dies leaves its peers wedged inside the
 next collective, so on any non-zero exit the pod is torn down fail-fast

@@ -2,12 +2,11 @@
 the sharding plan.
 
 Reference pattern: the `match_partition_rules` idiom from the pjit
-training stacks (SNIPPETS.md [2]) — an ordered list of
+training stacks — an ordered list of
 ``(regex, PartitionSpec)`` pairs is matched against every leaf name and
 the first hit wins, scalars are never partitioned, and a name no rule
-covers is an explicit decision, not an accident.  SNIPPETS.md [1] is the
-same idea from the measurement side: the pjit sharding schemes being
-priced are *data*, not code.
+covers is an explicit decision, not an accident: the sharding schemes
+being priced are *data*, not code.
 
 This module is the declarative layer the ZeRO pass family
 (`distributed/sharding.py` stages 1-3) selects its surface through:

@@ -18,8 +18,9 @@ requires:
     / blockwise-parallel-transformer pattern).  Compute overlaps the ICI
     transfer of the next shard.
 
-Both degrade gracefully off-TPU: Pallas runs in interpreter mode on CPU,
-ring attention is pure jax and runs under any shard_map mesh.
+Pallas runs in interpreter mode when the backend is the CPU and compiles
+everywhere else; ring attention is pure jax and runs under any shard_map
+mesh.
 """
 from __future__ import annotations
 
@@ -90,7 +91,7 @@ def use_flash_for(seq_len) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# reference (used for VJP and as the non-TPU fallback)
+# reference (what the kernels are tested against; the path for biased calls)
 # ---------------------------------------------------------------------------
 def reference_attention(q, k, v, bias=None, causal=False, scale=None):
     """Plain softmax(QK^T)V.  q,k,v: [B, H, S, D] (float)."""
@@ -176,18 +177,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, sk,
 
 def _fit_block(n, want):
     """Largest block size <= `want` that tiles `n` evenly and satisfies the
-    Mosaic sublane constraint (multiple of 8); None if impossible.  A bare
-    min() would reroute e.g. sq=384 with want=256 to the O(S^2) fallback
-    even though 128 tiles it."""
+    Mosaic sublane constraint (multiple of 8); None if impossible (a bare
+    min() would refuse e.g. sq=384 with want=256 although 128 tiles it)."""
     for b in range(min(want, n), 7, -1):
         if n % b == 0 and b % 8 == 0:
             return b
     return None
-
-
-def _tiles_ok(sq, sk, block_q, block_k):
-    return _fit_block(sq, block_q) is not None and \
-        _fit_block(sk, block_k) is not None
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
@@ -381,40 +376,42 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     return dq, dk, dv
 
 
-def _on_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+def _interpret() -> bool:
+    """Pallas interpret mode exactly when the backend is the CPU, by name;
+    every other backend compiles the kernels."""
+    return jax.default_backend() == "cpu"
+
+
+def _require_tiles(sq, sk, block_q, block_k):
+    """The kernels tile Sq/Sk exactly.  A shape that cannot be tiled is an
+    error: whoever selected flash (forced on, or past the auto crossover
+    where materialized scores stop fitting) must not silently get the
+    O(S^2) reference instead."""
+    if _fit_block(sq, block_q) is None or _fit_block(sk, block_k) is None:
+        raise ValueError(
+            f"flash_attention: sequence lengths (q={sq}, k={sk}) do not "
+            f"tile into blocks that are multiples of 8 (block_q<="
+            f"{block_q}, block_k<={block_k}); pad the sequence or call "
+            "reference_attention")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, causal, scale, block_q, block_k):
-    if not _tiles_ok(q.shape[2], k.shape[2], block_q, block_k):
-        return reference_attention(q, k, v, causal=causal, scale=scale)
     out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k,
-                        interpret=not _on_tpu())
+                        interpret=_interpret())
     return out
 
 
 def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
-    if not _tiles_ok(q.shape[2], k.shape[2], block_q, block_k):
-        out = reference_attention(q, k, v, causal=causal, scale=scale)
-        return out, (q, k, v, None, None)
     out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k,
-                          interpret=not _on_tpu())
+                          interpret=_interpret())
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_rule(causal, scale, block_q, block_k, res, g):
     q, k, v, out, lse = res
-    if lse is None:
-        # non-tiling fallback shapes: reference vjp (small/irregular only)
-        _, vjp = jax.vjp(lambda q_, k_, v_: reference_attention(
-            q_, k_, v_, causal=causal, scale=scale), q, k, v)
-        return vjp(g)
     return _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q,
-                      block_k, interpret=not _on_tpu())
+                      block_k, interpret=_interpret())
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -443,6 +440,7 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
     # common dtype first — lax.dot_general requires identical operands
     cdt = jnp.promote_types(jnp.promote_types(q.dtype, k.dtype), v.dtype)
     q, k, v = q.astype(cdt), k.astype(cdt), v.astype(cdt)
+    _require_tiles(q.shape[2], k.shape[2], block_q, block_k)
     return _flash(q, k, v, causal, scale, block_q, block_k)
 
 

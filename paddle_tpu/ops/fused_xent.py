@@ -169,11 +169,13 @@ def enable_fused_xent(on: bool = True):
 
 def maybe_fused_xent(logits, label, axis, soft_label, ignore_index):
     """Dispatch hook for the softmax_with_cross_entropy kernel: returns
-    (loss, lse) when the fused Pallas path applies, else None.
-    Conditions: flag on, hard labels, last-axis, the flattened token
-    count tiles into sublane blocks, and the call is TRACED (under jit):
-    in eager op-by-op execution the Softmax placeholder would really
-    allocate, so the base path is kept there."""
+    the loss when the fused Pallas path applies, else None.
+    Conditions: flag on, hard labels, last-axis, and the call is TRACED
+    (under jit): in eager op-by-op execution the Softmax placeholder
+    would really allocate, so the base path is kept there.  With the
+    flag on, a token count that does not tile into sublane blocks is an
+    error, not a quiet detour to the base path.  Interpret mode exactly
+    when the backend is the CPU, by name."""
     if not fused_xent_enabled() or soft_label:
         return None
     if axis != logits.ndim - 1:
@@ -182,8 +184,10 @@ def maybe_fused_xent(logits, label, axis, soft_label, ignore_index):
         return None
     lead = int(np.prod(logits.shape[:-1]))
     if lead % 8 != 0:
-        return None
-    interpret = jax.default_backend() != "tpu"
+        raise ValueError(
+            f"fused_xent: {lead} tokens do not tile into sublane blocks "
+            "(multiple of 8); pad the batch or turn FLAGS_fused_xent off")
+    interpret = jax.default_backend() == "cpu"
     flat = logits.reshape(lead, logits.shape[-1])
     lbl = label
     if lbl.ndim == logits.ndim and lbl.shape[-1] == 1:

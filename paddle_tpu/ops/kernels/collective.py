@@ -27,13 +27,8 @@ def _axes(ctx, attrs):
 
 
 def _axis_size(ax) -> int:
-    """Static size of a mesh axis from inside shard_map.  jax.lax.axis_size
-    only exists in newer jax; psum of a python 1 is constant-folded to the
-    axis size at trace time on every version."""
-    import jax as _jax
-    if hasattr(_jax.lax, "axis_size"):
-        return _jax.lax.axis_size(ax)
-    return int(_jax.lax.psum(1, ax))
+    """Static size of a mesh axis from inside shard_map."""
+    return jax.lax.axis_size(ax)
 
 
 def _c_allreduce(name, op):

@@ -14,8 +14,10 @@ executor jits — and prices every op from its resolved shapes:
     symbolic -1 batch dims bind to `batch`, derived names
     (``@GRAD``/``@RC``/...) borrow the base var's shape.
   * `peak_flops_per_chip()` — the MFU denominator's denominator: chip
-    peak from ``PADDLE_TPU_PEAK_FLOPS`` (env), defaulting to the v5e
-    bf16 peak on TPU and 0 (=unknown, MFU unreported) elsewhere.
+    peak from ``PADDLE_TPU_PEAK_FLOPS`` (env), else keyed on the
+    device's ``device_kind``: the v5e bf16 peak for ``"TPU v5 lite"``,
+    0 (=unknown, MFU unreported) on the CPU, an error for a TPU kind
+    that has no entry.
 
 Accounting conventions (chosen to agree with the analytic estimate the
 whole perf record is denominated in — bench cross-checks the two and
@@ -52,13 +54,15 @@ from typing import Dict, List, Optional, Tuple
 from ..core.program import Program
 
 __all__ = ["analyze_flops", "estimate_step_flops", "peak_flops_per_chip",
-           "INT8_MXU_RATE",
-           "PEAK_FLOPS_ENV", "DEFAULT_TPU_PEAK_FLOPS"]
+           "INT8_MXU_RATE", "PEAK_FLOPS_ENV", "V5E_DEVICE_KIND"]
 
 PEAK_FLOPS_ENV = "PADDLE_TPU_PEAK_FLOPS"
 
-# v5e bf16 MXU peak — the chip the north star is denominated in
-DEFAULT_TPU_PEAK_FLOPS = 197e12
+# `jax.devices()[0].device_kind` of the v5e, as the chip reports it
+V5E_DEVICE_KIND = "TPU v5 lite"
+# bf16 MXU peak per chip by device_kind (v5e: Google Cloud "TPU v5e"
+# documentation) — the one chip the north star is denominated in
+_PEAK_FLOPS_BY_KIND = {V5E_DEVICE_KIND: 197e12}
 
 # int8 MXU rate multiplier over the bf16 peak: the v5e runs int8
 # matmuls at 394 vs 197 TOPS (tools/bench_int8.py validates the 2x
@@ -67,24 +71,26 @@ DEFAULT_TPU_PEAK_FLOPS = 197e12
 INT8_MXU_RATE = 2.0
 
 
-def peak_flops_per_chip(platform: Optional[str] = None) -> float:
+def peak_flops_per_chip(device_kind: Optional[str] = None) -> float:
     """Chip peak FLOPs/s the MFU gauge divides by.  Env override
-    ``PADDLE_TPU_PEAK_FLOPS`` wins; else v5e bf16 peak on TPU and 0
-    (= unknown; MFU is not reported) on CPU hosts.  `platform` skips
-    device discovery when the caller already knows it."""
+    ``PADDLE_TPU_PEAK_FLOPS`` wins; else the peak of `device_kind`
+    (default: this process's first device).  The CPU is 0 (= unknown;
+    MFU is not reported); any other kind without an entry raises —
+    a guessed denominator is worse than none."""
     raw = os.environ.get(PEAK_FLOPS_ENV, "")
     if raw:
-        try:
-            return float(raw)
-        except ValueError:
-            pass
-    if platform is None:
-        try:
-            import jax
-            platform = jax.devices()[0].platform
-        except Exception:
-            platform = "cpu"
-    return DEFAULT_TPU_PEAK_FLOPS if platform == "tpu" else 0.0
+        return float(raw)
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind   # "cpu" on the CPU
+    if device_kind == "cpu":
+        return 0.0
+    if device_kind not in _PEAK_FLOPS_BY_KIND:
+        raise ValueError(
+            f"no peak FLOP/s recorded for device_kind {device_kind!r} "
+            f"(known: {sorted(_PEAK_FLOPS_BY_KIND)}); set "
+            f"{PEAK_FLOPS_ENV} or add the chip with its source")
+    return _PEAK_FLOPS_BY_KIND[device_kind]
 
 
 # ---------------------------------------------------------------------------

@@ -28,7 +28,7 @@ contains a deadlocking plan — and returns the argmax `Plan`.
 
 Roofline (per chip, per dispatched step):
 
-    compute_s      = walked FLOPs / peak_flops_per_chip("tpu")
+    compute_s      = walked FLOPs / peak_flops_per_chip(V5E_DEVICE_KIND)
     wire_overlap_s = ring-accounted bytes of the gradient REDUCTION
                      collectives / ICI bandwidth   (XLA overlaps these
                      with backward compute)
@@ -937,7 +937,7 @@ def plan_program(program: Program, startup: Optional[Program] = None,
       plan can hold, instead of the search returning
       ``predicted_fits=False``.
     * `peak_flops` / `ici_bytes_per_s` — roofline denominators (default:
-      the v5e targets via `peak_flops_per_chip("tpu")` and
+      the v5e targets via `peak_flops_per_chip(V5E_DEVICE_KIND)` and
       `ici_bytes_per_chip()`; planning always prices the TPU target even
       when the planner itself runs on a CPU host).
     * `verify` — gate every HBM-feasible candidate through
@@ -966,13 +966,14 @@ def plan_program(program: Program, startup: Optional[Program] = None,
     The search cost is estimator-cheap by construction: every candidate
     is clone + rewrite + three IR walks — no compilation, no device.
     """
-    from .flops_analysis import peak_flops_per_chip
+    from .flops_analysis import V5E_DEVICE_KIND, peak_flops_per_chip
     from .memory_analysis import hbm_budget_bytes
     from ..core.pass_framework import applied_passes, has_applied
 
     world = max(1, int(world))
     budget = int(hbm_budget) if hbm_budget else hbm_budget_bytes()
-    peak = float(peak_flops) if peak_flops else peak_flops_per_chip("tpu")
+    peak = float(peak_flops) if peak_flops else \
+        peak_flops_per_chip(V5E_DEVICE_KIND)
     ici = float(ici_bytes_per_s) if ici_bytes_per_s else ici_bytes_per_chip()
     calib = default_calibration() if calibration is None else \
         (calibration or None)

@@ -7,7 +7,7 @@ to the invariants that actually break THIS framework: paddle_tpu stacks
 five interacting program-rewrite passes (AMP, recompute, gradient_merge,
 ZeRO-1 sharding, elastic fold) whose composition contracts were, until
 now, enforced only by convention and caught only when an 8-device run
-deadlocked or diverged.  This module moves those failures from tunnel
+deadlocked or diverged.  This module moves those failures from chip
 time to compile time, the same way `static/memory_analysis.py` moved
 OOMs to estimator time.
 

@@ -26,7 +26,6 @@ def main():
     import numpy as np
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P, NamedSharding
-    from jax.experimental.shard_map import shard_map
     from paddle_tpu.ops.registry import run_kernel, OpContext
 
     devs = np.array(jax.devices())          # 4 global (2 per process)
@@ -39,7 +38,7 @@ def main():
                           {"ring_id": 0, "use_calc_stream": True},
                           ctx)["Out"]
 
-    fn = jax.jit(shard_map(step, mesh=mesh, in_specs=P("dp"),
+    fn = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=P("dp"),
                            out_specs=P("dp")))
     # per-device shard value = global shard index + 1 -> allreduce sum
     # over 4 shards = 1+2+3+4 = 10 everywhere

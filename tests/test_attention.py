@@ -95,17 +95,16 @@ def test_flash_backward_bf16():
             rtol=0.05, atol=0.1, err_msg=f"d{name} bf16")
 
 
-def test_flash_irregular_len_falls_back():
-    q, k, v = _qkv(S=100)  # not a multiple of the block size
-    out = flash_attention(q, k, v)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(reference_attention(q, k, v)),
-                               rtol=1e-4, atol=1e-5)
+def test_flash_irregular_len_raises():
+    """A length no multiple-of-8 block tiles is an error, never a silent
+    detour to the O(S^2) reference."""
+    q, k, v = _qkv(S=100)
+    with pytest.raises(ValueError, match="do not tile"):
+        flash_attention(q, k, v)
 
 
 def test_ring_attention_sharded():
     from jax.sharding import Mesh, PartitionSpec as P
-    from paddle_tpu.utils.shard_map_compat import shard_map_unchecked
     q, k, v = _qkv(S=128, D=32)
     mesh = Mesh(np.array(jax.devices()[:8]), ("sp",))
 
@@ -115,9 +114,9 @@ def test_ring_attention_sharded():
         def fn(q, k, v, causal=causal):
             return ring_attention(q, k, v, "sp", causal=causal)
 
-        sharded = shard_map_unchecked(
-            fn, mesh, in_specs=(P(None, None, "sp", None),) * 3,
-            out_specs=P(None, None, "sp", None))
+        sharded = jax.shard_map(
+            fn, mesh=mesh, in_specs=(P(None, None, "sp", None),) * 3,
+            out_specs=P(None, None, "sp", None), check_vma=False)
         out = jax.jit(sharded)(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-5,
@@ -126,26 +125,16 @@ def test_ring_attention_sharded():
 
 def test_ring_attention_grads_sharded():
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
     q, k, v = _qkv(S=64, D=16)
     mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
 
     def ring_loss(q, k, v):
         def fn(q, k, v):
             return ring_attention(q, k, v, "sp", causal=True)
-        try:
-            f = shard_map(fn, mesh=mesh,
+        f = jax.shard_map(fn, mesh=mesh,
                           in_specs=(P(None, None, "sp", None),) * 3,
                           out_specs=P(None, None, "sp", None),
                           check_vma=False)
-        except TypeError:
-            f = shard_map(fn, mesh=mesh,
-                          in_specs=(P(None, None, "sp", None),) * 3,
-                          out_specs=P(None, None, "sp", None),
-                          check_rep=False)
         return (f(q, k, v) ** 2).sum()
 
     def ref_loss(q, k, v):

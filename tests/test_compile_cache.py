@@ -222,12 +222,73 @@ def test_persistent_cache_dir_populated(tmp_path):
     compile_cache.initialize(force=True)
 
 
-def test_initialize_disabled_sentinel(monkeypatch):
-    monkeypatch.setenv(compile_cache.ENV_CACHE_DIR, "off")
-    assert compile_cache.initialize(force=True) is None
-    assert not compile_cache.is_enabled()
-    monkeypatch.delenv(compile_cache.ENV_CACHE_DIR)
+def test_default_dir_is_repo_jax_cache(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR unset: one fixed path inside the
+    checkout, derived from the package location."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.REPO_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert compile_cache.initialize(force=True) == \
+        compile_cache.REPO_CACHE_DIR
+    assert compile_cache.cache_stats()["persistent_dir"] == \
+        compile_cache.REPO_CACHE_DIR
+
+
+def test_unwritable_dir_raises(tmp_path):
+    blocker = tmp_path / "not_a_dir"
+    blocker.write_text("x")
+    with pytest.raises(OSError):
+        compile_cache.initialize(str(blocker / "cache"), force=True)
     compile_cache.initialize(force=True)
+
+
+_ENV_DIR_SCRIPT = """
+import os, sys
+import numpy as np
+import jax
+want = os.environ["JAX_COMPILATION_CACHE_DIR"]
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+import paddle_tpu.static as static
+from paddle_tpu.static import layers
+from paddle_tpu.core import compile_cache
+updates = []
+real = jax.config.update
+def spy(name, val):
+    updates.append(name)
+    return real(name, val)
+jax.config.update = spy
+assert compile_cache.initialize() == want
+assert "jax_compilation_cache_dir" not in updates, updates
+assert jax.config.jax_compilation_cache_dir == want
+main, startup = static.Program(), static.Program()
+with static.program_guard(main, startup):
+    x = layers.data("x", [-1, 6], dtype="float32")
+    y = layers.reduce_sum(layers.fc(x, 17, act="relu"), dim=1)
+exe = static.Executor()
+exe.run(startup)
+exe.run(main, feed={"x": np.ones((4, 6), np.float32)}, fetch_list=[y])
+assert jax.config.jax_compilation_cache_dir == want
+stats = exe.cache_stats()
+assert stats["persistent_dir"] == want, stats
+assert stats["persistent_entries"] > 0, stats
+print("ENV_DIR_OK")
+"""
+
+
+def test_env_placed_dir_is_left_to_jax(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set (fresh interpreter): initialize()
+    sets no directory in code, reads JAX's own back, entries land there."""
+    import subprocess
+    import sys
+    d = str(tmp_path / "placed")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=d, JAX_PLATFORMS="cpu",
+               PYTHONPATH=repo)
+    out = subprocess.run([sys.executable, "-c", _ENV_DIR_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "ENV_DIR_OK" in out.stdout
+    assert any(f.endswith("-cache") for f in os.listdir(d))
 
 
 # -- executor close / cache_stats contracts ---------------------------------

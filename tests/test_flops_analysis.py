@@ -166,5 +166,8 @@ def test_peak_flops_env_override(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "123e9")
     assert peak_flops_per_chip() == 123e9
     monkeypatch.delenv("PADDLE_TPU_PEAK_FLOPS")
-    assert peak_flops_per_chip(platform="cpu") == 0.0
-    assert peak_flops_per_chip(platform="tpu") == 197e12
+    assert peak_flops_per_chip() == 0.0          # tests run on the CPU
+    assert peak_flops_per_chip("cpu") == 0.0
+    assert peak_flops_per_chip("TPU v5 lite") == 197e12
+    with pytest.raises(ValueError, match="device_kind"):
+        peak_flops_per_chip("TPU v9")

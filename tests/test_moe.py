@@ -11,8 +11,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from paddle_tpu.incubate.moe import (switch_moe, init_moe_params,
                                      moe_aux_loss)
 
-from paddle_tpu.utils.shard_map_compat import shard_map_unchecked
-
 
 def _params(E=4, D=8, H=16, seed=0):
     return init_moe_params(jax.random.PRNGKey(seed), D, H, E)
@@ -75,11 +73,11 @@ def test_moe_expert_parallel_matches_dense():
                               capacity_factor=8.0, axis_name="ep")
         return out
 
-    sharded = shard_map_unchecked(
-        fn, mesh,
+    sharded = jax.shard_map(
+        fn, mesh=mesh,
         in_specs=(P(("dp", "ep")), P(), P("ep"), P("ep"), P("ep"),
                   P("ep")),
-        out_specs=P(("dp", "ep")))
+        out_specs=P(("dp", "ep")), check_vma=False)
     out = sharded(x, gw, w1, b1, w2, b2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                rtol=2e-4, atol=2e-5)
@@ -102,9 +100,9 @@ def test_moe_expert_parallel_matches_dense():
             jax.lax.psum(gi, "dp") / world for gi in g[1:])
 
     specs_p = (P(), P("ep"), P("ep"), P("ep"), P("ep"))
-    g_sh = shard_map_unchecked(
-        sharded_step, mesh, in_specs=(specs_p, P(("dp", "ep"))),
-        out_specs=specs_p)((gw, w1, b1, w2, b2), x)
+    g_sh = jax.shard_map(
+        sharded_step, mesh=mesh, in_specs=(specs_p, P(("dp", "ep"))),
+        out_specs=specs_p, check_vma=False)((gw, w1, b1, w2, b2), x)
     for a, b in zip(g_dense, g_sh):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    rtol=5e-4, atol=1e-6)

@@ -310,7 +310,6 @@ def test_run_steps_threads_sharded_slots():
 def test_reducescatter_allgather_roundtrip_pow2_pad():
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
-    from paddle_tpu.utils.shard_map_compat import shard_map_unchecked
     from paddle_tpu.ops.registry import get_op_info, OpContext
 
     rs = get_op_info("c_reducescatter").kernel
@@ -327,8 +326,9 @@ def test_reducescatter_allgather_roundtrip_pow2_pad():
         full = ag({"X": shard}, {"ring_id": 0}, ctx)["Out"]
         return shard, full
 
-    fn = jax.jit(shard_map_unchecked(
-        step, mesh, in_specs=(P(),), out_specs=(P("dp"), P())))
+    fn = jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(P(),), out_specs=(P("dp"), P()),
+        check_vma=False))
     shard, full = fn(padded)
     # reduce-scatter sums the replicated input over 8 ranks, each rank
     # keeping its slice; the gathered result reassembles rank-order
