@@ -1,0 +1,11 @@
+"""Whole-request latency at the HTTP client, 90th percentile."""
+from benchmark import stats
+
+LAYER, SOURCE, UNIT, BETTER = "entry_serve", "host_clock", "s", "lower"
+
+
+def reduce(run):
+    lat = run.samples.get("latency_s")
+    if not lat:
+        return None
+    return stats.percentile(lat, 90)
