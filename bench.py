@@ -1728,9 +1728,7 @@ def main():
         if megastep > 0:
             n_steps = megastep
             if prof_dir:
-                from paddle_tpu.profiler import set_device_trace_active
                 jax.profiler.start_trace(prof_dir)
-                set_device_trace_active(True)
             t0 = time.time()
             out = exe.run_steps(main_p, feed=sfeed, fetch_list=[loss])
             np.asarray(out[0])
@@ -1746,9 +1744,7 @@ def main():
             compile_time_s = time.time() - tc
             warm_traces = exe.cache_stats()["traces"]
             if prof_dir:
-                from paddle_tpu.profiler import set_device_trace_active
                 jax.profiler.start_trace(prof_dir)
-                set_device_trace_active(True)
             t0 = time.time()
             # steps WITHOUT per-step fetches: state buffers are donated
             # and stay on device, dispatch runs ahead of the chip; only
@@ -1776,9 +1772,7 @@ def main():
             assert exe.cache_stats()["traces"] == warm_traces, \
                 "recompile inside the timed loop"
         if prof_dir:
-            from paddle_tpu.profiler import set_device_trace_active
             jax.profiler.stop_trace()
-            set_device_trace_active(False)
 
     tokens_per_sec = n_steps * batch * seq / dt
 

@@ -100,6 +100,12 @@ def initialize(cache_dir: Optional[str] = None, *,
         compilation_cache.reset_cache()
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_enable_compilation_cache", True)
+    # JAX leaves op metadata out of the cache key by default, so a cache
+    # could hand back an executable compiled before an op carried its
+    # `named_scope` (BlockTracer.run_op): the device trace would then name
+    # nothing.  With it in the key a program is served only what was
+    # compiled from the same scopes and source lines.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs",
         _state["floor"] if min_compile_time_s is None

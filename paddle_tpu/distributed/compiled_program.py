@@ -28,6 +28,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.program import Program, OpRole, unique_name
 from ..ops.registry import get_op_info, OpContext
+from ..profiler import RecordEvent
 
 __all__ = ["CompiledProgram", "BuildStrategy", "ExecutionStrategy",
            "insert_grad_allreduce"]
@@ -427,30 +428,37 @@ class CompiledProgram:
 
         # pre-placed feeds (reader.Prefetcher via place_feed) pass through;
         # host arrays go straight to the shards the step reads them from
-        feed_vals = self._place_host_feeds(
-            feed, self._feed_specs(program, mesh, sorted(feed)), mesh)
-        state_names = [n for n in _persistable_names(program)
-                       if scope.get(n) is not None]
-        feed_sig = tuple(sorted((n, tuple(v.shape), str(v.dtype))
-                                for n, v in feed_vals.items()))
-        key = (program.fingerprint(), feed_sig, tuple(fetch_names),
-               tuple(state_names), n_dev,
-               getattr(self._build_strategy, "fetch_aggregation", "reduce"))
+        with RecordEvent("mesh/place_feed"):
+            feed_vals = self._place_host_feeds(
+                feed, self._feed_specs(program, mesh, sorted(feed)), mesh)
         from ..core import compile_cache as _ccache
-        fn = self._cache.get(key)
+        with RecordEvent("executor/prepare"):
+            state_names = [n for n in _persistable_names(program)
+                           if scope.get(n) is not None]
+            feed_sig = tuple(sorted((n, tuple(v.shape), str(v.dtype))
+                                    for n, v in feed_vals.items()))
+            key = (program.fingerprint(), feed_sig, tuple(fetch_names),
+                   tuple(state_names), n_dev,
+                   getattr(self._build_strategy, "fetch_aggregation",
+                           "reduce"))
+            fn = self._cache.get(key)
         if fn is None:
-            # env-gated IR verification rides the (already slow) first
-            # compile of each program (PADDLE_TPU_VERIFY, verifier.py)
-            from ..static.verifier import verify_first_compile
-            verify_first_compile(program, fetch_list=fetch_names)
-            _ccache.record_miss()
-            _ccache.record_trace()
-            from ..observability.journal import emit as _jemit
-            _jemit("compile", mode="compiled", world=int(n_dev),
-                   fingerprint=str(key[0])[:16])
-            fn = self._compile(program, state_names, sorted(feed_vals),
-                               fetch_names, mesh)
-            self._cache[key] = fn
+            fingerprint = str(key[0])[:16]
+            with RecordEvent("executor/trace_compile", mode="compiled",
+                             fingerprint=fingerprint):
+                # env-gated IR verification rides the (already slow)
+                # first compile of each program (PADDLE_TPU_VERIFY,
+                # verifier.py)
+                from ..static.verifier import verify_first_compile
+                verify_first_compile(program, fetch_list=fetch_names)
+                _ccache.record_miss()
+                _ccache.record_trace()
+                from ..observability.journal import emit as _jemit
+                _jemit("compile", mode="compiled", world=int(n_dev),
+                       fingerprint=fingerprint)
+                fn = self._compile(program, state_names,
+                                   sorted(feed_vals), fetch_names, mesh)
+                self._cache[key] = fn
         else:
             _ccache.record_hit()
 
@@ -475,7 +483,8 @@ class CompiledProgram:
                     executor._elastic_steps // micro_k) % (2 ** 31)
         else:
             seed = executor._seed_for_step(program)
-        fetches, new_state = fn(state, feed_vals, jnp.uint32(seed))
+        with RecordEvent("executor/launch"):
+            fetches, new_state = fn(state, feed_vals, jnp.uint32(seed))
         self._dispatches += 1
         executor._step += 1
         if elastic is not None:
@@ -483,7 +492,8 @@ class CompiledProgram:
         for n, v in new_state.items():
             scope.set(n, v)
         if return_numpy:
-            return [np.asarray(f) for f in fetches]
+            with RecordEvent("executor/fetch"):
+                return [np.asarray(f) for f in fetches]
         return list(fetches)
 
     def place_feed(self, feed: Dict[str, Any]) -> Dict[str, Any]:
@@ -496,11 +506,13 @@ class CompiledProgram:
             pf = Prefetcher(batches, place_fn=compiled.place_feed)
             for feed in pf: exe.run(compiled, feed=feed, ...)
         """
-        mesh = self._get_mesh()
-        dp = mesh.shape["dp"]
-        specs = {n: P("dp") if np.ndim(v) >= 1 and np.shape(v)[0] % dp == 0
-                 else P() for n, v in feed.items()}
-        return self._place_host_feeds(feed, specs, mesh)
+        with RecordEvent("mesh/place_feed"):
+            mesh = self._get_mesh()
+            dp = mesh.shape["dp"]
+            specs = {n: P("dp") if np.ndim(v) >= 1
+                     and np.shape(v)[0] % dp == 0 else P()
+                     for n, v in feed.items()}
+            return self._place_host_feeds(feed, specs, mesh)
 
     @staticmethod
     def _feed_specs(program, mesh, feed_names):
@@ -663,9 +675,10 @@ class CompiledProgram:
         shapes = {n: tuple(np.shape(v)) for n, v in feed.items()}
         dp = mesh.shape["dp"]
         even = all(len(s) < 2 or s[1] % dp == 0 for s in shapes.values())
-        feed_vals = self._place_host_feeds(
-            feed, self._steps_feed_specs(program, mesh, shapes)
-            if even else None, mesh)
+        with RecordEvent("mesh/place_feed"):
+            feed_vals = self._place_host_feeds(
+                feed, self._steps_feed_specs(program, mesh, shapes)
+                if even else None, mesh)
         state_names = [n for n in _persistable_names(program)
                        if scope.get(n) is not None]
 
@@ -698,19 +711,21 @@ class CompiledProgram:
                 key, feed_vals, bucket = bucketed
                 fn = self._cache.get(key)
         if fn is None:
-            from ..static.verifier import verify_first_compile
-            verify_first_compile(program, fetch_list=fetch_names)
-            _ccache.record_miss()
-            _ccache.record_trace()
-            from ..observability.journal import emit as _jemit
-            _jemit("compile",
-                   mode=("compiled_steps_hoisted" if hoist
-                         else "compiled_steps"), world=int(n_dev),
-                   fingerprint=str(key[2])[:16])
-            fn = self._compile_steps(program, state_names, feed_vals,
-                                     fetch_names, mesh,
-                                     split=split if hoist else None)
-            self._cache[key] = fn
+            mode = "compiled_steps_hoisted" if hoist else "compiled_steps"
+            fingerprint = str(key[2])[:16]
+            with RecordEvent("executor/trace_compile", mode=mode,
+                             fingerprint=fingerprint):
+                from ..static.verifier import verify_first_compile
+                verify_first_compile(program, fetch_list=fetch_names)
+                _ccache.record_miss()
+                _ccache.record_trace()
+                from ..observability.journal import emit as _jemit
+                _jemit("compile", mode=mode, world=int(n_dev),
+                       fingerprint=fingerprint)
+                fn = self._compile_steps(program, state_names, feed_vals,
+                                         fetch_names, mesh,
+                                         split=split if hoist else None)
+                self._cache[key] = fn
         else:
             _ccache.record_hit()
         from ..testing import chaos as _chaos
@@ -737,7 +752,8 @@ class CompiledProgram:
             seeds = jnp.asarray(
                 [(executor._seed_for_step(program) + i) % (2 ** 31)
                  for i in range(k)], jnp.uint32)
-        fetches, new_state = fn(state, feed_vals, seeds)
+        with RecordEvent("executor/launch"):
+            fetches, new_state = fn(state, feed_vals, seeds)
         self._dispatches += 1
         executor._step += k
         if elastic is not None:
@@ -749,7 +765,8 @@ class CompiledProgram:
                 fetches, bucket[0], bucket[1],
                 block=program.global_block(), fetch_names=fetch_names)
         if return_numpy:
-            return [np.asarray(f) for f in fetches]
+            with RecordEvent("executor/fetch"):
+                return [np.asarray(f) for f in fetches]
         return list(fetches)
 
     def _compile_steps(self, program, state_names, feed_vals,

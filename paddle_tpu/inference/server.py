@@ -52,6 +52,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 import numpy as np
+from ..profiler import RecordEvent
 
 __all__ = ["InferenceServer"]
 
@@ -188,39 +189,46 @@ class _Handler(BaseHTTPRequestHandler):
             self._reply(501, {"error": "no generation model attached "
                                        "(InferenceServer(generator=...))"})
             return
-        try:
-            seqs, kw = srv._parse_generate(req)
-        except Exception as e:
-            self._reply_error(400, e)
-            return
-        futs = []
-        try:
-            futs = [srv._engine.submit(s, **kw) for s in seqs]
-            # ONE deadline across all sequences of the request, not
-            # t_left per future
-            deadline = time.monotonic() + srv._engine.default_timeout_s \
-                + 5.0
-            outs = [f.result(timeout=max(0.0,
-                                         deadline - time.monotonic()))
-                    for f in futs]
-        except Exception as e:  # noqa: BLE001 — mapped to status below
-            # any partial failure: cancel the sequences already admitted
-            # so no decode slot keeps generating into a discarded future
-            for f in futs:
-                f.cancel()
-            if isinstance(e, FuturesTimeout):
-                self._reply_error(504, e)
-            elif isinstance(e, QueueFullError):
-                self._reply_error(e.http_status, e)
-            elif isinstance(e, BatcherError):
-                self._reply_error(e.http_status, e)
-            elif isinstance(e, ValueError):
+        # one id for the whole POST: the engine's `engine/prefill` spans
+        # of its sequences carry the same `req` (docs/observability.md)
+        rid = srv._engine.next_request_id()
+        with RecordEvent("server/generate", req=rid) as span:
+            try:
+                seqs, kw = srv._parse_generate(req)
+            except Exception as e:
                 self._reply_error(400, e)
-            else:
-                self._reply_error(500, e)
-            return
-        self._reply(200, {"output_ids": [np.asarray(o).tolist()
-                                         for o in outs]})
+                return
+            span.set(n=len(seqs))
+            futs = []
+            try:
+                futs = [srv._engine.submit(s, req_id=rid, **kw)
+                        for s in seqs]
+                # ONE deadline across all sequences of the request, not
+                # t_left per future
+                deadline = time.monotonic() \
+                    + srv._engine.default_timeout_s + 5.0
+                with RecordEvent("server/wait"):
+                    outs = [f.result(timeout=max(
+                        0.0, deadline - time.monotonic())) for f in futs]
+            except Exception as e:  # noqa: BLE001 — mapped to status below
+                # any partial failure: cancel the sequences already
+                # admitted so no decode slot keeps generating into a
+                # discarded future
+                for f in futs:
+                    f.cancel()
+                if isinstance(e, FuturesTimeout):
+                    self._reply_error(504, e)
+                elif isinstance(e, QueueFullError):
+                    self._reply_error(e.http_status, e)
+                elif isinstance(e, BatcherError):
+                    self._reply_error(e.http_status, e)
+                elif isinstance(e, ValueError):
+                    self._reply_error(400, e)
+                else:
+                    self._reply_error(500, e)
+                return
+            self._reply(200, {"output_ids": [np.asarray(o).tolist()
+                                             for o in outs]})
 
 
 class InferenceServer:

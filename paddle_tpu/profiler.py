@@ -10,6 +10,12 @@ TPU-native: host events are recorded here; DEVICE profiling delegates to
 jax.profiler (XPlane → TensorBoard/perfetto — the CUPTI analog,
 platform/device_tracer.h), started/stopped alongside the host profiler when
 a trace dir is given.
+
+`RecordEvent` is the program's ONE span mechanism (docs/observability.md
+"Spans").  It enters a `jax.profiler.TraceAnnotation` under whatever
+profiler session is live — started here, by a benchmark, by TensorBoard —
+so a span lands on the host plane of that trace, on the device trace's
+clock, with its fields as event stats.  A live session is the only switch.
 """
 from __future__ import annotations
 
@@ -22,24 +28,20 @@ from typing import Dict, List, Optional
 
 __all__ = ["profiler", "start_profiler", "stop_profiler", "reset_profiler",
            "RecordEvent", "record_event", "cuda_profiler",
-           "npu_profiler", "export_chrome_tracing",
-           "set_device_trace_active"]
-
-# sentinel jax_trace_dir value: a device trace started OUTSIDE
-# start_profiler (e.g. bench.py calling jax.profiler.start_trace
-# directly) — RecordEvent annotates into it, but stop_profiler must not
-# stop a trace it does not own
-_EXTERNAL_TRACE = "<external>"
+           "npu_profiler", "export_chrome_tracing"]
 
 
 class _Event:
-    __slots__ = ("name", "start", "end", "thread")
+    __slots__ = ("name", "start", "end", "thread", "fields", "parent")
 
-    def __init__(self, name, start, end, thread):
+    def __init__(self, name, start, end, thread, fields=None, parent=None):
         self.name = name
         self.start = start
         self.end = end
         self.thread = thread
+        self.fields = fields or {}
+        self.parent = parent    # name of the innermost span open on the
+        # same thread when this one began (None at the top)
 
 
 class _ProfilerState:
@@ -75,14 +77,13 @@ def stop_profiler(sorted_key="total", profile_path="/tmp/profile"):
     chrome trace next to profile_path."""
     with _state.lock:
         _state.enabled = False
-        if _state.jax_trace_dir and _state.jax_trace_dir != _EXTERNAL_TRACE:
+        if _state.jax_trace_dir:
             try:
                 import jax
                 jax.profiler.stop_trace()
             except (ImportError, RuntimeError):
                 pass
-        if _state.jax_trace_dir != _EXTERNAL_TRACE:
-            _state.jax_trace_dir = None
+        _state.jax_trace_dir = None
         events = list(_state.events)
     _print_summary(events, sorted_key)
     if profile_path:
@@ -93,18 +94,6 @@ def reset_profiler():
     with _state.lock:
         _state.events = []
         _state.t0 = time.perf_counter()
-
-
-def set_device_trace_active(active: bool = True):
-    """Tell RecordEvent a device trace started OUTSIDE start_profiler
-    (jax.profiler.start_trace called directly — bench's BENCH_PROFILE
-    path) is live, so host annotations keep nesting into it; pass False
-    after stopping it.  start_profiler-owned traces need no call."""
-    with _state.lock:
-        if active:
-            _state.jax_trace_dir = _EXTERNAL_TRACE
-        elif _state.jax_trace_dir == _EXTERNAL_TRACE:
-            _state.jax_trace_dir = None
 
 
 def _print_summary(events: List[_Event], sorted_key):
@@ -132,7 +121,8 @@ def export_chrome_tracing(path: str, events: Optional[List[_Event]] = None):
     trace = {"traceEvents": [
         {"name": e.name, "cat": "host", "ph": "X",
          "ts": e.start * 1e6, "dur": (e.end - e.start) * 1e6,
-         "pid": 0, "tid": e.thread}
+         "pid": 0, "tid": e.thread,
+         "args": dict(e.fields, parent=e.parent)}
         for e in events]}
     d = os.path.dirname(path)
     if d:
@@ -143,10 +133,12 @@ def export_chrome_tracing(path: str, events: Optional[List[_Event]] = None):
 
 
 # jax.profiler cached ONCE (None = not yet resolved, False = absent):
-# RecordEvent.__enter__ sits inside Executor.run, and re-running the
-# import machinery + constructing a TraceAnnotation on every step cost
-# real hot-path time even with the profiler disabled
+# RecordEvent.__enter__ sits inside Executor.run and the engine's decode
+# loop, and re-running the import machinery on every span cost real
+# hot-path time
 _jax_profiler = None
+# the spans open on this thread while the in-memory profiler is on
+_open = threading.local()
 
 
 def _resolve_jax_profiler():
@@ -161,28 +153,44 @@ def _resolve_jax_profiler():
 
 
 class RecordEvent:
-    """RAII host annotation (platform/profiler.h:127).  Also usable as a
-    decorator/context; while a device trace is active (start_profiler
-    with trace_dir) it nests a jax TraceAnnotation so host events appear
-    in the device trace.  With no device trace the annotation is skipped
-    entirely — the disabled-profiler cost is two attribute reads, not an
-    import plus a TraceAnnotation per call."""
+    """RAII host span (platform/profiler.h:127) with optional fields:
+    ``RecordEvent("engine/prefill", req=7, prompt=96)``.
 
-    __slots__ = ("name", "_t", "_jax_ctx")
+    Enters a `jax.profiler.TraceAnnotation` whenever a profiler session
+    is live — whoever started it: the span lands on the host plane of the
+    device trace with its fields as event stats.  With none live that is
+    one C++ flag check (`is_enabled`) and nothing is built or recorded.
+    Under `start_profiler()` it is also kept in memory (summary table,
+    Chrome export) with its fields and its `parent`: the innermost span
+    open on the same thread.  Cause across threads travels in a field (`req`),
+    not in a pointer."""
 
-    def __init__(self, name: str):
+    __slots__ = ("name", "fields", "_t", "_parent", "_jax_ctx")
+
+    def __init__(self, name: str, **fields):
         self.name = name
+        self.fields = fields
         self._t = None
+        self._parent = None
         self._jax_ctx = None
 
+    def set(self, **fields):
+        """Fields known only once the work is under way (bytes fetched,
+        the bucket chosen); call before the span closes."""
+        self.fields.update(fields)
+        if self._jax_ctx is not None:
+            self._jax_ctx.set_metadata(**fields)
+
     def __enter__(self):
+        prof = _jax_profiler or _resolve_jax_profiler()
+        if prof and prof.TraceAnnotation.is_enabled():
+            self._jax_ctx = prof.TraceAnnotation(self.name, **self.fields)
+            self._jax_ctx.__enter__()
         if _state.enabled:
+            stack = _open.__dict__.setdefault("stack", [])
+            self._parent = stack[-1] if stack else None
+            stack.append(self.name)
             self._t = time.perf_counter() - _state.t0
-        if _state.jax_trace_dir is not None:
-            prof = _resolve_jax_profiler()
-            if prof:
-                self._jax_ctx = prof.TraceAnnotation(self.name)
-                self._jax_ctx.__enter__()
         return self
 
     def __exit__(self, *a):
@@ -190,9 +198,13 @@ class RecordEvent:
             self._jax_ctx.__exit__(*a)
         if self._t is not None:
             end = time.perf_counter() - _state.t0
+            stack = _open.__dict__.get("stack")
+            if stack:
+                stack.pop()
             with _state.lock:
                 _state.events.append(_Event(
-                    self.name, self._t, end, threading.get_ident()))
+                    self.name, self._t, end, threading.get_ident(),
+                    self.fields, self._parent))
         return False
 
 

@@ -23,6 +23,7 @@ from __future__ import annotations
 import queue as _queue
 import threading
 from typing import Any, Callable, Iterable, Iterator, Optional
+from ..profiler import RecordEvent
 
 __all__ = ["Prefetcher", "place_feed"]
 
@@ -105,12 +106,18 @@ class Prefetcher:
     # -- worker --------------------------------------------------------------
     def _worker(self):
         try:
-            for item in self._source:
+            while True:
+                # the source builds a batch when it is asked for the next
+                with RecordEvent("prefetcher/build"):
+                    item = next(self._source, _END)
+                if item is _END:
+                    break
                 # closed-check BEFORE placing: a close() racing a blocked
                 # put must not pull + device_put yet another source batch
                 if self._closed.is_set():
                     return
-                staged = self._place_fn(item)
+                with RecordEvent("prefetcher/place"):
+                    staged = self._place_fn(item)
                 if not self._put(staged):
                     return  # closed mid-stream; drop silently
         except BaseException as e:  # noqa: BLE001 - re-raised at consumer
@@ -136,7 +143,8 @@ class Prefetcher:
     def __next__(self):
         if self._done:
             raise StopIteration
-        item = self._q.get()
+        with RecordEvent("prefetcher/wait"):
+            item = self._q.get()
         if item is _END:
             self._done = True
             if self._err is not None:

@@ -60,6 +60,7 @@ sharing:
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from concurrent.futures import Future
@@ -69,6 +70,7 @@ import numpy as np
 
 from . import metrics
 from ..core.compile_cache import next_pow2 as _next_pow2
+from ..profiler import RecordEvent
 from .batcher import (BatcherStoppedError, DeadlineExceededError,
                       QueueFullError, _jittered)
 from .kv_pool import PagedKVPool, PageTable
@@ -83,10 +85,11 @@ class GenerationRequest:
     token sequence (prompt + generated, truncated at EOS) as int64[n]."""
 
     __slots__ = ("prompt", "max_new", "strategy", "top_k", "temperature",
-                 "rng", "future", "deadline", "t_enqueue")
+                 "rng", "future", "deadline", "t_enqueue", "req_id")
 
     def __init__(self, prompt, max_new, strategy, top_k, temperature,
-                 seed, timeout_s):
+                 seed, timeout_s, req_id=0):
+        self.req_id = int(req_id)   # the `req` field of its spans
         self.prompt = np.asarray(prompt, np.int64).reshape(-1)
         self.max_new = int(max_new)
         self.strategy = strategy
@@ -246,6 +249,7 @@ class ContinuousBatchingEngine:
         self.default_timeout_s = float(default_timeout_s)
         self._kv_floor = int(kv_bucket_floor)
         self._queue: List[GenerationRequest] = []
+        self._req_ids = itertools.count(1)
         self._slots: List[Optional[_Slot]] = [None] * self.max_slots
         self._kv_buckets = set()   # distinct compiled KV lengths seen
         self._radix = None
@@ -373,7 +377,12 @@ class ContinuousBatchingEngine:
     def submit(self, input_ids, max_length: int = 20,
                decode_strategy: str = "greedy_search", top_k: int = 0,
                temperature: float = 1.0, seed: int = 0,
-               timeout_s: Optional[float] = None) -> Future:
+               timeout_s: Optional[float] = None,
+               req_id: Optional[int] = None) -> Future:
+        """Queue one sequence.  ``req_id`` (from ``next_request_id()``)
+        ties the engine's spans to the caller's: the HTTP handler gives
+        every sequence of one POST the id its ``server/generate`` span
+        carries; without one the engine draws its own."""
         if decode_strategy not in ("greedy_search", "sampling"):
             raise ValueError(
                 f"continuous batching supports 'greedy_search' and "
@@ -400,7 +409,8 @@ class ContinuousBatchingEngine:
                     f"({self._pool.page_tokens} tokens/page)")
         req = GenerationRequest(
             prompt, max_length, decode_strategy, top_k, temperature, seed,
-            self.default_timeout_s if timeout_s is None else timeout_s)
+            self.default_timeout_s if timeout_s is None else timeout_s,
+            req_id=self.next_request_id() if req_id is None else req_id)
         with self._mu:
             if not self._running or self._draining:
                 metrics.count("gen.rejected")
@@ -417,7 +427,14 @@ class ContinuousBatchingEngine:
             self._work.notify()
         return req.future
 
+    def next_request_id(self) -> int:
+        return next(self._req_ids)
+
     # -- decode loop --------------------------------------------------------
+    # Spans (docs/observability.md "Spans"): every stretch of this thread
+    # is inside one of engine/idle, engine/admit, engine/prefill or
+    # engine/step, and inside the last two each host<->device crossing
+    # has a child with its `bytes`.
     def _decode_loop(self):
         while True:
             with self._mu:
@@ -426,13 +443,27 @@ class ContinuousBatchingEngine:
                     self._idle.notify_all()
                     if self._draining:
                         return
-                    self._work.wait(timeout=0.05)
+                    with RecordEvent("engine/idle"):
+                        self._work.wait(timeout=0.05)
                 if not self._running:
                     return
-                pending = self._admit_locked()
+                with RecordEvent("engine/admit") as span:
+                    pending = self._admit_locked()
+                    span.set(admitted=len(pending),
+                             queued=len(self._queue))
             for req, table in pending:
+                # the wait a caller feels: from submit until the engine
+                # takes the prompt up (behind the prefills admitted with
+                # it, not only until admission)
+                waited = time.monotonic() - req.t_enqueue
+                metrics.count("gen.prefills")
+                metrics.count("gen.queue_wait_us", int(waited * 1e6))
                 try:
-                    self._prefill(req, table)
+                    with RecordEvent("engine/prefill", req=req.req_id,
+                                     prompt=int(req.prompt.size),
+                                     waited_ms=round(waited * 1e3, 3)
+                                     ) as span:
+                        self._prefill(req, table, span)
                 except Exception as e:  # noqa: BLE001 — this request only
                     metrics.count("gen.failed")
                     if table is not None:
@@ -440,12 +471,44 @@ class ContinuousBatchingEngine:
                     req.future.set_exception(e)
             try:
                 if any(self._slots):
-                    if self._spec is not None:
-                        self._step_spec()
-                    else:
-                        self._step()
+                    with RecordEvent("engine/step") as span:
+                        if self._spec is not None:
+                            self._step_spec(span)
+                        else:
+                            self._step(span)
             except Exception as e:  # noqa: BLE001 — fail loud, stay alive
                 self._fail_all(e)
+
+    @staticmethod
+    def _upload(*arrays):
+        """Host arrays to device tensors inside one `engine/upload` span
+        that carries their bytes."""
+        import paddle_tpu
+        n = sum(a.nbytes for a in arrays)
+        metrics.count("gen.h2d_bytes", n)
+        with RecordEvent("engine/upload", bytes=n):
+            return [paddle_tpu.to_tensor(a) for a in arrays]
+
+    @classmethod
+    def _upload_batch(cls, ids, mask, k, v):
+        """`_upload` of a forward's ids, mask and dense caches `k`, `v`
+        ``[L, ...]``: (ids, mask, per-layer attention caches)."""
+        from ..nn import MultiHeadAttention
+        ids_t, mask_t, *kv = cls._upload(
+            ids, mask, *[c[li] for li in range(len(k)) for c in (k, v)])
+        return ids_t, mask_t, [MultiHeadAttention.Cache(a, b)
+                               for a, b in zip(kv[0::2], kv[1::2])]
+
+    @staticmethod
+    def _download(span, *tensors):
+        """Device tensors to host arrays (waits for the device, then
+        copies WHOLE tensors down, whatever slice the caller keeps);
+        their bytes go on `span`."""
+        out = [np.asarray(t.numpy()) for t in tensors]
+        n = sum(a.nbytes for a in out)
+        metrics.count("gen.d2h_bytes", n)
+        span.set(bytes=n)
+        return out
 
     def _admit_locked(self) -> List[Tuple[GenerationRequest,
                                           Optional[PageTable]]]:
@@ -511,7 +574,7 @@ class ContinuousBatchingEngine:
 
     # -- model plumbing -----------------------------------------------------
     def _prefill(self, req: GenerationRequest,
-                 table: Optional[PageTable] = None):
+                 table: Optional[PageTable], span: RecordEvent):
         """Run the prompt through the model once: fills this sequence's
         KV (dense slot arrays, or pool pages through the prefix-sharing
         write path) and samples its first token, then installs it in a
@@ -523,7 +586,6 @@ class ContinuousBatchingEngine:
         tokens never touch the model (compute sharing, counted by
         ``kv.radix_hit_tokens``).  The hit is capped at ``p - 1`` so at
         least one suffix token always runs for next-token logits."""
-        import paddle_tpu
         if req.future.cancelled():
             if table is not None:
                 self._pool.close_sequence(table)
@@ -544,35 +606,37 @@ class ContinuousBatchingEngine:
             mpad = _next_pow2(m, self._kv_floor)
             spp = min(_next_pow2(sp, self._kv_floor),
                       int(self.config.max_position) - m)
+            span.set(bucket=spp, radix_hit=m)
             with self._mu:
                 self._kv_buckets.add(("reuse_prefill", mpad, spp))
                 metrics.gauge("gen.kv_buckets", len(self._kv_buckets))
             cfg = self.config
             heads = cfg.num_heads
             head_dim = cfg.hidden_size // heads
-            k_hit, v_hit = self._pool.gather(table)   # [L, H, m, Dh]
-            k_c = np.zeros((cfg.num_layers, 1, heads, mpad, head_dim),
-                           np.float32)
-            v_c = np.zeros_like(k_c)
-            k_c[:, 0, :, :m] = k_hit
-            v_c[:, 0, :, :m] = v_hit
-            from ..nn import MultiHeadAttention
-            caches = [MultiHeadAttention.Cache(
-                paddle_tpu.to_tensor(k_c[li]), paddle_tpu.to_tensor(v_c[li]))
-                for li in range(cfg.num_layers)]
-            ids = np.full((1, spp), cfg.eos_id, np.int64)
-            ids[0, :sp] = req.prompt[m:]
-            # suffix row u sees every adopted column plus suffix
-            # columns <= u (causal); pad cache columns stay -inf
-            mask = np.full((1, 1, spp, mpad + spp), _NEG_INF, np.float32)
-            mask[0, 0, :, :m] = 0.0
-            for u in range(spp):
-                mask[0, 0, u, mpad:mpad + u + 1] = 0.0
-            logits, caches = self._model.forward(
-                paddle_tpu.to_tensor(ids), cache=caches,
-                pos_offset=np.asarray([m], np.int64),
-                attn_mask=paddle_tpu.to_tensor(mask))
-            last = np.asarray(logits.numpy())[0, sp - 1]
+            with RecordEvent("engine/build"):
+                k_hit, v_hit = self._pool.gather(table)   # [L, H, m, Dh]
+                k_c = np.zeros((cfg.num_layers, 1, heads, mpad, head_dim),
+                               np.float32)
+                v_c = np.zeros_like(k_c)
+                k_c[:, 0, :, :m] = k_hit
+                v_c[:, 0, :, :m] = v_hit
+                ids = np.full((1, spp), cfg.eos_id, np.int64)
+                ids[0, :sp] = req.prompt[m:]
+                # suffix row u sees every adopted column plus suffix
+                # columns <= u (causal); pad cache columns stay -inf
+                mask = np.full((1, 1, spp, mpad + spp), _NEG_INF,
+                               np.float32)
+                mask[0, 0, :, :m] = 0.0
+                for u in range(spp):
+                    mask[0, 0, u, mpad:mpad + u + 1] = 0.0
+            ids_t, mask_t, caches = self._upload_batch(ids, mask, k_c, v_c)
+            with RecordEvent("engine/forward"):
+                logits, caches = self._model.forward(
+                    ids_t, cache=caches,
+                    pos_offset=np.asarray([m], np.int64),
+                    attn_mask=mask_t)
+            with RecordEvent("engine/fetch") as fetch:
+                last = self._download(fetch, logits)[0][0, sp - 1]
             metrics.count("gen.prefill_tokens", sp)
         else:
             # pad the prompt to a pow2 length bucket so prefill compiles
@@ -582,49 +646,59 @@ class ContinuousBatchingEngine:
             # away below
             pp = min(_next_pow2(p, self._kv_floor),
                      int(self.config.max_position))
+            span.set(bucket=pp, radix_hit=0)
             with self._mu:
                 self._kv_buckets.add(("prefill", pp))
                 metrics.gauge("gen.kv_buckets", len(self._kv_buckets))
-            ids = np.full((1, pp), self.config.eos_id, np.int64)
-            ids[0, :p] = req.prompt
-            caches = self._model.gen_cache(1)
-            logits, caches = self._model.forward(
-                paddle_tpu.to_tensor(ids), cache=caches,
-                pos_offset=np.zeros(1, np.int64),
-                attn_mask=self._model._mask(pp))
-            last = np.asarray(logits.numpy())[0, p - 1]
+            with RecordEvent("engine/build"):
+                ids = np.full((1, pp), self.config.eos_id, np.int64)
+                ids[0, :p] = req.prompt
+                caches = self._model.gen_cache(1)
+                mask_t = self._model._mask(pp)
+            ids_t, = self._upload(ids)
+            with RecordEvent("engine/forward"):
+                logits, caches = self._model.forward(
+                    ids_t, cache=caches, pos_offset=np.zeros(1, np.int64),
+                    attn_mask=mask_t)
+            with RecordEvent("engine/fetch") as fetch:
+                last = self._download(fetch, logits)[0][0, p - 1]
             metrics.count("gen.prefill_tokens", p)
-        nxt = self._sample(req, last)
+        with RecordEvent("engine/sample"):
+            nxt = self._sample(req, last)
         if nxt == self.config.eos_id or req.max_new <= 1:
             # never occupied a slot; adopted pages (if any) just drop
             # their refcount at close
-            if table is not None:
-                self._pool.close_sequence(table)
-            slot = _Slot(req, None, list(req.prompt), nxt)
-            slot.tokens.append(nxt)
-            self._finish(slot)
+            with RecordEvent("engine/finish"):
+                if table is not None:
+                    self._pool.close_sequence(table)
+                slot = _Slot(req, None, list(req.prompt), nxt)
+                slot.tokens.append(nxt)
+                self._finish(slot)
             return
-        if table is not None:
-            # KV column t is a pure function of tokens <= t, so the
-            # pool may satisfy whole prompt-head pages from another
-            # sequence's bitwise-identical prefill (COW prefix sharing).
-            # On a radix hit only the suffix columns install
-            # (start=m); adopted pages are already in the table.
-            off = mpad if m else 0
-            k_stack = np.stack(
-                [np.asarray(c.k.numpy())[0, :, off:off + p - m]
-                 for c in caches])
-            v_stack = np.stack(
-                [np.asarray(c.v.numpy())[0, :, off:off + p - m]
-                 for c in caches])
-            self._pool.open_sequence(req.prompt, k_stack, v_stack,
-                                     table=table, start=m)
-            slot = _Slot(req, None, list(req.prompt), nxt, table=table)
-        else:
-            kv = [(np.asarray(c.k.numpy())[0, :, :p],
-                   np.asarray(c.v.numpy())[0, :, :p])
-                  for c in caches]
-            slot = _Slot(req, kv, list(req.prompt), nxt)
+        with RecordEvent("engine/kv_install") as install:
+            n_layers = len(caches)
+            kv = self._download(install, *[c.k for c in caches],
+                                *[c.v for c in caches])
+            if table is not None:
+                # KV column t is a pure function of tokens <= t, so the
+                # pool may satisfy whole prompt-head pages from another
+                # sequence's bitwise-identical prefill (COW prefix
+                # sharing).  On a radix hit only the suffix columns
+                # install (start=m); adopted pages are already in the
+                # table.
+                off = mpad if m else 0
+                k_stack = np.stack([a[0, :, off:off + p - m]
+                                    for a in kv[:n_layers]])
+                v_stack = np.stack([a[0, :, off:off + p - m]
+                                    for a in kv[n_layers:]])
+                self._pool.open_sequence(req.prompt, k_stack, v_stack,
+                                         table=table, start=m)
+                slot = _Slot(req, None, list(req.prompt), nxt,
+                             table=table)
+            else:
+                slot = _Slot(req, [(k[0, :, :p], v[0, :, :p]) for k, v in
+                                   zip(kv[:n_layers], kv[n_layers:])],
+                             list(req.prompt), nxt)
         with self._mu:
             idx = self._slots.index(None)
             self._slots[idx] = slot
@@ -635,13 +709,11 @@ class ContinuousBatchingEngine:
             # thread owns both engines, so this cannot race a step)
             self._spec.open(idx, slot.tokens)
 
-    def _step(self):
+    def _step(self, span: RecordEvent):
         """One decode step over every active slot (ONE device batch).
         Paged and fixed slots feed the SAME batched dense cache — the
         pool's gather-by-page-table view never changes compiled
         shapes."""
-        import paddle_tpu
-        from ..nn import MultiHeadAttention
         with self._mu:
             # a cancelled future means the caller stopped waiting — free
             # the slot (and its pages) instead of decoding tokens nobody
@@ -662,76 +734,94 @@ class ContinuousBatchingEngine:
         head_dim = cfg.hidden_size // heads
         n_layers = cfg.num_layers
         lpad = _next_pow2(max(s.kv_len for _, s in active), self._kv_floor)
+        span.set(active=len(active), lpad=lpad)
         with self._mu:
             self._kv_buckets.add(("decode", lpad))
             metrics.gauge("gen.kv_buckets", len(self._kv_buckets))
 
-        ids = np.full((S, 1), cfg.eos_id, np.int64)
-        pos = np.zeros(S, np.int64)
-        # additive mask over [cache columns 0..lpad-1, new-token column]:
-        # valid history + self are 0, pad columns and idle rows -inf
-        mask = np.full((S, 1, 1, lpad + 1), _NEG_INF, np.float32)
-        mask[:, :, :, lpad] = 0.0
-        k_b = np.zeros((n_layers, S, heads, lpad, head_dim), np.float32)
-        v_b = np.zeros_like(k_b)
-        for i, s in active:
-            ln = s.kv_len
-            ids[i, 0] = s.next_id
-            pos[i] = ln
-            mask[i, :, :, :ln] = 0.0
-            if s.table is not None:
-                k_all, v_all = self._pool.gather(s.table)
-                k_b[:, i, :, :ln] = k_all
-                v_b[:, i, :, :ln] = v_all
-            else:
-                for li, (k, v) in enumerate(s.kv):
-                    k_b[li, i, :, :ln] = k
-                    v_b[li, i, :, :ln] = v
-        caches = [MultiHeadAttention.Cache(paddle_tpu.to_tensor(k_b[li]),
-                                           paddle_tpu.to_tensor(v_b[li]))
-                  for li in range(n_layers)]
-        logits, new_caches = self._model.forward(
-            paddle_tpu.to_tensor(ids), cache=caches, pos_offset=pos,
-            attn_mask=paddle_tpu.to_tensor(mask))
-        step_logits = np.asarray(logits.numpy())[:, 0]
-        # the new K/V column for every slot sits at index lpad
-        new_cols = [(np.asarray(c.k.numpy())[:, :, lpad],
-                     np.asarray(c.v.numpy())[:, :, lpad])
-                    for c in new_caches]
+        with RecordEvent("engine/gather") as gather:
+            ids = np.full((S, 1), cfg.eos_id, np.int64)
+            pos = np.zeros(S, np.int64)
+            # additive mask over [cache columns 0..lpad-1, new-token
+            # column]: valid history + self are 0, pad columns and idle
+            # rows -inf
+            mask = np.full((S, 1, 1, lpad + 1), _NEG_INF, np.float32)
+            mask[:, :, :, lpad] = 0.0
+            k_b = np.zeros((n_layers, S, heads, lpad, head_dim),
+                           np.float32)
+            v_b = np.zeros_like(k_b)
+            gathered = 0
+            for i, s in active:
+                ln = s.kv_len
+                ids[i, 0] = s.next_id
+                pos[i] = ln
+                mask[i, :, :, :ln] = 0.0
+                if s.table is not None:
+                    k_all, v_all = self._pool.gather(s.table)
+                    k_b[:, i, :, :ln] = k_all
+                    v_b[:, i, :, :ln] = v_all
+                    gathered += k_all.nbytes + v_all.nbytes
+                else:
+                    for li, (k, v) in enumerate(s.kv):
+                        k_b[li, i, :, :ln] = k
+                        v_b[li, i, :, :ln] = v
+                        gathered += k.nbytes + v.nbytes
+            gather.set(bytes=gathered)
+        ids_t, mask_t, caches = self._upload_batch(ids, mask, k_b, v_b)
+        with RecordEvent("engine/forward"):
+            logits, new_caches = self._model.forward(
+                ids_t, cache=caches, pos_offset=pos, attn_mask=mask_t)
+        with RecordEvent("engine/fetch") as fetch:
+            step_logits, *kv = self._download(
+                fetch, logits, *[t for c in new_caches
+                                 for t in (c.k, c.v)])
+            step_logits = step_logits[:, 0]
+            # the new K/V column for every slot sits at index lpad
+            new_cols = [(kv[2 * li][:, :, lpad], kv[2 * li + 1][:, :, lpad])
+                        for li in range(n_layers)]
         metrics.count("gen.steps")
         metrics.count("gen.tokens", len(active))
         metrics.observe("gen.step_occupancy", len(active))
 
         retired = []
-        for i, s in active:
-            if s.table is not None:
-                # write-through the page table: a fresh page at the
-                # boundary, a COW copy when the target page is shared
-                k_col = np.stack([new_cols[li][0][i]
-                                  for li in range(n_layers)])
-                v_col = np.stack([new_cols[li][1][i]
-                                  for li in range(n_layers)])
-                self._pool.append_column(s.table, k_col, v_col)
-            else:
-                for li, (k, v) in enumerate(s.kv):
-                    s.kv[li] = (
-                        np.concatenate([k, new_cols[li][0][i][:, None]], 1),
-                        np.concatenate([v, new_cols[li][1][i][:, None]], 1))
-            s.tokens.append(s.next_id)
-            nxt = self._sample(s.req, step_logits[i])
-            s.next_id = nxt
-            s.n_new += 1
-            if nxt == self.config.eos_id or s.n_new >= s.req.max_new:
-                s.tokens.append(nxt)
-                retired.append(i)
-        with self._mu:
+        appended = 0
+        with RecordEvent("engine/kv_append") as append:
+            for i, s in active:
+                if s.table is not None:
+                    # write-through the page table: a fresh page at the
+                    # boundary, a COW copy when the target page is shared
+                    k_col = np.stack([new_cols[li][0][i]
+                                      for li in range(n_layers)])
+                    v_col = np.stack([new_cols[li][1][i]
+                                      for li in range(n_layers)])
+                    self._pool.append_column(s.table, k_col, v_col)
+                    appended += k_col.nbytes + v_col.nbytes
+                else:
+                    for li, (k, v) in enumerate(s.kv):
+                        s.kv[li] = (
+                            np.concatenate(
+                                [k, new_cols[li][0][i][:, None]], 1),
+                            np.concatenate(
+                                [v, new_cols[li][1][i][:, None]], 1))
+                        appended += s.kv[li][0].nbytes + s.kv[li][1].nbytes
+            append.set(bytes=appended)
+        with RecordEvent("engine/sample"):
+            for i, s in active:
+                s.tokens.append(s.next_id)
+                nxt = self._sample(s.req, step_logits[i])
+                s.next_id = nxt
+                s.n_new += 1
+                if nxt == self.config.eos_id or s.n_new >= s.req.max_new:
+                    s.tokens.append(nxt)
+                    retired.append(i)
+        with RecordEvent("engine/finish"), self._mu:
             for i in retired:
                 slot, self._slots[i] = self._slots[i], None
                 self._finish(slot)
             metrics.gauge("gen.active_slots",
                           sum(s is not None for s in self._slots))
 
-    def _step_spec(self):
+    def _step_spec(self, span: RecordEvent):
         """One SPECULATIVE decode step over every active slot: the
         draft proposes up to k tokens per greedy row, the target
         verifies pending + proposals in ONE batched forward (query
@@ -741,9 +831,9 @@ class ContinuousBatchingEngine:
         replays the plain-greedy retire loop over the verified chain
         token by token, so output is token-equal to ``_step`` whatever
         the draft proposed.  Sampling rows ride along at width 1 (the
-        plain path inside the spec batch)."""
-        import paddle_tpu
-        from ..nn import MultiHeadAttention
+        plain path inside the spec batch).  Spans as in ``_step``; the
+        commit loop (append every fed column, emit, roll back) is one
+        ``engine/kv_append``."""
         with self._mu:
             for i, s in enumerate(self._slots):
                 if s is not None and s.req.future.cancelled():
@@ -778,42 +868,66 @@ class ContinuousBatchingEngine:
                                           n=w - 1)
             fed[i] = row
         lpad = _next_pow2(max_ln, self._kv_floor)
+        span.set(active=len(active), lpad=lpad)
         with self._mu:
             self._kv_buckets.add(("spec", lpad, W))
             metrics.gauge("gen.kv_buckets", len(self._kv_buckets))
-        ids = np.full((S, W), cfg.eos_id, np.int64)
-        pos = np.zeros(S, np.int64)
-        # additive mask over [cache cols 0..lpad-1, W new cols]: every
-        # query sees its row's valid history, new cols are causal among
-        # themselves (query u sees new cols <= u), pads stay -inf
-        mask = np.full((S, 1, W, lpad + W), _NEG_INF, np.float32)
-        for u in range(W):
-            mask[:, :, u, lpad:lpad + u + 1] = 0.0
-        k_b = np.zeros((n_layers, S, heads, lpad, head_dim), np.float32)
-        v_b = np.zeros_like(k_b)
-        for i, s in active:
-            ln = s.kv_len
-            row = fed[i]
-            ids[i, :len(row)] = row
-            pos[i] = ln
-            mask[i, :, :, :ln] = 0.0
-            k_all, v_all = self._pool.gather(s.table)
-            k_b[:, i, :, :ln] = k_all
-            v_b[:, i, :, :ln] = v_all
-        caches = [MultiHeadAttention.Cache(paddle_tpu.to_tensor(k_b[li]),
-                                           paddle_tpu.to_tensor(v_b[li]))
-                  for li in range(n_layers)]
-        logits, new_caches = self._model.forward(
-            paddle_tpu.to_tensor(ids), cache=caches, pos_offset=pos,
-            attn_mask=paddle_tpu.to_tensor(mask))
-        step_logits = np.asarray(logits.numpy())  # [S, W, V]
-        Ks = [np.asarray(c.k.numpy()) for c in new_caches]
-        Vs = [np.asarray(c.v.numpy()) for c in new_caches]
+        with RecordEvent("engine/gather") as gather:
+            ids = np.full((S, W), cfg.eos_id, np.int64)
+            pos = np.zeros(S, np.int64)
+            # additive mask over [cache cols 0..lpad-1, W new cols]: every
+            # query sees its row's valid history, new cols are causal
+            # among themselves (query u sees new cols <= u), pads stay
+            # -inf
+            mask = np.full((S, 1, W, lpad + W), _NEG_INF, np.float32)
+            for u in range(W):
+                mask[:, :, u, lpad:lpad + u + 1] = 0.0
+            k_b = np.zeros((n_layers, S, heads, lpad, head_dim),
+                           np.float32)
+            v_b = np.zeros_like(k_b)
+            gathered = 0
+            for i, s in active:
+                ln = s.kv_len
+                row = fed[i]
+                ids[i, :len(row)] = row
+                pos[i] = ln
+                mask[i, :, :, :ln] = 0.0
+                k_all, v_all = self._pool.gather(s.table)
+                k_b[:, i, :, :ln] = k_all
+                v_b[:, i, :, :ln] = v_all
+                gathered += k_all.nbytes + v_all.nbytes
+            gather.set(bytes=gathered)
+        ids_t, mask_t, caches = self._upload_batch(ids, mask, k_b, v_b)
+        with RecordEvent("engine/forward"):
+            logits, new_caches = self._model.forward(
+                ids_t, cache=caches, pos_offset=pos, attn_mask=mask_t)
+        with RecordEvent("engine/fetch") as fetch:
+            step_logits, *kv = self._download(      # logits [S, W, V]
+                fetch, logits, *[t for c in new_caches
+                                 for t in (c.k, c.v)])
+            Ks, Vs = kv[0::2], kv[1::2]
         metrics.count("gen.steps")
         metrics.count("spec.steps")
         metrics.observe("gen.step_occupancy", len(active))
 
         retired = []
+        with RecordEvent("engine/kv_append") as append:
+            append.set(bytes=self._commit_spec(
+                active, fed, step_logits, Ks, Vs, lpad, retired))
+        with RecordEvent("engine/finish"), self._mu:
+            for i in retired:
+                slot, self._slots[i] = self._slots[i], None
+                self._finish(slot)
+            metrics.gauge("gen.active_slots",
+                          sum(s is not None for s in self._slots))
+
+    def _commit_spec(self, active, fed, step_logits, Ks, Vs, lpad,
+                     retired) -> int:
+        """The speculative step's commit loop over the verified chains;
+        appends retired slot indices to `retired` and returns the bytes
+        written through the page tables."""
+        n_layers = len(Ks)
+        appended = 0
         for i, s in active:
             row = fed[i]
             w = len(row)
@@ -827,6 +941,7 @@ class ContinuousBatchingEngine:
                 v_col = np.stack([Vs[li][i, :, lpad + t]
                                   for li in range(n_layers)])
                 self._pool.append_column(s.table, k_col, v_col)
+                appended += k_col.nbytes + v_col.nbytes
             # emission: the plain-greedy loop replayed over the chain —
             # commit fed[t], derive the next token from the target's
             # own logits at t, continue only while the next draft
@@ -861,12 +976,7 @@ class ContinuousBatchingEngine:
                 # mirror the outcome into the draft's dense KV (its
                 # truncate-to-committed rollback)
                 self._spec.commit(i, s.tokens, s.next_id)
-        with self._mu:
-            for i in retired:
-                slot, self._slots[i] = self._slots[i], None
-                self._finish(slot)
-            metrics.gauge("gen.active_slots",
-                          sum(s is not None for s in self._slots))
+        return appended
 
     def _sample(self, req: GenerationRequest, logits: np.ndarray) -> int:
         if req.strategy == "sampling":

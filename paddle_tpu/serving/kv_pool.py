@@ -518,6 +518,7 @@ class PagedKVPool:
                     self._page_key[pid] = key
                 table.pages.append(pid)
             table.length = p
+        metrics.count("kv.append_bytes", k_prompt.nbytes + v_prompt.nbytes)
         self._publish()
         return table
 
@@ -552,6 +553,7 @@ class PagedKVPool:
                 metrics.count("kv.cow_copies")
             self._store_column(pid, off, k_col, v_col)
             table.length = pos + 1
+        metrics.count("kv.append_bytes", k_col.nbytes + v_col.nbytes)
         self._publish()
 
     def gather(self, table: PageTable):
@@ -578,6 +580,7 @@ class PagedKVPool:
                 * self.v_scale[:, idx][:, :, :, None, None]
         k = k.transpose(0, 2, 1, 3, 4).reshape(L, H, n * T, D)
         v = v.transpose(0, 2, 1, 3, 4).reshape(L, H, n * T, D)
+        metrics.count("kv.gather_bytes", k.nbytes + v.nbytes)
         return k[:, :, : table.length], v[:, :, : table.length]
 
     def close_sequence(self, table: PageTable):

@@ -5,7 +5,10 @@ whole serving story from a single ``/stats`` scrape:
 
   counters    serving.requests.admitted / rejected / timeout / completed /
               failed, serving.batch.runs, serving.batch.coalesced,
-              serving.gen.admitted / completed / steps / tokens
+              serving.gen.admitted / completed / steps / tokens,
+              serving.gen.prefills / queue_wait_us / h2d_bytes / d2h_bytes,
+              serving.kv.gather_bytes / append_bytes (the engine's span
+              sites, docs/observability.md "Spans")
   gauges      serving.queue.depth, serving.batch.last_size,
               serving.gen.active_slots, serving.server.inflight
   histograms  serving.latency_ms (end-to-end request latency),

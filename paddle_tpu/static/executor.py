@@ -29,6 +29,7 @@ from ..core.place import CPUPlace, XLAPlace, Place, _current_expected_place
 from ..core.dtype import np_dtype
 from ..core import compile_cache as _ccache
 from ..ops.registry import get_op_info, OpContext
+from ..profiler import RecordEvent
 from ..testing import chaos as _chaos
 
 __all__ = ["Executor", "Scope", "global_scope", "scope_guard",
@@ -151,7 +152,12 @@ class BlockTracer:
                 n = names[0] if names else None
                 ins[slot.name] = env.get(n) if n else None
         attrs = dict(op.attrs)
-        outs = info.kernel(ins, attrs, ctx)
+        # the device trace's name for what the PROGRAM asked for: XLA
+        # keeps this path as every lowered instruction's op_name, so the
+        # profiler's `tf_op` reads jit(step)/backward/mul_grad/...  Paid
+        # while tracing only, never per step.
+        with jax.named_scope(_device_scope(op)):
+            outs = info.kernel(ins, attrs, ctx)
         for slot in info.outputs:
             names = op.outputs.get(slot.name, [])
             if not names:
@@ -167,6 +173,21 @@ class BlockTracer:
                 if names[0]:
                     env[names[0]] = val
         return env
+
+
+def _device_scope(op) -> str:
+    """`<role>/<op type>` from the op's IR role.  `op_role` is bit flags:
+    Loss is or-ed onto Forward / Backward and names neither, so it only
+    decides between the two; LRSched (alone or or-ed onto Optimize) and
+    RPC / Dist keep their own names."""
+    role = int(op.attrs.get(OpRole.KEY, OpRole.Forward))
+    if role & OpRole.LRSched:
+        name = "lr_sched"
+    else:
+        name = {OpRole.Forward: "forward", OpRole.Backward: "backward",
+                OpRole.Optimize: "optimize", OpRole.RPC: "rpc",
+                OpRole.Dist: "dist"}.get(role & ~OpRole.Loss, "forward")
+    return f"{name}/{op.type}"
 
 
 def _persistable_names(program: Program) -> List[str]:
@@ -284,6 +305,8 @@ class Executor:
         # once per executor (None = not yet; 0.0 = unknown -> no MFU)
         self._peak_flops = None
         self._observed_steps = 0
+        # (program, process trace count, time) of the last observation
+        self._last_observed = None
 
     # -- public API ---------------------------------------------------------
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
@@ -296,19 +319,21 @@ class Executor:
             # CompiledProgram / Pipeline / PS trainer program dispatch.
             # The checkpoint hook still fires: multi-chip pretraining is
             # the workload the checkpoint tier exists for.
-            import time as _time
-            _t0 = _time.perf_counter()
-            results = program._run(self, feed, fetch_list, scope,
-                                   return_numpy)
-            self._observe_step(program, _time.perf_counter() - _t0,
-                               feed or {}, chips=_wrapper_chips(program))
-            # resolve the scope the wrapper actually ran in: some wrappers
-            # (ParallelExecutor) carry their own _scope — snapshotting
-            # global_scope() instead would commit an EMPTY checkpoint
-            self._maybe_checkpoint(
-                program, scope or getattr(program, "_scope", None)
-                or global_scope())
-            self._chaos_step(program)
+            with RecordEvent("Executor::Run"):
+                results = program._run(self, feed, fetch_list, scope,
+                                       return_numpy)
+                with RecordEvent("executor/observe"):
+                    self._observe_step(program, feed or {},
+                                       chips=_wrapper_chips(program))
+                # resolve the scope the wrapper actually ran in: some
+                # wrappers (ParallelExecutor) carry their own _scope —
+                # snapshotting global_scope() instead would commit an
+                # EMPTY checkpoint
+                with RecordEvent("executor/hooks"):
+                    self._maybe_checkpoint(
+                        program, scope or getattr(program, "_scope", None)
+                        or global_scope())
+                    self._chaos_step(program)
             return results
         if getattr(program, "_ps_server_config", None):
             # pserver program: exe.run(pserver_prog) == listen_and_serv
@@ -330,17 +355,14 @@ class Executor:
                 as_numpy(scope.get(n)) if return_numpy else scope.get(n)
                 for n in fetch_names]
 
-        # elastic auto-checkpoint hook (reference executor.py:1194)
-        from ..incubate.checkpoint.auto_checkpoint import _auto_checkpoint
-        _auto_checkpoint(self, program)
-
         from ..core.flags import flag
         from ..core.monitor import stat_add
-        from ..profiler import RecordEvent
-        import time as _time
-        stat_add("executor_run_times")
-        _t0 = _time.perf_counter()
+        from ..incubate.checkpoint.auto_checkpoint import _auto_checkpoint
         with RecordEvent("Executor::Run"):
+            # elastic auto-checkpoint hook (reference executor.py:1194)
+            with RecordEvent("executor/hooks"):
+                _auto_checkpoint(self, program)
+            stat_add("executor_run_times")
             if flag("eager_run", False):
                 self._run_eager(program, scope, feed, fetch_names)
                 fetched = [scope.get(n) for n in fetch_names]
@@ -349,12 +371,14 @@ class Executor:
             else:
                 results = self._run_compiled(program, scope, feed,
                                              fetch_names, return_numpy)
-        self._observe_step(program, _time.perf_counter() - _t0, feed)
-        if flag("check_nan_inf", False):
-            self._check_nan_inf(fetch_names, results, scope,
-                                program=program)
-        self._maybe_checkpoint(program, scope)
-        self._chaos_step(program)
+            with RecordEvent("executor/observe"):
+                self._observe_step(program, feed)
+            with RecordEvent("executor/hooks"):
+                if flag("check_nan_inf", False):
+                    self._check_nan_inf(fetch_names, results, scope,
+                                        program=program)
+                self._maybe_checkpoint(program, scope)
+                self._chaos_step(program)
         return results
 
     def _chaos_step(self, program):
@@ -452,7 +476,7 @@ class Executor:
                 cache[batch] = None  # telemetry never kills training
         return cache[batch]
 
-    def _observe_step(self, program, dt, feed_vals, steps=1, chips=1,
+    def _observe_step(self, program, feed_vals, steps=1, chips=1,
                       stacked=None):
         """Per-train-step telemetry: wall time, tokens/s, achieved-vs-
         peak MFU, retrace count into core/monitor; one journal event;
@@ -461,10 +485,26 @@ class Executor:
         eval).  Fully fenced: telemetry must never kill a training run,
         so ANY failure here (unparseable feed dtype, a user-registered
         metric-name collision, a sick disk under the journal) degrades
-        to a silently skipped observation."""
+        to a silently skipped observation.
+
+        The step's time is the interval since the previous observation
+        of the same program.  The time a dispatch takes to RETURN is only
+        the enqueue when fetches stay on the device (`return_numpy=
+        False`: every real training loop), while in steady state the
+        interval between dispatches is the step either way.  There is
+        none (`dt` None: counts and liveness only) for a program's first
+        observation and for the one after a trace, which holds the
+        compile."""
         p = _unwrap_program(program)
         if not self._is_training_cached(p):
             return
+        import time as _time
+        from ..core.monitor import stat_get
+        now = _time.perf_counter()
+        traces = stat_get(_ccache.STAT_TRACES)
+        last, self._last_observed = self._last_observed, (p, traces, now)
+        dt = now - last[2] if last is not None and last[0] is p \
+            and last[1] == traces else None
         try:
             self._observe_step_inner(
                 p, dt, feed_vals, steps, chips,
@@ -481,12 +521,15 @@ class Executor:
         maybe_start_from_env()
         self._observed_steps += steps
         stat_add("train.steps", steps)
-        step_ms = dt * 1e3 / max(1, steps)
-        hist_observe("train.step_ms", step_ms)
         gauge_set("executor.retraces", self._stats["traces"])
+        timed = {}      # what only an interval gives: journal + heartbeat
+        if dt:
+            step_ms = dt * 1e3 / max(1, steps)
+            hist_observe("train.step_ms", step_ms)
+            timed["wall_ms"] = round(step_ms, 3)
         tokens = self._feed_tokens(feed_vals, stacked=stacked)
         tps = None
-        if tokens and dt > 0:
+        if tokens and dt:
             tps = tokens / dt
             gauge_set("train.tokens_per_sec", tps)
         mfu = None
@@ -496,7 +539,7 @@ class Executor:
                 self._peak_flops = float(peak_flops_per_chip())
             except ValueError:  # a device_kind with no recorded peak
                 self._peak_flops = 0.0
-        if self._peak_flops and dt > 0:
+        if self._peak_flops and dt:
             batch = self._feed_batch(feed_vals, stacked=stacked)
             flops = self._flops_per_step(p, batch) if batch else None
             if flops:
@@ -508,9 +551,9 @@ class Executor:
         if self._observed_steps == steps or \
                 self._observed_steps % 64 < steps:
             self._observe_hbm(p, feed_vals, stacked)
-        _hb.maybe_beat(self._step, wall_ms=round(step_ms, 3))
+        _hb.maybe_beat(self._step, **timed)
         if _journal.journal_enabled():
-            ev = {"step": self._step, "wall_ms": round(step_ms, 3)}
+            ev = dict(timed, step=self._step)
             if steps > 1:
                 ev["micro_steps"] = steps
             if tps is not None:
@@ -698,40 +741,45 @@ class Executor:
     def _run_compiled(self, program: Program, scope: Scope, feed,
                       fetch_names, return_numpy):
         block = program.global_block()
-        feed_vals = {n: self._coerce_feed(block, n, v)
-                     for n, v in feed.items()}
-        state_names = [n for n in _persistable_names(program)
-                       if scope.get(n) is not None]
-        # signature from metadata only — np.asarray here would force a
-        # blocking device->host copy of every feed on every step
-        feed_sig = self._feed_signature(feed_vals)
-        key = (program.fingerprint(), feed_sig, tuple(fetch_names),
-               tuple(state_names))
-        fn = self._cache.get(key)
-        bucket = None  # (real batch, padded batch)
+        with RecordEvent("executor/prepare"):
+            feed_vals = {n: self._coerce_feed(block, n, v)
+                         for n, v in feed.items()}
+            state_names = [n for n in _persistable_names(program)
+                           if scope.get(n) is not None]
+            # signature from metadata only — np.asarray here would force
+            # a blocking device->host copy of every feed on every step
+            feed_sig = self._feed_signature(feed_vals)
+            key = (program.fingerprint(), feed_sig, tuple(fetch_names),
+                   tuple(state_names))
+            fn = self._cache.get(key)
+            bucket = None  # (real batch, padded batch)
+            if fn is None:
+                bucketed = self._bucket_lookup(key, feed_vals)
+                if bucketed is not None:
+                    key, feed_vals, bucket = bucketed
+                    fn = self._cache.get(key)
         if fn is None:
-            bucketed = self._bucket_lookup(key, feed_vals)
-            if bucketed is not None:
-                key, feed_vals, bucket = bucketed
-                fn = self._cache.get(key)
-        if fn is None:
-            # env-gated IR verification on the first compile of each
-            # program (PADDLE_TPU_VERIFY — static/verifier.py): the IR
-            # walk rides the already-slow trace path only
-            from .verifier import verify_first_compile
-            verify_first_compile(program, fetch_list=fetch_names)
-            self._record("miss")
-            self._record("trace")
-            from ..observability.journal import emit as _jemit
-            _jemit("compile", mode="run", fingerprint=str(key[0])[:16])
-            fn = self._compile(program, state_names, fetch_names)
-            self._cache[key] = fn
+            fingerprint = str(key[0])[:16]
+            with RecordEvent("executor/trace_compile", mode="run",
+                             fingerprint=fingerprint):
+                # env-gated IR verification on the first compile of each
+                # program (PADDLE_TPU_VERIFY — static/verifier.py): the
+                # IR walk rides the already-slow trace path only
+                from .verifier import verify_first_compile
+                verify_first_compile(program, fetch_list=fetch_names)
+                self._record("miss")
+                self._record("trace")
+                from ..observability.journal import emit as _jemit
+                _jemit("compile", mode="run", fingerprint=fingerprint)
+                fn = self._compile(program, state_names, fetch_names)
+                self._cache[key] = fn
         else:
             self._record("hit", bucketed=bucket is not None)
 
         state = {n: scope.get(n) for n in state_names}
-        seed = self._seed_for_step(program)
-        fetches, new_state = fn(state, feed_vals, jnp.uint32(seed))
+        with RecordEvent("executor/launch"):
+            seed = self._seed_for_step(program)
+            fetches, new_state = fn(state, feed_vals, jnp.uint32(seed))
         self._step += 1
         for n, v in new_state.items():
             scope.set(n, v)
@@ -740,7 +788,8 @@ class Executor:
                                           block=block,
                                           fetch_names=fetch_names)
         if return_numpy:
-            return [np.asarray(f) for f in fetches]
+            with RecordEvent("executor/fetch"):
+                return [np.asarray(f) for f in fetches]
         return list(fetches)
 
     # -- shape bucketing -----------------------------------------------------
@@ -977,6 +1026,12 @@ class Executor:
         axis is never padded, because scanned padding steps would replay
         extra optimizer updates.
         """
+        with RecordEvent("Executor::RunSteps"):
+            return self._dispatch_steps(program, feed, fetch_list, scope,
+                                        return_numpy)
+
+    def _dispatch_steps(self, program, feed, fetch_list, scope,
+                        return_numpy):
         from ..core.program import default_main_program
         from ..distributed.compiled_program import CompiledProgram
         program = program or default_main_program()
@@ -986,21 +1041,21 @@ class Executor:
             # multi-chip scanned dispatch (incl. the elastic K-micro-step
             # window: one global step = ONE device call instead of K
             # host dispatches — distributed/elastic.py)
-            import time as _time
-            _t0 = _time.perf_counter()
             results = program._run_steps(self, feed, fetch_list, scope,
                                          return_numpy)
             k = 0
             for v in (feed or {}).values():
                 k = int(getattr(v, "shape", (1,))[0] or 1)
                 break
-            self._observe_step(program, _time.perf_counter() - _t0,
-                               feed or {}, steps=max(1, k),
-                               chips=_wrapper_chips(program), stacked=True)
-            self._maybe_checkpoint(
-                program, scope or getattr(program, "_scope", None)
-                or global_scope())
-            self._chaos_step(program)
+            with RecordEvent("executor/observe"):
+                self._observe_step(program, feed or {}, steps=max(1, k),
+                                   chips=_wrapper_chips(program),
+                                   stacked=True)
+            with RecordEvent("executor/hooks"):
+                self._maybe_checkpoint(
+                    program, scope or getattr(program, "_scope", None)
+                    or global_scope())
+                self._chaos_step(program)
             return results
         scope = scope or global_scope()
         feed = feed or {}
@@ -1015,34 +1070,37 @@ class Executor:
         fetch_names = [v.name if hasattr(v, "name") else str(v)
                        for v in (fetch_list or [])]
         block = program.global_block()
-        feed_vals = {n: self._coerce_feed(block, n, v)
-                     for n, v in feed.items()}
-        if not feed_vals:
-            raise ValueError("run_steps needs at least one stacked feed "
-                             "to define the number of steps")
-        k = None
-        for n, v in feed_vals.items():
-            shape = getattr(v, "shape", ())
-            if len(shape) == 0:
-                raise ValueError(
-                    f"run_steps feed {n!r} is a scalar; every feed needs "
-                    f"a leading steps axis (stack K per-step values)")
-            k = shape[0] if k is None else k
-            if shape[0] != k:
-                raise ValueError(
-                    f"feed {n!r} leading (steps) dim {shape[0]} != {k}")
-        state_names = [n for n in _persistable_names(program)
-                       if scope.get(n) is not None]
-        key = ("run_steps", program.fingerprint(),
-               self._feed_signature(feed_vals), tuple(fetch_names),
-               tuple(state_names))
-        fn = self._cache.get(key)
-        bucket = None  # (real per-step batch, padded per-step batch)
-        if fn is None:
-            bucketed = self._bucket_lookup_steps(key, feed_vals)
-            if bucketed is not None:
-                key, feed_vals, bucket = bucketed
-                fn = self._cache.get(key)
+        with RecordEvent("executor/prepare"):
+            feed_vals = {n: self._coerce_feed(block, n, v)
+                         for n, v in feed.items()}
+            if not feed_vals:
+                raise ValueError("run_steps needs at least one stacked "
+                                 "feed to define the number of steps")
+            k = None
+            for n, v in feed_vals.items():
+                shape = getattr(v, "shape", ())
+                if len(shape) == 0:
+                    raise ValueError(
+                        f"run_steps feed {n!r} is a scalar; every feed "
+                        f"needs a leading steps axis (stack K per-step "
+                        f"values)")
+                k = shape[0] if k is None else k
+                if shape[0] != k:
+                    raise ValueError(
+                        f"feed {n!r} leading (steps) dim {shape[0]} != "
+                        f"{k}")
+            state_names = [n for n in _persistable_names(program)
+                           if scope.get(n) is not None]
+            key = ("run_steps", program.fingerprint(),
+                   self._feed_signature(feed_vals), tuple(fetch_names),
+                   tuple(state_names))
+            fn = self._cache.get(key)
+            bucket = None  # (real per-step batch, padded per-step batch)
+            if fn is None:
+                bucketed = self._bucket_lookup_steps(key, feed_vals)
+                if bucketed is not None:
+                    key, feed_vals, bucket = bucketed
+                    fn = self._cache.get(key)
         if fn is None and self.bucket_policy != "off" and \
                 self._has_longer_scan(key, k):
             # short FINAL chunk (K' < a compiled steady K): padding the
@@ -1055,53 +1113,59 @@ class Executor:
                                             fetch_list, scope,
                                             return_numpy)
         if fn is None:
-            from .verifier import verify_first_compile
-            verify_first_compile(program, fetch_list=fetch_names)
-            self._record("miss")
-            self._record("trace")
-            from ..observability.journal import emit as _jemit
-            _jemit("compile", mode="run_steps",
-                   fingerprint=str(key[1])[:16])
-            fn = self._compile_steps(program, state_names, fetch_names)
-            self._cache[key] = fn
+            fingerprint = str(key[1])[:16]
+            with RecordEvent("executor/trace_compile", mode="run_steps",
+                             fingerprint=fingerprint):
+                from .verifier import verify_first_compile
+                verify_first_compile(program, fetch_list=fetch_names)
+                self._record("miss")
+                self._record("trace")
+                from ..observability.journal import emit as _jemit
+                _jemit("compile", mode="run_steps",
+                       fingerprint=fingerprint)
+                fn = self._compile_steps(program, state_names,
+                                         fetch_names)
+                self._cache[key] = fn
         else:
             self._record("hit", bucketed=bucket is not None)
 
         # same side contracts as run(): elastic auto-checkpoint hook,
-        # run counters, profiler span, FLAGS_check_nan_inf post-scan
+        # run counters, profiler spans, FLAGS_check_nan_inf post-scan
         from ..incubate.checkpoint.auto_checkpoint import _auto_checkpoint
-        _auto_checkpoint(self, program)
+        with RecordEvent("executor/hooks"):
+            _auto_checkpoint(self, program)
         from ..core.flags import flag
         from ..core.monitor import stat_add
-        from ..profiler import RecordEvent
         stat_add("executor_run_times")
         state = {n: scope.get(n) for n in state_names}
-        seeds = jnp.asarray(
-            [self._seed_for_step(program) + i for i in range(k)],
-            jnp.uint32)
-        self._step += k
-        import time as _time
-        _t0 = _time.perf_counter()
-        with RecordEvent("Executor::RunSteps"):
+        with RecordEvent("executor/launch"):
+            seeds = jnp.asarray(
+                [self._seed_for_step(program) + i for i in range(k)],
+                jnp.uint32)
             fetches, new_state = fn(state, feed_vals, seeds)
-        _dt = _time.perf_counter() - _t0
+        self._step += k
         for n, v in new_state.items():
             scope.set(n, v)
         # stacked=True explicitly: a K=1 run_steps feed still has its
         # per-step batch on axis 1, not axis 0
-        self._observe_step(program, _dt, feed_vals, steps=int(k),
-                           stacked=True)
+        with RecordEvent("executor/observe"):
+            self._observe_step(program, feed_vals, steps=int(k),
+                               stacked=True)
         if bucket is not None:
             fetches = self._unpad_steps_fetches(fetches, *bucket,
                                                 block=block,
                                                 fetch_names=fetch_names)
-        results = [np.asarray(f) for f in fetches] if return_numpy \
-            else list(fetches)
-        if flag("check_nan_inf", False):
-            self._check_nan_inf(fetch_names, results, scope,
-                                program=program, steps=int(k))
-        self._maybe_checkpoint(program, scope)
-        self._chaos_step(program)
+        if return_numpy:
+            with RecordEvent("executor/fetch"):
+                results = [np.asarray(f) for f in fetches]
+        else:
+            results = list(fetches)
+        with RecordEvent("executor/hooks"):
+            if flag("check_nan_inf", False):
+                self._check_nan_inf(fetch_names, results, scope,
+                                    program=program, steps=int(k))
+            self._maybe_checkpoint(program, scope)
+            self._chaos_step(program)
         return results
 
     def _compile_steps(self, program: Program, state_names, fetch_names):

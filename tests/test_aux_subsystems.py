@@ -267,3 +267,112 @@ def test_weighted_average():
     wa.reset()
     wa.add([2.0, 4.0])  # arrays reduce to their mean
     assert abs(wa.eval() - 3.0) < 1e-9
+
+
+# ---------------------------------------------------------------------------
+# RecordEvent: fields, parent, and any profiler session as the switch
+# ---------------------------------------------------------------------------
+def test_record_event_keeps_fields_and_parent_per_thread(tmp_path, capsys):
+    import threading
+    from paddle_tpu import profiler as prof
+    path = str(tmp_path / "profile")
+    with prof.profiler(state="CPU", profile_path=path):
+        with prof.RecordEvent("outer", req=7):
+            with prof.RecordEvent("inner") as inner:
+                inner.set(bytes=128)
+            t = threading.Thread(
+                target=lambda: prof.RecordEvent("elsewhere").__enter__()
+                .__exit__(None, None, None))
+            t.start()
+            t.join(timeout=10)
+            assert not t.is_alive()
+        with prof.RecordEvent("after"):
+            pass
+    capsys.readouterr()
+    events = {e.name: e for e in prof._state.events}
+    assert events["outer"].fields == {"req": 7}
+    assert events["outer"].parent is None
+    assert events["inner"].fields == {"bytes": 128}
+    assert events["inner"].parent == "outer"
+    # a span open on another thread is no parent: cause across threads
+    # travels in a field
+    assert events["elsewhere"].parent is None
+    assert events["elsewhere"].thread != events["outer"].thread
+    assert events["after"].parent is None       # the stack unwound
+    with open(path + ".json") as f:
+        trace = json.load(f)
+    args = {e["name"]: e["args"] for e in trace["traceEvents"]}
+    assert args["inner"] == {"bytes": 128, "parent": "outer"}
+    assert args["outer"] == {"req": 7, "parent": None}
+
+
+def _host_events(trace_dir):
+    import os
+    from jax.profiler import ProfileData
+    paths = [os.path.join(d, f) for d, _, fs in os.walk(trace_dir)
+             for f in fs if f.endswith(".xplane.pb")]
+    data = ProfileData.from_file(paths[0])
+    return [(i, e.name, dict(e.stats))
+            for plane in data.planes if plane.name == "/host:CPU"
+            for i, line in enumerate(plane.lines) for e in line.events]
+
+
+def test_record_event_annotates_a_trace_it_did_not_start(tmp_path):
+    """Whoever starts the profiler session — a benchmark, TensorBoard —
+    gets the program's spans with their fields; there is no switch to
+    throw first and no sentinel to set."""
+    import jax
+    from paddle_tpu import profiler as prof
+    assert not hasattr(prof, "set_device_trace_active")
+    assert not hasattr(prof, "_EXTERNAL_TRACE")
+    # no session: nothing is built
+    with prof.RecordEvent("aux/quiet", k=1) as quiet:
+        assert quiet._jax_ctx is None
+    before = len(prof._state.events)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with prof.RecordEvent("aux/foreign", req=3) as span:
+            with prof.RecordEvent("aux/child"):
+                pass
+            span.set(bytes=4096)
+    finally:
+        jax.profiler.stop_trace()
+    assert len(prof._state.events) == before    # in-memory list: its own
+    found = {name: (line, stats)
+             for line, name, stats in _host_events(str(tmp_path))
+             if name.startswith("aux/")}
+    assert set(found) == {"aux/foreign", "aux/child"}
+    assert found["aux/foreign"][1] == {"req": 3, "bytes": 4096}
+    assert found["aux/foreign"][0] == found["aux/child"][0]   # one thread
+
+
+def test_executor_spans_nest_under_the_parity_names(capsys, tmp_path):
+    from paddle_tpu import profiler as prof
+    main, startup = static.Program(), static.Program()
+    with static.program_guard(main, startup):
+        x = layers.data("x", [-1, 4])
+        y = layers.fc(x, 2)
+    exe, scope = static.Executor(), static.Scope()
+    feed = {"x": np.ones((2, 4), np.float32)}
+    with static.scope_guard(scope):
+        exe.run(startup)
+        with prof.profiler(state="CPU",
+                           profile_path=str(tmp_path / "p")):
+            exe.run(main, feed=feed, fetch_list=[y])
+            exe.run_steps(main, feed={"x": np.ones((3, 2, 4), np.float32)},
+                          fetch_list=[y])
+    capsys.readouterr()
+    events = list(prof._state.events)
+    by_parent = {}
+    for e in events:
+        by_parent.setdefault(e.parent, set()).add(e.name)
+    assert by_parent[None] == {"Executor::Run", "Executor::RunSteps"}
+    children = {"executor/prepare", "executor/trace_compile",
+                "executor/launch", "executor/fetch", "executor/observe",
+                "executor/hooks"}
+    assert by_parent["Executor::Run"] == children
+    assert by_parent["Executor::RunSteps"] == children
+    compiled = [e.fields for e in events
+                if e.name == "executor/trace_compile"]
+    assert [f["mode"] for f in compiled] == ["run", "run_steps"]
+    assert all(len(f["fingerprint"]) == 16 for f in compiled)

@@ -206,13 +206,15 @@ def test_train_step_telemetry_gauges_journal_heartbeat(tmp_path,
         feed = {"x": rng.rand(4, 8).astype(np.float32),
                 "y": rng.rand(4, 1).astype(np.float32)}
         steps_before = monitor.stat_get("train.steps")
+        ms_before = monitor.hist_snapshot("train.step_ms")["count"]
         with static.scope_guard(scope):
             exe.run(startup)  # startup is NOT a train step: no telemetry
             assert monitor.stat_get("train.steps") == steps_before
             for _ in range(3):
                 exe.run(main, feed=feed, fetch_list=[loss])
         assert monitor.stat_get("train.steps") == steps_before + 3
-        assert monitor.hist_snapshot("train.step_ms")["count"] >= 3
+        assert monitor.hist_snapshot("train.step_ms")["count"] >= \
+            ms_before + 2
         assert monitor.gauge_get("train.tokens_per_sec") > 0
         assert monitor.gauge_get("train.mfu") > 0  # peak armed via env
         assert monitor.gauge_get("executor.retraces") >= 1
@@ -224,10 +226,15 @@ def test_train_step_telemetry_gauges_journal_heartbeat(tmp_path,
     kinds = [e["kind"] for e in events]
     assert kinds.count("step") == 3
     assert "compile" in kinds
-    step_ev = next(e for e in events if e["kind"] == "step")
-    assert step_ev["wall_ms"] > 0 and step_ev["tokens_per_sec"] > 0
+    # a step's time is the interval since the previous observation of the
+    # program: the first step (it holds the compile) has none, the other
+    # two do — in the journal, the histogram and the heartbeat alike
+    steps = [e for e in events if e["kind"] == "step"]
+    assert "wall_ms" not in steps[0] and "tokens_per_sec" not in steps[0]
+    for step_ev in steps[1:]:
+        assert step_ev["wall_ms"] > 0 and step_ev["tokens_per_sec"] > 0
     beats = obs.read_heartbeats(hdir)
-    assert beats[0]["beats"] == 3
+    assert beats[0]["beats"] == 3 and beats[0]["wall_ms"] > 0
 
 
 def test_run_steps_telemetry_counts_micro_steps(tmp_path):
@@ -242,13 +249,17 @@ def test_run_steps_telemetry_counts_micro_steps(tmp_path):
         before = monitor.stat_get("train.steps")
         with static.scope_guard(scope):
             exe.run(startup)
-            exe.run_steps(main, feed=feed, fetch_list=[loss])
-        assert monitor.stat_get("train.steps") == before + k
+            for _ in range(2):
+                exe.run_steps(main, feed=feed, fetch_list=[loss])
+        assert monitor.stat_get("train.steps") == before + 2 * k
     finally:
         obs.set_journal_dir(None)
     events = obs.read_rank_journals(str(tmp_path))[0]
-    step_ev = next(e for e in events if e["kind"] == "step")
-    assert step_ev["micro_steps"] == k
+    first, second = [e for e in events if e["kind"] == "step"]
+    assert first["micro_steps"] == second["micro_steps"] == k
+    # the window that compiled has no interval; the next one's is divided
+    # over its micro-steps
+    assert "wall_ms" not in first and second["wall_ms"] > 0
     compile_ev = next(e for e in events if e["kind"] == "compile")
     assert compile_ev["mode"] == "run_steps"
 
@@ -269,7 +280,9 @@ def test_compiled_program_mfu_scales_by_mesh_chips(monkeypatch):
             "y": rng.rand(8, 1).astype(np.float32)}
     with static.scope_guard(scope):
         exe.run(startup)
-        exe.run(cp, feed=feed, fetch_list=[loss])
+        monitor.gauge_set("train.mfu", 0)
+        for _ in range(3):      # the mesh compiles twice; then an interval
+            exe.run(cp, feed=feed, fetch_list=[loss])
     n_dev = len(jax.devices())
     assert _wrapper_chips(cp) == n_dev
     # an unbuilt wrapper (no mesh yet) falls back to 1
@@ -433,3 +446,40 @@ def test_kill_resume_timeline_reconstructs_from_journals(tmp_path):
         seqs = [e["seq"] for e in events
                 if e["run_id"] == inc["run_id"]]
         assert seqs == list(range(len(seqs))), inc["run_id"]
+
+
+# ---------------------------------------------------------------------------
+# step telemetry times the step, not the enqueue
+# ---------------------------------------------------------------------------
+def test_step_telemetry_is_the_interval_between_observations(tmp_path):
+    """With `return_numpy=False` (every real training loop) `run` returns
+    once the step is enqueued, so timing the call describes the host.  The
+    interval between consecutive observations of the program is the step
+    in steady state either way: a loop held to 40 ms a step reads >= 40 ms
+    however fast its dispatches return."""
+    obs.set_journal_dir(str(tmp_path))
+    try:
+        main, startup, loss = _build_train()
+        other, other_startup, other_loss = _build_train()
+        exe, scope = static.Executor(), static.Scope()
+        rng = np.random.RandomState(0)
+        feed = {"x": rng.rand(4, 8).astype(np.float32),
+                "y": rng.rand(4, 1).astype(np.float32)}
+        with static.scope_guard(scope):
+            exe.run(startup)
+            for _ in range(4):
+                exe.run(main, feed=feed, fetch_list=[loss],
+                        return_numpy=False)
+                time.sleep(0.04)
+            # another program in between: no interval across the switch
+            exe.run(other_startup)
+            exe.run(other, feed=feed, fetch_list=[other_loss])
+            exe.run(main, feed=feed, fetch_list=[loss])
+    finally:
+        obs.set_journal_dir(None)
+    steps = [e for e in obs.read_rank_journals(str(tmp_path))[0]
+             if e["kind"] == "step"]
+    assert len(steps) == 6
+    assert "wall_ms" not in steps[0]            # it holds the compile
+    assert all(e["wall_ms"] >= 40.0 for e in steps[1:4])
+    assert "wall_ms" not in steps[4] and "wall_ms" not in steps[5]

@@ -23,7 +23,25 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 
 def test_page_smoke_gate():
     import page_smoke
-    result = page_smoke.run_smoke()
+    import paddle_tpu
+    from paddle_tpu.core import generator
+    from paddle_tpu.core.program import (default_main_program,
+                                         default_startup_program)
+    # seeded: the warm-up request must reach a decode step, and with
+    # weights whose first sampled token is EOS it ends at its prefill, so
+    # the decode bucket reads as a retrace.  Drawn unseeded, the weights
+    # hang on whatever ran before in this worker (about 1 state in 50
+    # fails).  The generator is put back as found.
+    state = generator.get_rng_state()
+    seeds = (default_main_program().random_seed,
+             default_startup_program().random_seed)
+    paddle_tpu.seed(1234)
+    try:
+        result = page_smoke.run_smoke()
+    finally:
+        generator.set_rng_state(state)
+        default_main_program().random_seed, \
+            default_startup_program().random_seed = seeds
     assert result["traces_after_warmup"] == 0, result
     assert result["shared_pages_for_two"] < 2 * result["solo_pages"], \
         result

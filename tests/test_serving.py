@@ -5,6 +5,7 @@ Covers the serving-tier contracts: K concurrent callers coalesce into
 deadline backpressure, monitor gauges/histograms, continuous-batching
 decode equivalence with per-sequence generate(), and a threaded
 end-to-end server pass."""
+import contextlib
 import json
 import re
 import threading
@@ -494,6 +495,27 @@ def _tp_gpt(vocab=48):
     return GPTForGeneration(GPTModel(cfg))
 
 
+@contextlib.contextmanager
+def _seeded(seed):
+    """Weights drawn inside come from `seed`, whatever ran before in this
+    worker; the process-global generator and the default programs' seeds
+    are put back, so whatever runs after draws what it drew before."""
+    import paddle_tpu
+    from paddle_tpu.core import generator
+    from paddle_tpu.core.program import (default_main_program,
+                                         default_startup_program)
+    state = generator.get_rng_state()
+    seeds = (default_main_program().random_seed,
+             default_startup_program().random_seed)
+    paddle_tpu.seed(seed)
+    try:
+        yield
+    finally:
+        generator.set_rng_state(state)
+        default_main_program().random_seed, \
+            default_startup_program().random_seed = seeds
+
+
 def test_tp_sharded_engine_token_equal_matrix():
     """The ISSUE-19 equality matrix in one drain: a tp=2 engine with a
     planner-sized sharded pool, radix prefix retention, and a shallow
@@ -516,7 +538,11 @@ def test_tp_sharded_engine_token_equal_matrix():
     prompts += [rng.randint(2, 48, (n,)).astype(np.int64) for n in (3, 6)]
     prompts.append(prompts[0].copy())          # whole-prompt radix hit
     with dg.guard():
-        m = _tp_gpt()
+        # the outcome hangs on the weights (the shallow draft must be
+        # partly right, partly wrong): drawn unseeded, it passed or
+        # failed with whatever ran before in the worker
+        with _seeded(1234):
+            m = _tp_gpt()
         m.eval()
         plan1 = page_budget(m, page_tokens=4, max_context=64)
         ref_pool = PagedKVPool.from_plan(plan1)
@@ -615,3 +641,109 @@ def test_tp2_serves_model_infeasible_at_tp1():
     for ref, out in zip(refs, outs):
         np.testing.assert_array_equal(ref, out)
     pool.assert_drained()
+
+
+# ---------------------------------------------------------------------------
+# the engine's spans and the counters at the same sites
+# ---------------------------------------------------------------------------
+def test_engine_counters_move_at_the_span_sites():
+    """An operator without a trace gets the trace's ratios from /stats:
+    prefills and their queue wait, bytes over the host link each way, and
+    bytes through the pool's gather / append."""
+    import paddle_tpu.dygraph as dg
+    from paddle_tpu.core.monitor import prometheus_text
+    from paddle_tpu.serving import ContinuousBatchingEngine, PagedKVPool
+    names = ("gen.prefills", "gen.queue_wait_us", "gen.h2d_bytes",
+             "gen.d2h_bytes", "kv.gather_bytes", "kv.append_bytes")
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(2, 30, (n,)).astype(np.int64) for n in (5, 9, 3)]
+    with dg.guard():
+        with _seeded(99):
+            m = _tiny_gpt()
+        m.eval()
+        pool = PagedKVPool(1, 2, 8, page_tokens=4, num_pages=64)
+        before = {n: metrics.counter(n) for n in names}
+        eng = ContinuousBatchingEngine(m, max_slots=2, kv_pool=pool).start()
+        try:
+            outs = [eng.submit(p, max_length=4) for p in prompts]
+            outs = [np.asarray(f.result(timeout=120)) for f in outs]
+        finally:
+            eng.stop()
+    pool.assert_drained()
+    moved = {n: metrics.counter(n) - before[n] for n in names}
+    assert moved["gen.prefills"] == len(prompts)
+    assert all(v > 0 for v in moved.values()), moved
+    # a prefill uploads ids (the mask is the model's own) and brings the
+    # whole [1, bucket, vocab] fp32 logits down to keep one row
+    assert moved["gen.d2h_bytes"] >= len(prompts) * 16 * 30 * 4
+    # every generated token past the first appended one [L, H, Dh] K and V
+    # column; every prompt token was installed once
+    column = 2 * 1 * 2 * 8 * 4      # K and V x [L, H, Dh] fp32
+    decoded = sum(len(o) - len(p) - 1 for o, p in zip(outs, prompts))
+    assert moved["kv.append_bytes"] == column * (
+        decoded + sum(len(p) for p in prompts))
+    text = prometheus_text()
+    for n in names:
+        assert "serving_" + n.replace(".", "_") + "_total" in text
+
+
+def test_generate_and_its_prefill_share_a_req(tmp_path, capsys):
+    """`server/generate` on the handler's thread and `engine/prefill` on
+    the engine's carry the same `req`; every engine span has its parent on
+    the engine's thread."""
+    import sys, os
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "tools"))
+    import serve_smoke
+    import paddle_tpu.dygraph as dg
+    from paddle_tpu import profiler as prof
+    from paddle_tpu.inference.server import InferenceServer
+    serve_smoke.save_tiny_model(str(tmp_path))
+    with dg.guard():
+        with _seeded(99):
+            gen = _tiny_gpt()
+        gen.eval()
+        srv = InferenceServer(str(tmp_path), generator=gen, gen_slots=2)
+        srv.start()
+        prof.start_profiler(state="CPU")
+        try:
+            base = f"http://{srv.host}:{srv.port}"
+            for ids in ([4, 9, 7], [[5, 6], [8, 3, 2, 11]]):
+                _post(base + "/generate",
+                      {"input_ids": ids, "max_length": 3})
+        finally:
+            prof.stop_profiler(profile_path=None)
+            srv.stop()
+    capsys.readouterr()
+    events = list(prof._state.events)
+    posts = [e for e in events if e.name == "server/generate"]
+    assert [e.fields["n"] for e in posts] == [1, 2]
+    assert len({e.fields["req"] for e in posts}) == 2
+    prefills = [e for e in events if e.name == "engine/prefill"]
+    assert sorted(e.fields["req"] for e in prefills) == sorted(
+        [posts[0].fields["req"]] + 2 * [posts[1].fields["req"]])
+    for e in prefills:
+        assert e.fields["prompt"] in (2, 3, 4) and e.fields["bucket"] == 16
+        assert e.fields["radix_hit"] == 0 and e.fields["waited_ms"] >= 0
+        assert e.thread != posts[0].thread
+    parents = {}
+    for e in events:
+        parents.setdefault(e.name, set()).add(e.parent)
+    assert parents["server/wait"] == {"server/generate"}
+    assert parents["engine/prefill"] == parents["engine/step"] == {None}
+    assert parents["engine/idle"] == parents["engine/admit"] == {None}
+    for child in ("engine/build", "engine/kv_install"):
+        assert parents[child] == {"engine/prefill"}
+    for child in ("engine/gather", "engine/kv_append"):
+        assert parents[child] == {"engine/step"}
+    for child in ("engine/upload", "engine/forward", "engine/fetch",
+                  "engine/sample"):
+        assert parents[child] == {"engine/prefill", "engine/step"}
+    assert parents["engine/finish"] <= {"engine/prefill", "engine/step"}
+    steps = [e for e in events if e.name == "engine/step"]
+    assert all(1 <= e.fields["active"] <= 2 and e.fields["lpad"] == 16
+               for e in steps)
+    moved = [e for e in events if e.name in (
+        "engine/upload", "engine/fetch", "engine/gather",
+        "engine/kv_install", "engine/kv_append")]
+    assert moved and all(e.fields["bytes"] > 0 for e in moved)
