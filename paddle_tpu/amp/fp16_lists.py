@@ -22,6 +22,10 @@ white_list = {
     "depthwise_conv2d",
     # Pallas attention kernels: MXU-bound, fp32 accumulation inside
     "flash_attention", "ring_attention",
+    # the blocked LM head + loss (static/head_loss_rewrite.py emits it
+    # after this pass; listed for a program that already holds one):
+    # matmuls in the activations' dtype, softmax and Loss in fp32
+    "linear_softmax_xent",
 }
 
 # Numerically sensitive — keep fp32 (fp16_lists.py black_list analog)

@@ -26,6 +26,7 @@ _FLOAT = ("float32", "float64")
 # and loss values), so the rewrite must not declare them low-precision.
 _FP32_OUT_SLOTS = {
     "softmax_with_cross_entropy": {"Loss"},
+    "linear_softmax_xent": {"Loss", "Lse"},
     "layer_norm": {"Mean", "Variance"},
 }
 

@@ -913,7 +913,7 @@ class CompiledProgram:
         """The single traced (state, feed, seed) -> (fetches, state')
         step both the per-dispatch (`_compile`) and scanned
         (`_compile_steps`) paths wrap in shard_map."""
-        from ..static.executor import BlockTracer
+        from ..static.executor import BlockTracer, _fetch_value
         block = program.global_block()
         tracer = BlockTracer(block)
         axes = tuple(mesh.axis_names)
@@ -981,7 +981,7 @@ class CompiledProgram:
             new_state = {n: env[n] for n in state_names}
             fetches = []
             for n in fetch_names:
-                v = env[n]
+                v = _fetch_value(env, n, program)
                 if elastic is not None and (
                         n == elastic.get("loss_avg")
                         or n in elastic.get("accs", ())):

@@ -57,6 +57,162 @@ def softmax_with_cross_entropy(ins, attrs, ctx):
     return {"Softmax": sm.astype(logits.dtype), "Loss": loss}
 
 
+# ---------------------------------------------------------------------------
+# linear_softmax_xent: an LM head and its loss over blocks of tokens
+# ---------------------------------------------------------------------------
+# What `static/head_loss_rewrite.py` puts where a program had mul ->
+# elementwise_add (bias) -> softmax_with_cross_entropy on [B, S, H]
+# activations: loss[b, s] = logsumexp(x[b, s] @ W + bias) - picked logit.
+# The [B, S, V] logits, their softmax and their gradient exist one block
+# of positions at a time.  The backward recomputes a block's logits from
+# the op's inputs; beside them it keeps only `Lse`, the [B, S, 1]
+# log-sum-exp (saving it takes a third off the head's time on the v5e:
+# the backward then reads each block once, not three times).
+
+# One block's logits in fp32 may take this much.  Under it the whole
+# head is one piece (n = 1: small programs compute exactly what the
+# three ops computed).  512 MiB: at BERT-base b64 x s512 x V 30,522 the
+# 4.0 GB of logits fall into 8 blocks of 64 positions, each a
+# [4096, 768] x [768, 30522] matmul (far above an MXU tile), each 3% of
+# a 16 GB chip; the weight gradient is carried through the n blocks,
+# n x H x V x 4 bytes of traffic (1.5 GB, ~2 ms of a 330 ms step), so
+# smaller blocks buy little memory for more traffic (v5e, that shape,
+# forward + backward of this op's first version: n = 4 54.8 ms, 8
+# 56.8 ms, 16 57.6 ms; PERF.md section 6, PR 26).  Derived
+# from the shapes and not settable: it follows the device's memory, not
+# the job.
+HEAD_BLOCK_BYTES = 512 << 20
+
+
+def head_token_blocks(batch, seq, vocab):
+    """(positions per block, blocks) for a [batch, seq, vocab] head.
+    Blocks cut the SEQUENCE axis, which data parallelism never shards,
+    so a dp shard sees the same mathematics on its own rows.  An even
+    split is preferred (one loop, no ragged tail) when a divisor of
+    `seq` lies within twice the least block count."""
+    least = min(seq, max(1, -(-batch * seq * vocab * 4 // HEAD_BLOCK_BYTES)))
+    n = next((d for d in range(least, min(seq, 2 * least) + 1)
+              if seq % d == 0), least)
+    blk = seq // n
+    return blk, -(-seq // blk)
+
+
+def _cut(a, blk):
+    """[B, S, ...] -> the full blocks stacked [S // blk, B, blk, ...]
+    and the ragged tail [B, S % blk, ...]."""
+    b, full = a.shape[0], a.shape[1] // blk
+    head = a[:, :full * blk].reshape((b, full, blk) + a.shape[2:])
+    return jnp.moveaxis(head, 1, 0), a[:, full * blk:]
+
+
+def _over_blocks(body, carry, arrays, blk):
+    """`body(carry, block of each array) -> (carry, [B, blk, ...] out)`
+    over the blocks of S in order: a scan over the full blocks, so that
+    one block is live at a time, then once over the tail.  Returns the
+    carry and the outs joined back to [B, S, ...]."""
+    if blk >= arrays[0].shape[1]:
+        return body(carry, arrays)
+    cut = [_cut(a, blk) for a in arrays]
+    carry, ys = jax.lax.scan(body, carry, tuple(c[0] for c in cut))
+    out = jnp.moveaxis(ys, 0, 1)
+    out = out.reshape((out.shape[0], -1) + out.shape[3:])
+    if cut[0][1].shape[1]:
+        carry, y_tail = body(carry, tuple(c[1] for c in cut))
+        out = jnp.concatenate([out, y_tail], axis=1)
+    return carry, out
+
+
+class _Head:
+    """The operands of `linear_softmax_xent` and its grad, prepared once:
+    labels as clipped int32 [B, S] with the ignored rows marked, the
+    weight in the activations' dtype, the block size."""
+
+    def __init__(self, ins, attrs, ctx):
+        self.x, w, self.bias = ins["X"], ins["W"], ins["Bias"]
+        lbl = ins["Label"]
+        if lbl.ndim == self.x.ndim:
+            lbl = lbl[..., 0]
+        lbl = lbl.astype(jnp.int32)
+        vocab = w.shape[-1]
+        self.ignored = (lbl == attrs.get("ignore_index", -100))[..., None]
+        self.label = jnp.clip(lbl, 0, vocab - 1)
+        self.classes = jnp.arange(vocab, dtype=jnp.int32)
+        self.cdt = _compute_dtype(self.x)
+        self.w_lo = w.astype(self.x.dtype)
+        self.blk, n = head_token_blocks(self.x.shape[0], self.x.shape[1],
+                                        vocab)
+        if getattr(ctx, "program", None) is not None:
+            # traced into a Program's step (BlockTracer), not by shape
+            # inference
+            from ...core.monitor import gauge_set
+            gauge_set("static.head_loss.token_blocks", n)
+
+    def logits(self, xb):
+        """One block's logits: operands in the activations' dtype (bf16
+        under AMP), fp32 accumulation, the bias added in fp32; then ONE
+        rounding to the activations' dtype, where mul and
+        elementwise_add each made one — half the bytes wherever XLA
+        leaves the block in HBM.  The softmax upcasts, as
+        softmax_with_cross_entropy does."""
+        z = jnp.einsum("bsh,hv->bsv", xb, self.w_lo,
+                       preferred_element_type=self.cdt)
+        z = z + self.bias.astype(self.cdt)
+        return z.astype(self.x.dtype).astype(self.cdt)
+
+    def onehot(self, lb):
+        return self.classes == lb[..., None]
+
+
+def _linear_softmax_xent_grad(ins, attrs, ctx):
+    h = _Head(ins, attrs, ctx)
+    x, w, cdt = h.x, ins["W"], h.cdt
+    lse = ins.get("Lse")
+    if lse is None:
+        lse = linear_softmax_xent(ins, attrs, ctx)["Lse"]
+    g = ins.get("Loss@GRAD")
+    g = jnp.zeros(lse.shape, cdt) if g is None else g.astype(cdt)
+    g = jnp.where(h.ignored, 0, g)
+
+    def body(carry, block):
+        dw, db = carry
+        xb, lb, gb, lseb = block
+        p = jnp.exp(h.logits(xb) - lseb)
+        # d loss / d logits, rounded once to the matmuls' operand dtype
+        d = ((p - h.onehot(lb)) * gb).astype(x.dtype)
+        dx = jnp.einsum("bsv,hv->bsh", d, h.w_lo,
+                        preferred_element_type=cdt)
+        dw = dw + jnp.einsum("bsh,bsv->hv", xb, d,
+                             preferred_element_type=cdt)
+        db = db + jnp.sum(d, axis=(0, 1), dtype=cdt)
+        return (dw, db), dx.astype(x.dtype)
+
+    zero = (jnp.zeros(w.shape, cdt), jnp.zeros(h.bias.shape, cdt))
+    (dw, db), dx = _over_blocks(body, zero,
+                                (x, h.label, g, lse.astype(cdt)), h.blk)
+    return {"X@GRAD": dx, "W@GRAD": dw.astype(w.dtype),
+            "Bias@GRAD": db.astype(h.bias.dtype)}
+
+
+@register_op("linear_softmax_xent", inputs=["X", "W", "Bias", "Label!"],
+             outputs=["Loss", "Lse"], grad=_linear_softmax_xent_grad)
+def linear_softmax_xent(ins, attrs, ctx):
+    h = _Head(ins, attrs, ctx)
+
+    def body(carry, block):
+        xb, lb = block
+        logits = h.logits(xb)
+        lse = jax.nn.logsumexp(logits, axis=-1, keepdims=True)
+        # the label's logit by a masked sum, not a gather: it rides the
+        # pass over the block that the log-sum-exp makes anyway
+        picked = jnp.sum(jnp.where(h.onehot(lb), logits, 0), axis=-1,
+                         keepdims=True)
+        return carry, jnp.concatenate([lse - picked, lse], axis=-1)
+
+    _, out = _over_blocks(body, None, (h.x, h.label), h.blk)
+    return {"Loss": jnp.where(h.ignored, 0, out[..., :1]),
+            "Lse": out[..., 1:]}
+
+
 @register_op("cross_entropy", inputs=["X", "Label!"], outputs=["Y"])
 def cross_entropy(ins, attrs, ctx):
     x, label = ins["X"], ins["Label"]

@@ -190,6 +190,17 @@ def _device_scope(op) -> str:
     return f"{name}/{op.type}"
 
 
+def _fetch_value(env: Dict[str, Any], name: str, program: Program):
+    """A fetch target's value, or an error that says why no op of the
+    program produces it (a rewrite records what it fused away)."""
+    if name in env:
+        return env[name]
+    why = getattr(program, "_fused_away", {}).get(name)
+    raise KeyError(
+        f"fetch target {name!r} is not produced by this program"
+        + (f": {why}" if why else ""))
+
+
 def _persistable_names(program: Program) -> List[str]:
     return sorted(v.name for b in program.blocks for v in b.vars.values()
                   if v.persistable)
@@ -984,7 +995,8 @@ class Executor:
             ctx = OpContext(seed=seed)
             tracer.run(env, ctx)
             new_state = {n: env[n] for n in state_names}
-            fetches = tuple(env[n] for n in fetch_names)
+            fetches = tuple(_fetch_value(env, n, program)
+                            for n in fetch_names)
             return fetches, new_state
 
         return step

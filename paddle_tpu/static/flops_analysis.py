@@ -97,7 +97,7 @@ def peak_flops_per_chip(device_kind: Optional[str] = None) -> float:
 # per-class cost tables
 # ---------------------------------------------------------------------------
 _MATMUL_OPS = frozenset(("mul", "matmul", "matmul_v2", "bmm",
-                         "int8_matmul"))
+                         "int8_matmul", "linear_softmax_xent"))
 
 _ATTENTION_OPS = frozenset(("flash_attention", "ring_attention",
                             "multihead_matmul"))
@@ -196,6 +196,16 @@ def _matmul_flops(op, shaper, base: str) -> int:
         k = _prod(sx[a:])
         n = _prod(sy[b:])
         return 2 * m * k * n
+    if base == "linear_softmax_xent":
+        # the head's 2*T*H*V matmul plus what the three ops it replaced
+        # were charged per logit (bias add 1, softmax-with-loss 7)
+        sx = shaper(_first(op, "X"))
+        sw = shaper(_first(op, "W"))
+        if sx is None or sw is None or len(sw) != 2:
+            return 0
+        per_logit = 1 + _ELEMENTWISE_FLOPS_PER_ELEM[
+            "softmax_with_cross_entropy"]
+        return _prod(sx[:-1]) * sw[1] * (2 * sw[0] + per_logit)
     if base == "int8_matmul":
         # weight-only int8: X [..., K] contracts its last dim against
         # the int8 W [K, N] slot (there is no Y)

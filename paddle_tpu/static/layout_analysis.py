@@ -445,6 +445,8 @@ class _Engine:
             return self._softmax(op, op_idx)
         if t == "softmax_with_cross_entropy":
             return self._softmax_xent(op, op_idx)
+        if t == "linear_softmax_xent":
+            return self._head_loss(op, op_idx)
         if t == "layer_norm":
             return self._layer_norm(op, op_idx)
         if t in _REDUCE_OPS:
@@ -831,6 +833,33 @@ class _Engine:
                 op=op, op_idx=op_idx, var=logits)
         for slot in ("Softmax", "Loss"):
             self.set(_first(op.outputs.get(slot, [])), _REPL)
+
+    def _head_loss(self, op: OpDesc, op_idx: int):
+        """`linear_softmax_xent` contracts X's features against W and
+        normalizes over W's classes on ONE rank: a model-axis shard of
+        either is `_softmax_xent`'s local-softmax bug.  The rewrite that
+        emits the op refuses annotated heads
+        (static/head_loss_rewrite.py), so this catches hand edits."""
+        x = _first(op.inputs.get("X", []))
+        w = _first(op.inputs.get("W", []))
+        xs = self._consume(op, op_idx, x)
+        ws = self._consume(op, op_idx, w)
+        for n in op.inputs.get("Bias", []):
+            self._consume(op, op_idx, n)
+        x_shape = _shape_of(self.block, x)
+        a_x = xs.axis_at(len(x_shape) - 1) if x_shape is not None else None
+        sharded = [a for a in (a_x, ws.axis_at(0), ws.axis_at(1))
+                   if a in MODEL_AXES]
+        if sharded and x not in self.tainted and w not in self.tainted:
+            self.diag(
+                "V601",
+                f"linear_softmax_xent over {x!r} and {w!r} with the "
+                f"features or classes sharded over {sharded[0]!r}: the "
+                f"kernel's local matmul and softmax see 1/degree of "
+                f"them (a tensor-parallel head keeps mul + "
+                f"softmax_with_cross_entropy and their collectives)",
+                op=op, op_idx=op_idx, var=w)
+        self.set(_first(op.outputs.get("Loss", [])), _REPL)
 
     def _layer_norm(self, op: OpDesc, op_idx: int):
         x = _first(op.inputs.get("X", []))

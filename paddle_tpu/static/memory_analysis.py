@@ -161,6 +161,7 @@ _GRAD_KEPT_OUTPUTS = frozenset((
     ("softmax", "Out"),
     ("log_softmax", "Out"),
     ("softmax_with_cross_entropy", "Softmax"),
+    ("linear_softmax_xent", "Lse"),  # [B, S, 1]: all it keeps
     ("tanh", "Out"),
     ("sigmoid", "Out"),
     ("exp", "Out"),
@@ -221,6 +222,30 @@ def _op_internal_bytes(op, sizer) -> int:
     s = sizer.batch if shape[1] in (-1, None) else int(shape[1])
     h = int(op.attrs.get("num_heads", 1))
     return b * h * s * s * 4  # fp32 score accumulation
+
+def _op_scratch_bytes(op, sizer) -> int:
+    """HBM a kernel holds only WHILE it runs, released before the next
+    op: `linear_softmax_xent` (static/head_loss_rewrite.py) computes its
+    [B, S, V] logits one block of positions at a time — forward one
+    block in fp32, backward the block, its softmax-minus-onehot and the
+    fp32 weight-gradient accumulator.  Its residuals are its inputs and
+    the [B, S, 1] `Lse`, which the var-level walk keeps live."""
+    grad = op.type == "linear_softmax_xent_grad"
+    if not grad and op.type != "linear_softmax_xent":
+        return 0
+    x, w = (sizer.var_of((op.inputs.get(slot) or [""])[0])
+            for slot in ("X", "W"))
+    if x is None or w is None or x.shape is None or w.shape is None \
+            or len(x.shape) != 3 or len(w.shape) != 2:
+        return 0
+    from ..ops.kernels.loss import head_token_blocks
+    b, s = (sizer.batch if d in (-1, None) else int(d)
+            for d in x.shape[:2])
+    hidden, vocab = (int(d) for d in w.shape)
+    blk, _ = head_token_blocks(b, s, vocab)
+    block = b * blk * vocab * 4
+    return 2 * block + hidden * vocab * 4 if grad else block
+
 
 # name suffixes minted by the backward/remat/AMP/sharding rewrites; a var
 # whose shape was never inferred (grad pieces, @RC replay aliases) borrows
@@ -527,6 +552,7 @@ def analyze_program(program: Program, batch: Optional[int] = None,
                  if n in live and last_use.get(n, -1) <= i
                  and cost_of.get(n, 0) > 0]
         internal = _op_internal_bytes(op, sizer)
+        scratch = _op_scratch_bytes(op, sizer)
         for n in op.output_names():
             if not n or n in persistable or n in live:
                 continue
@@ -547,10 +573,10 @@ def analyze_program(program: Program, batch: Optional[int] = None,
             cost_of[n] = c
             live.add(n)
         phase = _phase_of(op)
-        if cur > phase_peaks[phase]:
-            phase_peaks[phase] = cur
-        if cur > peak:
-            peak, peak_idx, peak_type = cur, i, op.type
+        if cur + scratch > phase_peaks[phase]:
+            phase_peaks[phase] = cur + scratch
+        if cur + scratch > peak:
+            peak, peak_idx, peak_type = cur + scratch, i, op.type
             peak_live = set(live)
         # inputs AND outputs whose last use is behind us die here — and
         # so do the ROOT buffers of any alias among them: a buffer that

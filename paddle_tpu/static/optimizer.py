@@ -17,6 +17,7 @@ import numpy as np
 from ..core.program import (Program, VarDesc, OpRole, default_main_program,
                             default_startup_program, unique_name)
 from .backward import append_backward
+from .head_loss_rewrite import fuse_head_loss
 from .layer_helper import LayerHelper
 from .initializer import Constant
 from . import layers
@@ -195,6 +196,10 @@ class Optimizer:
     # -- API ----------------------------------------------------------------
     def backward(self, loss, startup_program=None, parameter_list=None,
                  no_grad_set=None, callbacks=None):
+        # the forward program is final here (AMP has inserted its casts):
+        # an LM head and its loss become one op before the backward is
+        # derived, so it gets one grad op (static/head_loss_rewrite.py)
+        fuse_head_loss(loss.block.program)
         return append_backward(loss, parameter_list or self._parameter_list,
                                no_grad_set, callbacks)
 
@@ -811,6 +816,8 @@ class RecomputeOptimizer(Optimizer):
                  no_grad_set=None, callbacks=None):
         assert self._checkpoints is not None, \
             "call _set_checkpoints before minimize (fluid contract)"
+        fuse_head_loss(loss.block.program, keep=[
+            getattr(c, "name", c) for c in self._checkpoints])
         return append_backward(loss, parameter_list, no_grad_set,
                                checkpoints=self._checkpoints)
 

@@ -268,8 +268,11 @@ def test_planner_rediscovers_bert96_remat_verdict():
     # before the ISSUE-11 liveness fix: buffers read only through
     # alias/fusable views — remat's replay aliases among them — were
     # never freed by the sweep; un-rematerialized peaks are unchanged,
-    # see the "Full parameter sharding" docs section.)
-    assert abs(plan.predicted_peak_bytes / 2 ** 30 - 7.8) < 0.5
+    # see the "Full parameter sharding" docs section.)  Since PR 26 the
+    # head and its loss run over blocks of tokens
+    # (static/head_loss_rewrite.py): the 3.0 GB logits gradient that
+    # was the remat build's peak no longer exists, and it walks 5.8.
+    assert abs(plan.predicted_peak_bytes / 2 ** 30 - 5.8) < 0.5
     plain = [c for c in plan.trace if not c["remat"]][0]
     assert not plain["fits"]          # b96 plain walks 24.9 GiB: OOM
 
