@@ -170,10 +170,19 @@ class StaticFunction:
     XLA computation; training mode bridges grads to the dygraph tape via
     jax.vjp over the whole computation."""
 
-    def __init__(self, fn, input_spec=None, layer: Optional[Layer] = None):
+    def __init__(self, fn, input_spec=None, layer: Optional[Layer] = None,
+                 abstract_trace: bool = False):
+        """``abstract_trace``: record the Program from shapes alone — the
+        trace-time call of ``fn`` sees its tensor arguments as abstract
+        values (``jax.eval_shape``), so no kernel runs and no per-op
+        executable is compiled for a result the compiled run recomputes
+        anyway.  For functions whose Python never reads a tensor's value
+        (a 3 B-parameter decode step: seconds instead of minutes, and none
+        of the trace's activations held on the device)."""
         self._fn = self._maybe_ast_transform(fn)
         self._input_spec = input_spec
         self._layer = layer
+        self._abstract_trace = bool(abstract_trace)
         self._cache: Dict[Tuple, ConcreteProgram] = {}
 
     @staticmethod
@@ -246,7 +255,7 @@ class StaticFunction:
         try:
             from ..dygraph.base import enable_grad
             with enable_grad():
-                result = self._fn(*args)
+                result = self._call_for_trace(args)
         finally:
             dytracer._PROGRAM_RECORDER = prev
 
@@ -295,6 +304,29 @@ class StaticFunction:
                     break
         return ConcreteProgram(program, feed_names, fetch_names,
                                dict(rec.params), struct, updates)
+
+    def _call_for_trace(self, args):
+        """The one call of the traced function: eager on the arguments'
+        values, or — ``abstract_trace`` — with them swapped for abstract
+        values while it runs.  Only the identity, shape and dtype of the
+        tensors it makes are read afterwards."""
+        if not self._abstract_trace:
+            return self._fn(*args)
+        tensors = [a for a in args if isinstance(a, Tensor)]
+        concrete = [t._value for t in tensors]
+        made = []
+
+        def body(raws):
+            for t, r in zip(tensors, raws):
+                t._value = r
+            made.append(self._fn(*args))
+
+        try:
+            jax.eval_shape(body, concrete)
+        finally:
+            for t, r in zip(tensors, concrete):
+                t._value = r
+        return made[0]
 
     def __call__(self, *args, **kwargs):
         if kwargs:

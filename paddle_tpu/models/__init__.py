@@ -13,3 +13,6 @@ from .gpt import (  # noqa: F401
     GPTConfig, GPTModel, GPTForGeneration, gpt_small,
 )
 from .static_lm import build_transformer_lm  # noqa: F401
+from .granite_hybrid import (  # noqa: F401
+    GraniteHybridConfig, GraniteHybridModel, granite_hybrid_tiny,
+)

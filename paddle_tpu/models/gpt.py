@@ -39,6 +39,13 @@ class GPTConfig:
         self.eos_id = eos_id
         self.dropout = dropout
 
+    def cache_spec(self):
+        """What one sequence keeps between steps (`serving/kv_pool.py`):
+        one `kv` group, every layer a full-attention layer."""
+        return [{"kind": "kv", "layers": self.num_layers,
+                 "kv_heads": self.num_heads,
+                 "head_dim": self.hidden_size // self.num_heads}]
+
 
 class _Block(Layer):
     """Pre-norm decoder block (GPT-2 style)."""
@@ -123,6 +130,9 @@ class GPTModel(Layer):
         x = self.ln_f(x)
         logits = paddle_tpu.matmul(x, self.wte.weight, transpose_y=True)
         return logits, new_caches
+
+    def cache_spec(self):
+        return self.config.cache_spec()
 
     def gen_cache(self, batch_size):
         """Fresh empty per-block KV caches for ``batch_size`` rows (the

@@ -44,6 +44,12 @@ def matmul_v2(ins, attrs, ctx):
         x = jnp.swapaxes(x, -1, -2)
     if attrs.get("trans_y", False):
         y = jnp.swapaxes(y, -1, -2)
+    if attrs.get("out_dtype"):
+        # the accumulator itself, not a rounded low-precision result (a
+        # served logits row: bf16 operands, float32 out)
+        from ...core.dtype import np_dtype
+        return {"Out": jnp.matmul(
+            x, y, preferred_element_type=np_dtype(attrs["out_dtype"]))}
     return {"Out": _matmul(x, y)}
 
 

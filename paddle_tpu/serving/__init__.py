@@ -13,7 +13,11 @@ Four pieces:
 * ``PagedKVPool`` (kv_pool.py) — fixed-size KV pages + per-sequence page
   tables with refcounted copy-on-write prefix sharing; admission is by
   free-page reservation, sizing by ``static.page_budget`` (the HBM
-  walker), drift detection by ``budget_drift``.
+  walker), drift detection by ``budget_drift``.  Geometry comes from the
+  model's cache description; a model with recurrent layers gets
+  ``StateSlots`` (device-resident per-sequence state) in the same
+  manager, and the engine runs it through ``StepPrograms``
+  (step_program.py): one compiled program per (phase, bucket).
 * ``RadixPrefixCache`` (prefix_cache.py) — retained radix tree over
   committed prefixes: pages pinned past last-sharer retirement
   (watermark-bounded LRU), radix hits skip prefill compute over the hit
@@ -40,8 +44,10 @@ from .generation import (  # noqa: F401
     ContinuousBatchingEngine, GenerationRequest,
 )
 from .kv_pool import (  # noqa: F401
-    PagedKVPool, PageTable, PagePoolExhaustedError, budget_drift,
+    PagedKVPool, PageTable, PagePoolExhaustedError, StateSlots,
+    budget_drift, cache_spec_of,
 )
+from .step_program import StepPrograms  # noqa: F401
 from .prefix_cache import RadixPrefixCache  # noqa: F401
 from .tp_decode import TPShardedDecoder, build_decode_program  # noqa: F401
 from .int8_decode import Int8Linear, quantize_decode_model  # noqa: F401
@@ -55,6 +61,7 @@ __all__ = [
     "DeadlineExceededError", "BatcherStoppedError",
     "ContinuousBatchingEngine", "GenerationRequest",
     "PagedKVPool", "PageTable", "PagePoolExhaustedError", "budget_drift",
+    "StateSlots", "StepPrograms", "cache_spec_of",
     "RadixPrefixCache", "TPShardedDecoder", "build_decode_program",
     "Int8Linear", "quantize_decode_model",
     "SpeculativeDecoder", "stamp_draft",

@@ -57,6 +57,18 @@ sharing:
   rejection rolls the page-table tail back via ``pool.truncate``.
   Greedy output stays token-equal to the target alone — acceptance
   replays the exact plain-greedy emission loop over the verified chain.
+
+Geometry (KV layers, kv heads, head dim) comes from the model's cache
+description (``kv_pool.cache_spec_of``).  A model whose description has
+a ``state`` group — recurrent layers beside attention layers — needs the
+paged pool: its per-sequence state lives in the pool's ``StateSlots`` on
+the device, reserved with the pages at admission and released with them.
+Such a model runs through ``StepPrograms`` (step_program.py): prefill
+and decode are ONE compiled program per (phase, bucket), prompts carry
+their lengths so that bucket padding never enters the recurrence, and an
+idle decode row's state comes back unchanged.  ``prefix_cache=`` and
+``speculative=`` refuse such a model (they need state snapshots at page
+boundaries and rollback of a state; ROADMAP R-h).
 """
 from __future__ import annotations
 
@@ -70,10 +82,12 @@ import numpy as np
 
 from . import metrics
 from ..core.compile_cache import next_pow2 as _next_pow2
+from ..dygraph.tensor import Tensor
 from ..profiler import RecordEvent
 from .batcher import (BatcherStoppedError, DeadlineExceededError,
                       QueueFullError, _jittered)
-from .kv_pool import PagedKVPool, PageTable
+from .kv_pool import (PagedKVPool, PageTable, cache_spec_of, kv_geometry,
+                      state_groups)
 
 __all__ = ["ContinuousBatchingEngine", "GenerationRequest"]
 
@@ -192,6 +206,14 @@ class ContinuousBatchingEngine:
                 model = quantize_decode_model(model)
         self._model = getattr(model, "gpt", model)
         self.config = self._model.config
+        # the model's cache description: KV geometry for the pool and the
+        # dense step caches, and whether sequences carry recurrent state
+        spec = cache_spec_of(self.config)
+        self._kv_layers, self._kv_heads, self._kv_head_dim = \
+            kv_geometry(spec)
+        self._stateful = bool(state_groups(spec))
+        if self._stateful:
+            self._refuse_for_state(kv_pool, prefix_cache, speculative)
         self._pool: Optional[PagedKVPool] = None
         if kv_pool is not None:
             if kv_pool == "auto":
@@ -208,13 +230,9 @@ class ContinuousBatchingEngine:
                     f"kv_pool must be None, 'auto', a plan dict or a "
                     f"PagedKVPool, got {type(kv_pool).__name__}")
             for name, want, got in (
-                    ("num_layers", self.config.num_layers,
-                     self._pool.num_layers),
-                    ("num_heads", self.config.num_heads,
-                     self._pool.num_heads),
-                    ("head_dim",
-                     self.config.hidden_size // self.config.num_heads,
-                     self._pool.head_dim)):
+                    ("num_layers", self._kv_layers, self._pool.num_layers),
+                    ("num_heads", self._kv_heads, self._pool.num_heads),
+                    ("head_dim", self._kv_head_dim, self._pool.head_dim)):
                 if int(want) != int(got):
                     raise ValueError(
                         f"kv_pool geometry mismatch: model {name}={want} "
@@ -237,6 +255,21 @@ class ContinuousBatchingEngine:
         if max_slots is None:
             max_slots = int(plan["max_slots"]) if plan else 4
         self.max_slots = int(max_slots)
+        self._steps = None
+        if self._stateful:
+            state = self._pool.state
+            if state is None or state.groups != state_groups(spec) \
+                    or not state.dense:
+                raise ValueError(
+                    "kv_pool holds no state slots for this model's cache "
+                    "description — build it with PagedKVPool.from_plan("
+                    "static.page_budget(model))")
+            if state.slots < self.max_slots:
+                raise ValueError(
+                    f"max_slots={self.max_slots} but the pool holds "
+                    f"{state.slots} state slots")
+            from .step_program import StepPrograms
+            self._steps = StepPrograms(self._model)
         # paged max-context: what the plan granted (never beyond the
         # model's positions); fixed mode keeps max_position
         self.max_context = int(self.config.max_position)
@@ -289,6 +322,30 @@ class ContinuousBatchingEngine:
         self._running = False
         self._draining = False
         self._thread: Optional[threading.Thread] = None
+
+    def _refuse_for_state(self, kv_pool, prefix_cache, speculative):
+        """What a model with recurrent state cannot have yet, each with
+        what is missing (ROADMAP R-h's remainder)."""
+        name = type(self._model).__name__
+        if prefix_cache is not None:
+            raise NotImplementedError(
+                f"prefix_cache= with {name}: a retained prefix would need "
+                "a snapshot of the recurrent state at each page boundary "
+                "to resume from; only KV pages are retained")
+        if speculative is not None:
+            raise NotImplementedError(
+                f"speculative= with {name}: rejecting a draft would need "
+                "rollback of the recurrent state; only the page table "
+                "can be truncated")
+        if kv_pool is None:
+            raise ValueError(
+                f"{name} carries recurrent state: it needs the paged pool "
+                "(kv_pool='auto', a plan or a PagedKVPool), whose manager "
+                "holds the state slots")
+        if self.tp_degree > 1 or self.weight_dtype != "float32":
+            raise NotImplementedError(
+                f"{name} serves at tp_degree 1 in its own weight dtype "
+                "(sharded state and int8 stamps are not built)")
 
     @property
     def kv_pool(self) -> Optional[PagedKVPool]:
@@ -463,7 +520,10 @@ class ContinuousBatchingEngine:
                                      prompt=int(req.prompt.size),
                                      waited_ms=round(waited * 1e3, 3)
                                      ) as span:
-                        self._prefill(req, table, span)
+                        if self._stateful:
+                            self._prefill_compiled(req, table, span)
+                        else:
+                            self._prefill(req, table, span)
                 except Exception as e:  # noqa: BLE001 — this request only
                     metrics.count("gen.failed")
                     if table is not None:
@@ -472,7 +532,9 @@ class ContinuousBatchingEngine:
             try:
                 if any(self._slots):
                     with RecordEvent("engine/step") as span:
-                        if self._spec is not None:
+                        if self._stateful:
+                            self._step_compiled(span)
+                        elif self._spec is not None:
                             self._step_spec(span)
                         else:
                             self._step(span)
@@ -611,11 +673,10 @@ class ContinuousBatchingEngine:
                 self._kv_buckets.add(("reuse_prefill", mpad, spp))
                 metrics.gauge("gen.kv_buckets", len(self._kv_buckets))
             cfg = self.config
-            heads = cfg.num_heads
-            head_dim = cfg.hidden_size // heads
+            heads, head_dim = self._kv_heads, self._kv_head_dim
             with RecordEvent("engine/build"):
                 k_hit, v_hit = self._pool.gather(table)   # [L, H, m, Dh]
-                k_c = np.zeros((cfg.num_layers, 1, heads, mpad, head_dim),
+                k_c = np.zeros((self._kv_layers, 1, heads, mpad, head_dim),
                                np.float32)
                 v_c = np.zeros_like(k_c)
                 k_c[:, 0, :, :m] = k_hit
@@ -730,9 +791,8 @@ class ContinuousBatchingEngine:
             return
         S = self.max_slots
         cfg = self.config
-        heads = cfg.num_heads
-        head_dim = cfg.hidden_size // heads
-        n_layers = cfg.num_layers
+        heads, head_dim = self._kv_heads, self._kv_head_dim
+        n_layers = self._kv_layers
         lpad = _next_pow2(max(s.kv_len for _, s in active), self._kv_floor)
         span.set(active=len(active), lpad=lpad)
         with self._mu:
@@ -821,6 +881,137 @@ class ContinuousBatchingEngine:
             metrics.gauge("gen.active_slots",
                           sum(s is not None for s in self._slots))
 
+    # -- the compiled route (models with recurrent state) -------------------
+    def _prefill_compiled(self, req: GenerationRequest, table: PageTable,
+                          span: RecordEvent):
+        """`_prefill` for a model with recurrent state: ONE compiled
+        program per prompt bucket.  The prompt is padded to its bucket
+        and its length goes in with it, so the pads never enter the
+        recurrence; out come the one logits row sampling needs, the
+        attention layers' KV (to the pool's pages, copy-on-write sharing
+        as ever) and the sequence's state after its last prompt token,
+        which is written into its state slot on the device."""
+        if req.future.cancelled():
+            self._pool.close_sequence(table)
+            return
+        p = req.prompt.size
+        slot_id = table.state_slot
+        pp = min(_next_pow2(p, self._kv_floor),
+                 int(self.config.max_position))
+        span.set(bucket=pp, radix_hit=0, state_slot=slot_id)
+        with self._mu:
+            self._kv_buckets.add(("prefill", pp))
+            metrics.gauge("gen.kv_buckets", len(self._kv_buckets))
+        with RecordEvent("engine/build"):
+            ids = np.zeros((1, pp), np.int32)
+            ids[0, :p] = req.prompt
+        ids_t, len_t, last_t = self._upload(
+            ids, np.asarray([p], np.int32), np.asarray([p - 1], np.int32))
+        with RecordEvent("engine/forward", bucket=pp, rows=1):
+            logits, k, v, *state = self._steps.prefill(ids_t, len_t, last_t)
+        with RecordEvent("engine/fetch") as fetch:
+            last = self._download(fetch, logits)[0][0]
+        metrics.count("gen.prefill_tokens", p)
+        with RecordEvent("engine/sample"):
+            nxt = self._sample(req, last)
+        if nxt == self.config.eos_id or req.max_new <= 1:
+            with RecordEvent("engine/finish"):
+                self._pool.close_sequence(table)
+                slot = _Slot(req, None, list(req.prompt), nxt)
+                slot.tokens.append(nxt)
+                self._finish(slot)
+            return
+        with RecordEvent("engine/kv_install") as install:
+            k_h, v_h = self._download(install, k, v)
+            self._pool.open_sequence(
+                req.prompt, k_h[:, 0, :, :p].astype(np.float32),
+                v_h[:, 0, :, :p].astype(np.float32), table=table)
+        pool_state = self._pool.state
+        new = {n: t._value for n, t in zip(pool_state.names, state)}
+        new.update(k_dense=k._value, v_dense=v._value)  # the prompt's KV
+        with RecordEvent("engine/state_install", slot=slot_id,
+                         bytes=pool_state.slot_bytes):
+            pool_state.install(slot_id, **new)
+        slot = _Slot(req, None, list(req.prompt), nxt, table=table)
+        with self._mu:
+            # the engine's row IS the state slot: the decode step runs
+            # over the state arrays whole, row i of the batch on row i
+            self._slots[slot_id] = slot
+            metrics.gauge("gen.active_slots",
+                          sum(s is not None for s in self._slots))
+
+    def _step_compiled(self, span: RecordEvent):
+        """`_step` for a model with recurrent state: ONE compiled program
+        per KV-length bucket over all `max_slots` rows.  In go the rows'
+        pending tokens, their cache lengths, which rows are active, the
+        dense KV (the pool's view of the live sequences, kept on the
+        device: nothing is gathered from the pages or uploaded) and the
+        state arrays as they sit on the device; out come a logits
+        row a slot, the new KV column a slot — appended to the view and,
+        for the record, to the pages — and the updated state arrays (an
+        idle row's state comes back as it went in)."""
+        with self._mu:
+            for i, s in enumerate(self._slots):
+                if s is not None and s.req.future.cancelled():
+                    metrics.count("gen.cancelled")
+                    self._pool.close_sequence(s.table)
+                    self._slots[i] = None
+            active = [(i, s) for i, s in enumerate(self._slots)
+                      if s is not None]
+        if not active:
+            return
+        S = self.max_slots
+        lpad = _next_pow2(max(s.kv_len for _, s in active), self._kv_floor)
+        span.set(active=len(active), lpad=lpad,
+                 context=sum(s.kv_len for _, s in active))
+        with self._mu:
+            self._kv_buckets.add(("decode", lpad))
+            metrics.gauge("gen.kv_buckets", len(self._kv_buckets))
+        state = self._pool.state
+        with RecordEvent("engine/build"):   # nothing to gather: the dense
+            ids = np.zeros((S, 1), np.int32)    # KV view is on the device
+            lengths = np.zeros(S, np.int32)
+            alive = np.zeros(S, np.int32)
+            for i, s in active:
+                ids[i, 0] = s.next_id
+                lengths[i], alive[i] = s.kv_len, 1
+        uploaded = self._upload(ids, lengths, alive)
+        with RecordEvent("engine/forward", bucket=lpad, rows=len(active)):
+            logits, k_new, v_new, *new_state = self._steps.decode(
+                *uploaded, *[Tensor(a) for a in state.kv_view(lpad)],
+                *state.arrays.values())
+            state.rebind(**{n: t._value
+                            for n, t in zip(state.names, new_state)})
+            state.append_kv(k_new._value, v_new._value, lengths)
+        with RecordEvent("engine/fetch") as fetch:
+            step_logits, k_col, v_col = self._download(
+                fetch, logits, k_new, v_new)
+            k_col = k_col[:, :, :, 0].astype(np.float32)   # [L, S, H, Dh]
+            v_col = v_col[:, :, :, 0].astype(np.float32)
+        metrics.count("gen.steps")
+        metrics.count("gen.tokens", len(active))
+        metrics.observe("gen.step_occupancy", len(active))
+        retired = []
+        with RecordEvent("engine/kv_append") as append:
+            for i, s in active:
+                self._pool.append_column(s.table, k_col[:, i], v_col[:, i])
+            append.set(bytes=len(active) * 2 * k_col[:, 0].nbytes)
+        with RecordEvent("engine/sample"):
+            for i, s in active:
+                s.tokens.append(s.next_id)
+                nxt = self._sample(s.req, step_logits[i])
+                s.next_id = nxt
+                s.n_new += 1
+                if nxt == self.config.eos_id or s.n_new >= s.req.max_new:
+                    s.tokens.append(nxt)
+                    retired.append(i)
+        with RecordEvent("engine/finish"), self._mu:
+            for i in retired:
+                slot, self._slots[i] = self._slots[i], None
+                self._finish(slot)
+            metrics.gauge("gen.active_slots",
+                          sum(s is not None for s in self._slots))
+
     def _step_spec(self, span: RecordEvent):
         """One SPECULATIVE decode step over every active slot: the
         draft proposes up to k tokens per greedy row, the target
@@ -848,9 +1039,8 @@ class ContinuousBatchingEngine:
             return
         S = self.max_slots
         cfg = self.config
-        heads = cfg.num_heads
-        head_dim = cfg.hidden_size // heads
-        n_layers = cfg.num_layers
+        heads, head_dim = self._kv_heads, self._kv_head_dim
+        n_layers = self._kv_layers
         max_ln = max(s.kv_len for _, s in active)
         # batch query width: pending token + up to k proposals, shrunk
         # only when a row's pad-query positions would leave the wpe

@@ -62,6 +62,20 @@ impossible; ``quant_scale_clips`` counts any defensive clamp anyway.
 ``page_bytes`` prices the int8 itemsize plus the scale sidecar, which
 is what lets ``static.page_budget(kv_dtype="int8")`` carve ~2× the
 pages at equal HBM.
+
+Geometry comes from the model's CACHE DESCRIPTION (``cache_spec_of``):
+a list of layer groups, each ``kv`` (layers, kv heads, head dim — a
+column a token, in pages) or ``state`` (layers, the arrays one sequence
+holds whatever its length — a recurrent layer's state).  A model with a
+``state`` group gets a SECOND KIND OF CACHE IN THE SAME MANAGER:
+``StateSlots``, device arrays ``[layers, slots, ...]`` allocated once.
+``reserve`` hands out a state slot with the page reservation (on
+``table.state_slot``) and ``close_sequence`` gives both back, so every
+retire / cancel / failure path that returns pages returns the state.
+The recurrent state never crosses the host link.  Where the model's
+``kv`` group states a ``dense_dtype``, the same slots also hold the decode
+step's dense KV view of the live sequences, so that a step uploads no KV;
+the pages stay the record.
 """
 from __future__ import annotations
 
@@ -71,9 +85,55 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from . import metrics
+from ..core.compile_cache import next_pow2 as _next_pow2
+from ..profiler import RecordEvent
 
 __all__ = ["PagedKVPool", "PageTable", "PagePoolExhaustedError",
-           "budget_drift"]
+           "StateSlots", "budget_drift", "cache_spec_of", "kv_geometry",
+           "state_groups", "state_slot_bytes"]
+
+
+# -- the model's cache description -------------------------------------------
+def cache_spec_of(config) -> List[Dict]:
+    """The cache description of a decoder config: what it states itself
+    (``config.cache_spec()``, or the ``"cache"`` key of a plain dict), else
+    one ``kv`` group of ``num_layers`` x ``num_heads`` full-attention
+    layers (every GPTConfig-shaped object)."""
+    if hasattr(config, "cache_spec"):
+        return config.cache_spec()
+    get = config.get if isinstance(config, dict) else \
+        lambda k, d=None: getattr(config, k, d)
+    if get("cache"):
+        return list(get("cache"))
+    heads = int(get("num_heads"))
+    return [{"kind": "kv", "layers": int(get("num_layers")),
+             "kv_heads": heads,
+             "head_dim": int(get("hidden_size")) // heads}]
+
+
+def kv_geometry(spec) -> tuple:
+    """(layers, kv heads, head dim) of the description's one ``kv`` group
+    (several — window beside global layers, a latent cache — are ROADMAP
+    D9's remainder)."""
+    kv = [g for g in spec if g["kind"] == "kv"]
+    if len(kv) != 1:
+        raise NotImplementedError(
+            f"the page pool holds exactly one kv layer group, the cache "
+            f"description has {len(kv)}")
+    return int(kv[0]["layers"]), int(kv[0]["kv_heads"]), \
+        int(kv[0]["head_dim"])
+
+
+def state_groups(spec) -> List[Dict]:
+    return [g for g in spec if g["kind"] == "state"]
+
+
+def state_slot_bytes(spec) -> int:
+    """Bytes one sequence's recurrent state occupies, all groups."""
+    from ..core.dtype import np_dtype
+    return sum(int(g["layers"]) * int(np.prod(a["shape"]))
+               * np_dtype(a["dtype"]).itemsize
+               for g in state_groups(spec) for a in g["arrays"])
 
 
 class PagePoolExhaustedError(RuntimeError):
@@ -88,15 +148,162 @@ class PageTable:
     ``pages[j]`` holds positions ``[j*T, (j+1)*T)``; ``length`` tokens
     are valid.  ``reserved`` is the worst-case page count admission
     granted; ``charged`` counts the allocations (fresh + COW) already
-    consumed from it."""
+    consumed from it.  ``state_slot`` is the sequence's slot in the
+    pool's ``StateSlots`` (None for a model without recurrent state)."""
 
-    __slots__ = ("pages", "length", "reserved", "charged")
+    __slots__ = ("pages", "length", "reserved", "charged", "state_slot")
 
-    def __init__(self, reserved: int):
+    def __init__(self, reserved: int, state_slot: Optional[int] = None):
         self.pages: List[int] = []
         self.length = 0
         self.reserved = int(reserved)
         self.charged = 0
+        self.state_slot = state_slot
+
+
+class StateSlots:
+    """Per-sequence state of fixed size, on the device.
+
+    One array per entry of each ``state`` group of the cache description,
+    ``[layers, slots, *shape]``, allocated ONCE here; a sequence owns slot
+    ``i`` (row ``i`` of every array) from ``reserve`` to ``release``.  The
+    decode step reads and writes the whole arrays (``arrays`` /
+    ``rebind``); ``install`` writes one slot from a prefill's result in
+    place (the old array is donated).  A prefill starts from zero state
+    and its result overwrites the whole slot, so a reused slot never
+    shows its last owner's state (``serving.gen.state_resets`` counts
+    those overwrites).
+
+    ``dense_kv = (layers, kv heads, context, head dim, dtype)`` adds the
+    decode step's DENSE KV VIEW of the live sequences, ``k_dense`` /
+    ``v_dense`` ``[layers, slots, kv heads, context, head dim]``: what
+    ``PagedKVPool.gather`` would build from each sequence's pages, kept
+    where the step reads it instead of being gathered in numpy and
+    uploaded every step (67-268 MB a step for 16 rows; docs/serving.md).
+    The pool's pages stay the record — admission, sharing, accounting —
+    and receive every column too; the view is the step's workspace, which
+    ``page_budget`` prices per slot.  Mutated on the engine's decode
+    thread only."""
+
+    def __init__(self, groups: Sequence[Dict], slots: int, dense_kv=None):
+        import jax
+        import jax.numpy as jnp
+        from ..core.dtype import np_dtype
+        self.groups = [dict(g) for g in groups]
+        self.slots = int(slots)
+        if self.slots < 1 or not self.groups:
+            raise ValueError("StateSlots needs >= 1 slot and a state group")
+        self.arrays: Dict[str, "jax.Array"] = {}
+        for g in self.groups:
+            for a in g["arrays"]:
+                if a["name"] in self.arrays:
+                    raise ValueError(f"two state arrays named {a['name']!r}")
+                self.arrays[a["name"]] = jnp.zeros(
+                    (int(g["layers"]), self.slots) + tuple(a["shape"]),
+                    np_dtype(a["dtype"]))
+        self.slot_bytes = sum(v.nbytes for v in self.arrays.values()) \
+            // self.slots
+        self.dense: Dict[str, "jax.Array"] = {}
+        if dense_kv is not None:
+            layers, heads, context, head_dim, dtype = dense_kv
+            for name in ("k_dense", "v_dense"):
+                self.dense[name] = jnp.zeros(
+                    (int(layers), self.slots, int(heads), int(context),
+                     int(head_dim)), np_dtype(dtype))
+        self._free: List[int] = list(range(self.slots - 1, -1, -1))
+        self._written = set()       # slots that have held a sequence
+        # one slot's entry (a prefill's result, shorter along the context
+        # where it is a prompt's KV) into its row, in place
+        self._write = jax.jit(
+            lambda slab, new, slot: jax.lax.dynamic_update_slice(
+                slab, new.astype(slab.dtype),
+                (slot * 0, slot) + (slot * 0,) * (slab.ndim - 2)),
+            donate_argnums=0)
+        # one new column a row at each row's own position, in place
+        self._append = jax.jit(
+            lambda slab, cols, pos: jax.vmap(
+                lambda row, col, p: jax.lax.dynamic_update_slice(
+                    row, col.astype(row.dtype), (p * 0, p * 0, p, p * 0)),
+                in_axes=(1, 1, 0), out_axes=1)(slab, cols, pos),
+            donate_argnums=0)
+        self._publish()
+
+    @property
+    def names(self) -> List[str]:
+        return list(self.arrays)
+
+    @property
+    def used(self) -> int:
+        return self.slots - len(self._free)
+
+    @property
+    def nbytes(self) -> int:
+        return self.slot_bytes * self.slots
+
+    def can_reserve(self) -> bool:
+        return bool(self._free)
+
+    def reserve(self) -> int:
+        if not self._free:
+            raise PagePoolExhaustedError(
+                f"all {self.slots} state slots are taken")
+        slot = self._free.pop()
+        self._publish()
+        return slot
+
+    def release(self, slot: int):
+        if slot in self._free or not 0 <= slot < self.slots:
+            raise ValueError(f"state slot {slot} is not held")
+        self._free.append(int(slot))
+        self._publish()
+
+    def install(self, slot: int, **new):
+        """Write one sequence's state — arrays ``[layers, 1, *shape]`` by
+        name, as a prefill returns them (``k_dense`` / ``v_dense``: its
+        prompt's KV columns) — into `slot`, on the device."""
+        if sorted(new) != sorted([*self.arrays, *self.dense]):
+            raise ValueError(
+                f"install needs {sorted([*self.arrays, *self.dense])}, "
+                f"got {sorted(new)}")
+        for name, value in new.items():
+            where = self.dense if name in self.dense else self.arrays
+            where[name] = self._write(where[name], value, np.int32(slot))
+        if slot in self._written:
+            metrics.count("gen.state_resets")
+        self._written.add(slot)
+
+    def kv_view(self, columns: int):
+        """The dense KV view's first `columns` columns: (k, v), each
+        ``[layers, slots, kv heads, columns, head dim]``, on the device."""
+        return tuple(a[:, :, :, :columns] for a in self.dense.values())
+
+    def append_kv(self, k_cols, v_cols, positions):
+        """A decode step's new columns ``[layers, slots, kv heads, 1, head
+        dim]`` into the dense view, row ``i`` at ``positions[i]`` (an idle
+        row's lands in a column its next prefill overwrites)."""
+        pos = np.asarray(positions, np.int32)
+        for name, cols in (("k_dense", k_cols), ("v_dense", v_cols)):
+            self.dense[name] = self._append(self.dense[name], cols, pos)
+
+    def rebind(self, **new):
+        """Take a decode step's updated arrays in place of the old."""
+        for name, value in new.items():
+            old = self.arrays[name]
+            if value.shape != old.shape or value.dtype != old.dtype:
+                raise ValueError(
+                    f"state array {name!r}: step returned {value.dtype}"
+                    f"{value.shape}, the slab is {old.dtype}{old.shape}")
+            self.arrays[name] = value
+
+    def row(self, slot: int) -> Dict[str, np.ndarray]:
+        """Host copies of one slot's state (tests and debugging: this is
+        the one place the state crosses the host link)."""
+        return {n: np.asarray(a[:, slot]) for n, a in self.arrays.items()}
+
+    def _publish(self):
+        metrics.gauge("state.slots_total", self.slots)
+        metrics.gauge("state.slots_used", self.used)
+        metrics.gauge("state.bytes", self.nbytes)
 
 
 class PagedKVPool:
@@ -116,7 +323,7 @@ class PagedKVPool:
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  page_tokens: int = 16, num_pages: int = 64,
                  dtype=np.float32, plan: Optional[Dict] = None,
-                 kv_dtype=None):
+                 kv_dtype=None, state: Optional[StateSlots] = None):
         if page_tokens < 1 or num_pages < 1:
             raise ValueError(
                 f"need positive page_tokens/num_pages, got "
@@ -161,18 +368,32 @@ class PagedKVPool:
         self.cow_copies = 0
         self.prefix_hits = 0
         self.plan = dict(plan) if plan else None
+        # the second kind of cache: recurrent state slots (None for a
+        # model whose cache description has no `state` group)
+        self.state = state
         self._publish()
 
     @classmethod
     def from_plan(cls, plan: Dict, dtype=np.float32) -> "PagedKVPool":
         """Build a pool from a ``static.page_budget`` plan dict (records
-        the plan so `budget_drift` can re-derive and compare it)."""
+        the plan so `budget_drift` can re-derive and compare it).  A plan
+        whose cache description has a ``state`` group gets its
+        ``StateSlots`` — ``max_slots`` of them — allocated here."""
+        groups = state_groups(plan.get("cache") or [])
+        dense = None
+        if groups:      # the step's dense KV view rides the state slots
+            kv = [g for g in plan["cache"] if g["kind"] == "kv"][0]
+            dense = (kv["layers"], kv["kv_heads"],
+                     _next_pow2(int(plan["max_context"])),
+                     kv["head_dim"], kv.get("dense_dtype", "float32"))
         return cls(num_layers=int(plan["num_layers"]),
                    num_heads=int(plan["num_heads"]),
                    head_dim=int(plan["head_dim"]),
                    page_tokens=int(plan["page_tokens"]),
                    num_pages=int(plan["pages"]),
-                   dtype=plan.get("kv_dtype", dtype), plan=plan)
+                   dtype=plan.get("kv_dtype", dtype), plan=plan,
+                   state=StateSlots(groups, int(plan["max_slots"]), dense)
+                   if groups else None)
 
     # -- geometry -----------------------------------------------------------
     @property
@@ -259,19 +480,22 @@ class PagedKVPool:
 
     # -- admission reservation ---------------------------------------------
     def can_reserve(self, n_pages: int) -> bool:
-        return int(n_pages) <= self.pages_available
+        return int(n_pages) <= self.pages_available and (
+            self.state is None or self.state.can_reserve())
 
     def reserve(self, n_pages: int) -> PageTable:
-        """Claim worst-case headroom for one sequence; the returned
-        table is the charge account every later allocation debits."""
+        """Claim worst-case headroom for one sequence — and, where the
+        model has recurrent state, its state slot; the returned table is
+        the charge account every later allocation debits."""
         n = int(n_pages)
         if n > self.pages_available:
             raise PagePoolExhaustedError(
                 f"cannot reserve {n} pages "
                 f"({self.pages_available} available of {self.num_pages})")
+        slot = self.state.reserve() if self.state is not None else None
         self._reserved_unallocated += n
         self._publish()
-        return PageTable(n)
+        return PageTable(n, state_slot=slot)
 
     def release(self, table: PageTable):
         """Return a table's unconsumed reservation (retire path, and the
@@ -280,6 +504,10 @@ class PagedKVPool:
         if left > 0:
             self._reserved_unallocated -= left
         table.reserved = table.charged
+        if table.state_slot is not None:
+            with RecordEvent("engine/state_release", slot=table.state_slot):
+                self.state.release(table.state_slot)
+            table.state_slot = None
         self._publish()
 
     # -- page plumbing ------------------------------------------------------
@@ -617,6 +845,10 @@ class PagedKVPool:
                 "kv_dtype": self.dtype.name,
                 "quant_scale_clips": self.quant_scale_clips,
                 "occupancy": round(1.0 - free / self.num_pages, 4),
+                **({"state_slots_total": self.state.slots,
+                    "state_slots_used": self.state.used,
+                    "state_bytes": self.state.nbytes}
+                   if self.state is not None else {}),
             }
 
     def _publish(self):
@@ -646,6 +878,10 @@ class PagedKVPool:
                                and self._refcount[pid] == 1)]
             stale = [k for k, pid in self._prefix.items()
                      if pid not in self._radix_pinned]
+            if self.state is not None and self.state.used:
+                raise AssertionError(
+                    f"state leak: {self.state.used} of {self.state.slots} "
+                    "state slots held by retired sequences")
             if leaked or self._reserved_unallocated or stale:
                 raise AssertionError(
                     f"page leak: {len(leaked)} pages held by retired "
@@ -692,6 +928,16 @@ def budget_drift(pool: PagedKVPool, model=None) -> List[str]:
             f"kv_dtype: pool stores {pool.dtype.name}, plan records "
             f"{want_dtype.name} — the carve assumed "
             f"{want_dtype.itemsize}-byte pages")
+    have = pool.state.slot_bytes if pool.state is not None else 0
+    if int(fresh.get("state_slot_bytes", 0)) != have:
+        drift.append(
+            f"state_slot_bytes: pool holds {have} B a slot, page_budget "
+            f"derives {fresh.get('state_slot_bytes', 0)}")
+    if pool.state is not None and pool.state.slots != int(
+            fresh["max_slots"]):
+        drift.append(
+            f"state slots: pool has {pool.state.slots}, page_budget "
+            f"derives {fresh['max_slots']} under the recorded inputs")
     for key, live in (("pages", pool.num_pages),
                       ("page_tokens", pool.page_tokens),
                       ("num_layers", pool.num_layers),

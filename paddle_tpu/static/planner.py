@@ -1241,6 +1241,10 @@ def _model_config(model=None, config=None) -> Dict:
         raise ValueError(
             f"hidden_size {out['hidden_size']} not divisible by "
             f"num_heads {out['num_heads']}")
+    # the cache description (serving/kv_pool.py): what the model states,
+    # else one kv group of num_layers x num_heads
+    from ..serving.kv_pool import cache_spec_of
+    out["cache"] = cache_spec_of(config)
     return out
 
 
@@ -1375,9 +1379,21 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
     """
     import numpy as np
     from .memory_analysis import hbm_budget_bytes
+    from ..core.dtype import np_dtype
+    from ..serving.kv_pool import kv_geometry, state_slot_bytes
     cfg = _model_config(model, config)
-    L, H = cfg["num_layers"], cfg["num_heads"]
-    Dh = cfg["hidden_size"] // H
+    # pool geometry from the model's cache description: the kv group's
+    # layers x kv heads x head dim, and what a sequence's recurrent state
+    # holds whatever its length (0 without a `state` group)
+    L, H, Dh = kv_geometry(cfg["cache"])
+    state_slot = state_slot_bytes(cfg["cache"])
+    if state_slot and (int(tp_degree or 1) > 1 or draft_layers
+                       or str(weight_dtype) != "float32"
+                       or str(kv_dtype) != "float32"):
+        raise NotImplementedError(
+            "page_budget: a model with recurrent state is sized at tp 1, "
+            "float32 pages, its own weight dtype and no draft — sharded "
+            "state, quantized pages and state rollback are not built")
     T = int(page_tokens)
     if T < 1:
         raise ValueError(f"page_tokens must be >= 1, got {page_tokens}")
@@ -1392,14 +1408,21 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
     budget = int(hbm_bytes) if hbm_bytes else hbm_budget_bytes()
     if weight_bytes is None:
         if model is not None:
+            # shape x dtype, as `p.numpy().nbytes` gave it, without
+            # bringing every parameter across the host link to count it
             weight_bytes = int(sum(
-                np.asarray(p.numpy()).nbytes
+                int(np.prod(p.shape)) * np_dtype(p.dtype).itemsize
                 for p in getattr(model, "gpt", model).parameters()))
+        elif state_slot:
+            raise ValueError(
+                "page_budget: give weight_bytes (or the model) for a "
+                "config the GPT closed form does not describe")
         else:
             weight_bytes = _decode_weight_bytes(cfg)
     weight_bytes = int(weight_bytes)
     weight_bytes_fp32 = weight_bytes
-    shardable = min(weight_bytes, _decode_shardable_bytes(cfg))
+    shardable = 0 if state_slot else \
+        min(weight_bytes, _decode_shardable_bytes(cfg))
     weight_dtype = str(weight_dtype)
     if weight_dtype not in ("float32", "int8"):
         raise ValueError(
@@ -1461,7 +1484,7 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
             draft_kv_slot_pc += 2 * draft_layers * H_loc * 4
     usable = int(budget * (1.0 - float(headroom))) - weight_bytes_pc \
         - draft_weight_bytes_pc
-    if usable < page_bytes_pc + ws_col_pc * _next_pow2(ctx):
+    if usable < page_bytes_pc + ws_col_pc * _next_pow2(ctx) + 2 * state_slot:
         raise ValueError(
             f"page_budget: {budget} B HBM/chip leaves {usable} B after "
             f"{weight_bytes_pc} B of per-chip weights"
@@ -1475,13 +1498,19 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
     # view at the largest pow2 KV bucket, plus this row's REPLICATED
     # logits (the row-parallel head allreduces full vocab everywhere),
     # and the draft model's per-slot dense KV when speculating
+    # ... and, where sequences carry recurrent state, a second copy of the
+    # row's state: the step returns fresh state arrays while the old ones
+    # are still its arguments (the compiled route does not donate)
     ws_slot = ws_col_pc * _next_pow2(ctx) \
-        + cfg["vocab_size"] * 4 + draft_kv_slot_pc
-    max_slots = max(1, min(cap, int(usable * 0.35) // ws_slot))
-    pages = (usable - max_slots * ws_slot) // page_bytes_pc
+        + cfg["vocab_size"] * 4 + draft_kv_slot_pc + state_slot
+    # what a slot holds for good comes off the budget before pages are
+    # cut: its recurrent state, resident from engine start
+    slot_bytes = ws_slot + state_slot
+    max_slots = max(1, min(cap, int(usable * 0.35) // slot_bytes))
+    pages = (usable - max_slots * slot_bytes) // page_bytes_pc
     while pages < 1 and max_slots > 1:      # tiny budgets: trade slots back
         max_slots -= 1
-        pages = (usable - max_slots * ws_slot) // page_bytes_pc
+        pages = (usable - max_slots * slot_bytes) // page_bytes_pc
     if pages < 1:
         raise ValueError(
             f"page_budget: workspace for one slot leaves no room for "
@@ -1518,6 +1547,9 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
         "page_bytes": int(page_bytes),
         "kv_bytes": int(pages * page_bytes),
         "workspace_bytes": int(max_slots * ws_slot),
+        "cache": cfg["cache"],
+        "state_slot_bytes": int(state_slot),
+        "state_bytes": int(max_slots * state_slot),
         "weight_bytes": weight_bytes,
         "weight_bytes_fp32": weight_bytes_fp32,
         "tp_degree": tp,

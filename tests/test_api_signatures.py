@@ -1,7 +1,7 @@
 """API-freeze check (reference: tools/check_api_approvals.sh +
 print_signatures.py): the public signature dump must match the checked-in
 snapshot; intentional changes regenerate it with
-`python tools/print_signatures.py > tests/api_signatures.txt`."""
+`PYTHONPATH=. python tools/print_signatures.py > tests/api_signatures.txt`."""
 import os
 import importlib.util
 

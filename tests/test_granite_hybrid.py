@@ -1,0 +1,558 @@
+"""The hybrid decoder (Mamba-2 layers beside grouped-query attention
+layers): its ops, the model against the plain float32 reference, the
+state slots beside the page pool, and the engine's compiled step route.
+
+Tolerances.  Model and reference are both float32 here (conftest turns
+x64 on; neither uses it), so they differ only by the order of float32
+additions: the system scans in chunks, pads prompts to a bucket and
+batches rows where the reference steps token by token over one unpadded
+sequence.  Logits are compared in units of their own spread: the largest
+error must stay under LOGIT_SIGMAS = 1e-3 standard deviations of the
+reference's logits (seen: <= 3e-6) — three orders under the spread, and
+well under the ~sigma/50 that separates neighbours in a 128-way argmax, so
+a wrong column, a missed mask or a lower precision (bfloat16 rounds at
+4e-3 relative) cannot hide in it.  State and padded-vs-unpadded
+comparisons are between two float32 runs of the same system: a few ulps,
+rtol 1e-5 with STATE_ATOL = 1e-6 for values near zero.
+"""
+import os
+import sys
+import time
+
+import numpy as np
+import pytest
+
+import paddle_tpu
+import paddle_tpu.dygraph as dg
+import paddle_tpu.static as static
+from paddle_tpu.models import (GPTConfig, GPTModel, GraniteHybridConfig,
+                               GraniteHybridModel, granite_hybrid_tiny)
+from paddle_tpu.ops.registry import OpContext, get_op_info, run_kernel
+from paddle_tpu.serving import (ContinuousBatchingEngine, PagedKVPool,
+                                StateSlots, budget_drift)
+from paddle_tpu.serving.metrics import reset_serving_stats, serving_stats
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from benchmark.reference import granite_hybrid as reference  # noqa: E402
+
+LOGIT_SIGMAS = 1e-3
+STATE_ATOL = 1e-6
+
+
+def _assert_logits(got, want, spread=None):
+    spread = float(np.std(want)) if spread is None else spread
+    assert float(np.abs(np.asarray(got) - want).max()) \
+        <= LOGIT_SIGMAS * spread
+
+
+def _assert_state(got, want):
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=STATE_ATOL)
+NEW_OPS = ("rms_norm", "gated_rms_norm", "causal_conv1d",
+           "mamba2_chunk_scan", "mamba2_state_update", "gqa_attention")
+
+
+def _t(a, dtype=None):
+    return paddle_tpu.to_tensor(np.asarray(a, dtype))
+
+
+def _model(seed, **kw):
+    paddle_tpu.seed(seed)            # every test seeds its own weights
+    return granite_hybrid_tiny(**kw)
+
+
+def _published(cfg):
+    return {k: getattr(cfg, k) for k in cfg._HF_KEYS}
+
+
+def _reference_logits(m, ids):
+    return np.asarray(reference.logits(
+        reference.params_of(m), np.asarray(ids, np.int32),
+        _published(m.config)))
+
+
+def _prefill(m, ids, bucket):
+    p = len(ids)
+    padded = np.zeros((1, bucket), np.int32)
+    padded[0, :p] = ids
+    with dg.no_grad():
+        out = m.prefill_step(_t(padded), _t([p], np.int32),
+                             _t([p - 1], np.int32))
+    return [np.asarray(o.numpy()) for o in out]
+
+
+# -- ops --------------------------------------------------------------------
+@pytest.mark.parametrize("op", NEW_OPS)
+def test_new_ops_are_registered_forward_only(op):
+    info = get_op_info(op)
+    assert info is not None and info.grad is None
+    assert get_op_info(op + "_grad") is None
+    assert "Forward only" in info.kernel.__doc__
+
+
+def test_rms_norms_against_their_formulas():
+    rng = np.random.default_rng(0)
+    x, g, w = rng.normal(size=(3, 8)), rng.normal(size=(3, 8)), \
+        rng.normal(size=8)
+    x, g, w = (a.astype(np.float32) for a in (x, g, w))
+    ctx = OpContext()
+    got = run_kernel("rms_norm", {"X": x, "Scale": w}, {"epsilon": 1e-5},
+                     ctx)["Out"]
+    want = x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-5) * w
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    got = run_kernel("gated_rms_norm", {"X": x, "Gate": g, "Scale": w},
+                     {"epsilon": 1e-5}, ctx)["Out"]
+    y = x * (g / (1 + np.exp(-g)))          # the gate comes BEFORE the norm
+    want = y / np.sqrt((y * y).mean(-1, keepdims=True) + 1e-5) * w
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+
+
+def test_causal_conv_carries_its_tail_by_length():
+    rng = np.random.default_rng(1)
+    x = rng.normal(size=(2, 6, 5)).astype(np.float32)
+    w = rng.normal(size=(5, 4)).astype(np.float32)
+    b = rng.normal(size=5).astype(np.float32)
+    ctx = OpContext()
+    whole = run_kernel("causal_conv1d", {"X": x, "Weight": w, "Bias": b},
+                       {"activation": ""}, ctx)
+    padded = np.concatenate([np.zeros((2, 3, 5), np.float32), x], 1)
+    want = sum(padded[:, j:j + 6] * w[:, j] for j in range(4)) + b
+    np.testing.assert_allclose(whole["Out"], want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(whole["NewTail"], x[:, 3:])
+    # in two pieces through the tail, the second row stopping early
+    first = run_kernel("causal_conv1d",
+                       {"X": x[:, :4], "Weight": w, "Bias": b,
+                        "Lengths": np.array([4, 2], np.int32)},
+                       {"activation": ""}, ctx)
+    np.testing.assert_array_equal(first["NewTail"][0], x[0, 1:4])
+    np.testing.assert_array_equal(first["NewTail"][1][1:], x[1, :2])
+    assert not first["NewTail"][1][0].any()       # still the zero start
+    rest = run_kernel("causal_conv1d",
+                      {"X": x[:, 4:], "Weight": w, "Bias": b,
+                       "Tail": first["NewTail"],
+                       "Lengths": np.array([2, 0], np.int32)},
+                      {"activation": ""}, ctx)
+    np.testing.assert_allclose(rest["Out"][0], want[0, 4:], rtol=1e-5,
+                               atol=1e-6)
+    # a row of length 0 keeps its tail bit for bit
+    np.testing.assert_array_equal(rest["NewTail"][1], first["NewTail"][1])
+    # slab_index: the tail is entry 1 of a pool's whole array, replaced
+    slab = np.stack([np.full_like(first["NewTail"], 7.0),
+                     np.asarray(first["NewTail"])])
+    in_slab = run_kernel("causal_conv1d",
+                         {"X": x[:, 4:], "Weight": w, "Bias": b,
+                          "Tail": slab,
+                          "Lengths": np.array([2, 0], np.int32)},
+                         {"activation": "", "slab_index": 1}, ctx)
+    np.testing.assert_array_equal(in_slab["Out"], rest["Out"])
+    np.testing.assert_array_equal(in_slab["NewTail"][1], rest["NewTail"])
+    np.testing.assert_array_equal(in_slab["NewTail"][0], slab[0])
+
+
+def _scan_inputs(rng, b=2, t=19, h=4, p=8, n=6):
+    f = np.float32
+    return {"X": rng.normal(size=(b, t, h, p)).astype(f),
+            "Dt": rng.normal(size=(b, t, h)).astype(f),
+            "A": -rng.uniform(1, 16, h).astype(f),
+            "B": rng.normal(size=(b, t, 1, n)).astype(f),
+            "C": rng.normal(size=(b, t, 1, n)).astype(f),
+            "D": np.ones(h, f),
+            "DtBias": rng.normal(size=h).astype(f) - 3.0}
+
+
+@pytest.mark.parametrize("chunk", [4, 8, 0])
+def test_chunk_scan_equals_the_token_by_token_update(chunk):
+    """Chunks of 4 / 8 / the whole sequence against each other through
+    the one-token recurrence, which is the definition."""
+    ins = _scan_inputs(np.random.default_rng(2))
+    ctx = OpContext()
+    got = run_kernel("mamba2_chunk_scan", ins, {"chunk_size": chunk}, ctx)
+    state = np.zeros((2, 4, 8, 6), np.float32)
+    ys = []
+    for t in range(19):
+        step = run_kernel("mamba2_state_update", {
+            **{k: ins[k][:, t] for k in ("X", "Dt", "B", "C")},
+            **{k: ins[k] for k in ("A", "D", "DtBias")}, "State": state},
+            {}, ctx)
+        state = step["NewState"]
+        ys.append(np.asarray(step["Y"]))
+    np.testing.assert_allclose(got["Y"], np.stack(ys, 1), atol=2e-5)
+    _assert_state(got["FinalState"], state)
+    # slab_index: the state is entry 0 of a pool's whole array, replaced
+    slab = np.stack([np.asarray(state), np.full_like(state, 3.0)])
+    one = {**{k: ins[k][:, 0] for k in ("X", "Dt", "B", "C")},
+           **{k: ins[k] for k in ("A", "D", "DtBias")}}
+    plain = run_kernel("mamba2_state_update", {**one, "State": state}, {},
+                       ctx)
+    in_slab = run_kernel("mamba2_state_update", {**one, "State": slab},
+                         {"slab_index": 0}, ctx)
+    np.testing.assert_array_equal(in_slab["Y"], plain["Y"])
+    np.testing.assert_array_equal(in_slab["NewState"][0], plain["NewState"])
+    np.testing.assert_array_equal(in_slab["NewState"][1], slab[1])
+
+
+def test_scan_stops_at_each_rows_length_and_resumes_from_a_state():
+    ins = _scan_inputs(np.random.default_rng(3))
+    ctx = OpContext()
+    lengths = np.array([19, 11], np.int32)
+    masked = run_kernel("mamba2_chunk_scan", {**ins, "Lengths": lengths},
+                        {"chunk_size": 8}, ctx)
+    short = run_kernel(
+        "mamba2_chunk_scan",
+        {k: (v[1:, :11] if v.ndim > 1 else v) for k, v in ins.items()},
+        {"chunk_size": 8}, ctx)
+    _assert_state(masked["FinalState"][1], short["FinalState"][0])
+    np.testing.assert_allclose(masked["Y"][1, :11], short["Y"][0],
+                               atol=2e-5)
+    # the other half of row 1, from the state the first half left
+    tail = run_kernel(
+        "mamba2_chunk_scan",
+        {**{k: (v[1:, 11:] if v.ndim > 1 else v) for k, v in ins.items()},
+         "InitialState": short["FinalState"]}, {"chunk_size": 8}, ctx)
+    whole = run_kernel("mamba2_chunk_scan", ins, {"chunk_size": 8}, ctx)
+    _assert_state(tail["FinalState"][0], whole["FinalState"][1])
+
+
+def test_gqa_attention_shares_kv_heads_and_reads_a_masked_cache():
+    rng = np.random.default_rng(4)
+    f = np.float32
+    q = rng.normal(size=(2, 4, 3, 8)).astype(f)
+    k, v = (rng.normal(size=(2, 2, 3, 8)).astype(f) for _ in range(2))
+    kc, vc = (rng.normal(size=(2, 2, 5, 8)).astype(f) for _ in range(2))
+    seen = np.array([5, 2], np.int32)
+    got = run_kernel("gqa_attention", {
+        "Q": q, "K": k, "V": v, "KCache": kc, "VCache": vc,
+        "CacheLengths": seen}, {"scale": 0.3}, OpContext())["Out"]
+    for b in range(2):
+        keys = np.concatenate([kc[b, :, :seen[b]], k[b]], 1)
+        vals = np.concatenate([vc[b, :, :seen[b]], v[b]], 1)
+        for h in range(4):
+            s = q[b, h] @ keys[h // 2].T * 0.3      # [3, seen + 3]
+            for t in range(3):
+                s[t, seen[b] + t + 1:] = -np.inf    # causal among the new
+            p = np.exp(s - s.max(-1, keepdims=True))
+            want = p / p.sum(-1, keepdims=True) @ vals[h // 2]
+            np.testing.assert_allclose(got[b, h], want, rtol=2e-5,
+                                       atol=2e-6)
+
+
+# -- the model against the reference ----------------------------------------
+def test_parameter_count_at_published_widths_is_the_issues_sum():
+    cfg = GraniteHybridConfig()                 # shapes only: no allocation
+    assert cfg.layers_of("attention") == [5, 15, 25, 35]
+    assert cfg.param_count() == 3_191_396_096           # 3,191 M
+    assert round(cfg.param_count() * 2 / 1e9, 2) == 6.38    # GB, bfloat16
+    kv, state = cfg.cache_spec()
+    assert (kv["layers"], kv["kv_heads"], kv["head_dim"]) == (4, 8, 64)
+    assert state["layers"] == 36
+    from paddle_tpu.serving.kv_pool import state_slot_bytes
+    assert state_slot_bytes(cfg.cache_spec()) == 75_497_472 + 940_032
+
+
+def test_built_model_has_exactly_the_shapes_the_config_states():
+    with dg.guard():
+        m = _model(1)
+        built = {n: tuple(p.shape) for n, p in m.named_parameters()}
+    assert built == {n: tuple(s) for n, s in
+                     m.config.param_shapes().items()}
+    assert all(p.stop_gradient for p in m.parameters())
+
+
+def test_full_forward_matches_the_reference():
+    with dg.guard():
+        m = _model(2)
+        ids = np.random.default_rng(0).integers(0, 127, 21)
+        with dg.no_grad():
+            got = m(_t(ids[None], np.int32)).numpy()[0]
+    want = _reference_logits(m, ids)
+    _assert_logits(got, want)
+
+
+def test_prefill_then_decode_through_the_cache_matches_the_full_pass():
+    """Prefill of p tokens (padded to a bucket of 16) in batch row 1 of 3,
+    then n decode steps through the dense KV cache and the state arrays,
+    against the reference's full forward of p + n; row 2 is idle and holds
+    a made-up state, which every step must hand back bit for bit."""
+    p, n, rows, cache_len = 13, 8, 3, 32
+    with dg.guard():
+        m = _model(3)
+        c = m.config
+        ids = np.random.default_rng(1).integers(0, 127, p + n)
+        want = _reference_logits(m, ids)
+        logits, k, v, ssm, conv = _prefill(m, ids[:p], 16)
+        _assert_logits(logits[0], want[p - 1], want.std())
+        kc = np.zeros((1, rows, 2, cache_len, 16), np.float32)
+        vc = np.zeros_like(kc)
+        kc[:, 1, :, :p], vc[:, 1, :, :p] = k[:, 0, :, :p], v[:, 0, :, :p]
+        ssm_s = np.zeros((3, rows) + ssm.shape[2:], np.float32)
+        conv_s = np.zeros((3, rows) + conv.shape[2:], np.float32)
+        ssm_s[:, 1], conv_s[:, 1] = ssm[:, 0], conv[:, 0]
+        ssm_s[:, 2], conv_s[:, 2] = 0.5, 0.25
+        for t in range(n):
+            step_ids = np.zeros((rows, 1), np.int32)
+            step_ids[1, 0] = ids[p + t]
+            with dg.no_grad():
+                out = m.decode_step(
+                    _t(step_ids), _t([0, p + t, 0], np.int32),
+                    _t([0, 1, 0], np.int32), _t(kc), _t(vc), _t(ssm_s),
+                    _t(conv_s))
+            logits, kn, vn, ssm_n, conv_n = (np.asarray(o.numpy())
+                                             for o in out)
+            _assert_logits(logits[1], want[p + t], want.std())
+            for new, old in ((ssm_n, ssm_s), (conv_n, conv_s)):
+                np.testing.assert_array_equal(new[:, 2], old[:, 2])
+                np.testing.assert_array_equal(new[:, 0], old[:, 0])
+            kc[:, 1, :, p + t], vc[:, 1, :, p + t] = kn[:, 1, :, 0], \
+                vn[:, 1, :, 0]
+            ssm_s, conv_s = ssm_n, conv_n
+    assert c.layers_of("mamba") == [0, 1, 3]
+
+
+def test_padded_prompt_leaves_the_unpadded_prompts_state_and_logits():
+    with dg.guard():
+        m = _model(4)
+        ids = np.random.default_rng(2).integers(0, 127, 13)
+        exact = _prefill(m, ids, 13)
+        for bucket in (16, 32):
+            padded = _prefill(m, ids, bucket)
+            _assert_logits(padded[0], exact[0])
+            for got, want in zip(padded[3:], exact[3:]):    # ssm, conv
+                _assert_state(got, want)
+            for got, want in zip(padded[1:3], exact[1:3]):  # K, V columns
+                _assert_state(got[:, :, :, :13], want)
+
+
+def test_chunk_size_does_not_change_the_model():
+    ids = np.random.default_rng(3).integers(0, 127, 21)
+    got = []
+    for chunk in (4, 8, 64):
+        with dg.guard():
+            m = _model(5, mamba_chunk_size=chunk)
+            got.append(_prefill(m, ids, 32))
+    for other in got[1:]:
+        _assert_logits(other[0], got[0][0])
+        _assert_state(other[3], got[0][3])
+
+
+# -- the manager: pages and state slots --------------------------------------
+def test_state_slots_reserve_install_release():
+    reset_serving_stats()
+    spec = GraniteHybridConfig(
+        vocab_size=8, hidden_size=8, layer_types=["mamba"],
+        num_attention_heads=1, num_key_value_heads=1,
+        shared_intermediate_size=8, mamba_n_heads=2, mamba_d_head=8,
+        mamba_d_state=4).cache_spec()
+    slots = StateSlots(spec, 2)
+    assert slots.names == ["ssm", "conv"] and slots.used == 0
+    a, b = slots.reserve(), slots.reserve()
+    assert {a, b} == {0, 1} and not slots.can_reserve()
+    with pytest.raises(RuntimeError):
+        slots.reserve()
+    new = {n: np.full((v.shape[0], 1) + v.shape[2:], 3.0, np.float32)
+           for n, v in slots.arrays.items()}
+    slots.install(b, **new)
+    assert (slots.row(b)["ssm"] == 3).all() and not slots.row(a)["ssm"].any()
+    slots.release(b)
+    with pytest.raises(ValueError):
+        slots.release(b)
+    assert slots.reserve() == b
+    slots.install(b, **new)                     # a reused slot: overwritten
+    stats = serving_stats()
+    assert stats["serving.gen.state_resets"] == 1
+    assert stats["serving.state.slots_total"] == 2
+    assert stats["serving.state.slots_used"] == 2
+    assert stats["serving.state.bytes"] == slots.nbytes == 2 * slots.slot_bytes
+
+
+def test_state_slots_keep_the_steps_dense_kv_view_on_the_device():
+    """(layers 2, kv heads 1, context 8, head dim 4): a prefill's prompt
+    columns go in with its state, a step's new columns land at each row's
+    own position, and a view is the first columns of every row."""
+    spec = GraniteHybridConfig(
+        vocab_size=8, hidden_size=8, layer_types=["mamba"],
+        num_attention_heads=1, num_key_value_heads=1,
+        shared_intermediate_size=8, mamba_n_heads=2, mamba_d_head=8,
+        mamba_d_state=4).cache_spec()
+    slots = StateSlots(spec, 3, dense_kv=(2, 1, 8, 4, "float32"))
+    state = {n: np.zeros((v.shape[0], 1) + v.shape[2:], np.float32)
+             for n, v in slots.arrays.items()}
+    prompt = np.arange(2 * 1 * 1 * 5 * 4, dtype=np.float32).reshape(
+        2, 1, 1, 5, 4)
+    with pytest.raises(ValueError):
+        slots.install(1, **state)               # the prompt's KV is missing
+    slots.install(1, **state, k_dense=prompt, v_dense=-prompt)
+    cols = np.full((2, 3, 1, 1, 4), 9.0, np.float32)
+    slots.append_kv(cols, 2 * cols, [0, 5, 0])
+    k, v = (np.asarray(a) for a in slots.kv_view(8))
+    np.testing.assert_array_equal(k[:, 1, :, :5], prompt[:, 0])
+    np.testing.assert_array_equal(v[:, 1, :, :5], -prompt[:, 0])
+    assert (k[:, 1, :, 5] == 9).all() and (v[:, 1, :, 5] == 18).all()
+    assert not k[:, 1, :, 6:].any()
+    # an idle row's column lands at its position 0, which the row's next
+    # prefill overwrites; nothing else of the row is touched
+    assert (k[:, 2, :, 0] == 9).all() and not k[:, 2, :, 1:].any()
+    assert slots.kv_view(4)[0].shape == (2, 3, 1, 4, 4)
+    assert slots.slot_bytes == sum(
+        a.nbytes for a in slots.arrays.values()) // 3     # state only
+
+
+def test_page_budget_sizes_from_the_cache_description():
+    with dg.guard():
+        m = _model(6)
+        plan = static.page_budget(m, page_tokens=4, max_context=64,
+                                  hbm_bytes=8 << 20, max_slots_cap=3)
+        assert (plan["num_layers"], plan["num_heads"], plan["head_dim"]) \
+            == (1, 2, 16)                     # the ONE attention layer, GQA
+        assert plan["page_bytes"] == 2 * 1 * 2 * 16 * 4 * 4
+        assert plan["state_slot_bytes"] == 3 * (8 * 16 * 16 + 3 * 160) * 4
+        assert plan["state_bytes"] == 3 * plan["state_slot_bytes"]
+        # the state comes off the budget before pages are cut
+        stateless = dict(plan["config"], cache=[plan["cache"][0]])
+        more = static.page_budget(
+            config=stateless, page_tokens=4, max_context=64,
+            hbm_bytes=8 << 20, max_slots_cap=3,
+            weight_bytes=plan["weight_bytes"])
+        assert (more["pages"] - plan["pages"]) * plan["page_bytes"] \
+            >= 2 * plan["state_bytes"] - plan["page_bytes"]
+        pool = PagedKVPool.from_plan(plan)
+        assert pool.state.slots == 3 and budget_drift(pool, m) == []
+        pool.state = StateSlots(plan["cache"][1:], 2)
+        assert any("state slots" in d for d in budget_drift(pool, m))
+        for bad in (dict(tp_degree=2), dict(draft_layers=1),
+                    dict(kv_dtype="int8")):
+            with pytest.raises(NotImplementedError):
+                static.page_budget(m, hbm_bytes=8 << 20, **bad)
+        # a GPT states one kv group of layers x heads: as before
+        g = static.page_budget(GPTModel(GPTConfig(
+            vocab_size=50, hidden_size=16, num_layers=2, num_heads=2,
+            max_position=32)), page_tokens=4, hbm_bytes=4 << 20)
+        assert (g["num_layers"], g["num_heads"], g["head_dim"]) == (2, 2, 8)
+        assert g["state_slot_bytes"] == 0 and g["cache"] == [
+            {"kind": "kv", "layers": 2, "kv_heads": 2, "head_dim": 8}]
+
+
+def test_pool_reserves_and_releases_the_state_slot_with_the_pages():
+    with dg.guard():
+        m = _model(7)
+        pool = PagedKVPool.from_plan(static.page_budget(
+            m, page_tokens=4, max_context=64, hbm_bytes=8 << 20,
+            max_slots_cap=2))
+    t1, t2 = pool.reserve(3), pool.reserve(3)
+    assert {t1.state_slot, t2.state_slot} == {0, 1}
+    assert not pool.can_reserve(1)          # pages are there, slots are not
+    with pytest.raises(AssertionError, match="state leak"):
+        pool.assert_drained()
+    pool.close_sequence(t1)
+    assert t1.state_slot is None and pool.can_reserve(1)
+    pool.close_sequence(t2)
+    pool.assert_drained()
+    assert pool.stats()["state_slots_used"] == 0
+
+
+# -- through the engine -------------------------------------------------------
+def _greedy(m, prompt, n, width=64):
+    """One sequence, no cache: the full forward again for every token.
+    The sequence sits in a buffer of one width (the model is causal: what
+    follows a position cannot reach it), so one shape is compiled."""
+    ids = list(prompt)
+    for _ in range(n):
+        buf = np.zeros((1, width), np.int32)
+        buf[0, :len(ids)] = ids
+        with dg.no_grad():
+            row = m(_t(buf)).numpy()[0, len(ids) - 1]
+        ids.append(int(row.argmax()))
+        if ids[-1] == m.config.eos_id:
+            break
+    return ids
+
+
+def test_engine_serves_the_hybrid_model_token_equal_to_one_sequence():
+    """Two requests of different lengths admitted at different steps, then
+    more requests than slots so that slots are reused after retirement:
+    greedy tokens equal one-sequence decoding with no cache at all."""
+    reset_serving_stats()
+    with dg.guard():
+        m = _model(8)
+        plan = static.page_budget(m, page_tokens=4, max_context=128,
+                                  hbm_bytes=8 << 20, max_slots_cap=2)
+        eng = ContinuousBatchingEngine(m, kv_pool=plan).start()
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(0, 126, n) for n in (5, 19, 33, 7)]
+        news = (9, 4, 6, 5)
+        futs = [eng.submit(prompts[0], max_length=news[0])]
+        while not eng.active_slots:           # the first is decoding ...
+            time.sleep(0.01)
+        futs += [eng.submit(p, max_length=n)  # ... when the others arrive
+                 for p, n in zip(prompts[1:], news[1:])]
+        outs = [f.result(timeout=600) for f in futs]
+        programs = eng._steps.programs
+        for prompt, n, out in zip(prompts, news, outs):
+            assert list(out) == _greedy(m, prompt, n)
+        # a fifth request changes no shape: nothing is traced again
+        again = eng.submit(prompts[1], max_length=news[1]).result(600)
+        assert list(again) == list(outs[1])
+        assert eng._steps.programs == programs
+        eng.stop()
+        eng.kv_pool.assert_drained()
+        assert budget_drift(eng.kv_pool, m) == []
+    stats = serving_stats()
+    # the step reads the dense view kept on the device: nothing is
+    # gathered from the pages, which hold every column all the same
+    assert stats.get("serving.kv.gather_bytes", 0) == 0
+    assert stats["serving.kv.append_bytes"] > 0
+    assert stats["serving.gen.state_resets"] >= 2    # 5 sequences, 2 slots
+    assert stats["serving.state.slots_used"] == 0
+    ops = {op.type for cp in eng._steps._decode._cache.values()
+           for op in cp.program.global_block().ops}
+    assert {"mamba2_state_update", "causal_conv1d", "gqa_attention",
+            "rms_norm", "gated_rms_norm"} <= ops
+
+
+def test_engine_refuses_what_a_recurrent_state_cannot_have_yet():
+    with dg.guard():
+        m = _model(9)
+        plan = static.page_budget(m, page_tokens=4, max_context=64,
+                                  hbm_bytes=8 << 20, max_slots_cap=2)
+        with pytest.raises(NotImplementedError, match="snapshot"):
+            ContinuousBatchingEngine(m, kv_pool=plan, prefix_cache="auto")
+        with pytest.raises(NotImplementedError, match="rollback"):
+            ContinuousBatchingEngine(m, kv_pool=plan, speculative="auto")
+        with pytest.raises(ValueError, match="paged pool"):
+            ContinuousBatchingEngine(m)
+        for heads, why in ((4, "geometry"), (2, "no state slots")):
+            gpt_plan = static.page_budget(GPTModel(GPTConfig(
+                vocab_size=128, hidden_size=32, num_layers=1,
+                num_heads=heads, max_position=64)), page_tokens=4,
+                hbm_bytes=4 << 20)
+            with pytest.raises(ValueError, match=why):
+                ContinuousBatchingEngine(m, kv_pool=gpt_plan)
+
+
+def test_abstract_trace_records_the_same_program_without_running_it():
+    """`StaticFunction(abstract_trace=True)` (what `StepPrograms` uses):
+    the Program is recorded from shapes, the compiled run gives what the
+    eagerly traced one gives, and a function that reads a tensor's value
+    while it is traced is refused by JAX, not silently mis-traced."""
+    import jax
+    from paddle_tpu.jit import StaticFunction
+    with dg.guard():
+        w = _t(np.arange(6, dtype=np.float32).reshape(2, 3))
+
+        def f(x):
+            return paddle_tpu.matmul(x, w) * 2.0 + 1.0
+
+        x = _t(np.ones((4, 2), np.float32))
+        eager, abstract = StaticFunction(f), StaticFunction(
+            f, abstract_trace=True)
+        with dg.no_grad():
+            np.testing.assert_array_equal(abstract(x).numpy(),
+                                          eager(x).numpy())
+        ops = [[op.type for op in sf.concrete_program(x).program
+                .global_block().ops] for sf in (eager, abstract)]
+        assert ops[0] == ops[1] and ops[0]
+        assert not isinstance(x._value, jax.core.Tracer)    # put back
+
+        def peeks(x):
+            return x * float(x.numpy().sum())
+
+        with pytest.raises(Exception), dg.no_grad():
+            StaticFunction(peeks, abstract_trace=True)(x)

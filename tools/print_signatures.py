@@ -10,6 +10,7 @@ INTENTIONAL change with:
 """
 from __future__ import annotations
 
+import enum
 import inspect
 import sys
 
@@ -55,7 +56,12 @@ def iter_api():
             obj = getattr(mod, name, None)
             if obj is None or inspect.ismodule(obj):
                 continue
-            if inspect.isclass(obj):
+            if inspect.isclass(obj) and issubclass(obj, enum.Enum):
+                # an Enum's surface is its members; its constructor is
+                # `Enum.__call__`, inherited, and its signature differs by
+                # Python version
+                yield f"{mod_name}.{name}[{', '.join(obj.__members__)}]"
+            elif inspect.isclass(obj):
                 yield f"{mod_name}.{name}{_sig(obj.__init__)}"
                 for m_name, m in sorted(vars(obj).items()):
                     if m_name.startswith("_") or not callable(m):
