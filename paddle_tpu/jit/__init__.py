@@ -133,34 +133,51 @@ class ConcreteProgram:
     running stats etc., whose dygraph layers rebind via set_value)."""
 
     def __init__(self, program, feed_names, fetch_names, params,
-                 out_struct, updates=None):
+                 out_struct, updates=None, donated=()):
         self.program = program
         self.feed_names = feed_names
         self.fetch_names = fetch_names
         self.params = params            # name -> Tensor (live, mutable)
         self.out_struct = out_struct    # "single" | "tuple" | "list"
         self.updates = dict(updates or {})
+        # indices into feed_names whose buffers the run may write into:
+        # those feeds are dead after every call (`StaticFunction`)
+        self.donated = tuple(sorted(set(donated)))
         self._composed = None
 
+    def split_feeds(self, raws):
+        """The feeds' raw values, in feed order, as `composed`'s
+        (input_raws, donated_raws)."""
+        return (tuple(r for i, r in enumerate(raws)
+                      if i not in self.donated),
+                tuple(raws[i] for i in self.donated))
+
     def composed(self):
-        """(seed, is_test, param_raws, input_raws) ->
-        (fetch raws + buffer-update raws), jitted."""
+        """(seed, param_raws, input_raws, is_test[, donated_raws]) ->
+        (fetch raws + buffer-update raws), jitted.  `input_raws` are the
+        feeds that are kept, in feed order; the `donated` feeds go in as
+        the last argument, which is donated to XLA."""
         if self._composed is None:
             from ..static.executor import BlockTracer
             tracer = BlockTracer(self.program.global_block())
-            pnames, fnames = list(self.params), list(self.feed_names)
+            pnames = list(self.params)
+            fnames = [n for i, n in enumerate(self.feed_names)
+                      if i not in self.donated] \
+                + [self.feed_names[i] for i in self.donated]
             onames = list(self.fetch_names) + list(self.updates.values())
 
-            def fn(seed, param_raws, input_raws, is_test):
+            def fn(seed, param_raws, input_raws, is_test, donated_raws=()):
                 env = dict(zip(pnames, param_raws))
-                env.update(zip(fnames, input_raws))
+                env.update(zip(fnames, (*input_raws, *donated_raws)))
                 ctx = OpContext(seed=seed, is_test=is_test)
                 # sub-block ops (dy2static cond) resolve their blocks here
                 ctx.program = self.program
                 tracer.run(env, ctx)
                 return tuple(env[n] for n in onames)
 
-            self._composed = jax.jit(fn, static_argnames=("is_test",))
+            self._composed = jax.jit(
+                fn, static_argnames=("is_test",),
+                donate_argnames=("donated_raws",) if self.donated else ())
         return self._composed
 
 
@@ -171,8 +188,16 @@ class StaticFunction:
     jax.vjp over the whole computation."""
 
     def __init__(self, fn, input_spec=None, layer: Optional[Layer] = None,
-                 abstract_trace: bool = False):
-        """``abstract_trace``: record the Program from shapes alone — the
+                 abstract_trace: bool = False,
+                 donate_args: Sequence[int] = ()):
+        """``donate_args``: positions of the call's Tensor arguments whose
+        device buffers the compiled run may write its results into (XLA
+        aliases an output of the same shape and dtype to each).  After
+        every call those arguments are DEAD — their arrays deleted — so
+        the caller must take the results in their place; such a function
+        runs forward only.  Default none: nothing is donated.
+
+        ``abstract_trace``: record the Program from shapes alone — the
         trace-time call of ``fn`` sees its tensor arguments as abstract
         values (``jax.eval_shape``), so no kernel runs and no per-op
         executable is compiled for a result the compiled run recomputes
@@ -183,6 +208,7 @@ class StaticFunction:
         self._input_spec = input_spec
         self._layer = layer
         self._abstract_trace = bool(abstract_trace)
+        self._donate_args = tuple(int(i) for i in donate_args)
         self._cache: Dict[Tuple, ConcreteProgram] = {}
 
     @staticmethod
@@ -239,7 +265,7 @@ class StaticFunction:
     def _trace(self, args) -> ConcreteProgram:
         program = Program()
         rec = _Recorder(program)
-        feed_names = []
+        feed_names, donated = [], []
         for i, a in enumerate(args):
             if not isinstance(a, Tensor):
                 continue
@@ -248,7 +274,13 @@ class StaticFunction:
                 name=name, shape=tuple(a.shape), dtype=a.dtype,
                 is_data=True)
             rec.register(a, name)
+            if i in self._donate_args:
+                donated.append(len(feed_names))
             feed_names.append(name)
+        if len(donated) != len(self._donate_args):
+            raise TypeError(
+                f"to_static: donate_args {self._donate_args} must be "
+                f"positions of Tensor arguments, the call has {len(args)}")
 
         prev = dytracer._PROGRAM_RECORDER
         dytracer._PROGRAM_RECORDER = rec
@@ -303,7 +335,7 @@ class StaticFunction:
                     pt._value = rec.initial_raw[pname]
                     break
         return ConcreteProgram(program, feed_names, fetch_names,
-                               dict(rec.params), struct, updates)
+                               dict(rec.params), struct, updates, donated)
 
     def _call_for_trace(self, args):
         """The one call of the traced function: eager on the arguments'
@@ -334,7 +366,8 @@ class StaticFunction:
                             "arguments only (trace-time contract)")
         args = self._to_tensors(args)
         cp = self.concrete_program(*args)
-        input_raws = tuple(a._value for a in args if isinstance(a, Tensor))
+        input_raws, donated_raws = cp.split_feeds(
+            [a._value for a in args if isinstance(a, Tensor)])
         param_ts = [cp.params[n] for n in cp.params]
         param_raws = tuple(t._value for t in param_ts)
         from ..core.generator import global_seed
@@ -349,8 +382,13 @@ class StaticFunction:
             or any(isinstance(a, Tensor) and not a.stop_gradient
                    for a in args))
         n_fetch = len(cp.fetch_names)
+        if needs_grad and cp.donated:
+            raise TypeError(
+                "to_static: a function that donates arguments runs "
+                "forward only (call it under no_grad)")
         if not needs_grad:
-            out_raws = fn(seed, param_raws, input_raws, is_test)
+            out_raws = fn(seed, param_raws, input_raws, is_test,
+                          donated_raws)
             outs = [Tensor(r) for r in out_raws[:n_fetch]]
         else:
             out_raws, vjp_fn = jax.vjp(

@@ -22,10 +22,15 @@ what a prefill rounds away every later decode step inherits).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ..attention import _interpret
 from ..registry import register_op
 
 _F32 = jnp.float32
@@ -195,6 +200,111 @@ def mamba2_chunk_scan(ins, attrs, ctx):
     return {"Y": y, "FinalState": final}
 
 
+# a row's entry [H * P, N] goes through VMEM whole, in and out, each
+# double-buffered: four of them must fit beside the small operands
+_SLAB_VMEM_BYTES = 12 << 20
+_TILE = 128     # rows and lanes of the square blocks the kernel transposes
+
+
+def _slab_update_fits(slab, h, p, n):
+    """Whether `_slab_update` takes this state array: float32, a row's
+    entry made of whole [128, 128] blocks, each block whole heads or a
+    part of one head on whole (8, 128) registers, four such entries within
+    the VMEM budget.  Read from shapes alone: the same answer on every
+    backend."""
+    return (slab.dtype == _F32 and n % _TILE == 0 and p % 8 == 0
+            and (h * p) % _TILE == 0
+            and (_TILE % p == 0 or p % _TILE == 0)
+            and 4 * h * p * n * 4 <= _SLAB_VMEM_BYTES)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _slab_update(slab, index, decay, xdt, bm, cm, interpret):
+    """``slab[index] <- decay * slab[index] + xdt (x) bm`` where it lies,
+    and the read-out ``sum_n(new * cm)`` from the values being written:
+    ONE read and ONE write of the layer's entry (a Pallas kernel; XLA
+    keeps the in-place write and the reduction as two fusions that each
+    read the entry).  slab [L, B, H, P, N] float32, aliased to the first
+    result; index an int32 scalar; decay [B, H]; xdt [B, H, P]; bm, cm
+    [B, G, N].  Returns (the slab, y [B, H, P]).
+
+    `index` is data (a prefetched scalar the block indices read) and the
+    function a `jax.jit` of its own, so every layer of every decode
+    program shares ONE trace and ONE lowering of the kernel: with the
+    index baked in, 36 kernels a program were traced and lowered apiece
+    and the cell's warm set-up went from 105 to 157 s (644 s with the
+    kernel unrolled; 110-117 s now; PERF.md section 6, PR 28).
+
+    One grid step a batch row; its entry is walked as [H * P, N] in
+    [128, 128] blocks, a few to a loop iteration (one block an iteration
+    leaves the units idle between blocks: 169 us a layer on the v5e
+    against 106-109 for two to eight, where a bare copy of the entry
+    through VMEM takes 104).  `xdt` and `y` stay lane-dense ([B, H * P /
+    128, 128]): a block's 128 values of `xdt` become per-row factors, and
+    its 128 row sums a lane-dense row of `y`, through one 128 x 128
+    transpose each — cheaper than a lane broadcast and a lane reduction a
+    register (118 us)."""
+    layers, b, h, p, n = slab.shape
+    r, rows = h // bm.shape[1], h * p
+    blocks = rows // _TILE
+    size = min(p, _TILE)            # rows of one head inside a block
+    unroll = next(u for u in (8, 4, 2, 1) if blocks % u == 0)
+
+    def kernel(index_ref, decay_ref, x_ref, b_ref, c_ref, s_ref, new_ref,
+               y_ref):
+        del index_ref               # read by the block indices
+        row = pl.program_id(0)
+
+        def block(k):
+            x_rows = jnp.broadcast_to(x_ref[0, pl.ds(k, 1), :],
+                                      (_TILE, _TILE)).T   # [i, :] = xdt[i]
+            y = jnp.zeros((1, _TILE), _F32)
+            for n0 in range(0, n, _TILE):
+                lanes = slice(n0, n0 + _TILE)
+                weighted = []
+                for j in range(_TILE // size):
+                    head = k * (_TILE // p) + j if p <= _TILE \
+                        else k * _TILE // p
+                    at = pl.ds(pl.multiple_of(k * _TILE + j * size, 8), size)
+                    group = pl.ds(head // r, 1)
+                    new = decay_ref[row, head] * s_ref[0, 0, at, lanes] \
+                        + x_rows[j * size:(j + 1) * size] \
+                        * b_ref[0, group, lanes]
+                    new_ref[0, 0, at, lanes] = new
+                    weighted.append(new * c_ref[0, group, lanes])
+                y = y + jnp.sum(jnp.concatenate(weighted, axis=0).T, axis=0,
+                                keepdims=True)
+            y_ref[0, pl.ds(k, 1), :] = y
+
+        def several(i, carry):
+            for u in range(unroll):
+                block(i * unroll + u)
+            return carry
+
+        lax.fori_loop(0, blocks // unroll, several, 0)
+
+    entry = pl.BlockSpec((1, 1, rows, n),
+                         lambda i, index_ref: (index_ref[0], i, 0, 0))
+    per_row = [pl.BlockSpec((1,) + v.shape[1:], lambda i, _: (i, 0, 0))
+               for v in (bm, cm)]
+    dense = pl.BlockSpec((1, blocks, _TILE), lambda i, _: (i, 0, 0))
+    new, y = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), dense,
+                      *per_row, entry],
+            out_specs=[entry, dense]),
+        out_shape=[jax.ShapeDtypeStruct((layers, b, rows, n), _F32),
+                   jax.ShapeDtypeStruct((b, blocks, _TILE), _F32)],
+        input_output_aliases={5: 0},
+        interpret=interpret,
+        name="mamba2_state_update",
+    )(index.reshape(1), decay, xdt.reshape(b, blocks, _TILE), bm, cm,
+      slab.reshape(layers, b, rows, n))      # the reshapes are bitcasts
+    return new.reshape(slab.shape), y.reshape(b, h, p)
+
+
 @register_op("mamba2_state_update",
              inputs=["X", "Dt", "A", "B", "C", "D", "State", "DtBias?",
                      "Lengths?!"],
@@ -209,7 +319,9 @@ def mamba2_state_update(ins, attrs, ctx):
     is what bounds them.  attr ``slab_index`` >= 0: State is the whole
     ``[layers, B, H, P, N]`` array of a state pool, this layer's state is
     ``State[slab_index]`` and NewState the whole array with that entry
-    replaced — read once, written once, in place.  Forward only."""
+    replaced — in place where the caller has donated the array, and, for
+    shapes `_slab_update` takes, in one pass: the entry read once and
+    written once, the read-out taken from what is written.  Forward only."""
     # a kernel of its own on the device: without the barriers XLA fuses
     # the state's read or its write into a neighbour's fusion, which then
     # carries the neighbour's scope (the first chip trace read 158% of
@@ -217,20 +329,26 @@ def mamba2_state_update(ins, attrs, ctx):
     x, slab, dt_in, b_in, c_in = lax.optimization_barrier(
         (ins["X"], ins["State"], ins["Dt"], ins["B"], ins["C"]))
     index = int(attrs.get("slab_index", -1))
-    s = (slab[index] if index >= 0 else slab).astype(_F32)
     b, h, p = x.shape
     g = b_in.shape[1]
     valid = _valid(ins.get("Lengths"), b, 1)[:, 0]
     dt, da = _dt_and_decay(dt_in, ins.get("DtBias"), ins["A"], valid)
-    bm = jnp.repeat(b_in.astype(_F32), h // g, axis=1)      # [B,H,N]
-    cm = jnp.repeat(c_in.astype(_F32), h // g, axis=1)
     x32 = x.astype(_F32)
-    new = jnp.exp(da)[:, :, None, None] * s \
-        + (x32 * dt[..., None])[..., None] * bm[:, :, None, :]
-    y = jnp.sum(new * cm[:, :, None, :], axis=-1) \
-        + x32 * ins["D"].astype(_F32)[None, :, None]
-    if index >= 0:
-        new = slab.at[index].set(new.astype(slab.dtype))
+    b32, c32 = b_in.astype(_F32), c_in.astype(_F32)         # [B,G,N]
+    if index >= 0 and _slab_update_fits(slab, h, p, b32.shape[-1]):
+        new, y = _slab_update(slab, jnp.int32(index), jnp.exp(da),
+                              x32 * dt[..., None], b32, c32,
+                              interpret=_interpret())
+    else:
+        s = (slab[index] if index >= 0 else slab).astype(_F32)
+        bm = jnp.repeat(b32, h // g, axis=1)                # [B,H,N]
+        cm = jnp.repeat(c32, h // g, axis=1)
+        new = jnp.exp(da)[:, :, None, None] * s \
+            + (x32 * dt[..., None])[..., None] * bm[:, :, None, :]
+        y = jnp.sum(new * cm[:, :, None, :], axis=-1)
+        if index >= 0:
+            new = slab.at[index].set(new.astype(slab.dtype))
+    y = y + x32 * ins["D"].astype(_F32)[None, :, None]
     y, new = lax.optimization_barrier((y.astype(x.dtype), new))
     return {"Y": y, "NewState": new}
 

@@ -622,6 +622,11 @@ class ContinuousBatchingEngine:
 
     def _fail_all(self, err):
         with self._mu:
+            if self._stateful:
+                # a step that raised had already given its state arrays
+                # away: every sequence is failed below, so the pool starts
+                # again from zeroed arrays in place of the dead ones
+                self._pool.state.recover()
             for i, slot in enumerate(self._slots):
                 if slot is not None:
                     if not slot.req.future.done():
@@ -949,7 +954,10 @@ class ContinuousBatchingEngine:
         state arrays as they sit on the device; out come a logits
         row a slot, the new KV column a slot — appended to the view and,
         for the record, to the pages — and the updated state arrays (an
-        idle row's state comes back as it went in)."""
+        idle row's state comes back as it went in).  The step is GIVEN
+        the state arrays: they are donated through the compiled program
+        and dead when it returns, so `rebind` follows the call at once
+        (a step that raises: `_fail_all` -> `StateSlots.recover`)."""
         with self._mu:
             for i, s in enumerate(self._slots):
                 if s is not None and s.req.future.cancelled():

@@ -166,10 +166,22 @@ class StateSlots:
 
     One array per entry of each ``state`` group of the cache description,
     ``[layers, slots, *shape]``, allocated ONCE here; a sequence owns slot
-    ``i`` (row ``i`` of every array) from ``reserve`` to ``release``.  The
-    decode step reads and writes the whole arrays (``arrays`` /
-    ``rebind``); ``install`` writes one slot from a prefill's result in
-    place (the old array is donated).  A prefill starts from zero state
+    ``i`` (row ``i`` of every array) from ``reserve`` to ``release``.
+
+    ONE COPY OF THE STATE EXISTS, and whoever writes it owns its buffers
+    while it does.  The decode step takes ``arrays`` whole and DONATES them
+    through its compiled program (``StepPrograms.decode``): XLA updates
+    each layer's entry where it lies, and when the step returns the arrays
+    that went in are dead (``is_deleted()``).  ``rebind`` is therefore not
+    optional: it must take the step's results before anything reads the
+    state again, so ``row`` and ``install`` only ever see rebound arrays
+    (``serving.gen.state_in_place`` counts the steps whose old arrays were
+    all dead at ``rebind``, ``serving.gen.state_copied`` those where a
+    backend ignored the donation and copied).  A step that raises after it
+    has donated leaves nothing to rebind: ``recover`` puts zeroed arrays
+    in place of dead ones (every sequence is failed then anyway).
+    ``install`` writes one slot from a prefill's result in place, by the
+    same hand-over (the old array is donated).  A prefill starts from zero state
     and its result overwrites the whole slot, so a reused slot never
     shows its last owner's state (``serving.gen.state_resets`` counts
     those overwrites).
@@ -286,14 +298,31 @@ class StateSlots:
             self.dense[name] = self._append(self.dense[name], cols, pos)
 
     def rebind(self, **new):
-        """Take a decode step's updated arrays in place of the old."""
+        """Take a decode step's updated arrays in place of the old, which
+        the step was given to write into: all of them, after every step."""
+        if sorted(new) != sorted(self.arrays):
+            raise ValueError(f"rebind needs {sorted(self.arrays)}, got "
+                             f"{sorted(new)}")
+        in_place = True
         for name, value in new.items():
             old = self.arrays[name]
             if value.shape != old.shape or value.dtype != old.dtype:
                 raise ValueError(
                     f"state array {name!r}: step returned {value.dtype}"
                     f"{value.shape}, the slab is {old.dtype}{old.shape}")
+            in_place &= old.is_deleted()
             self.arrays[name] = value
+        metrics.count("gen.state_in_place" if in_place
+                      else "gen.state_copied")
+
+    def recover(self):
+        """After a failed step or install: zeroed arrays in place of the
+        ones a donation left dead."""
+        import jax.numpy as jnp
+        for where in (self.arrays, self.dense):
+            for name, a in where.items():
+                if a.is_deleted():
+                    where[name] = jnp.zeros(a.shape, a.dtype)
 
     def row(self, slot: int) -> Dict[str, np.ndarray]:
         """Host copies of one slot's state (tests and debugging: this is

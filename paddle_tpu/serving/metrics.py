@@ -8,7 +8,12 @@ whole serving story from a single ``/stats`` scrape:
               serving.gen.admitted / completed / steps / tokens,
               serving.gen.prefills / queue_wait_us / h2d_bytes / d2h_bytes,
               serving.kv.gather_bytes / append_bytes (the engine's span
-              sites, docs/observability.md "Spans")
+              sites, docs/observability.md "Spans"),
+              serving.gen.state_resets (a prefill overwriting a state slot
+              that held a sequence before), serving.gen.state_in_place /
+              state_copied (decode steps whose donated state arrays were
+              all dead afterwards / steps where one was not: a backend
+              that ignored the donation)
   gauges      serving.queue.depth, serving.batch.last_size,
               serving.gen.active_slots, serving.server.inflight
   histograms  serving.latency_ms (end-to-end request latency),

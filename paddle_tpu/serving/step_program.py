@@ -15,9 +15,16 @@ is wrapped in `paddle_tpu.jit.to_static`'s `StaticFunction`: traced once
 per argument signature — the engine's power-of-two buckets — into a
 Program (from shapes alone, `abstract_trace`: nothing runs eagerly) and
 run as one jitted XLA computation, under `eval()` and `no_grad`, so no
-tape is kept.  The route does not donate its arguments:
-a decode step's state arrays are copied (old and new both live until the
-engine rebinds them; `static.page_budget` prices the second copy).
+tape is kept.
+
+THE DECODE STEP OWNS THE STATE ARRAYS while it runs: the contract's
+`*state` arguments of `decode_step` — every position from `DECODE_STATE_AT`
+on — are donated through the compiled program, whose `*state` results
+have their shapes and dtypes, so XLA writes each layer's new state where
+the old one lies and no second copy of the state exists.  After `decode`
+returns (or raises) the arrays that went in are dead: the caller takes the
+results in their place (`StateSlots.rebind`).  Nothing else is donated: no
+result has the shape of the ids, the lengths or the KV view's slices.
 
 `GPTModel` is not on this route yet (ROADMAP S2b): its eager forward has
 no such methods.
@@ -27,12 +34,16 @@ from __future__ import annotations
 from ..dygraph.base import no_grad
 from ..dygraph.tensor import Tensor
 
-__all__ = ["StepPrograms"]
+__all__ = ["StepPrograms", "DECODE_STATE_AT"]
+
+# decode_step(ids, cache_lengths, active, k_cache, v_cache, *state)
+DECODE_STATE_AT = 5
 
 
 class StepPrograms:
     def __init__(self, model):
         from ..jit import StaticFunction
+        from .kv_pool import cache_spec_of, state_groups
         for name in ("prefill_step", "decode_step"):
             if not callable(getattr(model, name, None)):
                 raise TypeError(
@@ -44,8 +55,11 @@ class StepPrograms:
         # compile hundreds of per-op programs to throw their results away
         self._prefill = StaticFunction(model.prefill_step, layer=model,
                                        abstract_trace=True)
-        self._decode = StaticFunction(model.decode_step, layer=model,
-                                      abstract_trace=True)
+        n_state = sum(len(g["arrays"]) for g in
+                      state_groups(cache_spec_of(model.config)))
+        self._decode = StaticFunction(
+            model.decode_step, layer=model, abstract_trace=True,
+            donate_args=range(DECODE_STATE_AT, DECODE_STATE_AT + n_state))
 
     @property
     def programs(self) -> int:
@@ -57,6 +71,8 @@ class StepPrograms:
             return self._prefill(ids, lengths, last)
 
     def decode(self, ids, cache_lengths, active, k_cache, v_cache, *state):
+        """One decode step.  `state`: the raw device arrays, DONATED — dead
+        when this returns; the `*state` results replace them."""
         with no_grad():
             return self._decode(ids, cache_lengths, active, k_cache,
                                 v_cache, *[Tensor(s) for s in state])

@@ -1484,7 +1484,7 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
             draft_kv_slot_pc += 2 * draft_layers * H_loc * 4
     usable = int(budget * (1.0 - float(headroom))) - weight_bytes_pc \
         - draft_weight_bytes_pc
-    if usable < page_bytes_pc + ws_col_pc * _next_pow2(ctx) + 2 * state_slot:
+    if usable < page_bytes_pc + ws_col_pc * _next_pow2(ctx) + state_slot:
         raise ValueError(
             f"page_budget: {budget} B HBM/chip leaves {usable} B after "
             f"{weight_bytes_pc} B of per-chip weights"
@@ -1498,13 +1498,13 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
     # view at the largest pow2 KV bucket, plus this row's REPLICATED
     # logits (the row-parallel head allreduces full vocab everywhere),
     # and the draft model's per-slot dense KV when speculating
-    # ... and, where sequences carry recurrent state, a second copy of the
-    # row's state: the step returns fresh state arrays while the old ones
-    # are still its arguments (the compiled route does not donate)
     ws_slot = ws_col_pc * _next_pow2(ctx) \
-        + cfg["vocab_size"] * 4 + draft_kv_slot_pc + state_slot
+        + cfg["vocab_size"] * 4 + draft_kv_slot_pc
     # what a slot holds for good comes off the budget before pages are
-    # cut: its recurrent state, resident from engine start
+    # cut: its recurrent state, resident from engine start — ONE copy: the
+    # decode step is given the state arrays to write into (they are
+    # donated through the compiled program, `serving/step_program.py`), so
+    # no second copy of a row's state exists while a step runs
     slot_bytes = ws_slot + state_slot
     max_slots = max(1, min(cap, int(usable * 0.35) // slot_bytes))
     pages = (usable - max_slots * slot_bytes) // page_bytes_pc

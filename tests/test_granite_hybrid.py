@@ -405,14 +405,18 @@ def test_page_budget_sizes_from_the_cache_description():
         assert plan["page_bytes"] == 2 * 1 * 2 * 16 * 4 * 4
         assert plan["state_slot_bytes"] == 3 * (8 * 16 * 16 + 3 * 160) * 4
         assert plan["state_bytes"] == 3 * plan["state_slot_bytes"]
-        # the state comes off the budget before pages are cut
+        # the state comes off the budget before pages are cut, ONCE: the
+        # decode step writes the state where it lies (no second copy)
         stateless = dict(plan["config"], cache=[plan["cache"][0]])
         more = static.page_budget(
             config=stateless, page_tokens=4, max_context=64,
             hbm_bytes=8 << 20, max_slots_cap=3,
             weight_bytes=plan["weight_bytes"])
-        assert (more["pages"] - plan["pages"]) * plan["page_bytes"] \
-            >= 2 * plan["state_bytes"] - plan["page_bytes"]
+        assert more["max_slots"] == plan["max_slots"] == 3
+        assert more["workspace_bytes"] == plan["workspace_bytes"]
+        given_up = (more["pages"] - plan["pages"]) * plan["page_bytes"]
+        assert plan["state_bytes"] - plan["page_bytes"] <= given_up \
+            <= plan["state_bytes"] + plan["page_bytes"]
         pool = PagedKVPool.from_plan(plan)
         assert pool.state.slots == 3 and budget_drift(pool, m) == []
         pool.state = StateSlots(plan["cache"][1:], 2)
@@ -428,6 +432,30 @@ def test_page_budget_sizes_from_the_cache_description():
         assert (g["num_layers"], g["num_heads"], g["head_dim"]) == (2, 2, 8)
         assert g["state_slot_bytes"] == 0 and g["cache"] == [
             {"kind": "kv", "layers": 2, "kv_heads": 2, "head_dim": 8}]
+
+
+def test_page_budget_charges_a_slots_state_once_at_the_published_widths():
+    """`granite-4.0-h-micro` on a v5e's 16.9 GB, shapes only: 16 slots as
+    before, and the 1.22 GB that paid for a second copy of the state while
+    a step ran (24,615 pages, PR 27) goes to pages."""
+    cfg = GraniteHybridConfig(dtype="bfloat16")
+    sizing = dict(config=cfg, page_tokens=16, max_context=1024,
+                  max_slots_cap=16, weight_bytes=cfg.param_count() * 2)
+    plan = static.page_budget(hbm_bytes=16_909_336_064, **sizing)
+    slot = plan["state_slot_bytes"]
+    assert (plan["max_slots"], slot) == (16, 76_437_504)
+    assert plan["state_bytes"] == 16 * slot == 1_223_000_064
+    dense_view = 2 * 4 * 8 * 1024 * 64 * 4      # K + V, 4 layers x 8 heads
+    assert plan["workspace_bytes"] == 16 * (dense_view + 100_352 * 4)
+    assert plan["pages"] == 29_281 >= 24_615 + slot * 16 // plan["page_bytes"]
+    # one slot's state, its workspace and a page are enough to start ...
+    least = int((plan["weight_bytes"] + slot + dense_view
+                 + plan["page_bytes"]) / (1.0 - plan["headroom"])) + 1
+    assert least + slot > int(least * 1.001)    # ... a second copy is not
+    tight = static.page_budget(hbm_bytes=int(least * 1.001), **sizing)
+    assert tight["max_slots"] == 1 and tight["pages"] >= 1
+    with pytest.raises(ValueError, match="not enough for one decode slot"):
+        static.page_budget(hbm_bytes=least - slot // 2, **sizing)
 
 
 def test_pool_reserves_and_releases_the_state_slot_with_the_pages():
@@ -446,6 +474,180 @@ def test_pool_reserves_and_releases_the_state_slot_with_the_pages():
     pool.close_sequence(t2)
     pool.assert_drained()
     assert pool.stats()["state_slots_used"] == 0
+
+
+# -- the decode step owns the state arrays ------------------------------------
+# the tiny model's state tiles ([16, 16]) take the plain jnp update; with
+# [8, 128] tiles `_slab_update` (Pallas, interpreted on the CPU) takes them
+SLAB_TILES = dict(mamba_n_heads=16, mamba_d_head=8, mamba_d_state=128)
+
+
+def _pool_and_steps(m, slots=3):
+    from paddle_tpu.serving.step_program import StepPrograms
+    plan = static.page_budget(m, page_tokens=4, max_context=64,
+                              hbm_bytes=16 << 20, max_slots_cap=slots)
+    return PagedKVPool.from_plan(plan).state, StepPrograms(m)
+
+
+def _decode_args(state, ids, lengths, active, columns=32):
+    return [_t(ids, np.int32), _t(lengths, np.int32), _t(active, np.int32),
+            *[dg.to_variable(a) for a in state.kv_view(columns)]]
+
+
+def test_decode_program_aliases_both_state_feeds_to_its_results():
+    """Read from the lowered module, not from a timing: the two `*state`
+    feeds of the step contract (positions 5 and 6) are donated and each is
+    aliased to a result; ids, lengths, `active` and the KV view are not,
+    and the prefill program donates nothing."""
+    import re
+    import jax.numpy as jnp
+    from paddle_tpu.serving.step_program import DECODE_STATE_AT
+    with dg.guard():
+        m = _model(11)
+        state, steps = _pool_and_steps(m)
+        args = _decode_args(state, np.zeros((3, 1)), [0, 0, 0], [0, 0, 0]) \
+            + [dg.to_variable(a) for a in state.arrays.values()]
+        with dg.no_grad():
+            cp = steps._decode.concrete_program(*args)
+            pre = steps._prefill.concrete_program(
+                _t(np.zeros((1, 16)), np.int32), _t([3], np.int32),
+                _t([2], np.int32))
+    assert DECODE_STATE_AT == 5 and cp.donated == (5, 6)
+    assert pre.donated == ()
+    kept, donated = cp.split_feeds([a._value for a in args])
+    assert (len(kept), len(donated)) == (5, 2)
+    text = cp.composed().lower(
+        jnp.uint32(0), tuple(t._value for t in cp.params.values()), kept,
+        True, donated).as_text()
+    aliased = re.findall(r"%arg\d+: tensor<([0-9x]+)x[a-z0-9]+> "
+                         r"\{[^}]*tf.aliasing_output = (\d+)", text)
+    shapes = ["x".join(map(str, a.shape)) for a in state.arrays.values()]
+    assert [a[0] for a in aliased] == shapes        # ssm, conv: nothing else
+    assert [int(a[1]) for a in aliased] == [3, 4]   # (logits, K, V, *state)
+
+
+@pytest.mark.parametrize("tiles", [{}, SLAB_TILES], ids=["jnp", "slab"])
+def test_decode_consumes_the_state_it_is_given_and_rebind_takes_its_result(
+        tiles):
+    """20 decode steps through `StepPrograms.decode` and `StateSlots`, as
+    the engine makes them: after every step the arrays that went in are
+    dead and the rebound ones carry the recurrence on (logits match the
+    reference's full forward at every step); an idle row's made-up state
+    and tail come back bit for bit; both counters count."""
+    from paddle_tpu.ops.kernels import ssm as ssm_kernels
+    p, n = 13, 20
+    reset_serving_stats()
+    with dg.guard():
+        m = _model(12, **tiles)
+        c = m.config
+        state, steps = _pool_and_steps(m)
+        assert ssm_kernels._slab_update_fits(
+            state.arrays["ssm"], c.mamba_n_heads, c.mamba_d_head,
+            c.mamba_d_state) == bool(tiles)
+        ids = np.random.default_rng(6).integers(0, 127, p + n)
+        want = _reference_logits(m, ids)
+        padded = np.zeros((1, 16), np.int32)
+        padded[0, :p] = ids[:p]
+        logits, k, v, ssm, conv = steps.prefill(
+            _t(padded), _t([p], np.int32), _t([p - 1], np.int32))
+        _assert_logits(logits.numpy()[0], want[p - 1], want.std())
+        state.install(1, ssm=ssm._value, conv=conv._value,
+                      k_dense=k._value, v_dense=v._value)
+        made_up = {name: np.full((a.shape[0], 1) + a.shape[2:], fill,
+                                 np.float32)
+                   for (name, a), fill in zip(state.arrays.items(),
+                                              (0.5, 0.25))}
+        state.install(2, **made_up, k_dense=k._value * 0, v_dense=v._value)
+        for t in range(n):
+            step_ids = np.zeros((3, 1), np.int32)
+            step_ids[1, 0] = ids[p + t]
+            lengths = [0, p + t, 0]
+            old = list(state.arrays.values())
+            logits, kn, vn, *new = steps.decode(
+                *_decode_args(state, step_ids, lengths, [0, 1, 0]), *old)
+            assert all(a.is_deleted() for a in old)
+            state.rebind(**{name: tensor._value
+                            for name, tensor in zip(state.names, new)})
+            state.append_kv(kn._value, vn._value, lengths)
+            assert not any(a.is_deleted() for a in state.arrays.values())
+            _assert_logits(logits.numpy()[1], want[p + t], want.std())
+        for name, a in state.row(2).items():
+            np.testing.assert_array_equal(a, made_up[name][:, 0])
+        assert not state.row(0)["ssm"].any()
+    stats = serving_stats()
+    assert stats["serving.gen.state_in_place"] == n
+    assert stats.get("serving.gen.state_copied", 0) == 0
+    # a step whose arrays were NOT given away (a backend that ignored the
+    # donation looks like this) is counted as a copy
+    state.rebind(**{name: a + 0 for name, a in state.arrays.items()})
+    assert serving_stats()["serving.gen.state_copied"] == 1
+    with pytest.raises(ValueError, match="rebind needs"):
+        state.rebind(ssm=state.arrays["ssm"])
+
+
+@pytest.mark.parametrize("groups, h, p, n", [
+    (1, 16, 8, 128),        # 16 heads share a [128, 128] block
+    (2, 16, 8, 128),        # ... and B, C by halves
+    (2, 4, 64, 256),        # two heads a block, two blocks of lanes
+    (1, 2, 256, 128)])      # a head spans two blocks
+def test_slab_update_is_the_plain_update_in_one_pass(groups, h, p, n):
+    """`_slab_update` (the Pallas kernel) against the jnp form of the same
+    op on the same inputs: entry 1 of a [3, B, H, P, N] array, B and C
+    shared by H / groups heads; an idle row and the other layers' entries
+    come back bit for bit."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.kernels import ssm as ssm_kernels
+    rng = np.random.default_rng(groups)
+    b = 3
+    f32 = lambda *shape: rng.normal(size=shape).astype(np.float32)  # noqa
+    ins = dict(X=f32(b, h, p), Dt=f32(b, h), A=-rng.uniform(1, 4, h).astype(
+        np.float32), B=f32(b, groups, n), C=f32(b, groups, n),
+        D=np.ones(h, np.float32), DtBias=f32(h),
+        Lengths=np.asarray([1, 0, 1], np.int32))
+    slab = f32(3, b, h, p, n)
+    assert ssm_kernels._slab_update_fits(jnp.asarray(slab), h, p, n)
+    assert not ssm_kernels._slab_update_fits(jnp.asarray(slab), h, p, 16)
+    assert not ssm_kernels._slab_update_fits(jnp.asarray(slab), 1, 8, n)
+    assert not ssm_kernels._slab_update_fits(
+        jnp.asarray(slab, jnp.bfloat16), h, p, n)
+    run = lambda state, attrs: run_kernel(  # noqa: E731
+        "mamba2_state_update", dict(ins, State=state), attrs,
+        OpContext(seed=0, is_test=True))
+    got, want = run(slab, {"slab_index": 1}), run(slab[1], {})
+    new = np.asarray(got["NewState"])
+    _assert_state(new[1], np.asarray(want["NewState"]))
+    _assert_state(np.asarray(got["Y"]), np.asarray(want["Y"]))
+    np.testing.assert_array_equal(new[[0, 2]], slab[[0, 2]])
+    np.testing.assert_array_equal(new[1, 1], slab[1, 1])    # the idle row
+
+
+def test_a_step_that_raises_leaves_zeroed_state_and_a_live_engine():
+    """A decode step that fails AFTER it has given its state arrays away:
+    every sequence is failed, the pool gets fresh zeroed arrays in place
+    of the dead ones, and the next request is served as ever."""
+    with dg.guard():
+        m = _model(13)
+        plan = static.page_budget(m, page_tokens=4, max_context=128,
+                                  hbm_bytes=8 << 20, max_slots_cap=2)
+        eng = ContinuousBatchingEngine(m, kv_pool=plan).start()
+        state, real = eng.kv_pool.state, eng._steps.decode
+
+        def failing(*args):
+            real(*args)
+            raise RuntimeError("the device fell over")
+
+        prompt = np.random.default_rng(7).integers(0, 126, 9)
+        eng._steps.decode = failing
+        with pytest.raises(RuntimeError, match="fell over"):
+            eng.submit(prompt, max_length=6).result(timeout=600)
+        eng._steps.decode = real
+        for a in state.arrays.values():     # the step had donated these
+            assert not a.is_deleted() and not np.asarray(a).any()
+        assert not any(a.is_deleted() for a in state.dense.values())
+        out = eng.submit(prompt, max_length=6).result(timeout=600)
+        assert list(out) == _greedy(m, prompt, 6)
+        eng.stop()
+        eng.kv_pool.assert_drained()
 
 
 # -- through the engine -------------------------------------------------------

@@ -800,3 +800,61 @@ def test_dy2static_list_append_in_tensor_loop():
 
     with pytest.raises(TypeError, match="loop-carried"):
         bad(_t([1.0]))
+
+
+# -- donated arguments --------------------------------------------------------
+def _accumulate(total, x, scale):
+    return total + x * scale, x - total
+
+
+def _lowered(sf, *args):
+    """The StableHLO text of a StaticFunction's compiled run on `args`."""
+    import jax.numpy as jnp
+    from paddle_tpu.dygraph.base import no_grad
+    with no_grad():
+        cp = sf.concrete_program(*args)
+    kept, gone = cp.split_feeds([a._value for a in args])
+    return cp, cp.composed().lower(
+        jnp.uint32(0), tuple(t._value for t in cp.params.values()), kept,
+        True, gone).as_text()
+
+
+@pytest.mark.parametrize("donate, dead", [((), []), ((0,), [0]),
+                                          ((0, 1), [0, 1])])
+def test_static_function_donates_only_the_positions_it_was_built_with(
+        donate, dead):
+    """A donated argument's buffer is given to XLA (aliased to the result
+    of its shape in the lowered module) and is dead after the call; every
+    other argument — and every argument of a function built without
+    positions — is alive and unchanged."""
+    from paddle_tpu.dygraph.base import no_grad
+    from paddle_tpu.jit import StaticFunction
+    sf = StaticFunction(_accumulate, donate_args=donate)
+    args = [_x(seed=1), _x(seed=2), paddle_tpu.to_tensor(
+        np.float32(3.0))]
+    before = [a.numpy().copy() for a in args]
+    cp, text = _lowered(sf, *args)
+    assert list(cp.donated) == dead
+    assert text.count("tf.aliasing_output") == len(dead)
+    with no_grad():
+        total, diff = sf(*args)
+    np.testing.assert_allclose(total.numpy(), before[0] + before[1] * 3.0,
+                               rtol=1e-6)
+    np.testing.assert_allclose(diff.numpy(), before[1] - before[0],
+                               rtol=1e-6)
+    for i, a in enumerate(args):
+        assert a._value.is_deleted() == (i in dead)
+        if i not in dead:
+            np.testing.assert_array_equal(a.numpy(), before[i])
+
+
+def test_static_function_that_donates_is_forward_only():
+    from paddle_tpu.jit import StaticFunction
+    sf = StaticFunction(_accumulate, donate_args=(0,))
+    x = _x(seed=2)
+    x.stop_gradient = False
+    with pytest.raises(TypeError, match="forward only"):
+        sf(_x(seed=1), x, paddle_tpu.to_tensor(np.float32(3.0)))
+    with pytest.raises(TypeError, match="positions of Tensor arguments"):
+        StaticFunction(_accumulate, donate_args=(5,))(
+            _x(), _x(), paddle_tpu.to_tensor(np.float32(1.0)))

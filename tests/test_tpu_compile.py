@@ -1,0 +1,95 @@
+"""Chip-free XLA:TPU + Mosaic compiles of kernels on the serving path, at
+the published sizes: what interpret mode cannot show (tiling, VMEM, the
+aliasing the compiler keeps).  The TPU compiler is installed beside JAX;
+it compiles for a v5e that is described, not attached.  Nothing runs, so
+nothing here is a time.
+
+The topology is described inside a fixture, never at import: only the
+worker that runs this file loads libtpu (guide `on-chip-measurement` §2).
+Keep every such test in THIS file.
+"""
+import os
+import re
+
+import numpy as np
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """An AOT compile for a described chip is written to the persistent
+    cache but cannot be read back without one: keep it out."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def test_state_update_of_granite_h_micro_compiles_in_place(
+        one_chip, no_compile_cache, monkeypatch):
+    """Three consecutive Mamba layers' one-token updates over the donated
+    `[36, 16, 64, 64, 128]` float32 state array of `granite-4.0-h-micro`:
+    each is one Mosaic kernel aliased to the array, no copy of the array
+    is left, and the program holds the array once."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.kernels import ssm
+    from paddle_tpu.ops.registry import OpContext
+    monkeypatch.setattr(ssm, "_interpret", lambda: False)
+    layers, b, h, p, n = 36, 16, 64, 64, 128
+    slab_shape = (layers, b, h, p, n)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def three_layers(slab, x, dt, a, bm, cm, d, bias, active):
+        ctx = OpContext(seed=0, is_test=True)
+        for index in (4, 5, 6):
+            out = ssm.mamba2_state_update(
+                {"X": x, "Dt": dt, "A": a, "B": bm, "C": cm, "D": d,
+                 "State": slab, "DtBias": bias, "Lengths": active},
+                {"slab_index": index}, ctx)
+            slab, x = out["NewState"], out["Y"]
+        return slab, x
+
+    assert ssm._slab_update_fits(
+        jax.ShapeDtypeStruct(slab_shape, jnp.float32), h, p, n)
+    # x64 off, as the chip runs (conftest turns it on, and Mosaic refuses
+    # the int64 block indices it makes)
+    with jax.enable_x64(False):
+        compiled = jax.jit(three_layers, donate_argnums=0).lower(
+            sds(slab_shape, jnp.float32), sds((b, h, p), jnp.bfloat16),
+            sds((b, h), jnp.bfloat16), sds((h,), jnp.float32),
+            sds((b, 1, n), jnp.bfloat16), sds((b, 1, n), jnp.bfloat16),
+            sds((h,), jnp.float32), sds((h,), jnp.float32),
+            sds((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    slab = r"f32\[36,16,64,64,128\]"
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          text)) == 3
+    assert not re.findall(rf"= {slab}\S* copy\(", text)
+    assert not re.findall(rf"= {slab}\S* fusion\(", text)
+    assert "input_output_alias={ {0}: (0, {}, may-alias)" in text
+    mem = compiled.memory_analysis()
+    slab_bytes = int(np.prod(slab_shape)) * 4
+    assert mem.alias_size_in_bytes == slab_bytes
+    assert mem.temp_size_in_bytes < slab_bytes // layers  # under one entry
