@@ -7,10 +7,11 @@ entry points, at the full width of models the repo supports, with random
 weights made from seeds:
 
   train          Program IR -> Executor: the BERT-base pretraining program
-                 of bench.py (bf16 AMP, Adam), 5 per-step dispatches.
+                 (models.build_bert_base: bf16 AMP, Adam), 5 per-step
+                 dispatches.
   train-scanned  the same program and scope through one Executor.run_steps
                  window, compared with the looped steps at a tolerance.
-  kernels        every hand-written Pallas kernel compiled (never
+  kernels        the Pallas flash-attention kernel compiled (never
                  interpreted) against the repo's own reference.
   serve          InferenceServer -> ContinuousBatchingEngine over a paged KV
                  pool: a GPT-2-small-width model answering concurrent HTTP
@@ -43,7 +44,6 @@ import numpy as np
 BERT_BASE = dict(vocab=30522, seq=512, hidden=768, layers_n=12, heads=12,
                  batch=32)
 ATTN_SHAPE = (4, 12, 4096, 64)          # B, H, S, D — bf16
-XENT_SHAPE = (2048, 30522)              # T, V — V is ragged over 2048 blocks
 GPT2_SMALL = dict(vocab_size=50257, hidden_size=768, num_layers=12,
                   num_heads=12, max_position=1024)
 SERVE = dict(n_requests=4, prompt_tokens=64, new_tokens=16, page_tokens=16,
@@ -128,10 +128,10 @@ def _assert_state_live(main, scope, platform, sample=4):
 # ---------------------------------------------------------------------------
 def phase_train(vocab, seq, hidden, layers_n, heads, batch, steps=5):
     import jax
-    import bench
     import paddle_tpu.static as static
+    from paddle_tpu.models import build_bert_base
 
-    main, startup, loss = bench.build_bert_base(
+    main, startup, loss = build_bert_base(
         vocab, seq, hidden, layers_n, heads, batch, use_amp=True)
     rng = np.random.RandomState(0)
     feed = {  # one fixed seeded batch, int32 (x64 is off)
@@ -230,11 +230,10 @@ def _max_err(got, want):
     return float(np.abs(got - want).max()), float(np.abs(want).max())
 
 
-def phase_kernels(attn_shape, xent_shape, attn_dtype="bfloat16"):
+def phase_kernels(attn_shape, attn_dtype="bfloat16"):
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.attention import flash_attention, reference_attention
-    from paddle_tpu.ops.fused_xent import fused_softmax_xent
 
     b, h, s, d = attn_shape
     dt = jnp.dtype(attn_dtype)
@@ -280,32 +279,6 @@ def phase_kernels(attn_shape, xent_shape, attn_dtype="bfloat16"):
         report[f"flash_causal_{causal}"] = {
             n: round(e, 5) for n, (e, _) in errs.items()}
 
-    # fused softmax-cross-entropy vs XLA's log-softmax + gather
-    t, vsz = xent_shape
-    k1, k2 = jax.random.split(jax.random.PRNGKey(1))
-    logits = jax.random.normal(k1, (t, vsz), jnp.float32) * 3.0
-    labels = jax.random.randint(k2, (t,), 0, vsz, jnp.int32)
-    interpret = jax.default_backend() == "cpu"
-
-    def fused(lg):
-        return fused_softmax_xent(lg, labels, -100, 256, 2048, interpret)
-
-    def xla(lg):
-        return -jax.nn.log_softmax(lg)[jnp.arange(t), labels][:, None]
-
-    _assert_lowering(jax.value_and_grad(lambda lg: fused(lg).sum()),
-                     (logits,), "fused_xent")
-    f_loss, f_grad = jax.jit(jax.value_and_grad(
-        lambda lg: fused(lg).sum()))(logits)
-    x_loss, x_grad = jax.jit(jax.value_and_grad(
-        lambda lg: xla(lg).sum()))(logits)
-    per_tok, _ = _max_err(jax.jit(fused)(logits), jax.jit(xla)(logits))
-    grad_err, _ = _max_err(f_grad, x_grad)
-    assert per_tok <= 1e-3, f"fused_xent loss max err {per_tok:.3e}"
-    assert grad_err <= 5e-4, f"fused_xent grad max err {grad_err:.3e}"
-    assert abs(float(f_loss) - float(x_loss)) <= 1e-4 * abs(float(x_loss))
-    report["fused_xent"] = {"loss": round(per_tok, 6),
-                            "grad": round(grad_err, 7)}
     print(f"kernels: compiled={jax.default_backend() != 'cpu'} "
           f"max errors {report}")
     return report
@@ -533,7 +506,7 @@ def main():
     # the later phases get the HBM back; multi keeps only the programs
     run.exe.close()
     run.exe = run.scope = None
-    timed("kernels", phase_kernels, ATTN_SHAPE, XENT_SHAPE)
+    timed("kernels", phase_kernels, ATTN_SHAPE)
     timed("serve", phase_serve, GPT2_SMALL, **SERVE)
     if report["count"] >= 4:
         timed("multi", phase_multi, run)

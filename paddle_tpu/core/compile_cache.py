@@ -12,7 +12,7 @@ Two related jobs, one subsystem:
 
 2. **Trace/hit/miss counters** — every in-process step-cache consult in
    `static/executor.py` / `distributed/compiled_program.py` records here
-   (through `core/monitor.py`'s StatRegistry), so tests and `bench.py`
+   (through `core/monitor.py`'s StatRegistry), so tests and the benchmark
    can assert hard properties like "zero new traces after warmup" and
    `Executor.cache_stats()` has one source of truth.
 

@@ -91,10 +91,6 @@ define_flag("tensor_array_max_len", 256,
             "default TensorArray capacity (static-shape buffer bound)")
 define_flag("use_flash_attention", False,
             "route attention through the Pallas flash kernel")
-define_flag("fused_xent", False,
-            "route softmax_with_cross_entropy through the Pallas online "
-            "fused kernel (softmax never materialized; Softmax output "
-            "slot becomes a placeholder)")
 define_flag("benchmark", False, "sync + time every executor run")
 define_flag("dataset_chunk_steps", 1,
             "train_from_dataset: batch this many consecutive same-shape "

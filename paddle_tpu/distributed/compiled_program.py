@@ -627,10 +627,12 @@ class CompiledProgram:
         window boundary, the scan runs HOISTED (scan_window.py): the
         commit tail — optimizer update, publish allgather, merged-grad
         allreduce — executes once per gm-K window instead of once per
-        micro-step, cutting the publish wire to 1/K.  Numerics are
+        micro-step, cutting the publish wire to 1/K.  The arithmetic is
         unchanged (the looped commit is masked off on the same steps);
-        set ``PADDLE_TPU_SCAN_HOIST=0`` to force the unhoisted scan
-        (the bench A/B switch).
+        the last bit is the compiler's: on XLA:CPU losses stay bit-equal
+        to the looped path and a float32 optimizer moment moves by up to
+        one ulp per window (tools/scan_smoke.py).
+        ``PADDLE_TPU_SCAN_HOIST=0`` forces the unhoisted scan.
 
         Stacked feeds ride the executor's FLAGS_feed_bucketing policy:
         a ragged PER-STEP batch pads up to an already-compiled stacked

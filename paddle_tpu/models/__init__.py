@@ -12,7 +12,7 @@ from .transformer import (  # noqa: F401
 from .gpt import (  # noqa: F401
     GPTConfig, GPTModel, GPTForGeneration, gpt_small,
 )
-from .static_lm import build_transformer_lm  # noqa: F401
+from .static_lm import build_bert_base, build_transformer_lm  # noqa: F401
 from .granite_hybrid import (  # noqa: F401
     GraniteHybridConfig, GraniteHybridModel, granite_hybrid_tiny,
 )

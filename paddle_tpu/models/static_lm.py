@@ -1,7 +1,15 @@
-"""Static-graph transformer LM builder with optional tensor parallelism.
+"""Static-graph builders: the benchmark's BERT-base trainer and a
+transformer LM with optional tensor parallelism.
 
-The v5e-32-scale rehearsal config: assembles embedding → N pre-LN
-transformer blocks → LM head as ONE static Program.  With
+`build_bert_base` is the model of the `bert-base` benchmark cells
+(BENCHMARK.json; `bench.build_bert_base` is an alias of it): post-LN
+BERT-base with bf16 AMP and Adam, returning (main, startup, loss).  Its
+Program is fingerprinted in tests/test_models_static_lm.py: an edit that
+changes it changes what the cells measure.
+
+`build_transformer_lm`, the v5e-32-scale rehearsal config, assembles
+embedding → N pre-LN transformer blocks → LM head as ONE static Program.
+With
 `tensor_parallel_degree > 1` every block uses the Megatron layers
 (distributed/tensor_parallel.py): column/row-parallel attention + MLP,
 weights annotated for the "tp" mesh axis — run it under
@@ -15,7 +23,7 @@ from __future__ import annotations
 
 from ..static import layers
 
-__all__ = ["build_transformer_lm"]
+__all__ = ["build_transformer_lm", "build_bert_base"]
 
 
 def build_transformer_lm(vocab_size, hidden, num_layers, num_heads, seq_len,
@@ -87,3 +95,49 @@ def build_transformer_lm(vocab_size, hidden, num_layers, num_heads, seq_len,
         loss = layers.mean(
             layers.softmax_with_cross_entropy(logits, labels))
     return main, startup, loss, logits
+
+
+def build_bert_base(vocab=30522, seq=512, hidden=768, layers_n=12, heads=12,
+                    batch=8, use_amp=True, use_ring=False):
+    import paddle_tpu.static as static
+    from paddle_tpu.static import layers, nets
+    from paddle_tpu import amp
+
+    main, startup = static.Program(), static.Program()
+    with static.program_guard(main, startup):
+        ids = layers.data("ids", [-1, seq], dtype="int64")
+        pos = layers.data("pos", [-1, seq], dtype="int64")
+        labels = layers.data("labels", [-1, seq, 1], dtype="int64")
+        emb = layers.embedding(ids, size=[vocab, hidden])
+        pemb = layers.embedding(pos, size=[seq, hidden])
+        h = layers.elementwise_add(emb, pemb)
+        h = layers.layer_norm(h, begin_norm_axis=2)
+        for _ in range(layers_n):
+            # self-attention (use_ring: the ring_attention op — sequence
+            # shards over an "sp" mesh axis under CompiledProgram, plain
+            # attention on one device; the long-seq path's kernel)
+            q = layers.fc(h, hidden, num_flatten_dims=2)
+            k = layers.fc(h, hidden, num_flatten_dims=2)
+            v = layers.fc(h, hidden, num_flatten_dims=2)
+            ctx = nets.scaled_dot_product_attention(
+                q, k, v, num_heads=heads, sequence_parallel=use_ring)
+            attn_out = layers.fc(ctx, hidden, num_flatten_dims=2)
+            h = layers.layer_norm(layers.elementwise_add(h, attn_out),
+                                  begin_norm_axis=2)
+            # ffn
+            ffn = layers.fc(h, hidden * 4, num_flatten_dims=2, act="gelu")
+            ffn = layers.fc(ffn, hidden, num_flatten_dims=2)
+            h = layers.layer_norm(layers.elementwise_add(h, ffn),
+                                  begin_norm_axis=2)
+        logits = layers.fc(h, vocab, num_flatten_dims=2)
+        loss = layers.mean(
+            layers.softmax_with_cross_entropy(logits, labels))
+        opt = static.Adam(learning_rate=1e-4)
+        if use_amp:
+            # bf16 compute on the MXU, fp32 master weights; bf16 shares
+            # fp32's exponent range so no dynamic loss scaling is needed
+            opt = amp.decorate(opt, init_loss_scaling=1.0,
+                               use_dynamic_loss_scaling=False,
+                               dest_dtype="bfloat16")
+        opt.minimize(loss)
+    return main, startup, loss

@@ -21,19 +21,6 @@ def softmax_with_cross_entropy(ins, attrs, ctx):
     axis = attrs.get("axis", -1) % logits.ndim
     soft_label = attrs.get("soft_label", False)
     ignore_index = attrs.get("ignore_index", -100)
-    # Pallas fused path (FLAGS_fused_xent, ops/fused_xent.py): one online
-    # pass over the vocab, softmax never materialized; the Softmax output
-    # slot then carries a zero placeholder (graphs fetching it must run
-    # with the flag off — the bench/training path only consumes Loss)
-    from ..fused_xent import maybe_fused_xent
-    fused = maybe_fused_xent(logits, label, axis, soft_label,
-                             ignore_index)
-    if fused is not None:
-        # Loss stays f32 like the base branch (bf16 rounding before the
-        # reduction would break the fused-vs-base A/B); the Softmax
-        # placeholder is DCE'd under jit (the fused path only engages
-        # when traced)
-        return {"Softmax": jnp.zeros_like(logits), "Loss": fused}
     cdt = _compute_dtype(logits)
     lf = logits.astype(cdt)
     logp = jax.nn.log_softmax(lf, axis=axis)

@@ -110,8 +110,8 @@ def _auto_remat_checkpoints(loss, block: Block, no_grad: Set[str]):
         # the clone walk is missing the optimizer's persistable slots.
         # Reserve 2x trainable-param bytes for them (Adam/Lamb moments,
         # the common case) so this verdict matches the post-minimize
-        # walk bench.py reports — without the reserve a config could be
-        # declared fitting here and over-budget in the same JSON record.
+        # walk — without the reserve a config could be declared fitting
+        # here and over-budget once the optimizer is appended.
         import numpy as _np
         from ..core.dtype import np_dtype as _np_dtype
         reserve = 0

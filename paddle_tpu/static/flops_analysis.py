@@ -1,9 +1,9 @@
 """Compile-time per-op FLOPs accounting: the exact denominator for MFU
 and the auto-parallel planner's third cost substrate.
 
-Until now the framework could not OBSERVE its own north-star metric:
-`bench.py` guessed FLOPs with the analytic ``6*params + 12*L*s*h``
-formula, and the planner had per-op HBM (`static/memory_analysis.py`)
+Before it the framework could not OBSERVE its own north-star metric:
+FLOPs were guessed with the analytic ``6*params + 12*L*s*h`` formula,
+and the planner had per-op HBM (`static/memory_analysis.py`)
 and per-op wire bytes (`static.collective_wire_bytes`) but no per-op
 compute cost.  This module walks the program IR — the same op list the
 executor jits — and prices every op from its resolved shapes:
@@ -65,8 +65,8 @@ V5E_DEVICE_KIND = "TPU v5 lite"
 _PEAK_FLOPS_BY_KIND = {V5E_DEVICE_KIND: 197e12}
 
 # int8 MXU rate multiplier over the bf16 peak: the v5e runs int8
-# matmuls at 394 vs 197 TOPS (tools/bench_int8.py validates the 2x
-# through preferred_element_type=int32) — the calibrated roofline
+# matmuls at 394 vs 197 TOPS (published peaks; not measured by this
+# repo) — the calibrated roofline
 # divides int8_flops by INT8_MXU_RATE*peak instead of peak
 INT8_MXU_RATE = 2.0
 

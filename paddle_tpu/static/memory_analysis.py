@@ -1,11 +1,11 @@
 """Compile-time HBM accounting: predict a program's peak device memory
 WITHOUT running it on the chip.
 
-Motivation (docs/perf.md round 5): the framework is memory-bound, not
-dispatch-bound — b64 hits the MFU north star while b96 misses HBM by
-274 MB — and until now the only way to learn a config's HBM fate was to
-spend chip time on it.  This module answers fits-or-OOMs at
-program-build time:
+Motivation: the trainer is bound by HBM before it is bound by anything
+else (the benchmark's b64 step fills most of the chip: PERF.md §4), and
+without a walker the only way to learn a config's HBM fate is to spend
+chip time on it.  This module answers fits-or-OOMs at program-build
+time (docs/perf.md "HBM accounting & remat"):
 
   * `estimate_peak_bytes(program, batch=...)` — an op-IR liveness walker
     over the Program: var sizes from shape×dtype (symbolic -1 batch dims

@@ -73,8 +73,7 @@ fit).
 real program, recording the plan in the `core/pass_framework`
 applied-passes registry first — the verifier's V504 plan-drift check
 then flags any later hand-edit whose applied passes disagree with the
-recorded plan.  `bench.py --auto` is the end-to-end wiring: plan, apply,
-run on the local mesh.
+recorded plan.
 """
 from __future__ import annotations
 
@@ -905,8 +904,8 @@ def plan_program(program: Program, startup: Optional[Program] = None,
 
     * `program`/`startup` — a minimized (optimizer ops appended)
       training program pair.  Neither is modified: every candidate is
-      applied to clones; call `apply_plan` (or `bench.py --auto`) to
-      apply the winner for real.
+      applied to clones; call `apply_plan` to apply the winner for
+      real.
     * `world` — total chip count the wire costs and shard candidates
       target (1 = single chip, no wire).  A tp-degree-`d` candidate
       carves it into a (world/d) × d dp×tp mesh.
@@ -945,9 +944,9 @@ def plan_program(program: Program, startup: Optional[Program] = None,
       "collective" for 1-D candidates, level "layout" (the V6xx
       sharding-propagation analyzer) for every 2-D tp candidate — the
       search space never contains a deadlocking or mis-reduced plan.
-      Leave on; it exists as a switch only for estimator-sweep modes
-      that re-plan the same program family many times
-      (`bench.py --seq-ladder`).
+      Leave on; it exists as a switch only for estimator sweeps that
+      re-plan the same program family many times
+      (`tools/plan_decision_table.py --fast`).
     * `calibration` — a `Calibration` every candidate's price passes
       through (``calibrated=True`` in the trace records).  Default
       (None) consults `default_calibration()`: the checked-in

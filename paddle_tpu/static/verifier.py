@@ -505,8 +505,7 @@ def collective_wire_bytes_by_axis(program: Program, world: int,
     to (`ring_axis`: ring 0 → "dp", the tensor ring → "mp", the
     sequence ring → "sp").  The 2-D planner's wire substrate — an
     mp-ring byte overlaps different hardware links than a dp-ring byte,
-    so the roofline must see them separately; also surfaced in the
-    ``bench.py --dp-shard`` / ``--tp`` JSON.  `batch` binds symbolic -1
+    so the roofline must see them separately.  `batch` binds symbolic -1
     dims (the mp ring's traffic is activations)."""
     seq = collective_sequence(program)
     if ring_degrees is None:

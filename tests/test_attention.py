@@ -271,75 +271,6 @@ def test_flash_sq_gt_sk_causal_valid_rows():
                                    rtol=1e-4, atol=1e-5, err_msg=tag)
 
 
-# ---------------------------------------------------------------------------
-# Pallas fused softmax-cross-entropy (ops/fused_xent.py — second kernel,
-# VERDICT r3 missing #4) — interpret-mode numerics vs XLA
-# ---------------------------------------------------------------------------
-
-def test_fused_xent_forward_matches_xla():
-    from paddle_tpu.ops.fused_xent import fused_softmax_xent
-    rng = np.random.RandomState(0)
-    T, V = 64, 777  # ragged vocab tail exercises the masked last block
-    logits = jnp.asarray(rng.randn(T, V).astype(np.float32) * 3)
-    labels = jnp.asarray(rng.randint(0, V, (T,)).astype(np.int32))
-    loss = fused_softmax_xent(logits, labels, -100, 32, 256, True)
-    ref = -jax.nn.log_softmax(logits)[jnp.arange(T), labels]
-    np.testing.assert_allclose(np.asarray(loss)[:, 0], np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_fused_xent_backward_matches_xla():
-    from paddle_tpu.ops.fused_xent import fused_softmax_xent
-    rng = np.random.RandomState(1)
-    T, V = 32, 300
-    logits = jnp.asarray(rng.randn(T, V).astype(np.float32))
-    labels = jnp.asarray(rng.randint(0, V, (T,)).astype(np.int32))
-
-    d1 = jax.grad(lambda lg: jnp.sum(
-        fused_softmax_xent(lg, labels, -100, 16, 128, True)))(logits)
-    d2 = jax.grad(lambda lg: jnp.sum(
-        -jax.nn.log_softmax(lg)[jnp.arange(T), labels]))(logits)
-    np.testing.assert_allclose(np.asarray(d1), np.asarray(d2),
-                               rtol=1e-5, atol=1e-6)
-
-
-def test_fused_xent_ignore_index():
-    from paddle_tpu.ops.fused_xent import fused_softmax_xent
-    rng = np.random.RandomState(2)
-    T, V = 16, 100
-    logits = jnp.asarray(rng.randn(T, V).astype(np.float32))
-    labels = np.asarray(rng.randint(0, V, (T,)), np.int32)
-    labels[::4] = 7  # use 7 as ignore_index
-    loss = fused_softmax_xent(logits, jnp.asarray(labels), 7, 8, 128,
-                              True)
-    assert (np.asarray(loss)[::4, 0] == 0).all()
-    g = jax.grad(lambda lg: jnp.sum(
-        fused_softmax_xent(lg, jnp.asarray(labels), 7, 8, 128, True)))(
-        logits)
-    assert (np.abs(np.asarray(g)[::4]) == 0).all()
-
-
-def test_fused_xent_through_op_flag():
-    from paddle_tpu.ops.registry import run_kernel, OpContext
-    from paddle_tpu.ops.fused_xent import enable_fused_xent
-    rng = np.random.RandomState(3)
-    logits = jnp.asarray(rng.randn(8, 16, 500).astype(np.float32))
-    labels = jnp.asarray(rng.randint(0, 500, (8, 16, 1)).astype(np.int64))
-    base = run_kernel("softmax_with_cross_entropy",
-                      {"Logits": logits, "Label": labels}, {},
-                      OpContext())
-    enable_fused_xent(True)
-    try:
-        fused = run_kernel("softmax_with_cross_entropy",
-                           {"Logits": logits, "Label": labels}, {},
-                           OpContext())
-    finally:
-        enable_fused_xent(False)
-    np.testing.assert_allclose(np.asarray(fused["Loss"]),
-                               np.asarray(base["Loss"]), rtol=1e-5,
-                               atol=1e-5)
-
-
 def test_wired_sequence_parallel_transformer_lm():
     """The PUBLIC long-seq wiring: build_transformer_lm(
     sequence_parallel=True) emits ring_attention ops per layer, runs

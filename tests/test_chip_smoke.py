@@ -21,9 +21,8 @@ def test_train_then_scanned_window():
 
 
 def test_kernels_interpreted_on_cpu():
-    report = chip_smoke.phase_kernels((1, 2, 64, 16), (32, 300))
-    assert set(report) == {"flash_causal_False", "flash_causal_True",
-                           "fused_xent"}
+    report = chip_smoke.phase_kernels((1, 2, 64, 16))
+    assert set(report) == {"flash_causal_False", "flash_causal_True"}
 
 
 def test_serve_over_http():

@@ -6,19 +6,12 @@ acceptance on all five BASELINE transformer shapes, grad = 2x forward,
 per-class/per-phase structure, remat pricing the replayed segments, and
 collectives costing zero compute.
 """
-import os
-import sys
-
 import numpy as np
 import pytest
 
 import paddle_tpu.static as static
 from paddle_tpu.core.program import _reset_unique_names
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
-
-import bench  # noqa: E402  (build_bert_base is the shape factory)
+from paddle_tpu.models import build_bert_base  # the shape factory
 
 
 def _build_mlp(in_dim=16, hidden=32, batch_dim=-1):
@@ -91,8 +84,8 @@ BASELINE_SHAPES = [
 def test_baseline_shapes_within_5pct_of_analytic(name, vocab, seq, hidden,
                                                  layers_n, heads, batch):
     _reset_unique_names()
-    main, _, _ = bench.build_bert_base(vocab, seq, hidden, layers_n,
-                                       heads, batch, use_amp=False)
+    main, _, _ = build_bert_base(vocab, seq, hidden, layers_n,
+                                 heads, batch, use_amp=False)
     rep = static.analyze_flops(main, batch=batch)
     n_params = sum(int(np.prod(v.shape)) for v in main.all_parameters()
                    if v.shape is not None)
@@ -115,13 +108,13 @@ def test_remat_replay_is_priced():
     so the rewritten program reports MORE flops than the plain build."""
     from paddle_tpu.core.flags import set_flags
     _reset_unique_names()
-    plain, _, _ = bench.build_bert_base(512, 64, 64, 2, 2, 4,
-                                        use_amp=False)
+    plain, _, _ = build_bert_base(512, 64, 64, 2, 2, 4,
+                                  use_amp=False)
     _reset_unique_names()
     set_flags({"recompute": "always", "hbm_assume_batch": 4})
     try:
-        remat, _, _ = bench.build_bert_base(512, 64, 64, 2, 2, 4,
-                                            use_amp=False)
+        remat, _, _ = build_bert_base(512, 64, 64, 2, 2, 4,
+                                      use_amp=False)
     finally:
         set_flags({"recompute": "", "hbm_assume_batch": 0})
     f_plain = static.analyze_flops(plain, batch=4)["total_flops"]
@@ -133,11 +126,11 @@ def test_ring_attention_op_priced_like_materialized_path():
     """The ring_attention op (one fused IR node) must price the same
     QK^T/PV work as the materialized matmul+softmax path it replaces."""
     _reset_unique_names()
-    plain, _, _ = bench.build_bert_base(512, 64, 64, 2, 2, 4,
-                                        use_amp=False, use_ring=False)
+    plain, _, _ = build_bert_base(512, 64, 64, 2, 2, 4,
+                                  use_amp=False, use_ring=False)
     _reset_unique_names()
-    ring, _, _ = bench.build_bert_base(512, 64, 64, 2, 2, 4,
-                                       use_amp=False, use_ring=True)
+    ring, _, _ = build_bert_base(512, 64, 64, 2, 2, 4,
+                                 use_amp=False, use_ring=True)
     rp = static.analyze_flops(plain, batch=4)
     rr = static.analyze_flops(ring, batch=4)
     att = rr["by_class"]["attention"]

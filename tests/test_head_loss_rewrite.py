@@ -12,6 +12,7 @@ import paddle_tpu.static.optimizer as static_optimizer
 from paddle_tpu import amp
 from paddle_tpu.core import monitor
 from paddle_tpu.core.program import _reset_unique_names
+from paddle_tpu.models import build_bert_base
 from paddle_tpu.ops.kernels import loss as loss_kernels
 from paddle_tpu.static import layers
 from paddle_tpu.static.head_loss_rewrite import fuse_head_loss
@@ -368,18 +369,16 @@ def test_data_parallel_step_has_the_same_collectives(monkeypatch, use_amp):
 # -- (e), (f) the walkers know the op, at the benchmark's size ---------------
 @pytest.fixture(scope="module")
 def bert_base_b64():
-    import bench
     _reset_unique_names()
-    return bench.build_bert_base(batch=64)[0]
+    return build_bert_base(batch=64)[0]
 
 
 def test_memory_walker_drops_the_head_tensors(bert_base_b64, monkeypatch):
-    import bench
     from paddle_tpu.static.memory_analysis import analyze_program
     monkeypatch.setattr(static_optimizer, "fuse_head_loss",
                         lambda *a, **k: 0)
     _reset_unique_names()
-    before = analyze_program(bench.build_bert_base(batch=64)[0], batch=64)
+    before = analyze_program(build_bert_base(batch=64)[0], batch=64)
     # ISSUE 26: 18.40 GB, at the head's mul_grad, the 2.0 GB bf16 logits
     # gradient the largest live tensor
     assert before["peak_bytes"] == pytest.approx(18.40e9, rel=1e-3)

@@ -6,7 +6,7 @@ Drives tools/layout_smoke.py in-process: a clean Megatron col→row
 tensor-parallel program infers its full SPMD layout with ZERO
 diagnostics and an exactly-ring-priced mp reshard table; a seeded
 dropped row-parallel allreduce (partial sums read as complete) is
-caught as V602 with op provenance, all in under 10 s.  Mirrors the
+caught as V602 with op provenance.  Mirrors the
 verify_smoke gate pattern; the CLI round-trip is `slow`.
 """
 import json
@@ -26,7 +26,6 @@ def test_layout_smoke_gate():
     assert result["clean_diagnostics"] == 0, result
     assert "V602" in result["seeded_codes"], result
     assert result["mp_reshard_bytes"] > 0, result
-    assert result["value"] < 10, result
 
 
 @pytest.mark.slow

@@ -4,8 +4,8 @@ perf round to notice the MFU denominator went wrong).
 
 Drives tools/obs_smoke.py in-process: the 2-layer-toy matmul FLOPs match
 the hand count, one journaled train step yields parseable JSONL with the
-step-event schema, and prometheus_text() renders the minted metrics —
-all under 10 s.  Mirrors the verify_smoke/mem_smoke gate pattern; the
+step-event schema, and prometheus_text() renders the minted metrics.
+Mirrors the verify_smoke/mem_smoke gate pattern; the
 CLI round-trip is `slow`.
 """
 import json
@@ -26,7 +26,6 @@ def test_obs_smoke_gate():
     assert result["journal_events"] >= 3, result
     assert "step" in result["journal_kinds"], result
     assert result["prometheus_bytes"] > 0, result
-    assert result["value"] < 10, result
 
 
 @pytest.mark.slow

@@ -195,6 +195,23 @@ class TestSoftmaxWithCrossEntropy(OpTest):
         self.check_output()
         self.check_grad(["Logits"], "Loss")
 
+    def test_ignore_index(self):
+        """Ignored rows lose nothing and get no gradient, whatever the
+        index's sign (here a valid class id)."""
+        self.setup()
+        logits = np.random.rand(8, 7).astype(np.float64)
+        label = np.random.randint(0, 7, (8, 1)).astype(np.int64)
+        label[::4] = 3
+        e = np.exp(logits - logits.max(-1, keepdims=True))
+        sm = e / e.sum(-1, keepdims=True)
+        loss = -np.log(sm[np.arange(8), label.ravel()]).reshape(8, 1)
+        loss[label == 3] = 0.0
+        self.inputs = {"Logits": logits, "Label": label}
+        self.attrs = {"ignore_index": 3}
+        self.outputs = {"Softmax": sm, "Loss": loss}
+        self.check_output()
+        self.check_grad(["Logits"], "Loss")
+
     def test_soft_label(self):
         self.setup()
         logits = np.random.rand(5, 7).astype(np.float64)

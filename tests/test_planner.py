@@ -256,10 +256,10 @@ def test_planner_rediscovers_bert96_remat_verdict():
     bert-base b96 shape the planner must rediscover the hand-tuned
     verdict (remat flips predicted OOM to FITS) with the documented
     walked peak, unprompted."""
-    import bench
+    from paddle_tpu.models import build_bert_base
     _reset_unique_names()
-    main, startup, _ = bench.build_bert_base(30522, 512, 768, 12, 12, 96,
-                                             use_amp=True)
+    main, startup, _ = build_bert_base(30522, 512, 768, 12, 12, 96,
+                                       use_amp=True)
     plan = static.plan_program(main, startup, world=1, batch=96,
                                knobs={"grad_merge": (1,)})
     assert plan.predicted_fits

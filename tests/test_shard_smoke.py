@@ -4,7 +4,7 @@ accounting, or a sharding-induced retrace must fail the suite, not wait
 for a perf round).
 
 Drives tools/shard_smoke.py in-process: small Adam model sharded for the
-8-device CPU mesh in under 15 s — rewrite applied, slot shapes correct
+8-device CPU mesh — rewrite applied, slot shapes correct
 and genuinely rank-sharded, slot bytes ≈ 1/8, zero post-warmup
 recompiles.  Mirrors the mem_smoke/ckpt_smoke gate pattern; the CLI
 round-trip is `slow` (a fresh interpreter + jit warmup buys no extra

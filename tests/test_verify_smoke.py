@@ -5,7 +5,7 @@ rediscover it).
 Drives tools/verify_smoke.py in-process: a clean ZeRO-1-sharded training
 program verifies with ZERO diagnostics, a seeded rank-conditional
 collective (guaranteed mesh deadlock) is caught as V205, a seeded
-read-after-donate ordering is caught as V302, all in under 10 s.
+read-after-donate ordering is caught as V302.
 Mirrors the mem_smoke/shard_smoke gate pattern; the CLI round-trip is
 `slow` (a fresh interpreter buys no extra coverage over the in-process
 gate).
@@ -28,7 +28,6 @@ def test_verify_smoke_gate():
     assert "V205" in result["deadlock_codes"], result
     assert "V302" in result["read_after_donate_codes"], result
     assert result["collectives_extracted"] >= 2, result
-    assert result["value"] < 10, result
 
 
 @pytest.mark.slow

@@ -24,12 +24,11 @@ into a wall-clock estimator for one host class:
 `plan_program` auto-loads that file once its residual is under
 `DEFAULT_CALIBRATION_RESIDUAL_PCT` (see `default_calibration`), so
 checking the report in IS the flag flip that turns calibrated pricing
-on for `bench.py --auto`.
+on for every `plan_program` call.
 
 Usage:
     python tools/calibrate_roofline.py            # fit + write JSON
     python tools/calibrate_roofline.py --report   # + markdown table
-                                                  #   (docs/perf.md)
     python tools/calibrate_roofline.py --out PATH # alternate output
 """
 from __future__ import annotations

@@ -13,11 +13,11 @@ verify_smoke/shard_smoke): builds a Megatron col→row fc pair on a 4×2
     diagnostics, and its reshard table prices the mp-ring allreduce at
     exact ring accounting (2(g−1)/g × bytes);
   * a seeded V602 (the row-parallel ``mp_allreduce_sum`` dropped — the
-    partial products read as if complete) is caught with op provenance;
-  * the whole walk (two full propagations + a level-"layout"
-    check_program) stays under the 10 s budget.
+    partial products read as if complete) is caught with op provenance.
 
-Prints one JSON line; correctness never depends on throughput.
+Prints one JSON line; `value` is the wall time of the walk (two full
+propagations + a level-"layout" check_program), reported and never
+asserted.
 
 Usage: python tools/layout_smoke.py
 """
@@ -116,9 +116,6 @@ def run_smoke():
     assert v602[0].op_uid is not None
 
     wall = time.time() - t0
-    assert wall < 10.0, (
-        f"layout smoke FAILED: gate took {wall:.1f}s (>10s) — "
-        f"compile-time analysis is no longer compile-time cheap")
 
     return {
         "metric": "layout_smoke_wall_s",

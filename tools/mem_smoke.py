@@ -7,9 +7,8 @@ perf_smoke/ckpt_smoke): builds bert-tiny twice — plain and with
 FLAGS_recompute=always auto-selected layer checkpoints — and asserts
 the contract the HBM accounting rests on:
 
-  * the estimator walks BOTH programs in seconds (<10 s for the whole
-    estimate phase — compile-time accounting must stay compile-time
-    cheap);
+  * the estimator walks BOTH programs (the estimate phase's wall time
+    is reported as `estimate_wall_s`, never asserted);
   * remat's walked activation peak shows the expected reduction vs the
     plain program (the rewrite actually cuts live ranges, not just adds
     barrier ops);
@@ -62,9 +61,6 @@ def run_smoke(steps: int = 4, batch: int = 8):
     remat = static.analyze_program(main_remat, batch=batch)
     est_wall = time.time() - t_est
 
-    assert est_wall < 10.0, (
-        f"mem smoke FAILED: estimate phase took {est_wall:.1f}s (>10s) — "
-        f"compile-time accounting is no longer compile-time cheap")
     n_barriers = sum(1 for op in main_remat.global_block().ops
                      if op.type == "optimization_barrier")
     assert n_barriers >= 1, \

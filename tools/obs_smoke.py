@@ -11,10 +11,10 @@ as a tier-1 test, mirroring verify_smoke/mem_smoke):
     JSONL whose `step` event carries the step/wall-time schema, and a
     heartbeat file with the same step;
   * `monitor.prometheus_text()` renders the train.* metrics that step
-    minted (TYPE lines present, non-empty);
-  * the whole gate stays under the 10 s budget.
+    minted (TYPE lines present, non-empty).
 
-Prints one JSON line; correctness never depends on throughput.
+Prints one JSON line; `value` is the gate's wall time, reported and
+never asserted.
 
 Usage: python tools/obs_smoke.py
 """
@@ -115,8 +115,6 @@ def run_smoke():
     assert "train_step_ms" in text, text[:400]
 
     wall = time.time() - t0
-    assert wall < 10.0, (
-        f"obs smoke FAILED: gate took {wall:.1f}s (>10s)")
     return {
         "metric": "obs_smoke_wall_s",
         "value": round(wall, 2),

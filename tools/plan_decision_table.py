@@ -30,15 +30,14 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 
 def _build_bert(batch, seq=512, layers_n=12, hidden=768, heads=12,
                 vocab=30522, ring=False):
-    import bench
     from paddle_tpu.core.program import _reset_unique_names
+    from paddle_tpu.models import build_bert_base
     _reset_unique_names()
-    main, startup, _ = bench.build_bert_base(
+    main, startup, _ = build_bert_base(
         vocab, seq, hidden, layers_n, heads, batch, use_amp=True,
         use_ring=ring)
     return main, startup
@@ -48,27 +47,179 @@ def _build_ernie_large(batch):
     return _build_bert(batch, layers_n=24, hidden=1024, heads=16)
 
 
+# The three non-BERT BASELINE shapes (LeNet-5; ResNet-50 v1.5;
+# Transformer-big, 6+6 layers, masks as feed inputs): only this table
+# builds them.
+def build_lenet(use_amp=False):
+    import paddle_tpu.static as static
+    from paddle_tpu.static import layers
+
+    main, startup = static.Program(), static.Program()
+    with static.program_guard(main, startup):
+        im = layers.data("image", [-1, 1, 28, 28])
+        lbl = layers.data("label", [-1, 1], dtype="int64")
+        h = layers.conv2d(im, 6, 5, padding=2, act="relu")
+        h = layers.pool2d(h, 2, pool_type="max", pool_stride=2)
+        h = layers.conv2d(h, 16, 5, act="relu")
+        h = layers.pool2d(h, 2, pool_type="max", pool_stride=2)
+        h = layers.fc(h, 120, act="relu")
+        h = layers.fc(h, 84, act="relu")
+        logits = layers.fc(h, 10)
+        loss = layers.mean(layers.softmax_with_cross_entropy(logits, lbl))
+        static.SGD(learning_rate=0.01).minimize(loss)
+    return main, startup, loss
+
+
+def conv_bn(layers, x, filters, ksize, stride=1, act=None):
+    y = layers.conv2d(x, filters, ksize, stride=stride,
+                      padding=(ksize - 1) // 2, bias_attr=False)
+    return layers.batch_norm(y, act=act)
+
+
+def bottleneck(layers, x, filters, stride, downsample):
+    out = conv_bn(layers, x, filters, 1, act="relu")
+    out = conv_bn(layers, out, filters, 3, stride=stride, act="relu")
+    out = conv_bn(layers, out, filters * 4, 1)
+    if downsample:
+        x = conv_bn(layers, x, filters * 4, 1, stride=stride)
+    return layers.relu(layers.elementwise_add(out, x))
+
+
+def build_resnet50(batch, img=224, classes=1000, use_amp=True):
+    import paddle_tpu.static as static
+    from paddle_tpu.static import layers
+    from paddle_tpu import amp
+
+    main, startup = static.Program(), static.Program()
+    with static.program_guard(main, startup):
+        im = layers.data("image", [-1, 3, img, img])
+        label = layers.data("label", [-1, 1], dtype="int64")
+        h = conv_bn(layers, im, 64, 7, stride=2, act="relu")
+        h = layers.pool2d(h, 3, pool_type="max", pool_stride=2,
+                          pool_padding=1)
+        for stage, (filters, blocks) in enumerate(
+                [(64, 3), (128, 4), (256, 6), (512, 3)]):
+            for b in range(blocks):
+                stride = 2 if (b == 0 and stage > 0) else 1
+                h = bottleneck(layers, h, filters, stride, b == 0)
+        h = layers.pool2d(h, pool_type="avg", global_pooling=True)
+        logits = layers.fc(h, classes)
+        loss = layers.mean(
+            layers.softmax_with_cross_entropy(logits, label))
+        opt = static.Momentum(learning_rate=0.1, momentum=0.9)
+        if use_amp:
+            opt = amp.decorate(opt, init_loss_scaling=1.0,
+                               use_dynamic_loss_scaling=False,
+                               dest_dtype="bfloat16")
+        opt.minimize(loss)
+    return main, startup, loss
+
+
+def _mha(layers, q_in, kv_in, d_model, heads, bias=None):
+    """Multi-head attention via raw static layers; bias is an additive
+    [-1, 1, Tq, Tk] feed (None = unmasked)."""
+    dk = d_model // heads
+
+    def split_heads(x, t):
+        y = layers.reshape(x, [-1, t, heads, dk])
+        y.shape = (-1, t, heads, dk)
+        return layers.transpose(y, [0, 2, 1, 3])
+
+    tq, tk = q_in.shape[1], kv_in.shape[1]
+    q = split_heads(layers.fc(q_in, d_model, num_flatten_dims=2), tq)
+    k = split_heads(layers.fc(kv_in, d_model, num_flatten_dims=2), tk)
+    v = split_heads(layers.fc(kv_in, d_model, num_flatten_dims=2), tk)
+    logits = layers.matmul(layers.scale(q, scale=dk ** -0.5), k,
+                           transpose_y=True)
+    if bias is not None:
+        logits = layers.elementwise_add(logits, bias)
+    ctx = layers.matmul(layers.softmax(logits), v)
+    ctx = layers.transpose(ctx, [0, 2, 1, 3])
+    ctx = layers.reshape(ctx, [-1, tq, d_model])
+    ctx.shape = (-1, tq, d_model)
+    return layers.fc(ctx, d_model, num_flatten_dims=2)
+
+
+def _block_post(layers, x, sub):
+    return layers.layer_norm(layers.elementwise_add(x, sub),
+                             begin_norm_axis=2)
+
+
+def _ffn(layers, x, d_model, d_inner):
+    h = layers.fc(x, d_inner, num_flatten_dims=2, act="relu")
+    return layers.fc(h, d_model, num_flatten_dims=2)
+
+
+def build_transformer_big(src_len, trg_len, vocab=32000, d_model=1024,
+                          heads=16, n_layers=6, d_inner=4096,
+                          use_amp=True):
+    import paddle_tpu.static as static
+    from paddle_tpu.static import layers
+    from paddle_tpu import amp
+
+    main, startup = static.Program(), static.Program()
+    with static.program_guard(main, startup):
+        src = layers.data("src_ids", [-1, src_len], dtype="int64")
+        trg = layers.data("trg_ids", [-1, trg_len], dtype="int64")
+        lbl = layers.data("labels", [-1, trg_len, 1], dtype="int64")
+        causal = layers.data("trg_bias", [-1, 1, trg_len, trg_len])
+        spos = layers.data("src_pos", [-1, src_len], dtype="int64")
+        tpos = layers.data("trg_pos", [-1, trg_len], dtype="int64")
+
+        enc = layers.elementwise_add(
+            layers.embedding(src, size=[vocab, d_model]),
+            layers.embedding(spos, size=[src_len, d_model]))
+        for _ in range(n_layers):
+            enc = _block_post(layers, enc,
+                              _mha(layers, enc, enc, d_model, heads))
+            enc = _block_post(layers, enc, _ffn(layers, enc, d_model,
+                                                d_inner))
+
+        dec = layers.elementwise_add(
+            layers.embedding(trg, size=[vocab, d_model]),
+            layers.embedding(tpos, size=[trg_len, d_model]))
+        for _ in range(n_layers):
+            dec = _block_post(layers, dec,
+                              _mha(layers, dec, dec, d_model, heads,
+                                   bias=causal))
+            dec = _block_post(layers, dec,
+                              _mha(layers, dec, enc, d_model, heads))
+            dec = _block_post(layers, dec, _ffn(layers, dec, d_model,
+                                                d_inner))
+
+        logits = layers.fc(dec, vocab, num_flatten_dims=2)
+        smoothed = layers.label_smooth(
+            layers.one_hot(layers.reshape(lbl, [-1, trg_len]), vocab),
+            epsilon=0.1)
+        loss = layers.mean(layers.softmax_with_cross_entropy(
+            logits, smoothed, soft_label=True))
+        opt = static.Adam(learning_rate=2e-4)
+        if use_amp:
+            opt = amp.decorate(opt, init_loss_scaling=1.0,
+                               use_dynamic_loss_scaling=False,
+                               dest_dtype="bfloat16")
+        opt.minimize(loss)
+    return main, startup, loss
+
+
 def _build_lenet(batch):
-    import bench_lenet
     from paddle_tpu.core.program import _reset_unique_names
     _reset_unique_names()
-    main, startup, _ = bench_lenet.build_lenet()
+    main, startup, _ = build_lenet()
     return main, startup
 
 
 def _build_resnet(batch):
-    import bench_resnet
     from paddle_tpu.core.program import _reset_unique_names
     _reset_unique_names()
-    main, startup = bench_resnet.build_resnet50(batch)[:2]
+    main, startup = build_resnet50(batch)[:2]
     return main, startup
 
 
 def _build_transformer(batch):
-    import bench_transformer
     from paddle_tpu.core.program import _reset_unique_names
     _reset_unique_names()
-    out = bench_transformer.build_transformer_big(256, 256)
+    out = build_transformer_big(256, 256)
     return out[0], out[1]
 
 
@@ -97,8 +248,8 @@ def _build_lm_tp_base(batch):
 
 
 # (row key, label, builder, batch, world, hand knobs, hand-fits)
-# Hand column = the human-tuned docs/perf.md verdicts (r5 on-chip ground
-# truth where measured) kept as the cross-check.
+# Hand column = the human-tuned docs/perf.md verdicts, kept as the
+# cross-check.
 ROWS = [
     ("lenet", "LeNet b256", _build_lenet, 256, 1,
      dict(remat=False, dp_shard=0, zero_stage=0, grad_merge=1,

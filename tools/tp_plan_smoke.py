@@ -19,10 +19,10 @@ runs it as a tier-1 test, mirroring plan_smoke/mem_smoke):
   4. apply the plan to the winning build variant, require
      ``check_program(level="all")`` strict-clean (the V6xx layout level
      included), and train it on the real 4×2 CPU mesh: finite
-     decreasing loss, ZERO post-warmup retraces;
-  5. the whole walk stays under the 15 s budget.
+     decreasing loss, ZERO post-warmup retraces.
 
-Prints one JSON line; correctness never depends on throughput.
+Prints one JSON line; `value` is the wall time of the walk, reported and
+never asserted.
 
 Usage: python tools/tp_plan_smoke.py
 """
@@ -165,9 +165,6 @@ def run_smoke():
     assert losses[-1] < losses[0], losses
 
     wall = time.time() - t0
-    assert wall < 15.0, (
-        f"tp plan smoke FAILED: {wall:.1f}s (>15s) — the 2-D search is "
-        f"no longer estimator-cheap")
     return {
         "metric": "tp_plan_smoke_wall_s",
         "value": round(wall, 2),
