@@ -892,10 +892,12 @@ class ContinuousBatchingEngine:
         """`_prefill` for a model with recurrent state: ONE compiled
         program per prompt bucket.  The prompt is padded to its bucket
         and its length goes in with it, so the pads never enter the
-        recurrence; out come the one logits row sampling needs, the
-        attention layers' KV (to the pool's pages, copy-on-write sharing
-        as ever) and the sequence's state after its last prompt token,
-        which is written into its state slot on the device."""
+        recurrence; out come the one logits row sampling needs and its
+        argmax (a greedy request fetches the 4 bytes of the id and leaves
+        the logits on the device), the attention layers' KV (to the
+        pool's pages, copy-on-write sharing as ever) and the sequence's
+        state after its last prompt token, which is written into its
+        state slot on the device."""
         if req.future.cancelled():
             self._pool.close_sequence(table)
             return
@@ -913,12 +915,16 @@ class ContinuousBatchingEngine:
         ids_t, len_t, last_t = self._upload(
             ids, np.asarray([p], np.int32), np.asarray([p - 1], np.int32))
         with RecordEvent("engine/forward", bucket=pp, rows=1):
-            logits, k, v, *state = self._steps.prefill(ids_t, len_t, last_t)
-        with RecordEvent("engine/fetch") as fetch:
-            last = self._download(fetch, logits)[0][0]
+            logits, next_id, k, v, *state = self._steps.prefill(
+                ids_t, len_t, last_t)
+        samples = req.strategy == "sampling"
+        with RecordEvent("engine/fetch") as fetch:      # 4 bytes if greedy
+            got, = self._download(fetch, logits if samples else next_id)
         metrics.count("gen.prefill_tokens", p)
+        metrics.count("gen.logits_rows_fetched" if samples
+                      else "gen.sampled_on_device")
         with RecordEvent("engine/sample"):
-            nxt = self._sample(req, last)
+            nxt = self._sample(req, got[0]) if samples else int(got[0])
         if nxt == self.config.eos_id or req.max_new <= 1:
             with RecordEvent("engine/finish"):
                 self._pool.close_sequence(table)
@@ -952,12 +958,18 @@ class ContinuousBatchingEngine:
         dense KV (the pool's view of the live sequences, kept on the
         device: nothing is gathered from the pages or uploaded) and the
         state arrays as they sit on the device; out come a logits
-        row a slot, the new KV column a slot — appended to the view and,
-        for the record, to the pages — and the updated state arrays (an
-        idle row's state comes back as it went in).  The step is GIVEN
-        the state arrays: they are donated through the compiled program
-        and dead when it returns, so `rebind` follows the call at once
-        (a step that raises: `_fail_all` -> `StateSlots.recover`)."""
+        row a slot and its argmax, the new KV column a slot — appended to
+        the view and, for the record, to the pages — and the updated state
+        arrays (an idle row's state comes back as it went in).  Greedy
+        tokens are the device's: the host fetches the ids, and the logits
+        only in a step where some active row's request samples, whole
+        then: a download costs the link a round trip, hardly its bytes
+        (`serving.gen.sampled_on_device` counts the tokens taken from
+        the ids, `logits_rows_fetched` the rows of logits that crossed).
+        The step is GIVEN the state arrays: they are donated through the
+        compiled program and dead when it returns, so `rebind` follows
+        the call at once (a step that raises: `_fail_all` ->
+        `StateSlots.recover`)."""
         with self._mu:
             for i, s in enumerate(self._slots):
                 if s is not None and s.req.future.cancelled():
@@ -985,19 +997,23 @@ class ContinuousBatchingEngine:
                 lengths[i], alive[i] = s.kv_len, 1
         uploaded = self._upload(ids, lengths, alive)
         with RecordEvent("engine/forward", bucket=lpad, rows=len(active)):
-            logits, k_new, v_new, *new_state = self._steps.decode(
+            logits, next_ids, k_new, v_new, *new_state = self._steps.decode(
                 *uploaded, *[Tensor(a) for a in state.kv_view(lpad)],
                 *state.arrays.values())
             state.rebind(**{n: t._value
                             for n, t in zip(state.names, new_state)})
             state.append_kv(k_new._value, v_new._value, lengths)
+        greedy = sum(s.req.strategy != "sampling" for _, s in active)
         with RecordEvent("engine/fetch") as fetch:
-            step_logits, k_col, v_col = self._download(
-                fetch, logits, k_new, v_new)
+            picked, k_col, v_col, *step_logits = self._download(
+                fetch, next_ids, k_new, v_new,
+                *([logits] if greedy < len(active) else []))
             k_col = k_col[:, :, :, 0].astype(np.float32)   # [L, S, H, Dh]
             v_col = v_col[:, :, :, 0].astype(np.float32)
         metrics.count("gen.steps")
         metrics.count("gen.tokens", len(active))
+        metrics.count("gen.sampled_on_device", greedy)
+        metrics.count("gen.logits_rows_fetched", S if step_logits else 0)
         metrics.observe("gen.step_occupancy", len(active))
         retired = []
         with RecordEvent("engine/kv_append") as append:
@@ -1007,7 +1023,8 @@ class ContinuousBatchingEngine:
         with RecordEvent("engine/sample"):
             for i, s in active:
                 s.tokens.append(s.next_id)
-                nxt = self._sample(s.req, step_logits[i])
+                nxt = self._sample(s.req, step_logits[0][i]) \
+                    if s.req.strategy == "sampling" else int(picked[i])
                 s.next_id = nxt
                 s.n_new += 1
                 if nxt == self.config.eos_id or s.n_new >= s.req.max_new:

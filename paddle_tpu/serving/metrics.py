@@ -13,7 +13,11 @@ whole serving story from a single ``/stats`` scrape:
               that held a sequence before), serving.gen.state_in_place /
               state_copied (decode steps whose donated state arrays were
               all dead afterwards / steps where one was not: a backend
-              that ignored the donation)
+              that ignored the donation),
+              serving.gen.sampled_on_device / logits_rows_fetched (the
+              compiled step route: tokens taken from the step program's
+              own argmax / logits rows downloaded, every slot's in a step
+              where some row samples, none under greedy traffic)
   gauges      serving.queue.depth, serving.batch.last_size,
               serving.gen.active_slots, serving.server.inflight
   histograms  serving.latency_ms (end-to-end request latency),

@@ -17,6 +17,24 @@ Program (from shapes alone, `abstract_trace`: nothing runs eagerly) and
 run as one jitted XLA computation, under `eval()` and `no_grad`, so no
 tape is kept.
 
+PICKING A TOKEN IS THE ENGINE'S BUSINESS, and the greedy pick is made
+here, inside the same program: what `prefill` / `decode` return is the
+model's tuple with `next_ids = argmax(logits, axis=-1)` (int32, the FIRST
+maximal index as `np.argmax` gives it, on the float32 logits themselves)
+put in after the logits:
+
+    prefill -> (logits [1, V], next_id [1], K, V, *state)
+    decode  -> (logits [S, V], next_ids [S], K, V columns, *state)
+
+A greedy row costs the host link 4 bytes; the logits stay a result, left
+on the device.  Only a step in which some active row's request samples
+(temperature, top-k, its own seeded host RNG) has them fetched, and
+whole, with no gather of those rows first: a download here costs a round
+trip, hardly the bytes (PERF.md §6, PR 30).  An idle row's
+`next_ids` entry means as little as its logits do.  A model that lands
+on this route gets all of it from this wrapper: its methods return
+logits and know nothing of sampling.
+
 THE DECODE STEP OWNS THE STATE ARRAYS while it runs: the contract's
 `*state` arguments of `decode_step` — every position from `DECODE_STATE_AT`
 on — are donated through the compiled program, whose `*state` results
@@ -33,6 +51,7 @@ from __future__ import annotations
 
 from ..dygraph.base import no_grad
 from ..dygraph.tensor import Tensor
+from ..tensor.search import argmax
 
 __all__ = ["StepPrograms", "DECODE_STATE_AT"]
 
@@ -53,12 +72,13 @@ class StepPrograms:
         # traced from shapes: the steps' Python never reads a tensor's
         # value, and an eager pass of a 3 B-parameter model a bucket would
         # compile hundreds of per-op programs to throw their results away
-        self._prefill = StaticFunction(model.prefill_step, layer=model,
-                                       abstract_trace=True)
+        self._prefill = StaticFunction(_with_greedy(model.prefill_step),
+                                       layer=model, abstract_trace=True)
         n_state = sum(len(g["arrays"]) for g in
                       state_groups(cache_spec_of(model.config)))
         self._decode = StaticFunction(
-            model.decode_step, layer=model, abstract_trace=True,
+            _with_greedy(model.decode_step), layer=model,
+            abstract_trace=True,
             donate_args=range(DECODE_STATE_AT, DECODE_STATE_AT + n_state))
 
     @property
@@ -67,12 +87,23 @@ class StepPrograms:
         return len(self._prefill._cache) + len(self._decode._cache)
 
     def prefill(self, ids, lengths, last):
+        """-> (logits [1, V], next_id [1], K, V, *state)."""
         with no_grad():
             return self._prefill(ids, lengths, last)
 
     def decode(self, ids, cache_lengths, active, k_cache, v_cache, *state):
-        """One decode step.  `state`: the raw device arrays, DONATED — dead
-        when this returns; the `*state` results replace them."""
+        """One decode step -> (logits [S, V], next_ids [S], K, V columns,
+        *state).  `state`: the raw device arrays, DONATED — dead when this
+        returns; the `*state` results replace them."""
         with no_grad():
             return self._decode(ids, cache_lengths, active, k_cache,
                                 v_cache, *[Tensor(s) for s in state])
+
+
+def _with_greedy(step):
+    """`step` with the greedy pick of its logits put in after them.  The
+    arguments keep their positions (`DECODE_STATE_AT`, the donation)."""
+    def step_and_pick(*args):
+        logits, *rest = step(*args)
+        return (logits, argmax(logits, axis=-1, dtype="int32"), *rest)
+    return step_and_pick

@@ -523,7 +523,8 @@ def test_decode_program_aliases_both_state_feeds_to_its_results():
                          r"\{[^}]*tf.aliasing_output = (\d+)", text)
     shapes = ["x".join(map(str, a.shape)) for a in state.arrays.values()]
     assert [a[0] for a in aliased] == shapes        # ssm, conv: nothing else
-    assert [int(a[1]) for a in aliased] == [3, 4]   # (logits, K, V, *state)
+    # (logits, next_ids, K, V, *state)
+    assert [int(a[1]) for a in aliased] == [4, 5]
 
 
 @pytest.mark.parametrize("tiles", [{}, SLAB_TILES], ids=["jnp", "slab"])
@@ -548,7 +549,7 @@ def test_decode_consumes_the_state_it_is_given_and_rebind_takes_its_result(
         want = _reference_logits(m, ids)
         padded = np.zeros((1, 16), np.int32)
         padded[0, :p] = ids[:p]
-        logits, k, v, ssm, conv = steps.prefill(
+        logits, _, k, v, ssm, conv = steps.prefill(
             _t(padded), _t([p], np.int32), _t([p - 1], np.int32))
         _assert_logits(logits.numpy()[0], want[p - 1], want.std())
         state.install(1, ssm=ssm._value, conv=conv._value,
@@ -563,7 +564,7 @@ def test_decode_consumes_the_state_it_is_given_and_rebind_takes_its_result(
             step_ids[1, 0] = ids[p + t]
             lengths = [0, p + t, 0]
             old = list(state.arrays.values())
-            logits, kn, vn, *new = steps.decode(
+            logits, _, kn, vn, *new = steps.decode(
                 *_decode_args(state, step_ids, lengths, [0, 1, 0]), *old)
             assert all(a.is_deleted() for a in old)
             state.rebind(**{name: tensor._value
@@ -583,6 +584,68 @@ def test_decode_consumes_the_state_it_is_given_and_rebind_takes_its_result(
     assert serving_stats()["serving.gen.state_copied"] == 1
     with pytest.raises(ValueError, match="rebind needs"):
         state.rebind(ssm=state.arrays["ssm"])
+
+
+def _tied_halves(m):
+    """The vocabulary's upper half made a copy of its lower half (the head
+    is tied to the table): every logit then occurs twice, so EVERY argmax
+    is a tie between v and v + V/2, and the first index must win.  Prompts
+    and pending tokens come from the lower half alone."""
+    table = np.array(m.embed.numpy())
+    half = table.shape[0] // 2
+    table[half:] = table[:half]
+    m.embed.set_value(table)
+    return half
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["distinct", "tied"])
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_step_programs_pick_the_first_argmax_of_the_logits_they_return(
+        phase, tied):
+    """`StepPrograms` returns, after the logits, their argmax as int32:
+    what `np.argmax` gives on the same float32 values, the first index
+    where the maximum occurs twice.  Prefill: five prompts; decode: 20
+    steps over three rows of which one is idle."""
+    with dg.guard():
+        m = _model(14)
+        vocab = _tied_halves(m) if tied else m.config.vocab_size - 1
+        state, steps = _pool_and_steps(m)
+        rng = np.random.default_rng(15)
+        picks, rows = [], []
+        for slot, p in enumerate((13, 9, 16, 3, 11)):
+            padded = np.zeros((1, 16), np.int32)
+            padded[0, :p] = rng.integers(0, vocab, p)
+            logits, nxt, k, v, ssm, conv = steps.prefill(
+                _t(padded), _t([p], np.int32), _t([p - 1], np.int32))
+            assert nxt.numpy().dtype == np.int32 and nxt.shape == [1]
+            picks.append(nxt.numpy())
+            rows.append(logits.numpy())
+            if slot < 2:
+                state.install(slot, ssm=ssm._value, conv=conv._value,
+                              k_dense=k._value, v_dense=v._value)
+        lengths = np.asarray([13, 9, 0], np.int32)
+        pending = np.concatenate(picks[:2] + [[0]]).astype(np.int32)
+        for _ in range(20 * (phase == "decode")):
+            logits, nxt, kn, vn, *new = steps.decode(
+                *_decode_args(state, pending[:, None], lengths, [1, 1, 0]),
+                *state.arrays.values())
+            state.rebind(**{name: tensor._value
+                            for name, tensor in zip(state.names, new)})
+            state.append_kv(kn._value, vn._value, lengths)
+            assert nxt.numpy().dtype == np.int32 and nxt.shape == [3]
+            picks.append(nxt.numpy()[:2])       # the idle row's is ignored
+            rows.append(logits.numpy()[:2])
+            pending[:2] = nxt.numpy()[:2]
+            lengths[:2] += 1
+    picks, rows = np.concatenate(picks), np.concatenate(rows)
+    assert len(picks) == (45 if phase == "decode" else 5)
+    np.testing.assert_array_equal(picks, rows.argmax(-1))
+    twice = (rows == rows.max(-1, keepdims=True)).sum(-1)
+    if tied:
+        np.testing.assert_array_equal(rows[:, :vocab], rows[:, vocab:])
+        assert (twice == 2).all() and (picks < vocab).all()
+    else:
+        assert (twice == 1).all()
 
 
 @pytest.mark.parametrize("groups, h, p, n", [
@@ -651,17 +714,18 @@ def test_a_step_that_raises_leaves_zeroed_state_and_a_live_engine():
 
 
 # -- through the engine -------------------------------------------------------
-def _greedy(m, prompt, n, width=64):
-    """One sequence, no cache: the full forward again for every token.
-    The sequence sits in a buffer of one width (the model is causal: what
-    follows a position cannot reach it), so one shape is compiled."""
+def _greedy(m, prompt, n, width=64, pick=lambda row: int(row.argmax())):
+    """One sequence, no cache: the full forward again for every token
+    (`pick`: logits row -> token, greedy unless given).  The sequence sits
+    in a buffer of one width (the model is causal: what follows a position
+    cannot reach it), so one shape is compiled."""
     ids = list(prompt)
     for _ in range(n):
         buf = np.zeros((1, width), np.int32)
         buf[0, :len(ids)] = ids
         with dg.no_grad():
             row = m(_t(buf)).numpy()[0, len(ids) - 1]
-        ids.append(int(row.argmax()))
+        ids.append(pick(row))
         if ids[-1] == m.config.eos_id:
             break
     return ids
@@ -707,6 +771,125 @@ def test_engine_serves_the_hybrid_model_token_equal_to_one_sequence():
            for op in cp.program.global_block().ops}
     assert {"mamba2_state_update", "causal_conv1d", "gqa_attention",
             "rms_norm", "gated_rms_norm"} <= ops
+
+
+def _sampled(eng, m, prompt, n, seed, top_k=0, temperature=1.0):
+    """`_greedy` for a request that samples: the engine's own `_sample`
+    over each full-forward logits row with the request's own seeded RNG —
+    what the engine did with every row's logits before greedy picks moved
+    onto the device."""
+    from paddle_tpu.serving.generation import GenerationRequest
+    req = GenerationRequest(prompt, n, "sampling", top_k, temperature,
+                            seed, 600)
+    return _greedy(m, prompt, n, pick=lambda row: eng._sample(req, row))
+
+
+def test_sampling_rows_beside_greedy_rows_keep_their_seeded_tokens():
+    """A batch of greedy and sampling requests: the greedy rows take the
+    device's argmax; a step with a row that samples brings the logits down
+    whole, as every step did before, and that row draws the tokens its
+    seed drew then."""
+    reset_serving_stats()
+    with dg.guard():
+        m = _model(8)
+        plan = static.page_budget(m, page_tokens=4, max_context=128,
+                                  hbm_bytes=8 << 20, max_slots_cap=3)
+        eng = ContinuousBatchingEngine(m, kv_pool=plan).start()
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(0, 126, n) for n in (5, 19, 11, 7, 9, 6)]
+        hows = [None, dict(seed=3, top_k=5, temperature=0.8),
+                dict(seed=4, temperature=1.3), dict(seed=5, top_k=3),
+                None, dict(seed=6, temperature=2.0)]
+        news = (3, 8, 12, 10, 5, 7)
+        futs = [eng.submit(p, max_length=n, **(
+            dict(decode_strategy="sampling", **how) if how else {}))
+            for p, n, how in zip(prompts, news, hows)]
+        outs = [f.result(timeout=600) for f in futs]
+        for prompt, n, how, out in zip(prompts, news, hows, outs):
+            want = _sampled(eng, m, prompt, n, **how) if how \
+                else _greedy(m, prompt, n)
+            assert list(out) == want
+        eng.stop()
+        eng.kv_pool.assert_drained()
+    stats = serving_stats()
+    generated = [len(o) - len(p) for o, p in zip(outs, prompts)]
+    assert stats["serving.gen.sampled_on_device"] == sum(
+        g for g, how in zip(generated, hows) if not how)
+    # one row at each sampling prefill, all 3 slots' rows in every step
+    # that held a sampling row: no sampled token without its row
+    fetched = stats["serving.gen.logits_rows_fetched"]
+    sampled = sum(g for g, how in zip(generated, hows) if how)
+    prefills = sum(1 for how in hows if how)
+    assert fetched >= sampled and (fetched - prefills) % 3 == 0
+
+
+def test_a_greedy_step_fetches_ids_and_kv_columns_and_no_logits():
+    """Under a profiler session: the `engine/fetch` span of a step of
+    greedy rows carries the bytes of `next_ids` and the two KV columns,
+    and `serving.gen.logits_rows_fetched` stays 0 while
+    `serving.gen.sampled_on_device` grows by the step's active rows; a
+    row that samples adds the whole logits to the step's bytes and every
+    slot's row to the fetched count, and its prefill fetches its one
+    logits row in place of the id."""
+    import paddle_tpu.profiler as prof
+    slots, vocab = 3, 128
+    with dg.guard():
+        m = _model(8)
+        plan = static.page_budget(m, page_tokens=4, max_context=128,
+                                  hbm_bytes=8 << 20, max_slots_cap=slots)
+        eng = ContinuousBatchingEngine(m, kv_pool=plan).start()
+        state = eng.kv_pool.state
+        columns = sum(a.nbytes // 16 for a in state.kv_view(16))
+        greedy_step = 4 * slots + columns
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(0, 126, n) for n in (5, 7)]
+
+        def serve(**how):
+            reset_serving_stats()
+            prof.start_profiler(state="CPU")
+            try:
+                futs = [eng.submit(prompts[0], max_length=6),
+                        eng.submit(prompts[1], max_length=6, **how)]
+                outs = [f.result(timeout=600) for f in futs]
+            finally:
+                prof.stop_profiler(profile_path=None)
+            events = list(prof._state.events)
+            # (the last `engine/step` span ends after its futures resolve:
+            # the session may close first, so steps are read off children)
+            active = [e.fields["rows"] for e in events
+                      if e.name == "engine/forward"
+                      and e.parent == "engine/step"]
+            fetches, prefills = ([e.fields["bytes"] for e in events
+                                  if e.name == "engine/fetch"
+                                  and e.parent == parent]
+                                 for parent in ("engine/step",
+                                                "engine/prefill"))
+            assert len(fetches) == len(active) and len(prefills) == 2
+            tokens = [len(o) - len(p) for o, p in zip(outs, prompts)]
+            return active, fetches, prefills, tokens, serving_stats()
+
+        active, fetches, prefills, tokens, stats = serve()
+        assert set(fetches) == {greedy_step} and set(prefills) == {4}
+        assert stats.get("serving.gen.logits_rows_fetched", 0) == 0
+        assert stats["serving.gen.sampled_on_device"] == sum(tokens) \
+            == 2 + sum(active)
+
+        active, fetches, prefills, tokens, stats = serve(
+            decode_strategy="sampling", seed=2)
+        one_row = 4 * vocab
+        # the steps that held the sampling row (one a token after its
+        # prefill's, whenever its neighbour was admitted) brought the
+        # logits of all the slots down, the others none
+        with_logits = tokens[1] - 1
+        assert sorted(fetches) == sorted(
+            [greedy_step + slots * one_row] * with_logits
+            + [greedy_step] * (len(active) - with_logits))
+        assert sorted(prefills) == [4, one_row]
+        assert stats["serving.gen.logits_rows_fetched"] \
+            == 1 + slots * with_logits
+        assert stats["serving.gen.sampled_on_device"] == tokens[0]
+        eng.stop()
+        eng.kv_pool.assert_drained()
 
 
 def test_engine_refuses_what_a_recurrent_state_cannot_have_yet():
