@@ -16,3 +16,6 @@ from .static_lm import build_bert_base, build_transformer_lm  # noqa: F401
 from .granite_hybrid import (  # noqa: F401
     GraniteHybridConfig, GraniteHybridModel, granite_hybrid_tiny,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig, NemotronHModel, nemotron_h_tiny,
+)

@@ -63,11 +63,17 @@ def rms_norm(ins, attrs, ctx):
              outputs=["Out"], grad=None)
 def gated_rms_norm(ins, attrs, ctx):
     """``RMSNorm(x * silu(gate)) * w``: the gate is applied BEFORE the norm
-    (Mamba-2's output norm with one group over the whole last axis).
-    Forward only."""
+    (Mamba-2's output norm).  attr ``groups`` (default 1): the statistics
+    are taken over each of ``groups`` equal parts of the last axis on its
+    own (``mean(y^2)`` over ``C / groups`` channels; Nemotron-H: 8 groups
+    of 1,024), one group being the whole axis; the weight is per channel
+    either way.  Forward only."""
     x = ins["X"]
-    g = ins["Gate"].astype(_F32)
-    y = _rms(x.astype(_F32) * jax.nn.silu(g), attrs.get("epsilon", 1e-5))
+    groups = int(attrs.get("groups", 1))
+    y = x.astype(_F32) * jax.nn.silu(ins["Gate"].astype(_F32))
+    if groups > 1:
+        y = y.reshape(y.shape[:-1] + (groups, y.shape[-1] // groups))
+    y = _rms(y, attrs.get("epsilon", 1e-5)).reshape(x.shape)
     return {"Out": (y * ins["Scale"].astype(_F32)).astype(x.dtype)}
 
 
@@ -200,22 +206,38 @@ def mamba2_chunk_scan(ins, attrs, ctx):
     return {"Y": y, "FinalState": final}
 
 
-# a row's entry [H * P, N] goes through VMEM whole, in and out, each
+# a part of a row's entry [H * P, N] goes through VMEM, in and out, each
 # double-buffered: four of them must fit beside the small operands
 _SLAB_VMEM_BYTES = 12 << 20
 _TILE = 128     # rows and lanes of the square blocks the kernel transposes
 
 
+def _slab_parts(h, p, n):
+    """Into how many equal parts of whole heads' [128, 128] blocks
+    `_slab_update` cuts a row's entry [H * P, N] so that four parts fit
+    the VMEM budget (1: the entry whole; 2 at 128 heads x 64 x 128, whose
+    entry is 4.2 MB); a part's lane-dense `xdt` / `y` rows are whole
+    (8, 128) registers.  0: no such cut."""
+    blocks = h * p // _TILE
+    for parts in range(1, blocks + 1):
+        per = blocks // parts
+        if blocks % parts or (parts > 1 and per % 8):
+            continue
+        if 4 * per * _TILE * n * 4 <= _SLAB_VMEM_BYTES:
+            return parts
+    return 0
+
+
 def _slab_update_fits(slab, h, p, n):
     """Whether `_slab_update` takes this state array: float32, a row's
     entry made of whole [128, 128] blocks, each block whole heads or a
-    part of one head on whole (8, 128) registers, four such entries within
-    the VMEM budget.  Read from shapes alone: the same answer on every
-    backend."""
+    part of one head on whole (8, 128) registers, and a cut of the entry
+    (`_slab_parts`) within the VMEM budget.  Read from shapes alone: the
+    same answer on every backend."""
     return (slab.dtype == _F32 and n % _TILE == 0 and p % 8 == 0
             and (h * p) % _TILE == 0
             and (_TILE % p == 0 or p % _TILE == 0)
-            and 4 * h * p * n * 4 <= _SLAB_VMEM_BYTES)
+            and _slab_parts(h, p, n) > 0)
 
 
 @functools.partial(jax.jit, static_argnames="interpret")
@@ -235,7 +257,9 @@ def _slab_update(slab, index, decay, xdt, bm, cm, interpret):
     and the cell's warm set-up went from 105 to 157 s (644 s with the
     kernel unrolled; 110-117 s now; PERF.md section 6, PR 28).
 
-    One grid step a batch row; its entry is walked as [H * P, N] in
+    One grid step a batch row and part of its entry (`_slab_parts`: the
+    VMEM budget holds per part, not per entry, so 128 heads go in two
+    halves); a part is walked as [rows, N] in
     [128, 128] blocks, a few to a loop iteration (one block an iteration
     leaves the units idle between blocks: 169 us a layer on the v5e
     against 106-109 for two to eight, where a bare copy of the entry
@@ -246,7 +270,8 @@ def _slab_update(slab, index, decay, xdt, bm, cm, interpret):
     register (118 us)."""
     layers, b, h, p, n = slab.shape
     r, rows = h // bm.shape[1], h * p
-    blocks = rows // _TILE
+    parts = _slab_parts(h, p, n)
+    blocks = rows // _TILE // parts                 # of one part
     size = min(p, _TILE)            # rows of one head inside a block
     unroll = next(u for u in (8, 4, 2, 1) if blocks % u == 0)
 
@@ -254,6 +279,7 @@ def _slab_update(slab, index, decay, xdt, bm, cm, interpret):
                y_ref):
         del index_ref               # read by the block indices
         row = pl.program_id(0)
+        k0 = pl.program_id(1) * blocks      # this part's first block
 
         def block(k):
             x_rows = jnp.broadcast_to(x_ref[0, pl.ds(k, 1), :],
@@ -263,8 +289,8 @@ def _slab_update(slab, index, decay, xdt, bm, cm, interpret):
                 lanes = slice(n0, n0 + _TILE)
                 weighted = []
                 for j in range(_TILE // size):
-                    head = k * (_TILE // p) + j if p <= _TILE \
-                        else k * _TILE // p
+                    head = (k0 + k) * (_TILE // p) + j if p <= _TILE \
+                        else (k0 + k) * _TILE // p
                     at = pl.ds(pl.multiple_of(k * _TILE + j * size, 8), size)
                     group = pl.ds(head // r, 1)
                     new = decay_ref[row, head] * s_ref[0, 0, at, lanes] \
@@ -283,24 +309,24 @@ def _slab_update(slab, index, decay, xdt, bm, cm, interpret):
 
         lax.fori_loop(0, blocks // unroll, several, 0)
 
-    entry = pl.BlockSpec((1, 1, rows, n),
-                         lambda i, index_ref: (index_ref[0], i, 0, 0))
-    per_row = [pl.BlockSpec((1,) + v.shape[1:], lambda i, _: (i, 0, 0))
+    entry = pl.BlockSpec((1, 1, rows // parts, n),
+                         lambda i, j, index_ref: (index_ref[0], i, j, 0))
+    per_row = [pl.BlockSpec((1,) + v.shape[1:], lambda i, j, _: (i, 0, 0))
                for v in (bm, cm)]
-    dense = pl.BlockSpec((1, blocks, _TILE), lambda i, _: (i, 0, 0))
+    dense = pl.BlockSpec((1, blocks, _TILE), lambda i, j, _: (i, j, 0))
     new, y = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b,),
+            num_scalar_prefetch=1, grid=(b, parts),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), dense,
                       *per_row, entry],
             out_specs=[entry, dense]),
         out_shape=[jax.ShapeDtypeStruct((layers, b, rows, n), _F32),
-                   jax.ShapeDtypeStruct((b, blocks, _TILE), _F32)],
+                   jax.ShapeDtypeStruct((b, blocks * parts, _TILE), _F32)],
         input_output_aliases={5: 0},
         interpret=interpret,
         name="mamba2_state_update",
-    )(index.reshape(1), decay, xdt.reshape(b, blocks, _TILE), bm, cm,
+    )(index.reshape(1), decay, xdt.reshape(b, blocks * parts, _TILE), bm, cm,
       slab.reshape(layers, b, rows, n))      # the reshapes are bitcasts
     return new.reshape(slab.shape), y.reshape(b, h, p)
 

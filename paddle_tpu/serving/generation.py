@@ -918,13 +918,21 @@ class ContinuousBatchingEngine:
             logits, next_id, k, v, *state = self._steps.prefill(
                 ids_t, len_t, last_t)
         samples = req.strategy == "sampling"
+        counted = bool(self._steps.counters)
         with RecordEvent("engine/fetch") as fetch:      # 4 bytes if greedy
-            got, = self._download(fetch, logits if samples else next_id)
+            # the id (what the step counted rides behind it), then the
+            # logits row for a request that samples
+            got = self._download(
+                fetch, *([next_id] if counted or not samples else []),
+                *([logits] if samples else []))
+        if counted:
+            self._count_step(span, got[0][1:])
         metrics.count("gen.prefill_tokens", p)
         metrics.count("gen.logits_rows_fetched" if samples
                       else "gen.sampled_on_device")
         with RecordEvent("engine/sample"):
-            nxt = self._sample(req, got[0]) if samples else int(got[0])
+            nxt = self._sample(req, got[-1][0]) if samples \
+                else int(got[0][0])
         if nxt == self.config.eos_id or req.max_new <= 1:
             with RecordEvent("engine/finish"):
                 self._pool.close_sequence(table)
@@ -1010,6 +1018,8 @@ class ContinuousBatchingEngine:
                 *([logits] if greedy < len(active) else []))
             k_col = k_col[:, :, :, 0].astype(np.float32)   # [L, S, H, Dh]
             v_col = v_col[:, :, :, 0].astype(np.float32)
+        if self._steps.counters:
+            self._count_step(span, picked[S:])
         metrics.count("gen.steps")
         metrics.count("gen.tokens", len(active))
         metrics.count("gen.sampled_on_device", greedy)
@@ -1036,6 +1046,20 @@ class ContinuousBatchingEngine:
                 self._finish(slot)
             metrics.gauge("gen.active_slots",
                           sum(s is not None for s in self._slots))
+
+    def _count_step(self, span: RecordEvent, counts):
+        """What a compiled step counted on the device (the model's
+        `step_counters`, downloaded behind the ids): fields of the step's
+        or prefill's span under the model's names, and whatever
+        `serving.*` counters and gauges the model makes of them
+        (`step_metrics`: the engine knows no model's names)."""
+        got = dict(zip(self._steps.counters, (int(c) for c in counts)))
+        span.set(**got)
+        counted, gauged = self._model.step_metrics(got)
+        for name, n in counted.items():
+            metrics.count(name, n)
+        for name, value in gauged.items():
+            metrics.gauge(name, value)
 
     def _step_spec(self, span: RecordEvent):
         """One SPECULATIVE decode step over every active slot: the

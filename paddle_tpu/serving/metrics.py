@@ -17,8 +17,16 @@ whole serving story from a single ``/stats`` scrape:
               serving.gen.sampled_on_device / logits_rows_fetched (the
               compiled step route: tokens taken from the step program's
               own argmax / logits rows downloaded, every slot's in a step
-              where some row samples, none under greedy traffic)
-  gauges      serving.queue.depth, serving.batch.last_size,
+              where some row samples, none under greedy traffic),
+              serving.moe.pairs_routed / pairs_held / experts_touched /
+              expert_steps (a model with routed experts, counted on the
+              device by its compiled steps and fetched behind the ids:
+              token-expert pairs routed, those that landed on experts
+              this chip holds, held experts with at least one pair, and
+              expert layers run)
+  gauges      serving.moe.load_max_over_mean (the last call's largest
+              expert load over the mean load of the held experts),
+              serving.queue.depth, serving.batch.last_size,
               serving.gen.active_slots, serving.server.inflight
   histograms  serving.latency_ms (end-to-end request latency),
               serving.batch.occupancy (rows per device run),

@@ -35,6 +35,17 @@ trip, hardly the bytes (PERF.md §6, PR 30).  An idle row's
 on this route gets all of it from this wrapper: its methods return
 logits and know nothing of sampling.
 
+WHAT A STEP COUNTED RIDES WITH THE IDS.  A model whose step has
+data-dependent work names it (`model.step_counters`, e.g. the routed
+experts' `moe_pairs`, `moe_touched`) and returns one int32 vector of those
+counts after the state; the wrapper takes it off the tuple and appends it
+to `next_ids` — `[S + len(counters)]` int32, the ids first — so the counts
+reach the host in the download the ids already cost (a download costs a
+link round trip whatever its size) and the tuple keeps its positions.
+`StepPrograms.counters` has the names and `model.step_metrics(counts)` says
+which `serving.*` counters and gauges the engine makes of them; a model
+without any returns the tuples above unchanged.
+
 THE DECODE STEP OWNS THE STATE ARRAYS while it runs: the contract's
 `*state` arguments of `decode_step` — every position from `DECODE_STATE_AT`
 on — are donated through the compiled program, whose `*state` results
@@ -51,6 +62,7 @@ from __future__ import annotations
 
 from ..dygraph.base import no_grad
 from ..dygraph.tensor import Tensor
+from ..tensor.manipulation import concat
 from ..tensor.search import argmax
 
 __all__ = ["StepPrograms", "DECODE_STATE_AT"]
@@ -69,16 +81,18 @@ class StepPrograms:
                     f"{type(model).__name__} has no {name}(): the compiled "
                     "step route needs the model's cache-aware entry points")
         model.eval()
+        self.counters = tuple(getattr(model, "step_counters", ()))
         # traced from shapes: the steps' Python never reads a tensor's
         # value, and an eager pass of a 3 B-parameter model a bucket would
         # compile hundreds of per-op programs to throw their results away
-        self._prefill = StaticFunction(_with_greedy(model.prefill_step),
-                                       layer=model, abstract_trace=True)
+        self._prefill = StaticFunction(
+            _with_greedy(model.prefill_step, bool(self.counters)),
+            layer=model, abstract_trace=True)
         n_state = sum(len(g["arrays"]) for g in
                       state_groups(cache_spec_of(model.config)))
         self._decode = StaticFunction(
-            _with_greedy(model.decode_step), layer=model,
-            abstract_trace=True,
+            _with_greedy(model.decode_step, bool(self.counters)),
+            layer=model, abstract_trace=True,
             donate_args=range(DECODE_STATE_AT, DECODE_STATE_AT + n_state))
 
     @property
@@ -87,23 +101,29 @@ class StepPrograms:
         return len(self._prefill._cache) + len(self._decode._cache)
 
     def prefill(self, ids, lengths, last):
-        """-> (logits [1, V], next_id [1], K, V, *state)."""
+        """-> (logits [1, V], next_id [1 + counters], K, V, *state)."""
         with no_grad():
             return self._prefill(ids, lengths, last)
 
     def decode(self, ids, cache_lengths, active, k_cache, v_cache, *state):
-        """One decode step -> (logits [S, V], next_ids [S], K, V columns,
-        *state).  `state`: the raw device arrays, DONATED — dead when this
-        returns; the `*state` results replace them."""
+        """One decode step -> (logits [S, V], next_ids [S + counters], K,
+        V columns, *state).  `state`: the raw device arrays, DONATED —
+        dead when this returns; the `*state` results replace them."""
         with no_grad():
             return self._decode(ids, cache_lengths, active, k_cache,
                                 v_cache, *[Tensor(s) for s in state])
 
 
-def _with_greedy(step):
-    """`step` with the greedy pick of its logits put in after them.  The
-    arguments keep their positions (`DECODE_STATE_AT`, the donation)."""
+def _with_greedy(step, counted=False):
+    """`step` with the greedy pick of its logits put in after them, and,
+    where the model's step ends in a vector of counts (`counted`), that
+    vector taken off the end and appended to the ids.  The arguments keep
+    their positions (`DECODE_STATE_AT`, the donation)."""
     def step_and_pick(*args):
         logits, *rest = step(*args)
-        return (logits, argmax(logits, axis=-1, dtype="int32"), *rest)
+        picked = argmax(logits, axis=-1, dtype="int32")
+        if counted:
+            *rest, counts = rest
+            picked = concat([picked, counts])
+        return (logits, picked, *rest)
     return step_and_pick

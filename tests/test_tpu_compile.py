@@ -93,3 +93,91 @@ def test_state_update_of_granite_h_micro_compiles_in_place(
     slab_bytes = int(np.prod(slab_shape)) * 4
     assert mem.alias_size_in_bytes == slab_bytes
     assert mem.temp_size_in_bytes < slab_bytes // layers  # under one entry
+
+
+def test_state_update_at_128_heads_compiles_in_two_parts_in_place(
+        one_chip, no_compile_cache, monkeypatch):
+    """The one-token update over the donated `[5, 64, 128, 64, 128]`
+    float32 state array of the decoder with 128 Mamba heads (a row's
+    entry is 4.2 MB: four of them pass the VMEM budget, so the kernel
+    takes it in two halves of 64 heads): still one Mosaic kernel a layer
+    aliased to the array, no copy of it, the array held once."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.kernels import ssm
+    from paddle_tpu.ops.registry import OpContext
+    monkeypatch.setattr(ssm, "_interpret", lambda: False)
+    layers, b, h, p, n, g = 5, 64, 128, 64, 128, 8
+    slab_shape = (layers, b, h, p, n)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def two_layers(slab, x, dt, a, bm, cm, d, bias, active):
+        ctx = OpContext(seed=0, is_test=True)
+        for index in (1, 2):
+            out = ssm.mamba2_state_update(
+                {"X": x, "Dt": dt, "A": a, "B": bm, "C": cm, "D": d,
+                 "State": slab, "DtBias": bias, "Lengths": active},
+                {"slab_index": index}, ctx)
+            slab, x = out["NewState"], out["Y"]
+        return slab, x
+
+    assert ssm._slab_parts(h, p, n) == 2
+    with jax.enable_x64(False):
+        compiled = jax.jit(two_layers, donate_argnums=0).lower(
+            sds(slab_shape, jnp.float32), sds((b, h, p), jnp.bfloat16),
+            sds((b, h), jnp.bfloat16), sds((h,), jnp.float32),
+            sds((b, g, n), jnp.bfloat16), sds((b, g, n), jnp.bfloat16),
+            sds((h,), jnp.float32), sds((h,), jnp.float32),
+            sds((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    slab = r"f32\[5,64,128,64,128\]"
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          text)) == 2
+    assert not re.findall(rf"= {slab}\S* copy\(", text)
+    assert not re.findall(rf"= {slab}\S* fusion\(", text)
+    mem = compiled.memory_analysis()
+    slab_bytes = int(np.prod(slab_shape)) * 4
+    assert mem.alias_size_in_bytes == slab_bytes
+    assert mem.temp_size_in_bytes < slab_bytes // layers
+
+
+@pytest.mark.parametrize("rows", [64, 512], ids=["decode64", "prefill512"])
+def test_grouped_experts_at_published_widths_compile_to_two_kernels(
+        one_chip, no_compile_cache, monkeypatch, rows):
+    """The held share of an expert layer at the published widths (128 of
+    512 experts of 1,024 -> 2,688 -> 1,024, top 22): the sorted pairs go
+    through two Mosaic grouped matmuls, and the program holds no dense
+    [pairs, experts, ...] product (a dense expansion of a 64-row step
+    would be 128x its 1,408 x 2,688 hidden rows)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.kernels import moe
+    from paddle_tpu.ops.registry import OpContext
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    total, k, d, f, held = 512, 22, 1024, 2688, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def experts(x, picked, weights, w1, w2, lengths):
+        out = moe.moe_grouped_experts(
+            {"X": x, "Experts": picked, "Weights": weights, "W1": w1,
+             "W2": w2, "Lengths": lengths},
+            {"n_experts": total, "first_held": 128, "held": held},
+            OpContext(seed=0, is_test=True))
+        return out["Out"], out["Stats"]
+
+    b, t = (rows, 1) if rows == 64 else (1, rows)
+    with jax.enable_x64(False):
+        compiled = jax.jit(experts).lower(
+            sds((b, t, d), jnp.bfloat16), sds((b, t, k), jnp.int32),
+            sds((b, t, k), jnp.float32), sds((held, d, f), jnp.bfloat16),
+            sds((held, f, d), jnp.bfloat16), sds((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          text)) == 2
+    pairs = rows * k
+    hidden_bytes = pairs * f * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * hidden_bytes
