@@ -66,7 +66,10 @@ the device, reserved with the pages at admission and released with them.
 Such a model runs through ``StepPrograms`` (step_program.py): prefill
 and decode are ONE compiled program per (phase, bucket), prompts carry
 their lengths so that bucket padding never enters the recurrence, and an
-idle decode row's state comes back unchanged.  ``prefix_cache=`` and
+idle decode row's state comes back unchanged.  On that route the loop
+keeps ONE decode step in flight: a step is read and booked after the next
+compiled program has been dispatched behind it, from the ids the step
+left on the device (``_step_compiled``).  ``prefix_cache=`` and
 ``speculative=`` refuse such a model (they need state snapshots at page
 boundaries and rollback of a state; ROADMAP R-h).
 """
@@ -76,7 +79,7 @@ import itertools
 import threading
 import time
 from concurrent.futures import Future
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -131,6 +134,17 @@ class _Slot:
         if self.table is not None:
             return self.table.length
         return self.kv[0][0].shape[1]
+
+
+class _Launch(NamedTuple):
+    """A compiled decode step whose results the host has not read: what it
+    was dispatched for and its results, on the device."""
+    pairs: list             # the (row, _Slot) it was dispatched for
+    lengths: np.ndarray     # the cache lengths it went in with, [S]
+    logits: Optional[Tensor]    # where a row of it samples, else None
+    next_ids: Tensor
+    k_new: Tensor
+    v_new: Tensor
 
 
 class ContinuousBatchingEngine:
@@ -256,6 +270,11 @@ class ContinuousBatchingEngine:
             max_slots = int(plan["max_slots"]) if plan else 4
         self.max_slots = int(max_slots)
         self._steps = None
+        # the compiled route's one decode step in flight (dispatched, not
+        # yet read) and the counts of the last step read, which wait for
+        # the next `engine/step` span
+        self._in_flight: Optional[_Launch] = None
+        self._step_counts = None
         if self._stateful:
             state = self._pool.state
             if state is None or state.groups != state_groups(spec) \
@@ -407,6 +426,7 @@ class ContinuousBatchingEngine:
         # the decode thread is dead now: fail whatever it left in-flight
         # (drain=False, or a drain that timed out) instead of letting
         # callers hang on their futures — and give its pages back
+        self._in_flight = self._step_counts = None
         for i, slot in enumerate(self._slots):
             if slot is not None:
                 if not slot.req.future.done():
@@ -491,12 +511,16 @@ class ContinuousBatchingEngine:
     # Spans (docs/observability.md "Spans"): every stretch of this thread
     # is inside one of engine/idle, engine/admit, engine/prefill or
     # engine/step, and inside the last two each host<->device crossing
-    # has a child with its `bytes`.
+    # has a child with its `bytes`.  On the compiled route a decode step
+    # is read AFTER the next launch has been dispatched (`_step_compiled`,
+    # `_prefill_compiled`), so the loop neither idles nor skips a step
+    # while one is in flight.
     def _decode_loop(self):
         while True:
             with self._mu:
                 while self._running and not self._queue \
-                        and not any(self._slots):
+                        and not any(self._slots) \
+                        and self._in_flight is None:
                     self._idle.notify_all()
                     if self._draining:
                         return
@@ -530,7 +554,7 @@ class ContinuousBatchingEngine:
                         self._pool.close_sequence(table)
                     req.future.set_exception(e)
             try:
-                if any(self._slots):
+                if any(self._slots) or self._in_flight is not None:
                     with RecordEvent("engine/step") as span:
                         if self._stateful:
                             self._step_compiled(span)
@@ -624,8 +648,11 @@ class ContinuousBatchingEngine:
         with self._mu:
             if self._stateful:
                 # a step that raised had already given its state arrays
-                # away: every sequence is failed below, so the pool starts
-                # again from zeroed arrays in place of the dead ones
+                # away: every sequence is failed below — the rows of a step
+                # still in flight with them, which is dropped unread — so
+                # the pool starts again from zeroed arrays in place of the
+                # dead ones
+                self._in_flight = None
                 self._pool.state.recover()
             for i, slot in enumerate(self._slots):
                 if slot is not None:
@@ -897,7 +924,10 @@ class ContinuousBatchingEngine:
         the logits on the device), the attention layers' KV (to the
         pool's pages, copy-on-write sharing as ever) and the sequence's
         state after its last prompt token, which is written into its
-        state slot on the device."""
+        state slot on the device.  A decode step in flight is read here,
+        between this program's dispatch and the fetch of its id: the
+        prompt runs behind that step while the host books it (`_retire`);
+        after a prefill nothing is in flight."""
         if req.future.cancelled():
             self._pool.close_sequence(table)
             return
@@ -917,6 +947,12 @@ class ContinuousBatchingEngine:
         with RecordEvent("engine/forward", bucket=pp, rows=1):
             logits, next_id, k, v, *state = self._steps.prefill(
                 ids_t, len_t, last_t)
+        ahead, self._in_flight = self._in_flight, None
+        if ahead is not None:
+            try:
+                self._retire(ahead)
+            except Exception as e:  # noqa: BLE001 — the step's rows, not
+                self._fail_all(e)   # this request: its program is its own
         samples = req.strategy == "sampling"
         counted = bool(self._steps.counters)
         with RecordEvent("engine/fetch") as fetch:      # 4 bytes if greedy
@@ -926,7 +962,7 @@ class ContinuousBatchingEngine:
                 fetch, *([next_id] if counted or not samples else []),
                 *([logits] if samples else []))
         if counted:
-            self._count_step(span, got[0][1:])
+            span.set(**self._count_step(got[0][1:]))
         metrics.count("gen.prefill_tokens", p)
         metrics.count("gen.logits_rows_fetched" if samples
                       else "gen.sampled_on_device")
@@ -968,42 +1004,68 @@ class ContinuousBatchingEngine:
         state arrays as they sit on the device; out come a logits
         row a slot and its argmax, the new KV column a slot — appended to
         the view and, for the record, to the pages — and the updated state
-        arrays (an idle row's state comes back as it went in).  Greedy
-        tokens are the device's: the host fetches the ids, and the logits
-        only in a step where some active row's request samples, whole
-        then: a download costs the link a round trip, hardly its bytes
-        (`serving.gen.sampled_on_device` counts the tokens taken from
-        the ids, `logits_rows_fetched` the rows of logits that crossed).
-        The step is GIVEN the state arrays: they are donated through the
-        compiled program and dead when it returns, so `rebind` follows
-        the call at once (a step that raises: `_fail_all` ->
-        `StateSlots.recover`)."""
+        arrays (an idle row's state comes back as it went in).  The step
+        is GIVEN the state arrays: they are donated through the compiled
+        program and dead when it returns, so `rebind` follows the call at
+        once (a step that raises: `_fail_all` -> `StateSlots.recover`).
+
+        ONE LAUNCH IS KEPT IN FLIGHT: a step is dispatched and left
+        unread (`_in_flight`), and read (`_retire`) right after the next
+        compiled program — the next step, here, or an admission's prefill
+        — has been dispatched behind it, while the device runs.  The next
+        step needs nothing the host has not got: its ids are the step in
+        flight's `next_ids`, on the device (greedy rows: the device's own
+        picks), a row's length is its last one + 1, and a row whose
+        budget ends with the step in flight goes in idle.  A row that
+        ends on EOS is found out a step late: the step ahead computed one
+        row too many (`serving.gen.rows_past_end`), which `_retire`
+        skips.  A step with a row that samples is read at once, as ever:
+        that row's next id is made on the host from the logits."""
+        ahead = self._in_flight
         with self._mu:
             for i, s in enumerate(self._slots):
                 if s is not None and s.req.future.cancelled():
                     metrics.count("gen.cancelled")
                     self._pool.close_sequence(s.table)
                     self._slots[i] = None
+            # with a step in flight every live row is one of its rows (an
+            # admission's prefill reads it), a token ahead of `n_new`
             active = [(i, s) for i, s in enumerate(self._slots)
-                      if s is not None]
-        if not active:
+                      if s is not None and s.n_new + (ahead is not None)
+                      < s.req.max_new]
+        if self._step_counts:   # of a step read since the last step span
+            span.set(**self._step_counts)
+            self._step_counts = None
+        if not active:          # every row ends with the step in flight
+            self._in_flight = None
+            if ahead is not None:
+                self._retire(ahead, span)
             return
         S = self.max_slots
-        lpad = _next_pow2(max(s.kv_len for _, s in active), self._kv_floor)
-        span.set(active=len(active), lpad=lpad,
-                 context=sum(s.kv_len for _, s in active))
+        state = self._pool.state
+        with RecordEvent("engine/build"):   # nothing to gather: the dense
+            lengths = np.zeros(S, np.int32)     # KV view is on the device
+            alive = np.zeros(S, np.int32)
+            for i, s in active:
+                # `table.length` lags by the append not yet made
+                lengths[i] = s.kv_len if ahead is None \
+                    else ahead.lengths[i] + 1
+                alive[i] = 1
+            feeds = [lengths, alive]
+            if ahead is None:   # in the form a step returns its ids
+                ids = np.zeros(S + len(self._steps.counters), np.int32)
+                for i, s in active:
+                    ids[i] = s.next_id
+                feeds.insert(0, ids)
+        lpad = _next_pow2(int(lengths.max()), self._kv_floor)
+        span.set(active=len(active), lpad=lpad, context=int(lengths.sum()),
+                 ahead=int(ahead is not None))
         with self._mu:
             self._kv_buckets.add(("decode", lpad))
             metrics.gauge("gen.kv_buckets", len(self._kv_buckets))
-        state = self._pool.state
-        with RecordEvent("engine/build"):   # nothing to gather: the dense
-            ids = np.zeros((S, 1), np.int32)    # KV view is on the device
-            lengths = np.zeros(S, np.int32)
-            alive = np.zeros(S, np.int32)
-            for i, s in active:
-                ids[i, 0] = s.next_id
-                lengths[i], alive[i] = s.kv_len, 1
-        uploaded = self._upload(ids, lengths, alive)
+        uploaded = self._upload(*feeds)
+        if ahead is not None:
+            uploaded.insert(0, ahead.next_ids)
         with RecordEvent("engine/forward", bucket=lpad, rows=len(active)):
             logits, next_ids, k_new, v_new, *new_state = self._steps.decode(
                 *uploaded, *[Tensor(a) for a in state.kv_view(lpad)],
@@ -1011,27 +1073,60 @@ class ContinuousBatchingEngine:
             state.rebind(**{n: t._value
                             for n, t in zip(state.names, new_state)})
             state.append_kv(k_new._value, v_new._value, lengths)
-        greedy = sum(s.req.strategy != "sampling" for _, s in active)
+        samples = any(s.req.strategy == "sampling" for _, s in active)
+        launch = _Launch(active, lengths, logits if samples else None,
+                         next_ids, k_new, v_new)
+        self._in_flight = None if samples else launch
+        metrics.count("gen.steps")
+        metrics.count("gen.steps_ahead", int(ahead is not None))
+        metrics.observe("gen.step_occupancy", len(active))
+        if ahead is not None:
+            self._retire(ahead, span)
+        if samples:
+            self._retire(launch)
+
+    def _retire(self, launch: _Launch, step_span: RecordEvent = None):
+        """Read a decode step's results and book them: the ids (the
+        logits too, whole, where a row of it samples: a download costs the
+        link a round trip, hardly its bytes), the two KV columns to the
+        pages, the tokens to their sequences; finished rows resolve their
+        futures and free their slots.  A row whose slot has meanwhile
+        finished, was cancelled or belongs to another request is skipped:
+        the step computed it past its sequence's end, its column goes to
+        no page, its id to no sequence, and its state and dense-view
+        column lie in a slot the next prefill overwrites.  A launch with
+        no row left is dropped unread.  What the step counted becomes
+        fields of the first `engine/step` span to open after the step's
+        own — `step_span` where that is the one it is read under, else the
+        next to open (a prefill's span carries the prefill's own counts):
+        every step span has them, one step behind its own launch."""
+        pairs = [(i, s) for i, s in launch.pairs if self._slots[i] is s]
+        metrics.count("gen.rows_past_end", len(launch.pairs) - len(pairs))
+        if not pairs:
+            return
+        S = self.max_slots
         with RecordEvent("engine/fetch") as fetch:
             picked, k_col, v_col, *step_logits = self._download(
-                fetch, next_ids, k_new, v_new,
-                *([logits] if greedy < len(active) else []))
+                fetch, launch.next_ids, launch.k_new, launch.v_new,
+                *([launch.logits] if launch.logits is not None else []))
             k_col = k_col[:, :, :, 0].astype(np.float32)   # [L, S, H, Dh]
             v_col = v_col[:, :, :, 0].astype(np.float32)
         if self._steps.counters:
-            self._count_step(span, picked[S:])
-        metrics.count("gen.steps")
-        metrics.count("gen.tokens", len(active))
+            self._step_counts = self._count_step(picked[S:])
+            if step_span is not None:
+                step_span.set(**self._step_counts)
+                self._step_counts = None
+        greedy = sum(s.req.strategy != "sampling" for _, s in pairs)
+        metrics.count("gen.tokens", len(pairs))
         metrics.count("gen.sampled_on_device", greedy)
         metrics.count("gen.logits_rows_fetched", S if step_logits else 0)
-        metrics.observe("gen.step_occupancy", len(active))
         retired = []
         with RecordEvent("engine/kv_append") as append:
-            for i, s in active:
+            for i, s in pairs:
                 self._pool.append_column(s.table, k_col[:, i], v_col[:, i])
-            append.set(bytes=len(active) * 2 * k_col[:, 0].nbytes)
+            append.set(bytes=len(pairs) * 2 * k_col[:, 0].nbytes)
         with RecordEvent("engine/sample"):
-            for i, s in active:
+            for i, s in pairs:
                 s.tokens.append(s.next_id)
                 nxt = self._sample(s.req, step_logits[0][i]) \
                     if s.req.strategy == "sampling" else int(picked[i])
@@ -1047,19 +1142,20 @@ class ContinuousBatchingEngine:
             metrics.gauge("gen.active_slots",
                           sum(s is not None for s in self._slots))
 
-    def _count_step(self, span: RecordEvent, counts):
+    def _count_step(self, counts) -> dict:
         """What a compiled step counted on the device (the model's
-        `step_counters`, downloaded behind the ids): fields of the step's
-        or prefill's span under the model's names, and whatever
+        `step_counters`, downloaded behind the ids) under the model's
+        names — fields for a span: a prefill's own, the next `engine/step`
+        span's for a decode step (`_retire`) — and, counted here, whatever
         `serving.*` counters and gauges the model makes of them
         (`step_metrics`: the engine knows no model's names)."""
         got = dict(zip(self._steps.counters, (int(c) for c in counts)))
-        span.set(**got)
         counted, gauged = self._model.step_metrics(got)
         for name, n in counted.items():
             metrics.count(name, n)
         for name, value in gauged.items():
             metrics.gauge(name, value)
+        return got
 
     def _step_spec(self, span: RecordEvent):
         """One SPECULATIVE decode step over every active slot: the

@@ -18,6 +18,12 @@ whole serving story from a single ``/stats`` scrape:
               compiled step route: tokens taken from the step program's
               own argmax / logits rows downloaded, every slot's in a step
               where some row samples, none under greedy traffic),
+              serving.gen.steps_ahead / rows_past_end (the compiled step
+              route keeps one launch in flight: decode steps dispatched
+              from the device's ids before the step before them was read,
+              over serving.gen.steps how often that engages / rows a
+              step computed for a sequence that had already ended, an EOS
+              found out one step late),
               serving.moe.pairs_routed / pairs_held / experts_touched /
               expert_steps (a model with routed experts, counted on the
               device by its compiled steps and fetched behind the ids:

@@ -17,14 +17,28 @@ Program (from shapes alone, `abstract_trace`: nothing runs eagerly) and
 run as one jitted XLA computation, under `eval()` and `no_grad`, so no
 tape is kept.
 
-PICKING A TOKEN IS THE ENGINE'S BUSINESS, and the greedy pick is made
-here, inside the same program: what `prefill` / `decode` return is the
-model's tuple with `next_ids = argmax(logits, axis=-1)` (int32, the FIRST
-maximal index as `np.argmax` gives it, on the float32 logits themselves)
-put in after the logits:
+WHAT `StepPrograms` RETURNS, AND THE FORM IT TAKES THE IDS IN.  Picking a
+token is the engine's business, and the greedy pick is made here, inside
+the same program: `prefill` / `decode` return the model's tuple with
+`next_ids = argmax(logits, axis=-1)` (int32, the FIRST maximal index as
+`np.argmax` gives it, on the float32 logits themselves) put in after the
+logits, and behind the ids whatever the step counted (`C` =
+`len(StepPrograms.counters)`, 0 for most models):
 
-    prefill -> (logits [1, V], next_id [1], K, V, *state)
-    decode  -> (logits [S, V], next_ids [S], K, V columns, *state)
+    prefill(ids [1, T], lengths [1], last [1])
+        -> (logits [1, V], next_id  [1 + C], K, V, *state)
+    decode(ids [S + C], cache_lengths [S], active [S], k_cache, v_cache,
+           *state)
+        -> (logits [S, V], next_ids [S + C], K, V columns, *state)
+
+`decode` TAKES ITS IDS IN THE FORM IT RETURNS THEM: int32 `[S + C]`, row
+`i`'s token at `i`, the tail ignored; the reshape to the model's `[S, 1]`
+is inside the program.  So a step's `next_ids` result IS the next step's
+`ids` argument, on the device: the engine dispatches step N+1 from step
+N's ids before it has read them (`generation.py`: one launch in flight),
+with no further launch and no second trace of a bucket, and a step whose
+ids come from the host uploads an array of that same shape.  The model's
+`prefill_step` / `decode_step` contract above is untouched.
 
 A greedy row costs the host link 4 bytes; the logits stay a result, left
 on the device.  Only a step in which some active row's request samples
@@ -39,12 +53,11 @@ WHAT A STEP COUNTED RIDES WITH THE IDS.  A model whose step has
 data-dependent work names it (`model.step_counters`, e.g. the routed
 experts' `moe_pairs`, `moe_touched`) and returns one int32 vector of those
 counts after the state; the wrapper takes it off the tuple and appends it
-to `next_ids` — `[S + len(counters)]` int32, the ids first — so the counts
-reach the host in the download the ids already cost (a download costs a
-link round trip whatever its size) and the tuple keeps its positions.
-`StepPrograms.counters` has the names and `model.step_metrics(counts)` says
-which `serving.*` counters and gauges the engine makes of them; a model
-without any returns the tuples above unchanged.
+to `next_ids`, the ids first, so the counts reach the host in the download
+the ids already cost (a download costs a link round trip whatever its
+size) and the tuple keeps its positions.  `StepPrograms.counters` has the
+names and `model.step_metrics(counts)` says which `serving.*` counters
+and gauges the engine makes of them.
 
 THE DECODE STEP OWNS THE STATE ARRAYS while it runs: the contract's
 `*state` arguments of `decode_step` — every position from `DECODE_STATE_AT`
@@ -52,8 +65,9 @@ on — are donated through the compiled program, whose `*state` results
 have their shapes and dtypes, so XLA writes each layer's new state where
 the old one lies and no second copy of the state exists.  After `decode`
 returns (or raises) the arrays that went in are dead: the caller takes the
-results in their place (`StateSlots.rebind`).  Nothing else is donated: no
-result has the shape of the ids, the lengths or the KV view's slices.
+results in their place (`StateSlots.rebind`).  Nothing else is donated:
+not the ids, which the engine still has to read where they are the step
+before's result, nor the lengths or the KV view's slices.
 
 `GPTModel` is not on this route yet (ROADMAP S2b): its eager forward has
 no such methods.
@@ -62,7 +76,7 @@ from __future__ import annotations
 
 from ..dygraph.base import no_grad
 from ..dygraph.tensor import Tensor
-from ..tensor.manipulation import concat
+from ..tensor.manipulation import concat, reshape, slice as slice_
 from ..tensor.search import argmax
 
 __all__ = ["StepPrograms", "DECODE_STATE_AT"]
@@ -91,7 +105,8 @@ class StepPrograms:
         n_state = sum(len(g["arrays"]) for g in
                       state_groups(cache_spec_of(model.config)))
         self._decode = StaticFunction(
-            _with_greedy(model.decode_step, bool(self.counters)),
+            _with_greedy(model.decode_step, bool(self.counters),
+                         ids_as_picked=True),
             layer=model, abstract_trace=True,
             donate_args=range(DECODE_STATE_AT, DECODE_STATE_AT + n_state))
 
@@ -101,26 +116,32 @@ class StepPrograms:
         return len(self._prefill._cache) + len(self._decode._cache)
 
     def prefill(self, ids, lengths, last):
-        """-> (logits [1, V], next_id [1 + counters], K, V, *state)."""
+        """ids [1, T] -> (logits [1, V], next_id [1 + C], K, V, *state)."""
         with no_grad():
             return self._prefill(ids, lengths, last)
 
     def decode(self, ids, cache_lengths, active, k_cache, v_cache, *state):
-        """One decode step -> (logits [S, V], next_ids [S + counters], K,
-        V columns, *state).  `state`: the raw device arrays, DONATED —
-        dead when this returns; the `*state` results replace them."""
+        """One decode step, ids [S + C] -> (logits [S, V], next_ids
+        [S + C], K, V columns, *state).  `state`: the raw device arrays,
+        DONATED — dead when this returns; the `*state` results replace
+        them."""
         with no_grad():
             return self._decode(ids, cache_lengths, active, k_cache,
                                 v_cache, *[Tensor(s) for s in state])
 
 
-def _with_greedy(step, counted=False):
+def _with_greedy(step, counted=False, ids_as_picked=False):
     """`step` with the greedy pick of its logits put in after them, and,
     where the model's step ends in a vector of counts (`counted`), that
-    vector taken off the end and appended to the ids.  The arguments keep
-    their positions (`DECODE_STATE_AT`, the donation)."""
-    def step_and_pick(*args):
-        logits, *rest = step(*args)
+    vector taken off the end and appended to the ids.  `ids_as_picked`:
+    the ids arrive as this wrapper returns them, `[S + C]`, and are given
+    to `step` as `[S, 1]`.  The arguments keep their positions
+    (`DECODE_STATE_AT`, the donation)."""
+    def step_and_pick(ids, *args):
+        if ids_as_picked:
+            rows = args[0].shape[0]             # cache_lengths [S]
+            ids = reshape(slice_(ids, [0], [0], [rows]), [rows, 1])
+        logits, *rest = step(ids, *args)
         picked = argmax(logits, axis=-1, dtype="int32")
         if counted:
             *rest, counts = rest

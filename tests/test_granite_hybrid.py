@@ -490,7 +490,10 @@ def _pool_and_steps(m, slots=3):
 
 
 def _decode_args(state, ids, lengths, active, columns=32):
-    return [_t(ids, np.int32), _t(lengths, np.int32), _t(active, np.int32),
+    """`StepPrograms.decode`'s arguments before the state: `ids` [S] int32
+    as a step returns them (or such a result itself, on the device)."""
+    ids = ids if isinstance(ids, dg.Tensor) else _t(ids, np.int32)
+    return [ids, _t(lengths, np.int32), _t(active, np.int32),
             *[dg.to_variable(a) for a in state.kv_view(columns)]]
 
 
@@ -505,7 +508,7 @@ def test_decode_program_aliases_both_state_feeds_to_its_results():
     with dg.guard():
         m = _model(11)
         state, steps = _pool_and_steps(m)
-        args = _decode_args(state, np.zeros((3, 1)), [0, 0, 0], [0, 0, 0]) \
+        args = _decode_args(state, np.zeros(3), [0, 0, 0], [0, 0, 0]) \
             + [dg.to_variable(a) for a in state.arrays.values()]
         with dg.no_grad():
             cp = steps._decode.concrete_program(*args)
@@ -560,8 +563,8 @@ def test_decode_consumes_the_state_it_is_given_and_rebind_takes_its_result(
                                               (0.5, 0.25))}
         state.install(2, **made_up, k_dense=k._value * 0, v_dense=v._value)
         for t in range(n):
-            step_ids = np.zeros((3, 1), np.int32)
-            step_ids[1, 0] = ids[p + t]
+            step_ids = np.zeros(3, np.int32)
+            step_ids[1] = ids[p + t]
             lengths = [0, p + t, 0]
             old = list(state.arrays.values())
             logits, _, kn, vn, *new = steps.decode(
@@ -605,7 +608,10 @@ def test_step_programs_pick_the_first_argmax_of_the_logits_they_return(
     """`StepPrograms` returns, after the logits, their argmax as int32:
     what `np.argmax` gives on the same float32 values, the first index
     where the maximum occurs twice.  Prefill: five prompts; decode: 20
-    steps over three rows of which one is idle."""
+    steps over three rows of which one is idle, each step taking the ids
+    in the form the last one returned them - the first an upload from the
+    host, every later one that result itself, on the device: one trace
+    and one executable serve both."""
     with dg.guard():
         m = _model(14)
         vocab = _tied_halves(m) if tied else m.config.vocab_size - 1
@@ -627,7 +633,7 @@ def test_step_programs_pick_the_first_argmax_of_the_logits_they_return(
         pending = np.concatenate(picks[:2] + [[0]]).astype(np.int32)
         for _ in range(20 * (phase == "decode")):
             logits, nxt, kn, vn, *new = steps.decode(
-                *_decode_args(state, pending[:, None], lengths, [1, 1, 0]),
+                *_decode_args(state, pending, lengths, [1, 1, 0]),
                 *state.arrays.values())
             state.rebind(**{name: tensor._value
                             for name, tensor in zip(state.names, new)})
@@ -635,8 +641,11 @@ def test_step_programs_pick_the_first_argmax_of_the_logits_they_return(
             assert nxt.numpy().dtype == np.int32 and nxt.shape == [3]
             picks.append(nxt.numpy()[:2])       # the idle row's is ignored
             rows.append(logits.numpy()[:2])
-            pending[:2] = nxt.numpy()[:2]
+            pending = nxt
             lengths[:2] += 1
+        if phase == "decode":
+            (traced,) = steps._decode._cache.values()
+            assert traced.composed()._cache_size() == 1
     picks, rows = np.concatenate(picks), np.concatenate(rows)
     assert len(picks) == (45 if phase == "decode" else 5)
     np.testing.assert_array_equal(picks, rows.argmax(-1))
@@ -684,26 +693,35 @@ def test_slab_update_is_the_plain_update_in_one_pass(groups, h, p, n):
     np.testing.assert_array_equal(new[1, 1], slab[1, 1])    # the idle row
 
 
-def test_a_step_that_raises_leaves_zeroed_state_and_a_live_engine():
-    """A decode step that fails AFTER it has given its state arrays away:
-    every sequence is failed, the pool gets fresh zeroed arrays in place
-    of the dead ones, and the next request is served as ever."""
+@pytest.mark.parametrize("fails_at", [1, 2], ids=["alone", "one_in_flight"])
+def test_a_step_that_raises_leaves_zeroed_state_and_a_live_engine(fails_at):
+    """A decode step that fails AFTER it has given its state arrays away -
+    the first, or the second, dispatched while the first is still unread:
+    every sequence is failed, the step in flight is dropped, the pool
+    gets fresh zeroed arrays in place of the dead ones, and the next
+    request is served as ever."""
     with dg.guard():
         m = _model(13)
         plan = static.page_budget(m, page_tokens=4, max_context=128,
                                   hbm_bytes=8 << 20, max_slots_cap=2)
         eng = ContinuousBatchingEngine(m, kv_pool=plan).start()
         state, real = eng.kv_pool.state, eng._steps.decode
+        calls, in_flight = [], []
 
         def failing(*args):
-            real(*args)
-            raise RuntimeError("the device fell over")
+            calls.append(real(*args))
+            if len(calls) == fails_at:
+                in_flight.append(eng._in_flight)
+                raise RuntimeError("the device fell over")
+            return calls[-1]
 
         prompt = np.random.default_rng(7).integers(0, 126, 9)
         eng._steps.decode = failing
         with pytest.raises(RuntimeError, match="fell over"):
             eng.submit(prompt, max_length=6).result(timeout=600)
         eng._steps.decode = real
+        assert len(calls) == fails_at and eng._in_flight is None
+        assert (in_flight[0] is not None) == (fails_at == 2)
         for a in state.arrays.values():     # the step had donated these
             assert not a.is_deleted() and not np.asarray(a).any()
         assert not any(a.is_deleted() for a in state.dense.values())
@@ -771,6 +789,115 @@ def test_engine_serves_the_hybrid_model_token_equal_to_one_sequence():
            for op in cp.program.global_block().ops}
     assert {"mamba2_state_update", "causal_conv1d", "gqa_attention",
             "rms_norm", "gated_rms_norm"} <= ops
+
+
+def test_one_launch_in_flight_finds_eos_a_step_late_and_books_no_column():
+    """Three greedy requests of different budgets over two slots, the
+    third waiting for a slot.  The first ends on EOS mid-answer (an id
+    taken from its recorded stream): the step dispatched ahead had
+    computed a row for it (`rows_past_end` 1) that goes to no page and no
+    sequence - a sequence's pages hold its prompt and every token fed, the
+    EOS never - and its slot is taken over by the third request while
+    that step is still unread.  Tokens stay equal to one-sequence
+    decoding; steps follow steps from the device's ids (`steps_ahead`),
+    and neither traces nor compiles anything once every bucket is warm."""
+    reset_serving_stats()
+    with dg.guard():
+        m = _model(8)
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(0, 126, n) for n in (5, 19, 11)]
+        news = (10, 12, 6)
+        streams = [_greedy(m, p, n)[len(p):] for p, n in zip(prompts, news)]
+        eos = streams[0][3]
+        assert eos not in streams[0][:3] + streams[1] + streams[2]
+        m.config.eos_id = eos
+        plan = static.page_budget(m, page_tokens=4, max_context=128,
+                                  hbm_bytes=8 << 20, max_slots_cap=2)
+        eng = ContinuousBatchingEngine(m, kv_pool=plan).start()
+        booked, finish = [], eng._finish
+
+        def finish_and_note(slot):
+            booked.append((len(slot.tokens), slot.table.length))
+            finish(slot)
+
+        eng._finish = finish_and_note
+        outs = [f.result(timeout=600) for f in
+                [eng.submit(p, max_length=n) for p, n in zip(prompts, news)]]
+        assert list(outs[0]) == list(prompts[0]) + streams[0][:4]
+        for prompt, n, out in zip(prompts, news, outs):
+            assert list(out) == _greedy(m, prompt, n)
+        assert sorted(booked) == sorted((len(o), len(o) - 1) for o in outs)
+        stats = serving_stats()
+        assert stats["serving.gen.rows_past_end"] == 1
+        assert stats["serving.gen.state_resets"] == 1   # its slot, re-used
+        assert stats["serving.state.slots_used"] == 0
+        assert 0 < stats["serving.gen.steps_ahead"] < stats["serving.gen.steps"]
+        programs = eng._steps.programs
+        again = eng.submit(prompts[1], max_length=news[1]).result(600)
+        assert list(again) == list(outs[1])
+        assert eng._steps.programs == programs
+        assert {cp.composed()._cache_size() for cp in
+                eng._steps._decode._cache.values()} == {1}
+        eng.stop()
+        eng.kv_pool.assert_drained()
+        assert budget_drift(eng.kv_pool, m) == []
+
+
+def test_a_step_with_a_row_that_samples_is_read_before_the_next_goes_out():
+    """While a request that samples is decoding no step is dispatched
+    ahead - its next id is made on the host, from that step's logits -
+    and it draws the tokens its seed draws; the greedy request that
+    follows it alone is served from the device's ids again."""
+    reset_serving_stats()
+    with dg.guard():
+        m = _model(8)
+        plan = static.page_budget(m, page_tokens=4, max_context=128,
+                                  hbm_bytes=8 << 20, max_slots_cap=2)
+        eng = ContinuousBatchingEngine(m, kv_pool=plan).start()
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(0, 126, n) for n in (5, 19, 7)]
+        how = dict(seed=3, top_k=5, temperature=0.8)
+        futs = [eng.submit(prompts[0], max_length=9,
+                           decode_strategy="sampling", **how),
+                eng.submit(prompts[1], max_length=4)]
+        outs = [f.result(timeout=600) for f in futs]
+        assert list(outs[0]) == _sampled(eng, m, prompts[0], 9, **how)
+        assert list(outs[1]) == _greedy(m, prompts[1], 4)
+        stats = serving_stats()
+        assert stats["serving.gen.steps"] == 8      # the sampler's tokens
+        assert stats["serving.gen.steps_ahead"] == 0
+        out = eng.submit(prompts[2], max_length=5).result(timeout=600)
+        assert list(out) == _greedy(m, prompts[2], 5)
+        stats = serving_stats()
+        assert stats["serving.gen.steps"] == 8 + 4
+        assert stats["serving.gen.steps_ahead"] == 3
+        assert stats["serving.gen.rows_past_end"] == 0
+        eng.stop()
+        eng.kv_pool.assert_drained()
+
+
+def test_stop_drains_with_a_launch_in_flight():
+    """`stop(drain=True)` called while a decode step is in flight and a
+    request still queued: every future resolves with its whole answer,
+    and nothing is left in flight."""
+    with dg.guard():
+        m = _model(8)
+        plan = static.page_budget(m, page_tokens=4, max_context=128,
+                                  hbm_bytes=8 << 20, max_slots_cap=2)
+        eng = ContinuousBatchingEngine(m, kv_pool=plan).start()
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(0, 126, n) for n in (5, 19, 11)]
+        news = (40, 30, 6)
+        futs = [eng.submit(p, max_length=n) for p, n in zip(prompts, news)]
+        deadline = time.monotonic() + 600
+        while eng._in_flight is None and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert eng._in_flight is not None
+        eng.stop(drain=True, timeout=600)
+        assert all(f.done() for f in futs) and eng._in_flight is None
+        for prompt, n, f in zip(prompts, news, futs):
+            assert list(f.result(timeout=0)) == _greedy(m, prompt, n)
+        eng.kv_pool.assert_drained()
 
 
 def _sampled(eng, m, prompt, n, seed, top_k=0, temperature=1.0):
@@ -859,11 +986,14 @@ def test_a_greedy_step_fetches_ids_and_kv_columns_and_no_logits():
             active = [e.fields["rows"] for e in events
                       if e.name == "engine/forward"
                       and e.parent == "engine/step"]
-            fetches, prefills = ([e.fields["bytes"] for e in events
-                                  if e.name == "engine/fetch"
-                                  and e.parent == parent]
-                                 for parent in ("engine/step",
-                                                "engine/prefill"))
+            # a step is read under the next step's span or under a
+            # prefill's, after that program's dispatch: told apart from a
+            # prefill's own fetch (an id, or one logits row) by its size
+            fetched = [e.fields["bytes"] for e in events
+                       if e.name == "engine/fetch"]
+            assert greedy_step > 4 * vocab
+            fetches = [b for b in fetched if b >= greedy_step]
+            prefills = [b for b in fetched if b < greedy_step]
             assert len(fetches) == len(active) and len(prefills) == 2
             tokens = [len(o) - len(p) for o, p in zip(outs, prompts)]
             return active, fetches, prefills, tokens, serving_stats()

@@ -569,27 +569,35 @@ def test_engine_serves_the_share_token_equal_and_counts_what_it_routed():
         assert stats["serving.moe.load_max_over_mean"] >= 1.0
     spans = [e for e in events if e.name in ("engine/step", "engine/prefill")]
     assert len([e for e in spans if e.name == "engine/prefill"]) == 10
+    # a prefill's counts are on its own span; a decode step is read after
+    # the next launch was dispatched, so its counts are on the next step
+    # span to open after its own: only the last step's are on no span
     for name, key in (("moe_routed", "pairs_routed"),
                       ("moe_pairs", "pairs_held"),
                       ("moe_touched", "experts_touched")):
-        # (the last step's span may close after the session: at most one
-        # call's worth is missing from the spans)
         got = sum(e.fields[name] for e in spans if name in e.fields)
         assert 0 <= stats["serving.moe." + key] - got <= slots * 3 * K
-    step = next(e for e in spans if e.name == "engine/step"
-                and e.fields.get("active", 0) >= 2)
-    assert step.fields["moe_routed"] == 3 * K * step.fields["active"]
-    assert step.fields["moe_touched"] <= min(12, step.fields["moe_pairs"])
-    assert step.fields["moe_max_load"] >= 1
+    assert sum(e.fields["moe_routed"] for e in spans
+               if e.name == "engine/prefill") == 3 * K * sum(lengths)
+    steps = [e for e in spans if e.name == "engine/step"
+             and "active" in e.fields]
+    assert "moe_routed" not in steps[0].fields and len(steps) >= 5
+    for before, step in zip(steps, steps[1:]):
+        # one step behind its own launch: the rows of the step before
+        assert step.fields["moe_routed"] == 3 * K * before.fields["active"]
+        assert step.fields["moe_touched"] <= min(12,
+                                                 step.fields["moe_pairs"])
+        assert step.fields["moe_max_load"] >= 1
+    assert any(step.fields["active"] >= 2 and step.fields["ahead"]
+               for step in steps)
     # no further download: ids (+ the 4 counts, 16 bytes in the same
-    # array) and the two KV columns a step, 4 + 16 bytes a prefill
+    # array) and the two KV columns a step - read under the next step's
+    # span or under a prefill's - and 4 + 16 bytes a prefill
     state = eng.kv_pool.state
     columns = sum(a.nbytes // 16 for a in state.kv_view(16))
-    fetches, prefills = ([e.fields["bytes"] for e in events
-                          if e.name == "engine/fetch" and e.parent == parent]
-                         for parent in ("engine/step", "engine/prefill"))
-    assert set(fetches) == {4 * (slots + 4) + columns}
-    assert set(prefills) == {4 * (1 + 4)}
+    fetches = [e.fields["bytes"] for e in events if e.name == "engine/fetch"]
+    assert set(fetches) == {4 * (slots + 4) + columns, 4 * (1 + 4)}
+    assert fetches.count(4 * (1 + 4)) == 10
     assert stats.get("serving.gen.logits_rows_fetched", 0) == 0
     ops = {op.type for cp in eng._steps._decode._cache.values()
            for op in cp.program.global_block().ops}
@@ -600,6 +608,47 @@ def test_engine_serves_the_share_token_equal_and_counts_what_it_routed():
               for op in cp.program.global_block().ops
               if op.type == "gated_rms_norm"}
     assert groups == {2}
+
+
+def test_eos_is_found_a_step_late_beside_the_counts():
+    """Three greedy requests over two slots, the first ending on EOS
+    mid-answer (an id from its recorded stream) while the step dispatched
+    ahead - from ids AND counts as the last step returned them, on the
+    device - had already computed its next row: that row is booked nowhere
+    (`rows_past_end` 1), the third request takes the slot over, tokens
+    equal one-sequence decoding, and the device's count of routed pairs
+    is every token fed plus that one row."""
+    reset_serving_stats()
+    with dg.guard():
+        m = _model(9, held_experts=4, first_held=4)
+        rng = np.random.default_rng(6)
+        prompts = [rng.integers(0, 126, n) for n in (7, 15, 10)]
+        news = (10, 12, 3)
+        streams = [_greedy(m, p, n)[len(p):] for p, n in zip(prompts, news)]
+        eos = streams[0][3]
+        assert eos not in streams[0][:3] + streams[1] + streams[2]
+        m.config.eos_id = eos
+        plan = static.page_budget(m, page_tokens=4, max_context=128,
+                                  hbm_bytes=16 << 20, max_slots_cap=2)
+        eng = ContinuousBatchingEngine(m, kv_pool=plan).start()
+        outs = [f.result(timeout=900) for f in
+                [eng.submit(p, max_length=n) for p, n in zip(prompts, news)]]
+        programs = eng._steps.programs
+        assert list(outs[0]) == list(prompts[0]) + streams[0][:4]
+        for prompt, n, out in zip(prompts, news, outs):
+            assert list(out) == _greedy(m, prompt, n)
+        again = eng.submit(prompts[1], max_length=news[1]).result(900)
+        assert list(again) == list(outs[1])
+        assert eng._steps.programs == programs
+        assert {cp.composed()._cache_size() for cp in
+                eng._steps._decode._cache.values()} == {1}
+        eng.stop()
+        eng.kv_pool.assert_drained()
+    stats = serving_stats()
+    assert stats["serving.gen.rows_past_end"] == 1
+    assert 0 < stats["serving.gen.steps_ahead"] < stats["serving.gen.steps"]
+    fed = sum(len(o) - 1 for o in outs + [again])   # all but the last token
+    assert stats["serving.moe.pairs_routed"] == 3 * K * (fed + 1)
 
 
 def test_a_sampling_row_keeps_its_seeded_tokens_beside_the_counts():
