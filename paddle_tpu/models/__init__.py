@@ -19,3 +19,6 @@ from .granite_hybrid import (  # noqa: F401
 from .nemotron_h import (  # noqa: F401
     NemotronHConfig, NemotronHModel, nemotron_h_tiny,
 )
+from .cohere2_moe import (  # noqa: F401
+    Cohere2MoeConfig, Cohere2MoeModel, cohere2_moe_tiny,
+)

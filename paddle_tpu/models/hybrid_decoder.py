@@ -1,17 +1,26 @@
 """A hybrid decoder from a per-layer block description: each layer has a
 mixer (Mamba-2 state-space, grouped-query attention, or none) and a
 feed-forward part (a gated MLP, routed experts, or none), each applied as
-``h = h + residual_multiplier * f(RMSNorm(h))``; an embedding multiplier,
-a logits divisor, and a tied or untied head.  `cache_spec`,
+``h = h + residual_multiplier * f(norm(h))`` one after the other, or, in
+the `parallel` block form, both from ONE norm of the same `h`; the norm is
+RMSNorm or a mean-subtracting LayerNorm (scale only); an attention layer
+may state a `window` and rotary positions (`AttentionSpec`); the experts
+are `latent_relu2` (a latent space, ``relu^2``, a selection bias, one
+shared expert) or `gated_silu` (full width, ``silu(g) * u``, `n_shared`
+shared experts averaged, no bias); an embedding multiplier, a logits
+divisor, and a tied or untied head.  `cache_spec`,
 `param_shapes`, the full `forward` and the two steps of the serving
 contract are emitted ONCE from that description; a model family is a
 config class that gives the description (`models/granite_hybrid.py`,
 `models/nemotron_h.py`), not a copy of the layers or the loops.
 
-Serving only: the ops (`ops/kernels/ssm.py`, `ops/kernels/moe.py`) are
-forward only, so the parameters do not ask for gradients and no tape is
-ever kept.  The model states its cache (`cache_spec`): one `kv` group for
-the attention layers and one `state` group for the Mamba layers —
+Serving only: the ops (`ops/kernels/ssm.py`, `ops/kernels/moe.py`,
+`ops/kernels/window_attention.py`) are forward only, so the parameters do
+not ask for gradients and no tape is ever kept.  The model states its
+cache (`cache_spec`): one `kv` group for the attention layers — or, for a
+config with `kv_on_device`, one `kv` group per kind of retention (`retain`:
+a window's columns in a ring, or `"all"`), whose arrays then ride the step
+contract like the state's — and one `state` group for the Mamba layers —
 `serving.PagedKVPool`, `static.page_budget` and the engine size
 themselves from it.  Two cache-aware entry points make the step contract
 (ids, per-row lengths, cache in; last-row logits, cache out):
@@ -38,16 +47,23 @@ from ..tensor.manipulation import (cast, gather, reshape, split, squeeze,
                                    stack, transpose, unsqueeze, unstack)
 from ..tensor.math import add, multiply, scale
 
-__all__ = ["LayerSpec", "HybridDecoderConfig", "HybridDecoder",
-           "MOE_COUNTERS"]
+__all__ = ["LayerSpec", "AttentionSpec", "HybridDecoderConfig",
+           "HybridDecoder", "MOE_COUNTERS"]
 
 # one layer: `mixer` "mamba" | "attention" | None; `ffn` "mlp" | "experts"
 # | None
 LayerSpec = collections.namedtuple("LayerSpec", "mixer ffn")
+# what an attention mixer may state beside (`config.attention_specs`, by
+# layer): `window` (None: every earlier token is seen) and `rotary` (None:
+# no positions, else theta)
+AttentionSpec = collections.namedtuple("AttentionSpec", "window rotary",
+                                       defaults=(None, None))
 MIXERS, FFNS = ("mamba", "attention", None), ("mlp", "experts", None)
 # what `moe_grouped_experts` counts a call (ops/kernels/moe.py `STATS`),
 # summed over a step's expert layers
 MOE_COUNTERS = ("moe_routed", "moe_pairs", "moe_touched", "moe_max_load")
+BLOCK_FORMS, NORM_KINDS = ("sequential", "parallel"), ("rms", "layer")
+EXPERT_FORMS = ("latent_relu2", "gated_silu")
 
 
 class HybridDecoderConfig:
@@ -60,12 +76,40 @@ class HybridDecoderConfig:
     `n_routed_experts` / `held_experts` / `first_held` sizes of an expert
     layer, `embedding_multiplier`, `residual_multiplier`, `logits_scaling`,
     `tie_word_embeddings`, `rms_norm_eps`, and serving's own
-    (`max_position`, `bos_id`, `eos_id`, `dtype`, `embed_init_rms`)."""
+    (`max_position`, `bos_id`, `eos_id`, `dtype`, `embed_init_rms`).  A
+    family that differs from the defaults below says so under their
+    names."""
+
+    attention_specs = {}            # layer index -> AttentionSpec
+    block_form = "sequential"       # | "parallel": one norm, both from x
+    norm_kind = "rms"               # | "layer": mean-subtracting, scale only
+    expert_form = "latent_relu2"    # | "gated_silu"
+    n_shared_experts = 1            # averaged where there are several
+    # the KV cache on the device only, a `kv` group per kind of retention,
+    # its arrays in the step contract (else one group and a dense view)
+    kv_on_device = False
+    # a family without Mamba layers states no `mamba_*` sizes
+    mamba_n_heads = mamba_d_head = mamba_d_state = mamba_n_groups = 0
+    mamba_expand = mamba_d_conv = 0
+    shared_intermediate_size = 0
 
     def _check(self):
         for spec in self.blocks:
             if spec.mixer not in MIXERS or spec.ffn not in FFNS:
                 raise ValueError(f"unknown layer description {spec}")
+        for i, spec in self.attention_specs.items():
+            if self.blocks[i].mixer != "attention":
+                raise ValueError(f"layer {i} has no attention to describe")
+            if (spec.window or spec.rotary) and not self.kv_on_device:
+                raise NotImplementedError(
+                    "a window or rotary positions need the blocked "
+                    "attention ops of a config with kv_on_device")
+        if self.block_form not in BLOCK_FORMS \
+                or self.norm_kind not in NORM_KINDS \
+                or self.expert_form not in EXPERT_FORMS:
+            raise ValueError(
+                f"unknown form: block {self.block_form!r}, norm "
+                f"{self.norm_kind!r}, experts {self.expert_form!r}")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("attention heads / kv heads do not divide")
         if self.mamba_inner != self.mamba_n_heads * self.mamba_d_head:
@@ -98,7 +142,22 @@ class HybridDecoderConfig:
 
     def layers_of(self, kind):
         """Indices of the layers whose mixer or feed-forward is `kind`."""
-        return [i for i, s in enumerate(self.blocks) if kind in s]
+        return [i for i, s in enumerate(self.blocks)
+                if kind in (s.mixer, s.ffn)]
+
+    def attention_spec(self, index):
+        return self.attention_specs.get(index, AttentionSpec())
+
+    def kv_groups(self):
+        """The attention layers by what they retain, in order of first
+        appearance: [(window or None, [layer indices])] — one entry for a
+        config whose KV is not on the device."""
+        groups = {}
+        for i in self.layers_of("attention"):
+            key = self.attention_spec(i).window if self.kv_on_device \
+                else None
+            groups.setdefault(key, []).append(i)
+        return list(groups.items())
 
     @property
     def expert_layers(self):
@@ -109,7 +168,17 @@ class HybridDecoderConfig:
         groups grow a column a token and live in pool pages; `state`
         groups are of fixed size and live in a state slot."""
         groups = []
-        if self.layers_of("attention"):
+        if self.kv_on_device:
+            # a group per kind of retention: its arrays K, V [layers,
+            # slots, kv heads, columns, head dim] live on the device only
+            # (`serving.kv_pool`), a window's ring or every column
+            for window, layers in self.kv_groups():
+                groups.append({"kind": "kv", "layers": len(layers),
+                               "kv_heads": self.num_key_value_heads,
+                               "head_dim": self.head_dim,
+                               "dense_dtype": self.dtype,
+                               "retain": int(window) if window else "all"})
+        elif self.layers_of("attention"):
             # dense_dtype: what `decode_step` reads its dense KV cache
             # in; the pool keeps that view of the live sequences on the
             # device, per slot, beside the state
@@ -156,22 +225,30 @@ class HybridDecoderConfig:
                 out[p + "mixer.norm_w"] = (inner,)
                 out[p + "mixer.a_log"] = out[p + "mixer.dt_bias"] = \
                     out[p + "mixer.d"] = (heads,)
-            if spec.ffn:
+            if spec.ffn and (self.block_form == "sequential"
+                             or not spec.mixer):
                 out[p + "norm2"] = (h,)
             if spec.ffn == "mlp":
                 f = self.shared_intermediate_size
                 out[p + "mlp.w_in"] = (h, 2 * f)
                 out[p + "mlp.w_out"] = (f, h)
             elif spec.ffn == "experts":
-                lat, f = self.moe_latent_size, self.moe_intermediate_size
-                sf = self.moe_shared_expert_intermediate_size
+                f = self.moe_intermediate_size
+                sf = self.moe_shared_expert_intermediate_size \
+                    * self.n_shared_experts
                 out[p + "experts.router_w"] = (h, self.n_routed_experts)
-                out[p + "experts.router_b"] = (self.n_routed_experts,)
-                out[p + "experts.w_down"] = (h, lat)
-                out[p + "experts.w_up"] = (lat, h)
-                out[p + "experts.w1"] = (self.held_experts, lat, f)
-                out[p + "experts.w2"] = (self.held_experts, f, lat)
-                out[p + "experts.shared_in"] = (h, sf)
+                if self.expert_form == "gated_silu":
+                    out[p + "experts.w1"] = (self.held_experts, h, 2 * f)
+                    out[p + "experts.w2"] = (self.held_experts, f, h)
+                    out[p + "experts.shared_in"] = (h, 2 * sf)
+                else:
+                    lat = self.moe_latent_size
+                    out[p + "experts.router_b"] = (self.n_routed_experts,)
+                    out[p + "experts.w_down"] = (h, lat)
+                    out[p + "experts.w_up"] = (lat, h)
+                    out[p + "experts.w1"] = (self.held_experts, lat, f)
+                    out[p + "experts.w2"] = (self.held_experts, f, lat)
+                    out[p + "experts.shared_in"] = (h, sf)
                 out[p + "experts.shared_out"] = (sf, h)
         return out
 
@@ -181,6 +258,13 @@ class HybridDecoderConfig:
 
 def _rms_norm(x, weight, eps):
     return dispatch("rms_norm", {"X": x, "Scale": weight}, {"epsilon": eps})
+
+
+def _norm(kind, x, weight, eps):
+    """`rms`, or `layer`: mean-subtracting over the last axis, scale only."""
+    if kind == "rms":
+        return _rms_norm(x, weight, eps)
+    return F.layer_norm(x, [x.shape[-1]], weight=weight, epsilon=eps)
 
 
 def _scaled(x, factor):
@@ -207,73 +291,112 @@ def _relu2(x):
 
 
 class _Experts(Layer):
-    """Routed experts in a latent space, this chip's share of them, beside
-    one shared expert: a sigmoid router over ALL `n_routed_experts` with a
-    selection bias and top-k (`moe_router_topk`); one pair of projections
-    a layer into and out of the `moe_latent_size`-wide latent the experts
-    work in; the `held_experts` experts from `first_held` on, dropless
-    (`moe_grouped_experts`); the shared expert on the full width.  What
-    the experts held elsewhere would add is left out.  `forward(x,
-    lengths)` -> (out, the op's counts [4] int32, the picks [B, T, k])."""
+    """Routed experts, this chip's share of them, beside the shared
+    expert(s): a sigmoid router over ALL `n_routed_experts` and top-k
+    (`moe_router_topk`); the `held_experts` experts from `first_held` on,
+    dropless (`moe_grouped_experts`); the shared experts on the full
+    width.  What the experts held elsewhere would add is left out.
+
+    `latent_relu2`: a selection bias on the router; one pair of
+    projections a layer into and out of the `moe_latent_size`-wide latent
+    the experts work in; ``relu(.)^2`` experts; one shared expert.
+    `gated_silu`: no bias, no latent; experts ``D(silu(G x) * U x)`` with
+    `w1` = [G | U]; `n_shared_experts` shared experts of the same form,
+    AVERAGED — kept side by side as one wide expert (`shared_in` = [G_1 ..
+    G_n | U_1 .. U_n], `shared_out` the D_j stacked) whose output is
+    divided by n.
+
+    `forward(x, lengths)` -> (out, the op's counts [4] int32, the picks
+    [B, T, k])."""
 
     def __init__(self, cfg, index):
         super().__init__(dtype=cfg.dtype)
         self.cfg = cfg
-        h, lat, f = cfg.hidden_size, cfg.moe_latent_size, \
-            cfg.moe_intermediate_size
-        sf, held = cfg.moe_shared_expert_intermediate_size, cfg.held_experts
+        self.gated = cfg.expert_form == "gated_silu"
+        h, f = cfg.hidden_size, cfg.moe_intermediate_size
+        sf = cfg.moe_shared_expert_intermediate_size * cfg.n_shared_experts
+        held = cfg.held_experts
         self.router_w = self.create_parameter([h, cfg.n_routed_experts])
-        # seeded small and NON-ZERO, so that selecting by `s + b` and
-        # weighting by `s` are told apart (the published bias is a buffer
-        # the load balancer moves; zero would hide a swapped pair)
-        rng = np.random.default_rng([global_seed(), 0x0E0E, index])
-        self.router_b = ParamBase(
-            rng.uniform(-0.02, 0.02, cfg.n_routed_experts).astype(
-                np.float32), name=self._full_name + ".router_b",
-            trainable=False)
-        self.w_down = self.create_parameter([h, lat])
-        self.w_up = self.create_parameter([lat, h])
+        width = h
+        if not self.gated:
+            width = cfg.moe_latent_size
+            # seeded small and NON-ZERO, so that selecting by `s + b` and
+            # weighting by `s` are told apart (the published bias is a
+            # buffer the load balancer moves; zero would hide a swapped
+            # pair)
+            rng = np.random.default_rng([global_seed(), 0x0E0E, index])
+            self.router_b = ParamBase(
+                rng.uniform(-0.02, 0.02, cfg.n_routed_experts).astype(
+                    np.float32), name=self._full_name + ".router_b",
+                trainable=False)
+            self.w_down = self.create_parameter([h, width])
+            self.w_up = self.create_parameter([width, h])
         # Xavier uniform an expert's matrix (the default reads a 3-D
         # shape as a convolution's)
-        bound = math.sqrt(6.0 / (lat + f))
+        bound = math.sqrt(6.0 / (width + f))
         self.w1 = self.create_parameter(
-            [held, lat, f], default_initializer=Uniform(-bound, bound))
+            [held, width, 2 * f if self.gated else f],
+            default_initializer=Uniform(-bound, bound))
         self.w2 = self.create_parameter(
-            [held, f, lat], default_initializer=Uniform(-bound, bound))
-        self.shared_in = self.create_parameter([h, sf])
-        self.shared_out = self.create_parameter([sf, h])
+            [held, f, width], default_initializer=Uniform(-bound, bound))
+        if self.gated:      # each shared expert's matrix as its own
+            one = cfg.moe_shared_expert_intermediate_size
+            bound = math.sqrt(6.0 / (h + one))
+            self.shared_in = self.create_parameter(
+                [h, 2 * sf], default_initializer=Uniform(-bound, bound))
+            self.shared_out = self.create_parameter(
+                [sf, h], default_initializer=Uniform(-bound, bound))
+        else:
+            self.shared_in = self.create_parameter([h, sf])
+            self.shared_out = self.create_parameter([sf, h])
 
     def forward(self, x, lengths):
         c = self.cfg
+        router = {"X": x, "Weight": self.router_w}
+        if not self.gated:
+            router["Bias"] = self.router_b
         experts, weights = dispatch(
-            "moe_router_topk",
-            {"X": x, "Weight": self.router_w, "Bias": self.router_b},
+            "moe_router_topk", router,
             {"top_k": c.num_experts_per_tok,
              "norm_topk_prob": c.norm_topk_prob,
              "routed_scaling_factor": c.routed_scaling_factor},
             ["Experts", "Weights"])
-        ins = {"X": matmul(x, self.w_down), "Experts": experts,
-               "Weights": weights, "W1": self.w1, "W2": self.w2}
+        ins = {"X": x if self.gated else matmul(x, self.w_down),
+               "Experts": experts, "Weights": weights, "W1": self.w1,
+               "W2": self.w2}
         if lengths is not None:
             ins["Lengths"] = lengths
-        routed, stats = dispatch(
-            "moe_grouped_experts", ins,
-            {"n_experts": c.n_routed_experts, "first_held": c.first_held,
-             "held": c.held_experts}, ["Out", "Stats"])
-        out = matmul(cast(routed, c.dtype), self.w_up)
-        shared = matmul(_relu2(matmul(x, self.shared_in)), self.shared_out)
+        attrs = {"n_experts": c.n_routed_experts,
+                 "first_held": c.first_held, "held": c.held_experts}
+        if self.gated:
+            attrs["activation"] = "silu_gated"
+        routed, stats = dispatch("moe_grouped_experts", ins, attrs,
+                                 ["Out", "Stats"])
+        if self.gated:
+            out = cast(routed, c.dtype)
+            g, u = split(matmul(x, self.shared_in), 2, axis=-1)
+            shared = _scaled(matmul(multiply(F.silu(g), u), self.shared_out),
+                             1.0 / c.n_shared_experts)
+        else:
+            out = matmul(cast(routed, c.dtype), self.w_up)
+            shared = matmul(_relu2(matmul(x, self.shared_in)),
+                            self.shared_out)
         return add(out, shared), stats, experts
 
 
 class _Attention(Layer):
-    """Grouped-query attention, no positions, the config's own score
-    multiplier.  `cache`: None (prefill: the new tokens alone) or (k, v,
-    lengths) of the earlier tokens.  Returns (out, (k, v)) with k, v the
-    new tokens' [B, Hkv, T, D]."""
+    """Grouped-query attention with the config's own score multiplier;
+    of its layer's description, a `window` and rotary positions (`rotary`:
+    theta), each or neither.  `forward(x, cache)`: `cache` None (prefill:
+    the new tokens alone) or (k, v, lengths) of the earlier tokens; returns
+    (out, (k, v)) with k, v the new tokens' [B, Hkv, T, D].  For a config
+    with `kv_on_device`, `forward(x)` runs a prompt through the blocked
+    kernel and `step` one token a row over the cache group's arrays."""
 
-    def __init__(self, cfg):
+    def __init__(self, cfg, spec=AttentionSpec()):
         super().__init__(dtype=cfg.dtype)
         self.cfg = cfg
+        self.window, self.rotary = spec.window, spec.rotary
         h = cfg.hidden_size
         q, kv = (n * cfg.head_dim for n in (cfg.num_attention_heads,
                                             cfg.num_key_value_heads))
@@ -282,7 +405,9 @@ class _Attention(Layer):
         self.wv = self.create_parameter([h, kv])
         self.wo = self.create_parameter([q, h])
 
-    def forward(self, x, cache=None):
+    def _heads(self, x, positions=None):
+        """q, k, v [B, heads, T, D] of x [B, T, hidden], q and k turned to
+        their positions where the layer has them."""
         c = self.cfg
         b, t = x.shape[0], x.shape[1]
 
@@ -292,15 +417,50 @@ class _Attention(Layer):
         q = heads(matmul(x, self.wq), c.num_attention_heads)
         k = heads(matmul(x, self.wk), c.num_key_value_heads)
         v = heads(matmul(x, self.wv), c.num_key_value_heads)
+        if self.rotary:
+            ins = {} if positions is None else {"Positions": positions}
+            q, k = (dispatch("rotary_embedding", dict(ins, X=y),
+                             {"theta": float(self.rotary)}) for y in (q, k))
+        return q, k, v
+
+    def _out(self, ctx):
+        b, t = ctx.shape[0], ctx.shape[2]
+        c = self.cfg
+        ctx = reshape(transpose(ctx, [0, 2, 1, 3]),
+                      [b, t, c.num_attention_heads * c.head_dim])
+        return matmul(ctx, self.wo)
+
+    def forward(self, x, cache=None):
+        c = self.cfg
+        q, k, v = self._heads(x)
+        if c.kv_on_device:      # a prompt: blocks of queries, the window
+            ctx = dispatch("windowed_prefill_attention",
+                           {"Q": q, "K": k, "V": v},
+                           {"scale": c.attention_multiplier,
+                            "window": int(self.window or 0)})
+            return self._out(ctx), (k, v)
         ins = {"Q": q, "K": k, "V": v}
         if cache is not None:
             ins.update(KCache=cache[0], VCache=cache[1],
                        CacheLengths=cache[2])
         ctx = dispatch("gqa_attention", ins,
                        {"scale": c.attention_multiplier})
-        ctx = reshape(transpose(ctx, [0, 2, 1, 3]),
-                      [b, t, c.num_attention_heads * c.head_dim])
-        return matmul(ctx, self.wo), (k, v)
+        return self._out(ctx), (k, v)
+
+    def step(self, x, lengths, active, k_cache, v_cache, index):
+        """One token a row: `k_cache`, `v_cache` the whole arrays [Lg, S,
+        Hkv, columns, D] of this layer's cache group; this layer writes
+        its new column into entry `index` of each (a ring: at ``length
+        mod window``) and reads it."""
+        q, k, v = self._heads(x, unsqueeze(lengths, 1))
+        ctx, k_cache, v_cache = dispatch(
+            "cached_decode_attention",
+            {"Q": q, "K": k, "V": v, "KCache": k_cache, "VCache": v_cache,
+             "CacheLengths": lengths, "Active": active},
+            {"scale": self.cfg.attention_multiplier, "slab_index": index,
+             "window": int(self.window or 0)},
+            ["Out", "NewKCache", "NewVCache"])
+        return self._out(ctx), (k_cache, v_cache)
 
 
 class _Mamba(Layer):
@@ -399,19 +559,24 @@ class _Mamba(Layer):
 
 class _Block(Layer):
     """One layer of the description: its mixer, then its feed-forward,
-    each present or not and each behind its own norm."""
+    each present or not and each behind its own norm — or, in the
+    `parallel` form, both from ONE norm of the same input."""
 
     def __init__(self, cfg, index):
         super().__init__(dtype=cfg.dtype)
-        self.kind, self.ffn = cfg.blocks[index]
+        spec = cfg.blocks[index]
+        self.kind, self.ffn = spec.mixer, spec.ffn
         self.eps, self.res = cfg.rms_norm_eps, cfg.residual_multiplier
+        self.norm_kind = cfg.norm_kind
+        self.parallel = cfg.block_form == "parallel" and self.kind \
+            and self.ffn
         ones = Constant(1.0)
         if self.kind:
             self.norm1 = self.create_parameter([cfg.hidden_size],
                                                default_initializer=ones)
-            self.mixer = _Attention(cfg) if self.kind == "attention" \
-                else _Mamba(cfg, index)
-        if self.ffn:
+            self.mixer = _Attention(cfg, cfg.attention_spec(index)) \
+                if self.kind == "attention" else _Mamba(cfg, index)
+        if self.ffn and not self.parallel:
             self.norm2 = self.create_parameter([cfg.hidden_size],
                                                default_initializer=ones)
         if self.ffn == "mlp":
@@ -419,21 +584,30 @@ class _Block(Layer):
         elif self.ffn == "experts":
             self.experts = _Experts(cfg, index)
 
+    def _feed(self, x, lengths):
+        if self.ffn == "mlp":
+            return self.mlp(x), None
+        out, *routed = self.experts(x, lengths)
+        return out, routed
+
     def forward(self, h, mix, lengths):
         """`mix(mixer, normed h)` -> (mixer output, whatever cache it
         made); `lengths` [B] the valid positions a row (None: all), which
         the experts route.  Returns (h, that cache or None, the expert
         layer's (counts, picks) or None)."""
         made = routed = None
+        if self.parallel:
+            x = _norm(self.norm_kind, h, self.norm1, self.eps)
+            out, made = mix(self.mixer, x)
+            fed, routed = self._feed(x, lengths)
+            return add(h, _scaled(add(out, fed), self.res)), made, routed
         if self.kind:
-            out, made = mix(self.mixer, _rms_norm(h, self.norm1, self.eps))
+            out, made = mix(self.mixer, _norm(self.norm_kind, h, self.norm1,
+                                              self.eps))
             h = add(h, _scaled(out, self.res))
         if self.ffn:
-            x = _rms_norm(h, self.norm2, self.eps)
-            if self.ffn == "mlp":
-                out = self.mlp(x)
-            else:
-                out, *routed = self.experts(x, lengths)
+            out, routed = self._feed(
+                _norm(self.norm_kind, h, self.norm2, self.eps), lengths)
             h = add(h, _scaled(out, self.res))
         return h, made, routed
 
@@ -502,7 +676,7 @@ class HybridDecoder(Layer):
         """Final norm and the head on rows `h` [..., hidden]: float32
         logits (the matmul's own accumulator, not a rounded bf16 row)."""
         c = self.config
-        h = _rms_norm(h, self.norm_f, c.rms_norm_eps)
+        h = _norm(c.norm_kind, h, self.norm_f, c.rms_norm_eps)
         tied = c.tie_word_embeddings
         out = dispatch("matmul_v2",
                        {"X": h, "Y": self.embed if tied else self.head},
@@ -520,13 +694,13 @@ class HybridDecoder(Layer):
             total = add(total, counts)
         return (total,)
 
-    def _scan_layers(self, ids, lengths):
+    def _scan_layers(self, ids, lengths, upto=None):
         """The whole (padded) sequences from empty caches: (h, new K, new
         V per attention layer, ssm state and conv tail per Mamba layer,
-        (counts, picks) per expert layer)."""
+        (counts, picks) per expert layer); `upto`: the first layers only."""
         h = self._embed(ids)
         ks, vs, ssm, conv, routed = [], [], [], [], []
-        for blk in self.layers:
+        for blk in list(self.layers)[:upto]:
             if blk.kind == "mamba":
                 mix = lambda m, x: m.scan(x, lengths)      # noqa: E731
             else:
@@ -554,6 +728,14 @@ class HybridDecoder(Layer):
         return stack([picks for _, picks in
                       self._scan_layers(ids, lengths)[5]])
 
+    def hidden_row(self, ids, lengths, last, upto):
+        """The residual stream after the first `upto` layers at row `last`
+        [B] of a padded prompt, [B, hidden], on the path `prefill_step`
+        takes: what a comparison of one layer's served arithmetic with a
+        reference's reads.  Not part of the step contract."""
+        return _take_rows(self._scan_layers(ids, lengths, int(upto))[0],
+                          last)
+
     # -- the step contract ----------------------------------------------------
     def prefill_step(self, ids, lengths, last):
         """A prompt padded to its bucket, from empty caches.
@@ -563,23 +745,55 @@ class HybridDecoder(Layer):
         float32, K, V [La, B, Hkv, T, D] of the attention layers, ssm
         [Lm, B, H, P, N] float32 and conv [Lm, B, K-1, C] of the Mamba
         layers: the state after each row's last VALID token; then, for a
-        model with expert layers, the counts of `step_counters`)."""
+        model with expert layers, the counts of `step_counters`).
+
+        A config with `kv_on_device` returns, in place of the one K, V,
+        a K, V per cache group of `cache_spec`, in its order: [Lg, B, Hkv,
+        T, D] for a group that keeps every column, and for a window group
+        the RING as it stands after the prompt's last valid token, [Lg, B,
+        Hkv, window, D] (`kv_ring_pack`); then the state arrays where the
+        model has Mamba layers."""
         h, ks, vs, ssm, conv, routed = self._scan_layers(ids, lengths)
         rows = _take_rows(h, last)
-        return (self._logits(rows), stack(ks), stack(vs), stack(ssm),
-                stack(conv), *self._counted(routed))
+        if not self.config.kv_on_device:
+            return (self._logits(rows), stack(ks), stack(vs), stack(ssm),
+                    stack(conv), *self._counted(routed))
+        at = {i: n for n, i in enumerate(
+            self.config.layers_of("attention"))}
+        kv = []
+        for window, layers in self.config.kv_groups():
+            for made in (ks, vs):
+                group = [made[at[i]] for i in layers]
+                if window:
+                    group = [dispatch("kv_ring_pack",
+                                      {"X": x, "Lengths": lengths},
+                                      {"window": int(window)})
+                             for x in group]
+                kv.append(stack(group))
+        state = [stack(ssm), stack(conv)] if ssm else []
+        return (self._logits(rows), *kv, *state, *self._counted(routed))
 
-    def decode_step(self, ids, cache_lengths, active, k_cache, v_cache,
-                    ssm, conv):
+    def decode_step(self, ids, cache_lengths, active, *cache):
         """One token a row on the carried caches.
 
         ids [S, 1]; cache_lengths [S] valid columns of each row's KV cache;
         active [S] 1 for a row that takes its token, 0 for an idle row
         (its state comes back unchanged and it routes to no expert);
-        k_cache, v_cache [La, S, Hkv, L, D]; ssm [Lm, S, H, P, N]; conv
-        [Lm, S, K-1, C].  Returns (logits [S, V] float32, the new K, V
-        columns [La, S, Hkv, 1, D], ssm, conv; then, for a model with
-        expert layers, the counts of `step_counters`)."""
+        `cache` = k_cache, v_cache [La, S, Hkv, L, D]; ssm [Lm, S, H, P,
+        N]; conv [Lm, S, K-1, C].  Returns (logits [S, V] float32, the new
+        K, V columns [La, S, Hkv, 1, D], ssm, conv; then, for a model with
+        expert layers, the counts of `step_counters`).
+
+        A config with `kv_on_device`: `cache` = a K, V per cache group of
+        `cache_spec`, [Lg, S, Hkv, columns, D] (then ssm, conv where the
+        model has Mamba layers), and they come back WHOLE in the result in
+        place of the columns, each attention layer's new column written
+        into its entry (`_Attention.step`): the caller donates them, like
+        the state."""
+        if self.config.kv_on_device:
+            return self._decode_on_device(ids, cache_lengths, active,
+                                          list(cache))
+        k_cache, v_cache, ssm, conv = cache
         c = self.config
         h = self._embed(ids)
         kc = unstack(k_cache, 0) if c.layers_of("attention") else []
@@ -605,6 +819,34 @@ class HybridDecoder(Layer):
                 routed.append(expert)
         return (self._logits(squeeze(h, 1)), stack(ks), stack(vs), ssm,
                 conv, *self._counted(routed))
+
+    def _decode_on_device(self, ids, cache_lengths, active, cache):
+        c = self.config
+        # layer -> (its group's position in `cache`, its entry there)
+        where = {i: (2 * g, n) for g, (_, layers) in
+                 enumerate(c.kv_groups()) for n, i in enumerate(layers)}
+        n_kv = 2 * len(c.kv_groups())
+        ssm, conv = cache[n_kv:] if c.layers_of("mamba") else (None, None)
+        h = self._embed(ids)
+        routed, n_mamba = [], 0
+        for i, blk in enumerate(self.layers):
+            if blk.kind == "attention":
+                at, entry = where[i]
+                h, (cache[at], cache[at + 1]), expert = blk(
+                    h, lambda m, x: m.step(x, cache_lengths, active,
+                                           cache[at], cache[at + 1], entry),
+                    active)
+            elif blk.kind == "mamba":
+                h, (ssm, conv), expert = blk(h, lambda m, x: m.update(
+                    x, active, ssm, conv, n_mamba), active)
+                n_mamba += 1
+            else:
+                h, _, expert = blk(h, None, active)
+            if expert is not None:
+                routed.append(expert)
+        state = [ssm, conv] if ssm is not None else []
+        return (self._logits(squeeze(h, 1)), *cache[:n_kv], *state,
+                *self._counted(routed))
 
 
 def _take_rows(h, index):

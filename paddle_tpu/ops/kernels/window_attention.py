@@ -1,0 +1,306 @@
+"""Attention for a decoder whose KV cache lives on the device per slot, a
+layer keeping either every column or a window's worth in a RING: rotary
+positions, a prompt's blocked causal attention (window or none), the ring a
+window layer keeps of a prompt, and the one-token step over the cache with
+the new column written in place.
+
+All are FORWARD ONLY (``grad=None``): the serving path is their one caller
+(the rotary op's gradient is what is left of ROADMAP R-a).
+
+A window layer with window ``W`` lets query ``i`` see key ``j`` iff ``j <=
+i`` and ``i - j < W``.  Its cache is a ring of ``W`` columns: the key of
+position ``p`` lies at column ``p mod W``.  Positions are applied (rotary)
+BEFORE a key is stored, and a softmax does not care in which order it
+meets its keys, so the ring is never unrolled: a row of cache length ``n``
+reads its first ``min(n, W)`` columns.  The new column of a decode step
+overwrites the one that has just left the window.
+
+Numerics: angles, scores' accumulation, the softmax and its statistics are
+float32 whatever the activations' dtype; the matmuls take their operands as
+given (bfloat16 when served).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..attention import _fit_block, _interpret
+from ..registry import register_op
+
+_F32 = jnp.float32
+# rows of queries a grid step takes per kv head (times the heads that share
+# it) and the columns of keys a turn of its loop reads
+_BLOCK_Q, _BLOCK_K = 64, 512
+# columns of the cache a turn of the decode step's loop reads
+_DECODE_BLOCK = 512
+# positions of one head a grid step of the rotary kernel turns
+_ROTARY_BLOCK = 2048
+_VMEM_LIMIT = 64 << 20
+
+
+def _pairs(x32, lane_even, nxt, prv):
+    """The partner of lane 2i is -x[2i+1], of lane 2i+1 it is x[2i]: two
+    lane rotations (`nxt`, `prv`: x turned one lane down / up), no
+    [D/2, 2] reshape of the minor axis."""
+    return jnp.where(lane_even, -nxt, prv)
+
+
+def _rotary_kernel(x_ref, cos_ref, sin_ref, o_ref):
+    x32 = x_ref[0, 0].astype(_F32)                              # [bt, D]
+    d = x32.shape[-1]
+    even = lax.rem(lax.broadcasted_iota(jnp.int32, x32.shape, 1),
+                   jnp.int32(2)) == 0
+    partner = _pairs(x32, even, pltpu.roll(x32, d - 1, 1),
+                     pltpu.roll(x32, 1, 1))
+    o_ref[0, 0] = (x32 * cos_ref[0] + partner * sin_ref[0]).astype(
+        o_ref.dtype)
+
+
+@register_op("rotary_embedding", inputs=["X", "Positions?!"],
+             outputs=["Out"], grad=None)
+def rotary_embedding(ins, attrs, ctx):
+    """Rotary positions, interleaved pairs (the GPT-J form): dims ``(2i,
+    2i + 1)`` of every head turn by ``position * theta^(-2i / D)``, all
+    ``D`` dims.
+
+    X [B, H, T, D]; Positions [B, T] int32 (default ``0..T-1``: a prompt;
+    a decode row's position is its cache length).  Angles, cos and sin are
+    float32 from the int32 positions; Out in X's dtype.  attr ``theta``.
+    A prompt goes through one pass of a kernel (a block of positions of
+    one head a grid step); a single position a row is a few elementwise
+    ops.  Forward only."""
+    x, pos = lax.optimization_barrier((ins["X"], ins.get("Positions")))
+    b, h, t, d = x.shape
+    if pos is None:
+        pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
+    theta = float(attrs["theta"])
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)       # [D/2]
+    angle = pos.astype(_F32)[:, :, None] * inv                 # [B,T,D/2]
+    cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)                # [B,T,D]
+    sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)
+    block = _fit_block(t, _ROTARY_BLOCK)
+    if block is None:           # a decode step's one position a row
+        x32 = x.astype(_F32)
+        partner = _pairs(x32, jnp.arange(d) % 2 == 0,
+                         jnp.roll(x32, -1, axis=-1), jnp.roll(x32, 1, axis=-1))
+        out = (x32 * cos[:, None] + partner * sin[:, None]).astype(x.dtype)
+        return {"Out": lax.optimization_barrier(out)}
+    tile = pl.BlockSpec((1, 1, block, d), lambda bi, hi, ti: (bi, hi, ti, 0))
+    angles = pl.BlockSpec((1, block, d), lambda bi, hi, ti: (bi, ti, 0))
+    out = pl.pallas_call(
+        _rotary_kernel, grid=(b, h, t // block),
+        in_specs=[tile, angles, angles], out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        interpret=_interpret(), name="rotary_embedding")(x, cos, sin)
+    return {"Out": lax.optimization_barrier(out)}
+
+
+def _prefill_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, window,
+                    scale):
+    """One block of `block_q` queries of the `G` heads that share a kv head
+    against the key blocks it can see: online softmax over them."""
+    groups, d = q_ref.shape[2], q_ref.shape[4]
+    rows = groups * block_q
+    first = pl.program_id(2) * block_q
+    q = q_ref[0, 0].reshape(rows, d)
+    # a row of the merged [G * block_q] axis is query first + row % block_q
+    qpos = first + lax.rem(
+        lax.broadcasted_iota(jnp.int32, (rows, block_k), 0),
+        jnp.int32(block_q))
+    col = lax.broadcasted_iota(jnp.int32, (rows, block_k), 1)
+
+    def body(kb, carry):
+        m, l, acc = carry
+        ks = k_ref[0, 0, pl.ds(kb * block_k, block_k), :]
+        vs = v_ref[0, 0, pl.ds(kb * block_k, block_k), :]
+        s = lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
+                            preferred_element_type=_F32) * scale
+        kpos = kb * block_k + col
+        seen = kpos <= qpos
+        if window:
+            seen &= qpos - kpos < window
+        s = jnp.where(seen, s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        # a row none of whose keys lies in this block yet: exp(-inf + inf)
+        safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.exp(s - safe)
+        alpha = jnp.exp(m - safe)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = alpha * acc + lax.dot_general(
+            p.astype(vs.dtype), vs, (((1,), (0,)), ((), ())),
+            preferred_element_type=_F32)
+        return m_new, l, acc
+
+    # key blocks from the first that holds a key inside the FIRST query's
+    # window to the one that holds the LAST query's own key
+    lo = jnp.maximum(first - window + 1, 0) // block_k if window else 0
+    hi = (first + block_q - 1) // block_k + 1
+    m, l, acc = lax.fori_loop(
+        lo, hi, body, (jnp.full((rows, 1), -jnp.inf, _F32),
+                       jnp.zeros((rows, 1), _F32),
+                       jnp.zeros((rows, d), _F32)))
+    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).reshape(
+        groups, block_q, d).astype(o_ref.dtype)
+
+
+@register_op("windowed_prefill_attention", inputs=["Q", "K", "V"],
+             outputs=["Out"], grad=None)
+def windowed_prefill_attention(ins, attrs, ctx):
+    """Causal grouped-query attention of a whole prompt among its own
+    tokens, over blocks of queries: `[T, T]` scores never exist for all
+    heads, and a window layer reads only the key blocks inside the window,
+    so its work is ``sum_i min(i, W)``, not ``T^2 / 2``.
+
+    Q [B, Hq, T, D]; K, V [B, Hkv, T, D] (positions already applied).
+    attrs ``scale`` (default ``D^-0.5``), ``window`` (0: every earlier key
+    is seen).  Pads need no lengths: they lie after every valid token and
+    causality hides them; their own rows are nobody's to read (the op
+    itself pads T to whole sublanes that way).  Forward only."""
+    q, k, v = lax.optimization_barrier((ins["Q"], ins["K"], ins["V"]))
+    b, hq, t, d = q.shape
+    hkv = k.shape[1]
+    groups = hq // hkv
+    window = int(attrs.get("window", 0) or 0)
+    scale = float(attrs.get("scale", d ** -0.5))
+    real, pad = t, -t % 8
+    if pad:     # whole sublanes: trailing rows, which causality hides
+        q, k, v = (jnp.pad(y, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                   for y in (q, k, v))
+        t += pad
+    block_q, block_k = _fit_block(t, _BLOCK_Q), _fit_block(t, _BLOCK_K)
+    kernel = functools.partial(_prefill_kernel, block_q=block_q,
+                               block_k=block_k, window=window, scale=scale)
+    whole = pl.BlockSpec((1, 1, t, d), lambda bi, hi, qi: (bi, hi, 0, 0))
+    tile = pl.BlockSpec((1, 1, groups, block_q, d),
+                        lambda bi, hi, qi: (bi, hi, 0, qi, 0))
+    out = pl.pallas_call(
+        kernel, grid=(b, hkv, t // block_q), in_specs=[tile, whole, whole],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, groups, t, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(), name="windowed_prefill_attention",
+    )(q.reshape(b, hkv, groups, t, d), k, v)
+    out = out.reshape(b, hq, t, d)[:, :, :real]
+    return {"Out": lax.optimization_barrier(out)}
+
+
+@register_op("kv_ring_pack", inputs=["X", "Lengths!"], outputs=["Out"],
+             grad=None)
+def kv_ring_pack(ins, attrs, ctx):
+    """The ring a window layer keeps of a prompt: column ``c`` of Out holds
+    the key (or value) of the LAST valid position ``p < Lengths[b]`` with
+    ``p mod window == c`` — the last ``min(length, window)`` positions,
+    each at its own column; a column no valid position maps to holds
+    whatever lies at position ``c`` (a pad's, never read).
+
+    X [B, Hkv, T, D]; Lengths [B]; attr ``window``.  Out [B, Hkv, window,
+    D].  Forward only."""
+    x = ins["X"]
+    t, w = x.shape[2], int(attrs["window"])
+    if t <= w:
+        return {"Out": jnp.pad(x, ((0, 0), (0, 0), (0, w - t), (0, 0)))}
+    col = jnp.arange(w, dtype=jnp.int32)[None, :]
+    last = ins["Lengths"].astype(jnp.int32).reshape(-1, 1) - 1
+    pos = col + w * jnp.maximum((last - col) // w, 0)           # [B, W]
+    pos = jnp.minimum(pos, t - 1)
+    return {"Out": jnp.take_along_axis(x, pos[:, None, :, None], axis=2)}
+
+
+def _at(*index):
+    """Indices of one dtype (int32), whatever x64 makes of a Python int."""
+    return tuple(jnp.asarray(i, jnp.int32) for i in index)
+
+
+def _write_columns(cache, cols, index, pos):
+    """`cols` [S, Hkv, 1, D] into `cache` [La, S, Hkv, L, D], row `s` at
+    `(index, s, :, pos[s])`: a chain of one-column updates, each of which
+    XLA makes where the array lies, so a donated cache is never copied (a
+    loop over the rows, a scatter, or reading the old column first each
+    cost one copy of the whole array on the v5e's compiler)."""
+    for s in range(cols.shape[0]):
+        cache = lax.dynamic_update_slice(
+            cache, cols[s][None, None].astype(cache.dtype),
+            _at(index, s, 0, pos[s], 0))
+    return cache
+
+
+@register_op("cached_decode_attention",
+             inputs=["Q", "K", "V", "KCache", "VCache", "CacheLengths!",
+                     "Active!"],
+             outputs=["Out", "NewKCache", "NewVCache"], grad=None)
+def cached_decode_attention(ins, attrs, ctx):
+    """One token a row over its cached keys and values, the new column
+    written first, in place.
+
+    Q [S, Hq, 1, D]; K, V [S, Hkv, 1, D] the new token's (positions
+    applied); KCache, VCache [La, S, Hkv, L, D] the whole arrays of a
+    cache group, this layer's entry at attr ``slab_index``; CacheLengths
+    [S] the tokens a row holds BEFORE this one; Active [S] 1 for a row
+    that takes its token (an idle row reads nothing, its Out row is
+    nobody's to read, and its new column lands at the column its
+    CacheLengths names: where its own next token will be written, a
+    column no query of its sequence can see before that; for a slot
+    without a sequence the engine passes 0, which the slot's next prompt
+    overwrites).  attr ``window``: 0, the group keeps
+    every column and the new one goes to column ``length``; W = L, the
+    group is a ring and it goes to ``length mod W``.  The row then reads
+    its first ``min(length + 1, L)`` columns, block by block up to the
+    longest row's, so a step reads what its rows hold and not the array.
+    attr ``scale``.  NewKCache, NewVCache: the whole arrays, updated where
+    the caller donated them.  Forward only."""
+    q, k, v, kc, vc = lax.optimization_barrier(
+        (ins["Q"], ins["K"], ins["V"], ins["KCache"], ins["VCache"]))
+    s, hq, _, d = q.shape
+    hkv, columns = kc.shape[2], kc.shape[3]
+    index = int(attrs["slab_index"])
+    window = int(attrs.get("window", 0) or 0)
+    scale = float(attrs.get("scale", d ** -0.5))
+    if window and window != columns:
+        raise ValueError(
+            f"cached_decode_attention: a ring of {columns} columns for a "
+            f"window of {window}")
+    lengths = ins["CacheLengths"].astype(jnp.int32)
+    active = ins["Active"].astype(jnp.int32)
+    pos = lengths % columns if window else jnp.minimum(lengths, columns - 1)
+    kc = _write_columns(kc, k, index, pos)
+    vc = _write_columns(vc, v, index, pos)
+    valid = jnp.where(active > 0, jnp.minimum(lengths + 1, columns), 0)
+    block = _fit_block(columns, _DECODE_BLOCK) or columns
+    qg = q.reshape(s, hkv, hq // hkv, d)
+    col = jnp.arange(block, dtype=jnp.int32)
+
+    def body(j, carry):
+        m, l, acc = carry
+        at, size = _at(index, 0, 0, j * block, 0), (1, s, hkv, block, d)
+        kb = lax.dynamic_slice(kc, at, size)[0].astype(q.dtype)
+        vb = lax.dynamic_slice(vc, at, size)[0].astype(q.dtype)
+        sc = jnp.einsum("shgd,shcd->shgc", qg, kb,
+                        preferred_element_type=_F32) * scale
+        seen = (j * block + col)[None, :] < valid[:, None]      # [S, C]
+        sc = jnp.where(seen[:, None, None, :], sc, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.exp(sc - safe)
+        alpha = jnp.exp(m - safe)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.einsum(
+            "shgc,shcd->shgd", p.astype(q.dtype), vb,
+            preferred_element_type=_F32)
+        return m_new, l, acc
+
+    shape = (s, hkv, hq // hkv)
+    blocks = (jnp.max(valid) + block - 1) // block
+    _, l, acc = lax.fori_loop(
+        0, blocks, body, (jnp.full(shape + (1,), -jnp.inf, _F32),
+                          jnp.zeros(shape + (1,), _F32),
+                          jnp.zeros(shape + (d,), _F32)))
+    out = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype).reshape(s, hq, 1, d)
+    out, kc, vc = lax.optimization_barrier((out, kc, vc))
+    return {"Out": out, "NewKCache": kc, "NewVCache": vc}

@@ -59,19 +59,23 @@ sharing:
   replays the exact plain-greedy emission loop over the verified chain.
 
 Geometry (KV layers, kv heads, head dim) comes from the model's cache
-description (``kv_pool.cache_spec_of``).  A model whose description has
-a ``state`` group — recurrent layers beside attention layers — needs the
-paged pool: its per-sequence state lives in the pool's ``StateSlots`` on
-the device, reserved with the pages at admission and released with them.
-Such a model runs through ``StepPrograms`` (step_program.py): prefill
-and decode are ONE compiled program per (phase, bucket), prompts carry
-their lengths so that bucket padding never enters the recurrence, and an
-idle decode row's state comes back unchanged.  On that route the loop
-keeps ONE decode step in flight: a step is read and booked after the next
-compiled program has been dispatched behind it, from the ids the step
-left on the device (``_step_compiled``).  ``prefix_cache=`` and
-``speculative=`` refuse such a model (they need state snapshots at page
-boundaries and rollback of a state; ROADMAP R-h).
+description (``kv_pool.cache_spec_of``).  WHICH ROUTE A MODEL LANDS ON: a
+model that states the step contract — ``prefill_step`` and ``decode_step``
+(step_program.py) — runs through ``StepPrograms``, the compiled route;
+every other (``GPTModel``) through the eager forwards above.  The compiled
+route needs the paged pool: what a sequence holds on the device — its
+recurrent state (a ``state`` group), the step's dense KV view, or, for a
+description whose ``kv`` groups state their ``retain``, the KV itself, a
+window's ring among it — lives in the pool's ``StateSlots``, reserved with
+the pages at admission and released with them.  Prefill and decode are ONE
+compiled program per (phase, bucket), prompts carry their lengths so that
+bucket padding never enters a recurrence, and an idle decode row's state
+comes back unchanged.  On that route the loop keeps ONE decode step in
+flight: a step is read and booked after the next compiled program has been
+dispatched behind it, from the ids the step left on the device
+(``_step_compiled``).  ``prefix_cache=`` and ``speculative=`` refuse such
+a model (they need state snapshots at page boundaries and rollback of a
+state, ROADMAP R-h; a ring can be neither shared nor truncated by page).
 """
 from __future__ import annotations
 
@@ -90,7 +94,7 @@ from ..profiler import RecordEvent
 from .batcher import (BatcherStoppedError, DeadlineExceededError,
                       QueueFullError, _jittered)
 from .kv_pool import (PagedKVPool, PageTable, cache_spec_of, kv_geometry,
-                      state_groups)
+                      retained_kv_groups, state_groups)
 
 __all__ = ["ContinuousBatchingEngine", "GenerationRequest"]
 
@@ -143,8 +147,8 @@ class _Launch(NamedTuple):
     lengths: np.ndarray     # the cache lengths it went in with, [S]
     logits: Optional[Tensor]    # where a row of it samples, else None
     next_ids: Tensor
-    k_new: Tensor
-    v_new: Tensor
+    k_new: Optional[Tensor]     # None: the step wrote its columns into
+    v_new: Optional[Tensor]     # the device-only KV arrays itself
 
 
 class ContinuousBatchingEngine:
@@ -225,8 +229,11 @@ class ContinuousBatchingEngine:
         spec = cache_spec_of(self.config)
         self._kv_layers, self._kv_heads, self._kv_head_dim = \
             kv_geometry(spec)
-        self._stateful = bool(state_groups(spec))
-        if self._stateful:
+        # the compiled route is for a model that states the step contract
+        self._compiled = all(callable(getattr(self._model, name, None))
+                             for name in ("prefill_step", "decode_step"))
+        self._kv_groups = retained_kv_groups(spec)
+        if self._compiled:
             self._refuse_for_state(kv_pool, prefix_cache, speculative)
         self._pool: Optional[PagedKVPool] = None
         if kv_pool is not None:
@@ -275,10 +282,11 @@ class ContinuousBatchingEngine:
         # the next `engine/step` span
         self._in_flight: Optional[_Launch] = None
         self._step_counts = None
-        if self._stateful:
+        if self._compiled:
             state = self._pool.state
             if state is None or state.groups != state_groups(spec) \
-                    or not state.dense:
+                    or bool(state.device_kv) != bool(self._kv_groups) \
+                    or not (state.dense or state.device_kv):
                 raise ValueError(
                     "kv_pool holds no state slots for this model's cache "
                     "description — build it with PagedKVPool.from_plan("
@@ -343,24 +351,31 @@ class ContinuousBatchingEngine:
         self._thread: Optional[threading.Thread] = None
 
     def _refuse_for_state(self, kv_pool, prefix_cache, speculative):
-        """What a model with recurrent state cannot have yet, each with
+        """What a model on the compiled route cannot have yet, each with
         what is missing (ROADMAP R-h's remainder)."""
         name = type(self._model).__name__
+        ring = any(g["retain"] != "all" for g in self._kv_groups)
         if prefix_cache is not None:
             raise NotImplementedError(
-                f"prefix_cache= with {name}: a retained prefix would need "
-                "a snapshot of the recurrent state at each page boundary "
-                "to resume from; only KV pages are retained")
+                f"prefix_cache= with {name}: " + (
+                    "a window layer keeps a ring of its last columns, "
+                    "which cannot be shared by page" if ring else
+                    "a retained prefix would need "
+                    "a snapshot of the recurrent state at each page "
+                    "boundary to resume from; only KV pages are retained"))
         if speculative is not None:
             raise NotImplementedError(
-                f"speculative= with {name}: rejecting a draft would need "
-                "rollback of the recurrent state; only the page table "
-                "can be truncated")
+                f"speculative= with {name}: " + (
+                    "rejecting a draft would need the columns a ring has "
+                    "overwritten; a ring cannot be truncated" if ring else
+                    "rejecting a draft would need "
+                    "rollback of the recurrent state; only the page table "
+                    "can be truncated"))
         if kv_pool is None:
             raise ValueError(
-                f"{name} carries recurrent state: it needs the paged pool "
-                "(kv_pool='auto', a plan or a PagedKVPool), whose manager "
-                "holds the state slots")
+                f"{name} serves through compiled steps: it needs the paged "
+                "pool (kv_pool='auto', a plan or a PagedKVPool), whose "
+                "manager holds the state slots")
         if self.tp_degree > 1 or self.weight_dtype != "float32":
             raise NotImplementedError(
                 f"{name} serves at tp_degree 1 in its own weight dtype "
@@ -369,6 +384,13 @@ class ContinuousBatchingEngine:
     @property
     def kv_pool(self) -> Optional[PagedKVPool]:
         return self._pool
+
+    @property
+    def step_programs(self):
+        """The compiled route's `StepPrograms` (None on the eager route):
+        the programs this engine serves with, for whoever replays a
+        sequence through them while the engine is idle."""
+        return self._steps
 
     @property
     def paged(self) -> bool:
@@ -544,7 +566,7 @@ class ContinuousBatchingEngine:
                                      prompt=int(req.prompt.size),
                                      waited_ms=round(waited * 1e3, 3)
                                      ) as span:
-                        if self._stateful:
+                        if self._compiled:
                             self._prefill_compiled(req, table, span)
                         else:
                             self._prefill(req, table, span)
@@ -556,7 +578,7 @@ class ContinuousBatchingEngine:
             try:
                 if any(self._slots) or self._in_flight is not None:
                     with RecordEvent("engine/step") as span:
-                        if self._stateful:
+                        if self._compiled:
                             self._step_compiled(span)
                         elif self._spec is not None:
                             self._step_spec(span)
@@ -646,7 +668,7 @@ class ContinuousBatchingEngine:
 
     def _fail_all(self, err):
         with self._mu:
-            if self._stateful:
+            if self._compiled:
                 # a step that raised had already given its state arrays
                 # away: every sequence is failed below — the rows of a step
                 # still in flight with them, which is dropped unread — so
@@ -945,7 +967,7 @@ class ContinuousBatchingEngine:
         ids_t, len_t, last_t = self._upload(
             ids, np.asarray([p], np.int32), np.asarray([p - 1], np.int32))
         with RecordEvent("engine/forward", bucket=pp, rows=1):
-            logits, next_id, k, v, *state = self._steps.prefill(
+            logits, next_id, *made = self._steps.prefill(
                 ids_t, len_t, last_t)
         ahead, self._in_flight = self._in_flight, None
         if ahead is not None:
@@ -976,14 +998,28 @@ class ContinuousBatchingEngine:
                 slot.tokens.append(nxt)
                 self._finish(slot)
             return
-        with RecordEvent("engine/kv_install") as install:
-            k_h, v_h = self._download(install, k, v)
-            self._pool.open_sequence(
-                req.prompt, k_h[:, 0, :, :p].astype(np.float32),
-                v_h[:, 0, :, :p].astype(np.float32), table=table)
         pool_state = self._pool.state
-        new = {n: t._value for n, t in zip(pool_state.names, state)}
-        new.update(k_dense=k._value, v_dense=v._value)  # the prompt's KV
+        if self._pool.device_only:
+            # the prompt's KV stays where the program left it: a window
+            # group's ring and the other groups' columns go from the
+            # result into the slot's rows of the device arrays below; the
+            # table books the pages, no byte crosses the host link
+            with RecordEvent("engine/kv_install", bytes=0):
+                self._pool.account_prompt(table, p)
+            new = {n: t._value for n, t in zip(pool_state.names, made)}
+            # the prompt's writes at a multiple of a window's columns
+            wraps = sum((p - 1) // a["window"]
+                        for a in pool_state.device_kv[::2] if a["window"])
+            metrics.count("kv.ring_wraps", wraps)
+        else:
+            k, v, *state = made
+            with RecordEvent("engine/kv_install") as install:
+                k_h, v_h = self._download(install, k, v)
+                self._pool.open_sequence(
+                    req.prompt, k_h[:, 0, :, :p].astype(np.float32),
+                    v_h[:, 0, :, :p].astype(np.float32), table=table)
+            new = {n: t._value for n, t in zip(pool_state.names, state)}
+            new.update(k_dense=k._value, v_dense=v._value)  # the prompt's
         with RecordEvent("engine/state_install", slot=slot_id,
                          bytes=pool_state.slot_bytes):
             pool_state.install(slot_id, **new)
@@ -1057,7 +1093,24 @@ class ContinuousBatchingEngine:
                 for i, s in active:
                     ids[i] = s.next_id
                 feeds.insert(0, ids)
-        lpad = _next_pow2(int(lengths.max()), self._kv_floor)
+        device_kv = state.device_kv
+        if device_kv:
+            # ONE decode program: it is given the arrays whole and reads,
+            # block by block, what its rows hold (`lpad`: their columns)
+            lpad = max(a["shape"][1] for a in device_kv)
+            live = lengths[alive > 0]
+            window = min((a["window"] for a in device_kv if a["window"]),
+                         default=0)
+            past = int((live >= window).sum()) if window else 0
+            wraps = sum(int(((live > 0) & (live % a["window"] == 0)).sum())
+                        for a in device_kv[::2] if a["window"])
+            metrics.count("kv.ring_wraps", wraps)
+            metrics.gauge("kv.rows_past_window", past)
+            span.set(ring_rows=past, kv_columns=int(sum(
+                a["layers"] * np.minimum(live + 1, a["shape"][1]).sum()
+                for a in device_kv[::2])))
+        else:
+            lpad = _next_pow2(int(lengths.max()), self._kv_floor)
         span.set(active=len(active), lpad=lpad, context=int(lengths.sum()),
                  ahead=int(ahead is not None))
         with self._mu:
@@ -1067,12 +1120,20 @@ class ContinuousBatchingEngine:
         if ahead is not None:
             uploaded.insert(0, ahead.next_ids)
         with RecordEvent("engine/forward", bucket=lpad, rows=len(active)):
-            logits, next_ids, k_new, v_new, *new_state = self._steps.decode(
-                *uploaded, *[Tensor(a) for a in state.kv_view(lpad)],
-                *state.arrays.values())
+            if device_kv:       # the KV arrays ARE state: donated, rebound
+                k_new = v_new = None
+                logits, next_ids, *new_state = self._steps.decode(
+                    *uploaded, *state.arrays.values())
+            else:
+                logits, next_ids, k_new, v_new, *new_state = \
+                    self._steps.decode(
+                        *uploaded,
+                        *[Tensor(a) for a in state.kv_view(lpad)],
+                        *state.arrays.values())
             state.rebind(**{n: t._value
                             for n, t in zip(state.names, new_state)})
-            state.append_kv(k_new._value, v_new._value, lengths)
+            if not device_kv:
+                state.append_kv(k_new._value, v_new._value, lengths)
         samples = any(s.req.strategy == "sampling" for _, s in active)
         launch = _Launch(active, lengths, logits if samples else None,
                          next_ids, k_new, v_new)
@@ -1105,12 +1166,17 @@ class ContinuousBatchingEngine:
         if not pairs:
             return
         S = self.max_slots
+        on_device = launch.k_new is None    # the step wrote its own columns
         with RecordEvent("engine/fetch") as fetch:
-            picked, k_col, v_col, *step_logits = self._download(
-                fetch, launch.next_ids, launch.k_new, launch.v_new,
+            picked, *got = self._download(
+                fetch, launch.next_ids,
+                *([] if on_device else [launch.k_new, launch.v_new]),
                 *([launch.logits] if launch.logits is not None else []))
-            k_col = k_col[:, :, :, 0].astype(np.float32)   # [L, S, H, Dh]
-            v_col = v_col[:, :, :, 0].astype(np.float32)
+            if not on_device:
+                k_col, v_col, *got = got
+                k_col = k_col[:, :, :, 0].astype(np.float32)  # [L,S,H,Dh]
+                v_col = v_col[:, :, :, 0].astype(np.float32)
+            step_logits = got
         if self._steps.counters:
             self._step_counts = self._count_step(picked[S:])
             if step_span is not None:
@@ -1123,8 +1189,13 @@ class ContinuousBatchingEngine:
         retired = []
         with RecordEvent("engine/kv_append") as append:
             for i, s in pairs:
-                self._pool.append_column(s.table, k_col[:, i], v_col[:, i])
-            append.set(bytes=len(pairs) * 2 * k_col[:, 0].nbytes)
+                if on_device:
+                    self._pool.account_column(s.table)
+                else:
+                    self._pool.append_column(s.table, k_col[:, i],
+                                             v_col[:, i])
+            append.set(bytes=0 if on_device
+                       else len(pairs) * 2 * k_col[:, 0].nbytes)
         with RecordEvent("engine/sample"):
             for i, s in pairs:
                 s.tokens.append(s.next_id)

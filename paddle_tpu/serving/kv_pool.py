@@ -76,6 +76,21 @@ The recurrent state never crosses the host link.  Where the model's
 ``kv`` group states a ``dense_dtype``, the same slots also hold the decode
 step's dense KV view of the live sequences, so that a step uploads no KV;
 the pages stay the record.
+
+SEVERAL ``kv`` GROUPS, EACH WITH ITS OWN RETENTION.  A description may
+state more than one ``kv`` group where every one says what it retains:
+``"retain": "all"`` (every column, up to the context served) or a window
+in columns (the last W tokens, a RING written at ``position mod W``).
+Such a description keeps its KV ON THE DEVICE ONLY: per group two arrays
+``[layers, slots, kv heads, columns, head dim]`` (``device_kv_arrays``)
+ride the ``StateSlots`` beside the recurrent state, the compiled steps
+are given them whole and write the new column in place, and no KV byte
+crosses the host link.  The pool then allocates NO host slabs
+(``device_only``): its page tables do admission and accounting — a
+sequence's pages count the tokens its ``"all"`` group holds
+(``account_prompt`` / ``account_column``), a window's ring is a constant
+of the slot, priced with the state — and nothing is registered for prefix
+sharing (a ring cannot be shared or truncated by page).
 """
 from __future__ import annotations
 
@@ -89,8 +104,9 @@ from ..core.compile_cache import next_pow2 as _next_pow2
 from ..profiler import RecordEvent
 
 __all__ = ["PagedKVPool", "PageTable", "PagePoolExhaustedError",
-           "StateSlots", "budget_drift", "cache_spec_of", "kv_geometry",
-           "state_groups", "state_slot_bytes"]
+           "StateSlots", "budget_drift", "cache_spec_of", "device_kv_arrays",
+           "kv_geometry", "retained_kv_groups", "state_groups",
+           "state_slot_bytes"]
 
 
 # -- the model's cache description -------------------------------------------
@@ -111,29 +127,78 @@ def cache_spec_of(config) -> List[Dict]:
              "head_dim": int(get("hidden_size")) // heads}]
 
 
-def kv_geometry(spec) -> tuple:
-    """(layers, kv heads, head dim) of the description's one ``kv`` group
-    (several — window beside global layers, a latent cache — are ROADMAP
-    D9's remainder)."""
+def retained_kv_groups(spec) -> List[Dict]:
+    """The ``kv`` groups that state what they retain (``"all"`` or a
+    window in columns): all of the description's ``kv`` groups, or none —
+    a description with them keeps its KV on the device only."""
     kv = [g for g in spec if g["kind"] == "kv"]
+    said = [g for g in kv if g.get("retain") is not None]
+    if said and len(said) != len(kv):
+        raise ValueError(
+            "a cache description states `retain` on every kv group or on "
+            "none (device-only KV and host pages do not mix)")
+    for g in said:
+        if g["retain"] != "all" and not (
+                isinstance(g["retain"], int) and g["retain"] > 0):
+            raise ValueError(f"retain is 'all' or a window in columns, "
+                             f"got {g['retain']!r}")
+    return said
+
+
+def kv_geometry(spec) -> tuple:
+    """(layers, kv heads, head dim) of the ``kv`` group whose tokens the
+    pool's pages count: the description's one ``kv`` group, or, where the
+    groups state their retention (``retained_kv_groups``), the one that
+    retains ``"all"`` (else the first: every group a window).  Several
+    groups without ``retain`` — a latent cache beside a plain one on the
+    host-page path — are ROADMAP D9's remainder."""
+    kv = [g for g in spec if g["kind"] == "kv"]
+    said = retained_kv_groups(spec)
+    if said:
+        kv = [g for g in said if g["retain"] == "all"][:1] or said[:1]
     if len(kv) != 1:
         raise NotImplementedError(
-            f"the page pool holds exactly one kv layer group, the cache "
-            f"description has {len(kv)}")
+            f"the page pool holds exactly one kv layer group without "
+            f"`retain`, the cache description has {len(kv)}")
     return int(kv[0]["layers"]), int(kv[0]["kv_heads"]), \
         int(kv[0]["head_dim"])
+
+
+def device_kv_arrays(spec, max_context: int) -> List[Dict]:
+    """The arrays a description with ``retain`` keeps on the device, a K
+    and a V per group in the description's order — {"name" (``k<g>`` /
+    ``v<g>``), "layers", "dtype", "shape" [kv heads, columns, head dim] of
+    one slot's entry a layer, "window" (0: every column)}: a window's
+    columns, or the power of two over `max_context`."""
+    out = []
+    for g, group in enumerate(retained_kv_groups(spec)):
+        window = 0 if group["retain"] == "all" else int(group["retain"])
+        columns = window or _next_pow2(int(max_context))
+        for name in ("k", "v"):
+            out.append({"name": f"{name}{g}", "layers": int(group["layers"]),
+                        "dtype": group.get("dense_dtype", "float32"),
+                        "shape": [int(group["kv_heads"]), columns,
+                                  int(group["head_dim"])],
+                        "window": window})
+    return out
 
 
 def state_groups(spec) -> List[Dict]:
     return [g for g in spec if g["kind"] == "state"]
 
 
-def state_slot_bytes(spec) -> int:
-    """Bytes one sequence's recurrent state occupies, all groups."""
+def state_slot_bytes(spec, max_context: int = 0) -> int:
+    """Bytes one sequence holds on the device whatever its length: its
+    recurrent state, all groups, and — for a description with ``retain``,
+    given the context served — its slot of the device-only KV arrays."""
     from ..core.dtype import np_dtype
+    kv = device_kv_arrays(spec, max_context) \
+        if retained_kv_groups(spec) else []
     return sum(int(g["layers"]) * int(np.prod(a["shape"]))
                * np_dtype(a["dtype"]).itemsize
-               for g in state_groups(spec) for a in g["arrays"])
+               for g in state_groups(spec) for a in g["arrays"]) \
+        + sum(a["layers"] * int(np.prod(a["shape"]))
+              * np_dtype(a["dtype"]).itemsize for a in kv)
 
 
 class PagePoolExhaustedError(RuntimeError):
@@ -194,18 +259,35 @@ class StateSlots:
     uploaded every step (67-268 MB a step for 16 rows; docs/serving.md).
     The pool's pages stay the record — admission, sharing, accounting —
     and receive every column too; the view is the step's workspace, which
-    ``page_budget`` prices per slot.  Mutated on the engine's decode
-    thread only."""
+    ``page_budget`` prices per slot.
 
-    def __init__(self, groups: Sequence[Dict], slots: int, dense_kv=None):
+    ``device_kv`` (``device_kv_arrays``) puts a description's device-only
+    KV arrays FIRST among ``arrays``, before the state's, in the order the
+    step contract takes them: they are the cache itself, not a view —
+    donated through the decode step, which writes each row's new column
+    where the array lies, and written by ``install`` from a prefill's
+    result (a window group's ring whole; a group that keeps every column
+    up to the prompt's bucket).  Mutated on the engine's decode thread
+    only."""
+
+    def __init__(self, groups: Sequence[Dict], slots: int, dense_kv=None,
+                 device_kv: Sequence[Dict] = ()):
         import jax
         import jax.numpy as jnp
         from ..core.dtype import np_dtype
         self.groups = [dict(g) for g in groups]
+        self.device_kv = [dict(a) for a in device_kv]
         self.slots = int(slots)
-        if self.slots < 1 or not self.groups:
-            raise ValueError("StateSlots needs >= 1 slot and a state group")
+        if self.slots < 1 or not (self.groups or self.device_kv):
+            raise ValueError("StateSlots needs >= 1 slot and a state group "
+                             "or device-only KV arrays")
         self.arrays: Dict[str, "jax.Array"] = {}
+        for a in self.device_kv:
+            self.arrays[a["name"]] = jnp.zeros(
+                (a["layers"], self.slots) + tuple(a["shape"]),
+                np_dtype(a["dtype"]))
+        self.kv_slot_bytes = sum(v.nbytes for v in self.arrays.values()) \
+            // self.slots
         for g in self.groups:
             for a in g["arrays"]:
                 if a["name"] in self.arrays:
@@ -333,6 +415,8 @@ class StateSlots:
         metrics.gauge("state.slots_total", self.slots)
         metrics.gauge("state.slots_used", self.used)
         metrics.gauge("state.bytes", self.nbytes)
+        if self.device_kv:
+            metrics.gauge("kv.device_bytes", self.kv_slot_bytes * self.slots)
 
 
 class PagedKVPool:
@@ -368,10 +452,15 @@ class PagedKVPool:
         # ONE slab per tensor, allocated up front: page id p is
         # self.k[:, p] across every layer (no per-sequence allocation
         # ever happens again)
+        # the second kind of cache: recurrent state slots (None for a
+        # model whose cache description has no `state` group); where they
+        # hold the KV itself (`device_only`: a cache description with
+        # `retain`) the tables account and nothing is stored here
+        self.state = state
         shape = (self.num_layers, self.num_pages, self.num_heads,
                  self.page_tokens, self.head_dim)
-        self.k = np.zeros(shape, self.dtype)
-        self.v = np.zeros(shape, self.dtype)
+        self.k = None if self.device_only else np.zeros(shape, self.dtype)
+        self.v = None if self.device_only else np.zeros(shape, self.dtype)
         if self.is_quantized:
             # per-(layer, page, head) fp32 dequant scale: x ≈ q * scale
             sshape = (self.num_layers, self.num_pages, self.num_heads)
@@ -397,9 +486,6 @@ class PagedKVPool:
         self.cow_copies = 0
         self.prefix_hits = 0
         self.plan = dict(plan) if plan else None
-        # the second kind of cache: recurrent state slots (None for a
-        # model whose cache description has no `state` group)
-        self.state = state
         self._publish()
 
     @classmethod
@@ -408,10 +494,13 @@ class PagedKVPool:
         the plan so `budget_drift` can re-derive and compare it).  A plan
         whose cache description has a ``state`` group gets its
         ``StateSlots`` — ``max_slots`` of them — allocated here."""
-        groups = state_groups(plan.get("cache") or [])
-        dense = None
-        if groups:      # the step's dense KV view rides the state slots
-            kv = [g for g in plan["cache"] if g["kind"] == "kv"][0]
+        spec = plan.get("cache") or []
+        groups = state_groups(spec)
+        dense, device_kv = None, []
+        if retained_kv_groups(spec):    # the KV itself, on the device only
+            device_kv = device_kv_arrays(spec, int(plan["max_context"]))
+        elif groups:    # the step's dense KV view rides the state slots
+            kv = [g for g in spec if g["kind"] == "kv"][0]
             dense = (kv["layers"], kv["kv_heads"],
                      _next_pow2(int(plan["max_context"])),
                      kv["head_dim"], kv.get("dense_dtype", "float32"))
@@ -421,10 +510,17 @@ class PagedKVPool:
                    page_tokens=int(plan["page_tokens"]),
                    num_pages=int(plan["pages"]),
                    dtype=plan.get("kv_dtype", dtype), plan=plan,
-                   state=StateSlots(groups, int(plan["max_slots"]), dense)
-                   if groups else None)
+                   state=StateSlots(groups, int(plan["max_slots"]), dense,
+                                    device_kv)
+                   if groups or device_kv else None)
 
     # -- geometry -----------------------------------------------------------
+    @property
+    def device_only(self) -> bool:
+        """Whether the KV is the state slots' device arrays and no host
+        slab exists: what the state slots were built with says it."""
+        return self.state is not None and bool(self.state.device_kv)
+
     @property
     def page_bytes(self) -> int:
         """Bytes one page occupies across both tensors and all layers —
@@ -813,11 +909,36 @@ class PagedKVPool:
         metrics.count("kv.append_bytes", k_col.nbytes + v_col.nbytes)
         self._publish()
 
+    def account_prompt(self, table: PageTable, n_tokens: int):
+        """`open_sequence` for a device-only pool: the pages a prompt of
+        `n_tokens` occupies are charged to `table` and nothing is stored
+        or registered (its KV went from the prefill program into the state
+        slots' arrays, on the device)."""
+        with self._mu:
+            for _ in range(self.pages_needed(n_tokens) - len(table.pages)):
+                table.pages.append(self._alloc(table))
+            table.length = int(n_tokens)
+        self._publish()
+        return table
+
+    def account_column(self, table: PageTable):
+        """`append_column` for a device-only pool: one more token, a fresh
+        page when it crosses a boundary (the decode program wrote the
+        column where the device arrays lie)."""
+        with self._mu:
+            if table.length % self.page_tokens == 0:
+                table.pages.append(self._alloc(table))
+            table.length += 1
+        self._publish()
+
     def gather(self, table: PageTable):
         """Dense per-layer KV view of one sequence: ``(k, v)`` each
         ``[L, H, length, Dh]`` — the gather-by-page-table read the
         decode step feeds into the model's existing cache path (compiled
         shapes never see page structure)."""
+        if self.device_only:
+            raise RuntimeError("a device-only pool stores no pages to "
+                               "gather: the KV is StateSlots' arrays")
         L, H, T, D = (self.num_layers, self.num_heads, self.page_tokens,
                       self.head_dim)
         out_dtype = np.float32 if self.is_quantized else self.dtype
@@ -876,7 +997,9 @@ class PagedKVPool:
                 "occupancy": round(1.0 - free / self.num_pages, 4),
                 **({"state_slots_total": self.state.slots,
                     "state_slots_used": self.state.used,
-                    "state_bytes": self.state.nbytes}
+                    "state_bytes": self.state.nbytes,
+                    "kv_device_bytes": self.state.kv_slot_bytes
+                    * self.state.slots}
                    if self.state is not None else {}),
             }
 

@@ -69,6 +69,25 @@ results in their place (`StateSlots.rebind`).  Nothing else is donated:
 not the ids, which the engine still has to read where they are the step
 before's result, nor the lengths or the KV view's slices.
 
+A MODEL WHOSE CACHE DESCRIPTION STATES `retain` (several `kv` groups, a
+window's ring among them; `kv_pool.retained_kv_groups`) keeps its KV on
+the device only, and its contract carries the groups' arrays in place of
+the one K, V and the columns:
+
+    prefill_step(ids [1, T], lengths [1], last [1])
+        -> (logits [1, V], *kv [Lg, 1, Hkv, T | window, D], *state)
+    decode_step(ids [S, 1], cache_lengths [S], active [S],
+                *kv [Lg, S, Hkv, columns, D], *state)
+        -> (logits [S, V], *kv, *state)
+
+`*kv`: a K and a V per group in the description's order
+(`kv_pool.device_kv_arrays`).  A prefill returns a window group's RING as
+it stands after the prompt's last valid token; the decode step takes the
+arrays whole — every position from `DECODE_CACHE_AT` on is donated, the KV
+like the state — writes each row's new column where the array lies (a ring
+at ``length mod window``) and returns them.  The greedy pick, the ids as
+picked, the counts behind the ids and the launch in flight are the same.
+
 `GPTModel` is not on this route yet (ROADMAP S2b): its eager forward has
 no such methods.
 """
@@ -79,16 +98,20 @@ from ..dygraph.tensor import Tensor
 from ..tensor.manipulation import concat, reshape, slice as slice_
 from ..tensor.search import argmax
 
-__all__ = ["StepPrograms", "DECODE_STATE_AT"]
+__all__ = ["StepPrograms", "DECODE_STATE_AT", "DECODE_CACHE_AT"]
 
 # decode_step(ids, cache_lengths, active, k_cache, v_cache, *state)
 DECODE_STATE_AT = 5
+# decode_step(ids, cache_lengths, active, *kv, *state) of a description
+# with `retain`: the KV arrays are donated too
+DECODE_CACHE_AT = 3
 
 
 class StepPrograms:
     def __init__(self, model):
         from ..jit import StaticFunction
-        from .kv_pool import cache_spec_of, state_groups
+        from .kv_pool import (cache_spec_of, retained_kv_groups,
+                              state_groups)
         for name in ("prefill_step", "decode_step"):
             if not callable(getattr(model, name, None)):
                 raise TypeError(
@@ -102,13 +125,15 @@ class StepPrograms:
         self._prefill = StaticFunction(
             _with_greedy(model.prefill_step, bool(self.counters)),
             layer=model, abstract_trace=True)
-        n_state = sum(len(g["arrays"]) for g in
-                      state_groups(cache_spec_of(model.config)))
+        spec = cache_spec_of(model.config)
+        n_state = sum(len(g["arrays"]) for g in state_groups(spec))
+        n_kv = 2 * len(retained_kv_groups(spec))
+        first = DECODE_CACHE_AT if n_kv else DECODE_STATE_AT
         self._decode = StaticFunction(
             _with_greedy(model.decode_step, bool(self.counters),
                          ids_as_picked=True),
             layer=model, abstract_trace=True,
-            donate_args=range(DECODE_STATE_AT, DECODE_STATE_AT + n_state))
+            donate_args=range(first, first + n_kv + n_state))
 
     @property
     def programs(self) -> int:
@@ -120,14 +145,17 @@ class StepPrograms:
         with no_grad():
             return self._prefill(ids, lengths, last)
 
-    def decode(self, ids, cache_lengths, active, k_cache, v_cache, *state):
+    def decode(self, ids, cache_lengths, active, *cache):
         """One decode step, ids [S + C] -> (logits [S, V], next_ids
-        [S + C], K, V columns, *state).  `state`: the raw device arrays,
+        [S + C], K, V columns, *state).  `cache` = k_cache, v_cache (the
+        dense view's slices, tensors) then the state's raw device arrays,
         DONATED — dead when this returns; the `*state` results replace
-        them."""
+        them.  For a description with `retain`, `cache` = the device KV
+        arrays then the state's, all raw and all donated, and the result
+        is (logits, next_ids, *kv, *state)."""
         with no_grad():
-            return self._decode(ids, cache_lengths, active, k_cache,
-                                v_cache, *[Tensor(s) for s in state])
+            return self._decode(ids, cache_lengths, active, *[
+                c if isinstance(c, Tensor) else Tensor(c) for c in cache])
 
 
 def _with_greedy(step, counted=False, ids_as_picked=False):

@@ -1294,6 +1294,40 @@ def _decode_quantizable_counts(cfg: Dict):
     return elems, col_channels, row_channels
 
 
+def _device_kv_slot(cfg, ctx: int, page_tokens: int) -> Dict:
+    """What ONE slot holds for good under a cache description whose ``kv``
+    groups state their ``retain`` (serving/kv_pool.py: the KV lives on the
+    device only, so there is no host pool to carve and no gather view to
+    rent)::
+
+        a window group   2 x layers x kv heads x window x head dim
+        an "all" group   2 x layers x kv heads x pow2(ctx) x head dim
+        its recurrent state, where the model has any
+        a float32 logits row
+
+    Returns the per-slot terms ``page_budget`` sizes with: ``slot`` (KV +
+    state), ``kv_slot`` (the KV alone), ``ws_slot`` (the logits row),
+    ``slot_pages`` (the tokens of the ``"all"`` group, else the first, in
+    pages + the partial-page allowance: every slot's worst case, since all
+    of it is resident anyway, so admission is by slots and the page tables
+    account) and ``page_bytes`` (that group's K+V columns a page, in the
+    dtype the device holds)."""
+    from ..core.dtype import np_dtype
+    from ..serving.kv_pool import (device_kv_arrays, kv_geometry,
+                                   state_slot_bytes)
+    L, H, Dh = kv_geometry(cfg["cache"])
+    slot = state_slot_bytes(cfg["cache"], ctx)
+    item = np_dtype(device_kv_arrays(cfg["cache"], ctx)[0]["dtype"]).itemsize
+    return {
+        "slot": int(slot),
+        "kv_slot": int(slot - state_slot_bytes(
+            [g for g in cfg["cache"] if g["kind"] == "state"])),
+        "ws_slot": int(cfg["vocab_size"]) * 4,
+        "slot_pages": -(-_next_pow2(ctx) // page_tokens) + 1,
+        "page_bytes": 2 * L * H * Dh * item * page_tokens,
+    }
+
+
 def page_budget(model=None, config=None, *, page_tokens: int = 16,
                 max_context: Optional[int] = None,
                 hbm_bytes: Optional[int] = None,
@@ -1379,17 +1413,24 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
     import numpy as np
     from .memory_analysis import hbm_budget_bytes
     from ..core.dtype import np_dtype
-    from ..serving.kv_pool import kv_geometry, state_slot_bytes
+    from ..serving.kv_pool import (kv_geometry, retained_kv_groups,
+                                   state_slot_bytes)
     cfg = _model_config(model, config)
     # pool geometry from the model's cache description: the kv group's
     # layers x kv heads x head dim, and what a sequence's recurrent state
-    # holds whatever its length (0 without a `state` group)
+    # holds whatever its length (0 without a `state` group); `on_device`:
+    # its kv groups state their `retain`, so the KV itself is per-slot
+    # device arrays (`_device_kv_slot`) and the pages only account
     L, H, Dh = kv_geometry(cfg["cache"])
     state_slot = state_slot_bytes(cfg["cache"])
-    if state_slot and (int(tp_degree or 1) > 1 or draft_layers
-                       or str(weight_dtype) != "float32"
-                       or str(kv_dtype) != "float32"):
+    on_device = bool(retained_kv_groups(cfg["cache"]))
+    if (state_slot or on_device) and (
+            int(tp_degree or 1) > 1 or draft_layers
+            or str(weight_dtype) != "float32" or str(kv_dtype) != "float32"):
         raise NotImplementedError(
+            "page_budget: a description with `retain` is sized at tp 1, its "
+            "own KV and weight dtypes and no draft — sharded or quantized "
+            "device KV and a ring's rollback are not built" if on_device else
             "page_budget: a model with recurrent state is sized at tp 1, "
             "float32 pages, its own weight dtype and no draft — sharded "
             "state, quantized pages and state rollback are not built")
@@ -1412,7 +1453,7 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
             weight_bytes = int(sum(
                 int(np.prod(p.shape)) * np_dtype(p.dtype).itemsize
                 for p in getattr(model, "gpt", model).parameters()))
-        elif state_slot:
+        elif state_slot or on_device:
             raise ValueError(
                 "page_budget: give weight_bytes (or the model) for a "
                 "config the GPT closed form does not describe")
@@ -1420,7 +1461,7 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
             weight_bytes = _decode_weight_bytes(cfg)
     weight_bytes = int(weight_bytes)
     weight_bytes_fp32 = weight_bytes
-    shardable = 0 if state_slot else \
+    shardable = 0 if state_slot or on_device else \
         min(weight_bytes, _decode_shardable_bytes(cfg))
     weight_dtype = str(weight_dtype)
     if weight_dtype not in ("float32", "int8"):
@@ -1483,57 +1524,86 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
             draft_kv_slot_pc += 2 * draft_layers * H_loc * 4
     usable = int(budget * (1.0 - float(headroom))) - weight_bytes_pc \
         - draft_weight_bytes_pc
-    if usable < page_bytes_pc + ws_col_pc * _next_pow2(ctx) + state_slot:
-        raise ValueError(
-            f"page_budget: {budget} B HBM/chip leaves {usable} B after "
-            f"{weight_bytes_pc} B of per-chip weights"
-            + (f" + {draft_weight_bytes_pc} B of draft weights"
-               if draft_layers else "") +
-            f" — not enough for one decode "
-            f"slot at context {ctx} at tp={tp} (raise "
-            f"PADDLE_TPU_HBM_BYTES, raise tp_degree, or shrink the "
-            f"model)")
-    # per-slot step workspace: the dense [L, H/tp, lpad, Dh] K+V gather
-    # view at the largest pow2 KV bucket, plus this row's REPLICATED
-    # logits (the row-parallel head allreduces full vocab everywhere),
-    # and the draft model's per-slot dense KV when speculating
-    ws_slot = ws_col_pc * _next_pow2(ctx) \
-        + cfg["vocab_size"] * 4 + draft_kv_slot_pc
-    # what a slot holds for good comes off the budget before pages are
-    # cut: its recurrent state, resident from engine start — ONE copy: the
-    # decode step is given the state arrays to write into (they are
-    # donated through the compiled program, `serving/step_program.py`), so
-    # no second copy of a row's state exists while a step runs
-    slot_bytes = ws_slot + state_slot
-    max_slots = max(1, min(cap, int(usable * 0.35) // slot_bytes))
-    pages = (usable - max_slots * slot_bytes) // page_bytes_pc
-    while pages < 1 and max_slots > 1:      # tiny budgets: trade slots back
-        max_slots -= 1
+    if on_device:
+        # `max_slots` slots may take HALF of what the weights leave: the
+        # other half stays free for a prefill's transient workspace (a
+        # prompt of thousands of tokens through an expert layer is
+        # gigabytes); all of a slot is resident, so the pages are every
+        # slot's worst case and `kv_bytes` the device arrays whole
+        per = _device_kv_slot(cfg, ctx, T)
+        ws_slot, state_slot = per["ws_slot"], per["slot"]
+        if usable // 2 < state_slot + ws_slot:
+            raise ValueError(
+                f"page_budget: {budget} B HBM/chip leaves {usable} B after "
+                f"{weight_bytes} B of weights — not enough for one slot of "
+                f"{state_slot} B of device KV and state at context {ctx} "
+                "beside a prefill's workspace")
+        max_slots = int(max(1, min(
+            cap, (usable // 2) // (state_slot + ws_slot))))
+        pages = max_slots * per["slot_pages"]
+        page_bytes = page_bytes_pc = per["page_bytes"]
+        kv_bytes = max_slots * per["kv_slot"]
+        wm_low, wm_high = 1, 2
+        on_device_keys = {"kv_slot_bytes": per["kv_slot"],
+                          "kv_on_device": True}
+        source = ("static.page_budget (device-only KV: per-slot arrays of "
+                  "each kv group's retention + parameter persistable walk)")
+    else:
+        if usable < page_bytes_pc + ws_col_pc * _next_pow2(ctx) + state_slot:
+            raise ValueError(
+                f"page_budget: {budget} B HBM/chip leaves {usable} B after "
+                f"{weight_bytes_pc} B of per-chip weights"
+                + (f" + {draft_weight_bytes_pc} B of draft weights"
+                   if draft_layers else "") +
+                f" — not enough for one decode "
+                f"slot at context {ctx} at tp={tp} (raise "
+                f"PADDLE_TPU_HBM_BYTES, raise tp_degree, or shrink the "
+                f"model)")
+        # per-slot step workspace: the dense [L, H/tp, lpad, Dh] K+V gather
+        # view at the largest pow2 KV bucket, plus this row's REPLICATED
+        # logits (the row-parallel head allreduces full vocab everywhere),
+        # and the draft model's per-slot dense KV when speculating
+        ws_slot = ws_col_pc * _next_pow2(ctx) \
+            + cfg["vocab_size"] * 4 + draft_kv_slot_pc
+        # what a slot holds for good comes off the budget before pages are
+        # cut: its recurrent state, resident from engine start — ONE copy:
+        # the decode step is given the state arrays to write into (they
+        # are donated through the compiled program,
+        # `serving/step_program.py`), so no second copy of a row's state
+        # exists while a step runs
+        slot_bytes = ws_slot + state_slot
+        max_slots = max(1, min(cap, int(usable * 0.35) // slot_bytes))
         pages = (usable - max_slots * slot_bytes) // page_bytes_pc
-    if pages < 1:
-        raise ValueError(
-            f"page_budget: workspace for one slot leaves no room for "
-            f"pages ({usable} usable, {ws_slot} per slot)")
-    pages = int(pages)
-    # the honest advertised max-context: ANY prompt shape within it must
-    # fit its admission reservation (pages_for_request), which includes
-    # the +1 COW allowance for a partial final prompt page — so the top
-    # page cannot be promised (ctx = pages*T would reject in-limit
-    # requests as "can never fit")
-    ctx = min(ctx, max(T, (pages - 1) * T))
-    max_slots = int(min(max_slots, pages))
-    # retention watermarks, in FREE pages: the radix cache evicts LRU
-    # leaves when free drops below `low` and releases until free climbs
-    # back to `high` — retention is bounded, admission never starves
-    wm_low = max(1, pages // 8)
-    wm_high = max(wm_low + 1, pages // 4)
+        while pages < 1 and max_slots > 1:  # tiny budgets: trade slots back
+            max_slots -= 1
+            pages = (usable - max_slots * slot_bytes) // page_bytes_pc
+        if pages < 1:
+            raise ValueError(
+                f"page_budget: workspace for one slot leaves no room for "
+                f"pages ({usable} usable, {ws_slot} per slot)")
+        pages = int(pages)
+        # the honest advertised max-context: ANY prompt shape within it must
+        # fit its admission reservation (pages_for_request), which includes
+        # the +1 COW allowance for a partial final prompt page — so the top
+        # page cannot be promised (ctx = pages*T would reject in-limit
+        # requests as "can never fit")
+        ctx = min(ctx, max(T, (pages - 1) * T))
+        max_slots = int(min(max_slots, pages))
+        # retention watermarks, in FREE pages: the radix cache evicts LRU
+        # leaves when free drops below `low` and releases until free climbs
+        # back to `high` — retention is bounded, admission never starves
+        wm_low = max(1, pages // 8)
+        wm_high = min(max(wm_low + 1, pages // 4), pages)
+        kv_bytes = pages * page_bytes
+        on_device_keys = {}
+        source = ("static.page_budget (memory_analysis.hbm_budget_bytes "
+                  "+ parameter persistable walk)")
     return {
-        "pages": pages,
+        "pages": int(pages),
         "page_tokens": T,
         "max_slots": max_slots,
         "max_context": int(ctx),
-        "retained_watermarks": {"low": int(wm_low),
-                                "high": int(min(wm_high, pages))},
+        "retained_watermarks": {"low": int(wm_low), "high": int(wm_high)},
         "draft_layers": draft_layers,
         "draft_weight_bytes": int(draft_weight_bytes),
         "draft_kv_bytes": int(max_slots * draft_kv_slot_pc * tp),
@@ -1544,7 +1614,8 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
         "kv_dtype": str(kv_dtype),
         "weight_dtype": weight_dtype,
         "page_bytes": int(page_bytes),
-        "kv_bytes": int(pages * page_bytes),
+        "kv_bytes": int(kv_bytes),
+        **on_device_keys,
         "workspace_bytes": int(max_slots * ws_slot),
         "cache": cfg["cache"],
         "state_slot_bytes": int(state_slot),
@@ -1558,6 +1629,5 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
         "headroom": float(headroom),
         "max_slots_cap": cap,
         "config": cfg,
-        "source": "static.page_budget (memory_analysis.hbm_budget_bytes "
-                  "+ parameter persistable walk)",
+        "source": source,
     }

@@ -181,3 +181,114 @@ def test_grouped_experts_at_published_widths_compile_to_two_kernels(
     pairs = rows * k
     hidden_bytes = pairs * f * 4
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * hidden_bytes
+
+
+@pytest.fixture(scope="module")
+def command_a_plus_programs():
+    """The traced prefill (8,192-token bucket) and decode (32 rows) step
+    Programs of the published `command-a-plus-05-2026` share, recorded from
+    shapes alone: the model is built INSIDE `jax.eval_shape`, so its 9.47 GB
+    of parameters are never allocated on this machine; what leaves is the
+    Programs and the shapes of their feeds and parameters."""
+    import json
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.dygraph as dg
+    from paddle_tpu.core.dtype import np_dtype
+    from paddle_tpu.dygraph.tensor import Tensor
+    from paddle_tpu.models import Cohere2MoeModel
+    from paddle_tpu.serving.kv_pool import device_kv_arrays
+    from paddle_tpu.serving.step_program import StepPrograms
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    import sys
+    sys.path.insert(0, here)
+    from benchmark import serving_cohere2_moe
+    with open(os.path.join(here, "benchmark", "configs",
+                           "command-a-plus-05-2026.json")) as f:
+        file = json.load(f)
+    cfg = serving_cohere2_moe.model_config(file, file["engine"])
+    slots = file["engine"]["max_slots_cap"]
+    kv = device_kv_arrays(cfg.cache_spec(), file["engine"]["max_context"])
+    got = {}
+
+    def build():
+        def zeros(shape, dtype):
+            return Tensor(jnp.zeros(shape, dtype))
+
+        with dg.guard():
+            steps = StepPrograms(Cohere2MoeModel(cfg))
+            got["prefill"] = steps._prefill.concrete_program(
+                zeros((1, 8192), jnp.int32), zeros((1,), jnp.int32),
+                zeros((1,), jnp.int32))
+            got["decode"] = steps._decode.concrete_program(
+                zeros((slots + len(steps.counters),), jnp.int32),
+                zeros((slots,), jnp.int32), zeros((slots,), jnp.int32),
+                *[zeros((a["layers"], slots) + tuple(a["shape"]),
+                        np_dtype(a["dtype"])) for a in kv])
+        return 0
+
+    with jax.enable_x64(False):
+        jax.eval_shape(build)
+    return got, cfg, kv, slots
+
+
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_command_a_plus_step_programs_compile_inside_hbm(
+        one_chip, no_compile_cache, monkeypatch, command_a_plus_programs,
+        phase):
+    """The 8,192-token prefill and the 32-row decode program of the
+    published configuration's share, whole, for XLA:TPU + Mosaic: the
+    weights are 9.47 GB, the prefill's temporaries stay under 2 GB (no
+    `[T, T]` scores for all heads, no full-width rows of absent experts),
+    and the decode program aliases every KV array to its result — 2.68 GB
+    written in place, no copy left — so both fit the v5e's 16.9 GB beside
+    the resident cache."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.kernels import moe, window_attention
+    for module in (moe, window_attention):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    got, cfg, kv, slots = command_a_plus_programs
+    cp = got[phase]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    block = cp.program.global_block()
+    feeds = [sds(block.var(n).shape, block.var(n).dtype)
+             for n in cp.feed_names]
+    kept = tuple(f for i, f in enumerate(feeds) if i not in cp.donated)
+    donated = tuple(feeds[i] for i in cp.donated)
+    params = tuple(sds(t.shape, t._value.dtype) for t in cp.params.values())
+    args = (sds((), jnp.uint32), params, kept, True) \
+        + ((donated,) if donated else ())
+    with jax.enable_x64(False):
+        compiled = cp.composed().lower(*args).compile()
+    mem = compiled.memory_analysis()
+    weights = 2 * cfg.param_count()
+    kv_bytes = sum(a["layers"] * slots * int(np.prod(a["shape"])) * 2
+                   for a in kv)
+    assert weights == 9_466_585_088 and kv_bytes == 2_684_354_560
+    hbm = 16_909_336_064
+    if phase == "prefill":
+        assert not cp.donated and mem.alias_size_in_bytes == 0
+        assert weights <= mem.argument_size_in_bytes < weights + (1 << 20)
+        assert mem.temp_size_in_bytes < 2 << 30
+        # beside the resident cache of 32 slots
+        assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+            + mem.temp_size_in_bytes + kv_bytes < hbm
+        text = compiled.as_text()
+        assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                              text)) >= 4 + 6 + 8   # attention, rotary, gmm
+        assert not re.findall(r"f32\[\d*,?128,8192,8192\]", text)
+    else:
+        assert len(cp.donated) == len(kv) == 4
+        assert mem.alias_size_in_bytes == kv_bytes
+        assert mem.temp_size_in_bytes < 1 << 29
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes < hbm
+        text = compiled.as_text()
+        for a in kv[::2]:       # no copy of a cache array is left
+            shape = f"bf16\\[{a['layers']},{slots}," + ",".join(
+                str(n) for n in a["shape"]) + "\\]"
+            assert not re.findall(rf"= {shape}\S* copy\(", text)
