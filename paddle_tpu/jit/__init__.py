@@ -372,7 +372,9 @@ class StaticFunction:
         param_raws = tuple(t._value for t in param_ts)
         from ..core.generator import global_seed
         from ..dygraph.base import is_grad_enabled
-        seed = jnp.uint32(global_seed())
+        # a host scalar: `jnp.uint32` would launch a convert on the device
+        # for it, one more XLA module a call
+        seed = np.uint32(global_seed())
         training = self._layer.training if self._layer is not None else True
         is_test = not training
         fn = cp.composed()

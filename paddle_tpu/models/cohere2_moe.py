@@ -26,7 +26,7 @@ its published width and top-k; what the experts held elsewhere would add
 is left out, and no code stands in for the other chips or the exchange
 with them (ROADMAP R-d).  The KV cache is on the device only, a ring of
 `sliding_window` columns a window layer and every column a full layer
-(`kv_on_device`; docs/serving.md).
+(docs/serving.md).
 """
 from __future__ import annotations
 
@@ -49,7 +49,7 @@ class Cohere2MoeConfig(HybridDecoderConfig):
     tie_word_embeddings = True
     embedding_multiplier = residual_multiplier = 1.0
     block_form, norm_kind = "parallel", "layer"
-    expert_form, kv_on_device = "gated_silu", True
+    expert_form = "gated_silu"
     routed_scaling_factor = 1.0
 
     def __init__(self, vocab_size=262144, hidden_size=4096,

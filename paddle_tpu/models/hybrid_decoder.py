@@ -17,18 +17,23 @@ config class that gives the description (`models/granite_hybrid.py`,
 Serving only: the ops (`ops/kernels/ssm.py`, `ops/kernels/moe.py`,
 `ops/kernels/window_attention.py`) are forward only, so the parameters do
 not ask for gradients and no tape is ever kept.  The model states its
-cache (`cache_spec`): one `kv` group for the attention layers — or, for a
-config with `kv_on_device`, one `kv` group per kind of retention (`retain`:
-a window's columns in a ring, or `"all"`), whose arrays then ride the step
-contract like the state's — and one `state` group for the Mamba layers —
-`serving.PagedKVPool`, `static.page_budget` and the engine size
-themselves from it.  Two cache-aware entry points make the step contract
-(ids, per-row lengths, cache in; last-row logits, cache out):
+cache (`cache_spec`): one `kv` group per kind of retention of its attention
+layers (`retain`: a window's columns in a ring, or `"all"`) and one `state`
+group for the Mamba layers — `serving.PagedKVPool`, `static.page_budget`
+and the engine size themselves from it.  ALL of it lives on the device, a
+row a slot, and rides the step contract: two cache-aware entry points
+(ids, per-row lengths, cache in; last-row logits, cache out),
 `prefill_step` and `decode_step`, flat tensor arguments and a tuple
 result, so `jit.to_static` turns each into one compiled program per shape
-bucket (`serving.step_program`).  A model with routed experts also counts
-what its steps routed (`step_counters`): one int32 vector a call, after
-the state in the result tuple.
+bucket (`serving.step_program`); the decode step is given the KV and state
+arrays whole and every layer writes its own entry where the array lies.
+The one fork is read from the description (`kv_ring`): with a window layer
+the attention ops are the blocked ones (`ops/kernels/window_attention.py`)
+and the decode step one program that reads what its rows hold; without,
+a prompt goes through `gqa_attention` and the decode step reads its
+caller's static bound on the columns (`decode_step(columns=)`).  A model
+with routed experts also counts what its steps routed (`step_counters`):
+one int32 vector a call, after the state in the result tuple.
 """
 from __future__ import annotations
 
@@ -44,7 +49,7 @@ from ..nn.initializer import Constant, Normal, Uniform
 from ..tensor._dispatch import dispatch
 from ..tensor.linalg import matmul
 from ..tensor.manipulation import (cast, gather, reshape, split, squeeze,
-                                   stack, transpose, unsqueeze, unstack)
+                                   stack, transpose, unsqueeze)
 from ..tensor.math import add, multiply, scale
 
 __all__ = ["LayerSpec", "AttentionSpec", "HybridDecoderConfig",
@@ -85,9 +90,6 @@ class HybridDecoderConfig:
     norm_kind = "rms"               # | "layer": mean-subtracting, scale only
     expert_form = "latent_relu2"    # | "gated_silu"
     n_shared_experts = 1            # averaged where there are several
-    # the KV cache on the device only, a `kv` group per kind of retention,
-    # its arrays in the step contract (else one group and a dense view)
-    kv_on_device = False
     # a family without Mamba layers states no `mamba_*` sizes
     mamba_n_heads = mamba_d_head = mamba_d_state = mamba_n_groups = 0
     mamba_expand = mamba_d_conv = 0
@@ -100,10 +102,6 @@ class HybridDecoderConfig:
         for i, spec in self.attention_specs.items():
             if self.blocks[i].mixer != "attention":
                 raise ValueError(f"layer {i} has no attention to describe")
-            if (spec.window or spec.rotary) and not self.kv_on_device:
-                raise NotImplementedError(
-                    "a window or rotary positions need the blocked "
-                    "attention ops of a config with kv_on_device")
         if self.block_form not in BLOCK_FORMS \
                 or self.norm_kind not in NORM_KINDS \
                 or self.expert_form not in EXPERT_FORMS:
@@ -150,43 +148,37 @@ class HybridDecoderConfig:
 
     def kv_groups(self):
         """The attention layers by what they retain, in order of first
-        appearance: [(window or None, [layer indices])] — one entry for a
-        config whose KV is not on the device."""
+        appearance: [(window or None, [layer indices])]."""
         groups = {}
         for i in self.layers_of("attention"):
-            key = self.attention_spec(i).window if self.kv_on_device \
-                else None
-            groups.setdefault(key, []).append(i)
+            groups.setdefault(self.attention_spec(i).window or None,
+                              []).append(i)
         return list(groups.items())
+
+    @property
+    def kv_ring(self):
+        """Whether some attention layer keeps a window's columns in a
+        ring: the description's one fork (the blocked attention ops and
+        ONE decode program, against `gqa_attention` and a decode program
+        a bound on the columns)."""
+        return any(window for window, _ in self.kv_groups())
 
     @property
     def expert_layers(self):
         return len(self.layers_of("experts"))
 
     def cache_spec(self):
-        """What one sequence keeps between steps, by layer group: `kv`
-        groups grow a column a token and live in pool pages; `state`
-        groups are of fixed size and live in a state slot."""
-        groups = []
-        if self.kv_on_device:
-            # a group per kind of retention: its arrays K, V [layers,
-            # slots, kv heads, columns, head dim] live on the device only
-            # (`serving.kv_pool`), a window's ring or every column
-            for window, layers in self.kv_groups():
-                groups.append({"kind": "kv", "layers": len(layers),
-                               "kv_heads": self.num_key_value_heads,
-                               "head_dim": self.head_dim,
-                               "dense_dtype": self.dtype,
-                               "retain": int(window) if window else "all"})
-        elif self.layers_of("attention"):
-            # dense_dtype: what `decode_step` reads its dense KV cache
-            # in; the pool keeps that view of the live sequences on the
-            # device, per slot, beside the state
-            groups.append({"kind": "kv",
-                           "layers": len(self.layers_of("attention")),
-                           "kv_heads": self.num_key_value_heads,
-                           "head_dim": self.head_dim,
-                           "dense_dtype": self.dtype})
+        """What one sequence keeps between steps, by layer group, all of
+        it in its slot on the device (`serving.kv_pool.StateSlots`): a
+        `kv` group per kind of retention — arrays K, V [layers, slots, kv
+        heads, columns, head dim], a window's ring or every column
+        (`"all"`: a column a token, which the pool's pages count) — and
+        the Mamba layers' `state` group, of fixed size."""
+        groups = [{"kind": "kv", "layers": len(layers),
+                   "kv_heads": self.num_key_value_heads,
+                   "head_dim": self.head_dim, "dtype": self.dtype,
+                   "retain": int(window) if window else "all"}
+                  for window, layers in self.kv_groups()]
         if self.layers_of("mamba"):
             groups.append({
                 "kind": "state", "layers": len(self.layers_of("mamba")),
@@ -387,11 +379,11 @@ class _Experts(Layer):
 class _Attention(Layer):
     """Grouped-query attention with the config's own score multiplier;
     of its layer's description, a `window` and rotary positions (`rotary`:
-    theta), each or neither.  `forward(x, cache)`: `cache` None (prefill:
-    the new tokens alone) or (k, v, lengths) of the earlier tokens; returns
-    (out, (k, v)) with k, v the new tokens' [B, Hkv, T, D].  For a config
-    with `kv_on_device`, `forward(x)` runs a prompt through the blocked
-    kernel and `step` one token a row over the cache group's arrays."""
+    theta), each or neither.  `forward(x)` runs whole sequences from
+    empty caches — through the blocked kernel where the description has a
+    ring (`kv_ring`), else `gqa_attention` — and returns (out, (k, v)), k,
+    v the tokens' [B, Hkv, T, D]; `step` one token a row over the cache
+    group's arrays."""
 
     def __init__(self, cfg, spec=AttentionSpec()):
         super().__init__(dtype=cfg.dtype)
@@ -430,35 +422,31 @@ class _Attention(Layer):
                       [b, t, c.num_attention_heads * c.head_dim])
         return matmul(ctx, self.wo)
 
-    def forward(self, x, cache=None):
+    def forward(self, x):
         c = self.cfg
         q, k, v = self._heads(x)
-        if c.kv_on_device:      # a prompt: blocks of queries, the window
+        attrs = {"scale": c.attention_multiplier}
+        if c.kv_ring:       # blocks of queries, the window's key blocks
             ctx = dispatch("windowed_prefill_attention",
                            {"Q": q, "K": k, "V": v},
-                           {"scale": c.attention_multiplier,
-                            "window": int(self.window or 0)})
-            return self._out(ctx), (k, v)
-        ins = {"Q": q, "K": k, "V": v}
-        if cache is not None:
-            ins.update(KCache=cache[0], VCache=cache[1],
-                       CacheLengths=cache[2])
-        ctx = dispatch("gqa_attention", ins,
-                       {"scale": c.attention_multiplier})
+                           dict(attrs, window=int(self.window or 0)))
+        else:
+            ctx = dispatch("gqa_attention", {"Q": q, "K": k, "V": v}, attrs)
         return self._out(ctx), (k, v)
 
-    def step(self, x, lengths, active, k_cache, v_cache, index):
+    def step(self, x, lengths, active, k_cache, v_cache, index, columns):
         """One token a row: `k_cache`, `v_cache` the whole arrays [Lg, S,
         Hkv, columns, D] of this layer's cache group; this layer writes
         its new column into entry `index` of each (a ring: at ``length
-        mod window``) and reads it."""
+        mod window``) and reads its row's: up to the longest row's
+        (`columns` None), or the first `columns` of every row."""
         q, k, v = self._heads(x, unsqueeze(lengths, 1))
         ctx, k_cache, v_cache = dispatch(
             "cached_decode_attention",
             {"Q": q, "K": k, "V": v, "KCache": k_cache, "VCache": v_cache,
              "CacheLengths": lengths, "Active": active},
             {"scale": self.cfg.attention_multiplier, "slab_index": index,
-             "window": int(self.window or 0)},
+             "window": int(self.window or 0), "columns": int(columns or 0)},
             ["Out", "NewKCache", "NewVCache"])
         return self._out(ctx), (k_cache, v_cache)
 
@@ -742,22 +730,15 @@ class HybridDecoder(Layer):
 
         ids [B, T]; lengths [B] valid tokens a row; last [B] = lengths - 1
         (the row whose logits sampling needs).  Returns (logits [B, V]
-        float32, K, V [La, B, Hkv, T, D] of the attention layers, ssm
-        [Lm, B, H, P, N] float32 and conv [Lm, B, K-1, C] of the Mamba
+        float32; a K, V per cache group of `cache_spec`, in its order:
+        [Lg, B, Hkv, T, D] for a group that keeps every column, and for a
+        window group the RING as it stands after the prompt's last valid
+        token, [Lg, B, Hkv, window, D] (`kv_ring_pack`); ssm [Lm, B, H, P,
+        N] float32 and conv [Lm, B, K-1, C] where the model has Mamba
         layers: the state after each row's last VALID token; then, for a
-        model with expert layers, the counts of `step_counters`).
-
-        A config with `kv_on_device` returns, in place of the one K, V,
-        a K, V per cache group of `cache_spec`, in its order: [Lg, B, Hkv,
-        T, D] for a group that keeps every column, and for a window group
-        the RING as it stands after the prompt's last valid token, [Lg, B,
-        Hkv, window, D] (`kv_ring_pack`); then the state arrays where the
-        model has Mamba layers."""
+        model with expert layers, the counts of `step_counters`)."""
         h, ks, vs, ssm, conv, routed = self._scan_layers(ids, lengths)
         rows = _take_rows(h, last)
-        if not self.config.kv_on_device:
-            return (self._logits(rows), stack(ks), stack(vs), stack(ssm),
-                    stack(conv), *self._counted(routed))
         at = {i: n for n, i in enumerate(
             self.config.layers_of("attention"))}
         kv = []
@@ -773,55 +754,28 @@ class HybridDecoder(Layer):
         state = [stack(ssm), stack(conv)] if ssm else []
         return (self._logits(rows), *kv, *state, *self._counted(routed))
 
-    def decode_step(self, ids, cache_lengths, active, *cache):
+    def decode_step(self, ids, cache_lengths, active, *cache, columns=None):
         """One token a row on the carried caches.
 
         ids [S, 1]; cache_lengths [S] valid columns of each row's KV cache;
         active [S] 1 for a row that takes its token, 0 for an idle row
         (its state comes back unchanged and it routes to no expert);
-        `cache` = k_cache, v_cache [La, S, Hkv, L, D]; ssm [Lm, S, H, P,
-        N]; conv [Lm, S, K-1, C].  Returns (logits [S, V] float32, the new
-        K, V columns [La, S, Hkv, 1, D], ssm, conv; then, for a model with
-        expert layers, the counts of `step_counters`).
+        `cache` = a K, V per cache group of `cache_spec`, [Lg, S, Hkv,
+        columns, D], then ssm [Lm, S, H, P, N] and conv [Lm, S, K-1, C]
+        where the model has Mamba layers.  They come back WHOLE in the
+        result, each attention layer's new column written into its entry
+        (`_Attention.step`) and each Mamba layer's state replaced in its:
+        the caller donates them.  Returns (logits [S, V] float32, *cache;
+        then, for a model with expert layers, the counts of
+        `step_counters`).
 
-        A config with `kv_on_device`: `cache` = a K, V per cache group of
-        `cache_spec`, [Lg, S, Hkv, columns, D] (then ssm, conv where the
-        model has Mamba layers), and they come back WHOLE in the result in
-        place of the columns, each attention layer's new column written
-        into its entry (`_Attention.step`): the caller donates them, like
-        the state."""
-        if self.config.kv_on_device:
-            return self._decode_on_device(ids, cache_lengths, active,
-                                          list(cache))
-        k_cache, v_cache, ssm, conv = cache
+        `columns` (static, not a tensor): None, a row reads what it holds,
+        block by block up to the longest row's — what a description with a
+        ring takes, ONE program; n, every row's earlier tokens lie in the
+        first n columns of the arrays and that many are read — a program
+        a bound, for a description without a ring."""
         c = self.config
-        h = self._embed(ids)
-        kc = unstack(k_cache, 0) if c.layers_of("attention") else []
-        vc = unstack(v_cache, 0) if c.layers_of("attention") else []
-        ks, vs, routed, n_mamba = [], [], [], 0
-        for blk in self.layers:
-            if blk.kind == "attention":
-                cache = (cast(kc[len(ks)], c.dtype),
-                         cast(vc[len(vs)], c.dtype), cache_lengths)
-                h, (k, v), expert = blk(
-                    h, lambda m, x: m(x, cache), active)
-                ks.append(k)
-                vs.append(v)
-            elif blk.kind == "mamba":
-                # the state arrays go through the Mamba layers whole, each
-                # replacing its own entry: no unstack / stack copies
-                h, (ssm, conv), expert = blk(h, lambda m, x: m.update(
-                    x, active, ssm, conv, n_mamba), active)
-                n_mamba += 1
-            else:
-                h, _, expert = blk(h, None, active)
-            if expert is not None:
-                routed.append(expert)
-        return (self._logits(squeeze(h, 1)), stack(ks), stack(vs), ssm,
-                conv, *self._counted(routed))
-
-    def _decode_on_device(self, ids, cache_lengths, active, cache):
-        c = self.config
+        cache = list(cache)
         # layer -> (its group's position in `cache`, its entry there)
         where = {i: (2 * g, n) for g, (_, layers) in
                  enumerate(c.kv_groups()) for n, i in enumerate(layers)}
@@ -834,9 +788,12 @@ class HybridDecoder(Layer):
                 at, entry = where[i]
                 h, (cache[at], cache[at + 1]), expert = blk(
                     h, lambda m, x: m.step(x, cache_lengths, active,
-                                           cache[at], cache[at + 1], entry),
+                                           cache[at], cache[at + 1], entry,
+                                           columns),
                     active)
             elif blk.kind == "mamba":
+                # the state arrays go through the Mamba layers whole, each
+                # replacing its own entry: no unstack / stack copies
                 h, (ssm, conv), expert = blk(h, lambda m, x: m.update(
                     x, active, ssm, conv, n_mamba), active)
                 n_mamba += 1

@@ -2,7 +2,8 @@
 layer keeping either every column or a window's worth in a RING: rotary
 positions, a prompt's blocked causal attention (window or none), the ring a
 window layer keeps of a prompt, and the one-token step over the cache with
-the new column written in place.
+the new column written in place (to a trip count read from the rows, or to
+a bound on their columns fixed at trace time).
 
 All are FORWARD ONLY (``grad=None``): the serving path is their one caller
 (the rotary op's gradient is what is left of ROADMAP R-a).
@@ -36,8 +37,11 @@ _F32 = jnp.float32
 # rows of queries a grid step takes per kv head (times the heads that share
 # it) and the columns of keys a turn of its loop reads
 _BLOCK_Q, _BLOCK_K = 64, 512
-# columns of the cache a turn of the decode step's loop reads
-_DECODE_BLOCK = 512
+# columns of the cache a turn of the decode step's loop reads, and the
+# fewest a bounded read takes: a lane tile (an array of 64-wide heads lies
+# with its columns along the lanes; for a narrower slice XLA:TPU turns the
+# whole array into another layout and back, two copies of it a layer)
+_DECODE_BLOCK, _DECODE_LANES = 512, 128
 # positions of one head a grid step of the rotary kernel turns
 _ROTARY_BLOCK = 2048
 _VMEM_LIMIT = 64 << 20
@@ -218,16 +222,23 @@ def _at(*index):
     return tuple(jnp.asarray(i, jnp.int32) for i in index)
 
 
+@jax.jit
 def _write_columns(cache, cols, index, pos):
     """`cols` [S, Hkv, 1, D] into `cache` [La, S, Hkv, L, D], row `s` at
     `(index, s, :, pos[s])`: a chain of one-column updates, each of which
     XLA makes where the array lies, so a donated cache is never copied (a
     loop over the rows, a scatter, or reading the old column first each
-    cost one copy of the whole array on the v5e's compiler)."""
-    for s in range(cols.shape[0]):
+    cost one copy of the whole array on the v5e's compiler).  A function
+    of its own under the step's trace (`index` a traced scalar): every
+    layer's K and V call the ONE chain the module holds, which XLA inlines
+    — written out per call it was a third of a decode program's text and
+    doubled the time to lower it."""
+    cols = cols[None].astype(cache.dtype)               # [1, S, Hkv, 1, D]
+    zero = jnp.int32(0)
+    for s in range(cols.shape[1]):
         cache = lax.dynamic_update_slice(
-            cache, cols[s][None, None].astype(cache.dtype),
-            _at(index, s, 0, pos[s], 0))
+            cache, lax.slice_in_dim(cols, s, s + 1, axis=1),
+            (index, jnp.int32(s), zero, pos[s], zero))
     return cache
 
 
@@ -250,40 +261,55 @@ def cached_decode_attention(ins, attrs, ctx):
     without a sequence the engine passes 0, which the slot's next prompt
     overwrites).  attr ``window``: 0, the group keeps
     every column and the new one goes to column ``length``; W = L, the
-    group is a ring and it goes to ``length mod W``.  The row then reads
-    its first ``min(length + 1, L)`` columns, block by block up to the
-    longest row's, so a step reads what its rows hold and not the array.
-    attr ``scale``.  NewKCache, NewVCache: the whole arrays, updated where
-    the caller donated them.  Forward only."""
+    group is a ring and it goes to ``length mod W``.  attr ``scale``.
+
+    HOW MUCH OF THE ARRAY A STEP READS.  attr ``columns`` 0 (a ring's
+    description, ONE program whatever its rows hold): the row reads its
+    first ``min(length + 1, L)`` columns, block by block up to the longest
+    row's — a trip count read from the data.  ``columns`` = n > 0 (no ring;
+    the engine's power-of-two bucket over the longest live row, so a
+    program a bucket): every row's EARLIER tokens lie in the first n
+    columns, which are read in ``n / block`` turns fixed at trace time (one
+    up to 512 columns; never under a lane tile of 128, masked), and the new
+    token is attended from K, V as given, not read back.  NewKCache,
+    NewVCache: the whole arrays, updated where the caller donated them.
+    Forward only."""
     q, k, v, kc, vc = lax.optimization_barrier(
         (ins["Q"], ins["K"], ins["V"], ins["KCache"], ins["VCache"]))
     s, hq, _, d = q.shape
     hkv, columns = kc.shape[2], kc.shape[3]
     index = int(attrs["slab_index"])
     window = int(attrs.get("window", 0) or 0)
+    bound = int(attrs.get("columns", 0) or 0)
     scale = float(attrs.get("scale", d ** -0.5))
-    if window and window != columns:
+    if window and (window != columns or bound):
         raise ValueError(
             f"cached_decode_attention: a ring of {columns} columns for a "
-            f"window of {window}")
+            f"window of {window}, read to a bound of {bound}")
+    if bound > columns:
+        raise ValueError(f"cached_decode_attention: {bound} columns of an "
+                         f"array that holds {columns}")
     lengths = ins["CacheLengths"].astype(jnp.int32)
     active = ins["Active"].astype(jnp.int32)
     pos = lengths % columns if window else jnp.minimum(lengths, columns - 1)
-    kc = _write_columns(kc, k, index, pos)
-    vc = _write_columns(vc, v, index, pos)
-    valid = jnp.where(active > 0, jnp.minimum(lengths + 1, columns), 0)
-    block = _fit_block(columns, _DECODE_BLOCK) or columns
+    entry = jnp.int32(index)
+    kc = _write_columns(kc, k, entry, pos)
+    vc = _write_columns(vc, v, entry, pos)
+    # the columns of the array a row sees: with a bound its earlier tokens
+    # (the new one is folded in from K, V below), else the new one too
+    read = min(columns, max(bound, _DECODE_LANES)) if bound else columns
+    valid = jnp.where(active > 0,
+                      jnp.minimum(lengths + (0 if bound else 1), read), 0)
+    block = _fit_block(read, _DECODE_BLOCK) or read
     qg = q.reshape(s, hkv, hq // hkv, d)
     col = jnp.arange(block, dtype=jnp.int32)
 
-    def body(j, carry):
+    def fold(carry, kb, vb, seen):
+        """Online softmax over one more block of keys `kb`, values `vb`
+        [S, Hkv, C, D], `seen` [S, C]."""
         m, l, acc = carry
-        at, size = _at(index, 0, 0, j * block, 0), (1, s, hkv, block, d)
-        kb = lax.dynamic_slice(kc, at, size)[0].astype(q.dtype)
-        vb = lax.dynamic_slice(vc, at, size)[0].astype(q.dtype)
         sc = jnp.einsum("shgd,shcd->shgc", qg, kb,
                         preferred_element_type=_F32) * scale
-        seen = (j * block + col)[None, :] < valid[:, None]      # [S, C]
         sc = jnp.where(seen[:, None, None, :], sc, -jnp.inf)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
         safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -295,12 +321,25 @@ def cached_decode_attention(ins, attrs, ctx):
             preferred_element_type=_F32)
         return m_new, l, acc
 
+    def body(j, carry):
+        at, size = _at(index, 0, 0, j * block, 0), (1, s, hkv, block, d)
+        kb = lax.dynamic_slice(kc, at, size)[0].astype(q.dtype)
+        vb = lax.dynamic_slice(vc, at, size)[0].astype(q.dtype)
+        return fold(carry, kb, vb,
+                    (j * block + col)[None, :] < valid[:, None])
+
     shape = (s, hkv, hq // hkv)
-    blocks = (jnp.max(valid) + block - 1) // block
-    _, l, acc = lax.fori_loop(
-        0, blocks, body, (jnp.full(shape + (1,), -jnp.inf, _F32),
-                          jnp.zeros(shape + (1,), _F32),
-                          jnp.zeros(shape + (d,), _F32)))
+    carry = (jnp.full(shape + (1,), -jnp.inf, _F32),
+             jnp.zeros(shape + (1,), _F32), jnp.zeros(shape + (d,), _F32))
+    if bound:
+        for j in range(read // block):
+            carry = body(j, carry)
+        carry = fold(carry, k.astype(q.dtype), v.astype(q.dtype),
+                     active[:, None] > 0)
+    else:
+        carry = lax.fori_loop(0, (jnp.max(valid) + block - 1) // block,
+                              body, carry)
+    _, l, acc = carry
     out = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype).reshape(s, hq, 1, d)
     out, kc, vc = lax.optimization_barrier((out, kc, vc))
     return {"Out": out, "NewKCache": kc, "NewVCache": vc}

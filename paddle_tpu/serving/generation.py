@@ -63,14 +63,17 @@ description (``kv_pool.cache_spec_of``).  WHICH ROUTE A MODEL LANDS ON: a
 model that states the step contract — ``prefill_step`` and ``decode_step``
 (step_program.py) — runs through ``StepPrograms``, the compiled route;
 every other (``GPTModel``) through the eager forwards above.  The compiled
-route needs the paged pool: what a sequence holds on the device — its
-recurrent state (a ``state`` group), the step's dense KV view, or, for a
-description whose ``kv`` groups state their ``retain``, the KV itself, a
-window's ring among it — lives in the pool's ``StateSlots``, reserved with
-the pages at admission and released with them.  Prefill and decode are ONE
-compiled program per (phase, bucket), prompts carry their lengths so that
-bucket padding never enters a recurrence, and an idle decode row's state
-comes back unchanged.  On that route the loop keeps ONE decode step in
+route needs the paged pool and keeps ONE cache, on the device: what a
+sequence holds — the KV of its attention layers (every ``kv`` group of the
+description states its ``retain``, a window's ring among them) and its
+recurrent state (a ``state`` group) — lives in the pool's ``StateSlots``,
+reserved with the pages at admission and released with them; the pages
+only account, and no KV byte crosses the host link.  Prefill and decode are
+ONE compiled program per (phase, bucket), prompts carry their lengths so
+that bucket padding never enters a recurrence, an idle decode row's state
+comes back unchanged, and the decode program writes each row's new column
+where the arrays lie (what a decode bucket is, with and without a ring:
+``_step_compiled``).  On that route the loop keeps ONE decode step in
 flight: a step is read and booked after the next compiled program has been
 dispatched behind it, from the ids the step left on the device
 (``_step_compiled``).  ``prefix_cache=`` and ``speculative=`` refuse such
@@ -94,7 +97,7 @@ from ..profiler import RecordEvent
 from .batcher import (BatcherStoppedError, DeadlineExceededError,
                       QueueFullError, _jittered)
 from .kv_pool import (PagedKVPool, PageTable, cache_spec_of, kv_geometry,
-                      retained_kv_groups, state_groups)
+                      retained_kv_groups)
 
 __all__ = ["ContinuousBatchingEngine", "GenerationRequest"]
 
@@ -147,8 +150,6 @@ class _Launch(NamedTuple):
     lengths: np.ndarray     # the cache lengths it went in with, [S]
     logits: Optional[Tensor]    # where a row of it samples, else None
     next_ids: Tensor
-    k_new: Optional[Tensor]     # None: the step wrote its columns into
-    v_new: Optional[Tensor]     # the device-only KV arrays itself
 
 
 class ContinuousBatchingEngine:
@@ -284,9 +285,8 @@ class ContinuousBatchingEngine:
         self._step_counts = None
         if self._compiled:
             state = self._pool.state
-            if state is None or state.groups != state_groups(spec) \
-                    or bool(state.device_kv) != bool(self._kv_groups) \
-                    or not (state.dense or state.device_kv):
+            if state is None or not state.device_kv \
+                    or not state.built_for(spec):
                 raise ValueError(
                     "kv_pool holds no state slots for this model's cache "
                     "description — build it with PagedKVPool.from_plan("
@@ -935,18 +935,19 @@ class ContinuousBatchingEngine:
             metrics.gauge("gen.active_slots",
                           sum(s is not None for s in self._slots))
 
-    # -- the compiled route (models with recurrent state) -------------------
+    # -- the compiled route (models that state the step contract) -----------
     def _prefill_compiled(self, req: GenerationRequest, table: PageTable,
                           span: RecordEvent):
-        """`_prefill` for a model with recurrent state: ONE compiled
-        program per prompt bucket.  The prompt is padded to its bucket
+        """`_prefill` on the compiled route: ONE compiled program per
+        prompt bucket.  The prompt is padded to its bucket
         and its length goes in with it, so the pads never enter the
         recurrence; out come the one logits row sampling needs and its
         argmax (a greedy request fetches the 4 bytes of the id and leaves
-        the logits on the device), the attention layers' KV (to the
-        pool's pages, copy-on-write sharing as ever) and the sequence's
-        state after its last prompt token, which is written into its
-        state slot on the device.  A decode step in flight is read here,
+        the logits on the device), the attention layers' KV by cache
+        group and the sequence's state after its last prompt token, all
+        of which go from the result into the sequence's slot on the
+        device (the table books the prompt's pages; no byte of them
+        crosses the host link).  A decode step in flight is read here,
         between this program's dispatch and the fetch of its id: the
         prompt runs behind that step while the host books it (`_retire`);
         after a prefill nothing is in flight."""
@@ -999,30 +1000,20 @@ class ContinuousBatchingEngine:
                 self._finish(slot)
             return
         pool_state = self._pool.state
-        if self._pool.device_only:
-            # the prompt's KV stays where the program left it: a window
-            # group's ring and the other groups' columns go from the
-            # result into the slot's rows of the device arrays below; the
-            # table books the pages, no byte crosses the host link
-            with RecordEvent("engine/kv_install", bytes=0):
-                self._pool.account_prompt(table, p)
-            new = {n: t._value for n, t in zip(pool_state.names, made)}
-            # the prompt's writes at a multiple of a window's columns
-            wraps = sum((p - 1) // a["window"]
-                        for a in pool_state.device_kv[::2] if a["window"])
-            metrics.count("kv.ring_wraps", wraps)
-        else:
-            k, v, *state = made
-            with RecordEvent("engine/kv_install") as install:
-                k_h, v_h = self._download(install, k, v)
-                self._pool.open_sequence(
-                    req.prompt, k_h[:, 0, :, :p].astype(np.float32),
-                    v_h[:, 0, :, :p].astype(np.float32), table=table)
-            new = {n: t._value for n, t in zip(pool_state.names, state)}
-            new.update(k_dense=k._value, v_dense=v._value)  # the prompt's
+        # the prompt's KV stays where the program left it: a window
+        # group's ring and the other groups' columns go from the result
+        # into the slot's rows of the device arrays below; the table books
+        # the pages
+        with RecordEvent("engine/kv_install", bytes=0):
+            self._pool.account_prompt(table, p)
+        # the prompt's writes at a multiple of a window's columns
+        wraps = sum((p - 1) // a["window"]
+                    for a in pool_state.device_kv[::2] if a["window"])
+        metrics.count("kv.ring_wraps", wraps)
         with RecordEvent("engine/state_install", slot=slot_id,
                          bytes=pool_state.slot_bytes):
-            pool_state.install(slot_id, **new)
+            pool_state.install(slot_id, **{
+                n: t._value for n, t in zip(pool_state.names, made)})
         slot = _Slot(req, None, list(req.prompt), nxt, table=table)
         with self._mu:
             # the engine's row IS the state slot: the decode step runs
@@ -1032,18 +1023,21 @@ class ContinuousBatchingEngine:
                           sum(s is not None for s in self._slots))
 
     def _step_compiled(self, span: RecordEvent):
-        """`_step` for a model with recurrent state: ONE compiled program
-        per KV-length bucket over all `max_slots` rows.  In go the rows'
-        pending tokens, their cache lengths, which rows are active, the
-        dense KV (the pool's view of the live sequences, kept on the
-        device: nothing is gathered from the pages or uploaded) and the
-        state arrays as they sit on the device; out come a logits
-        row a slot and its argmax, the new KV column a slot — appended to
-        the view and, for the record, to the pages — and the updated state
-        arrays (an idle row's state comes back as it went in).  The step
-        is GIVEN the state arrays: they are donated through the compiled
-        program and dead when it returns, so `rebind` follows the call at
-        once (a step that raises: `_fail_all` -> `StateSlots.recover`).
+        """`_step` on the compiled route: ONE compiled program per
+        KV-length bucket over all `max_slots` rows.  In go the rows'
+        pending tokens, their cache lengths, which rows are active, and
+        the KV and state arrays as they sit on the device (nothing is
+        gathered or uploaded); out come a logits row a slot and its
+        argmax, and the same arrays, each row's new KV column written at
+        its own position and its state updated (an idle row's state comes
+        back as it went in).  The step is GIVEN the arrays: they are
+        donated through the compiled program and dead when it returns, so
+        `rebind` follows the call at once (a step that raises: `_fail_all`
+        -> `StateSlots.recover`).  `lpad`, the bucket: with a ring in the
+        description the arrays' length — ONE decode program that reads,
+        block by block, what its rows hold; without, the power of two over
+        the longest live row, the static bound the program reads the
+        arrays to (`StepPrograms.decode(columns=)`).
 
         ONE LAUNCH IS KEPT IN FLIGHT: a step is dispatched and left
         unread (`_in_flight`), and read (`_retire`) right after the next
@@ -1079,8 +1073,8 @@ class ContinuousBatchingEngine:
             return
         S = self.max_slots
         state = self._pool.state
-        with RecordEvent("engine/build"):   # nothing to gather: the dense
-            lengths = np.zeros(S, np.int32)     # KV view is on the device
+        with RecordEvent("engine/build"):   # nothing to gather: the KV is
+            lengths = np.zeros(S, np.int32)     # on the device
             alive = np.zeros(S, np.int32)
             for i, s in active:
                 # `table.length` lags by the append not yet made
@@ -1094,25 +1088,23 @@ class ContinuousBatchingEngine:
                     ids[i] = s.next_id
                 feeds.insert(0, ids)
         device_kv = state.device_kv
-        if device_kv:
-            # ONE decode program: it is given the arrays whole and reads,
-            # block by block, what its rows hold (`lpad`: their columns)
-            lpad = max(a["shape"][1] for a in device_kv)
-            live = lengths[alive > 0]
-            window = min((a["window"] for a in device_kv if a["window"]),
-                         default=0)
-            past = int((live >= window).sum()) if window else 0
+        live = lengths[alive > 0]
+        window = min((a["window"] for a in device_kv if a["window"]),
+                     default=0)
+        if window:
+            columns, lpad = None, max(a["shape"][1] for a in device_kv)
+            past = int((live >= window).sum())
             wraps = sum(int(((live > 0) & (live % a["window"] == 0)).sum())
                         for a in device_kv[::2] if a["window"])
             metrics.count("kv.ring_wraps", wraps)
             metrics.gauge("kv.rows_past_window", past)
-            span.set(ring_rows=past, kv_columns=int(sum(
-                a["layers"] * np.minimum(live + 1, a["shape"][1]).sum()
-                for a in device_kv[::2])))
+            span.set(ring_rows=past)
         else:
-            lpad = _next_pow2(int(lengths.max()), self._kv_floor)
+            columns = lpad = _next_pow2(int(lengths.max()), self._kv_floor)
         span.set(active=len(active), lpad=lpad, context=int(lengths.sum()),
-                 ahead=int(ahead is not None))
+                 ahead=int(ahead is not None), kv_columns=int(sum(
+                     a["layers"] * np.minimum(live + 1, a["shape"][1]).sum()
+                     for a in device_kv[::2])))
         with self._mu:
             self._kv_buckets.add(("decode", lpad))
             metrics.gauge("gen.kv_buckets", len(self._kv_buckets))
@@ -1120,23 +1112,14 @@ class ContinuousBatchingEngine:
         if ahead is not None:
             uploaded.insert(0, ahead.next_ids)
         with RecordEvent("engine/forward", bucket=lpad, rows=len(active)):
-            if device_kv:       # the KV arrays ARE state: donated, rebound
-                k_new = v_new = None
-                logits, next_ids, *new_state = self._steps.decode(
-                    *uploaded, *state.arrays.values())
-            else:
-                logits, next_ids, k_new, v_new, *new_state = \
-                    self._steps.decode(
-                        *uploaded,
-                        *[Tensor(a) for a in state.kv_view(lpad)],
-                        *state.arrays.values())
+            # the KV and state arrays: donated, rebound
+            logits, next_ids, *new_state = self._steps.decode(
+                *uploaded, *state.arrays.values(), columns=columns)
             state.rebind(**{n: t._value
                             for n, t in zip(state.names, new_state)})
-            if not device_kv:
-                state.append_kv(k_new._value, v_new._value, lengths)
         samples = any(s.req.strategy == "sampling" for _, s in active)
         launch = _Launch(active, lengths, logits if samples else None,
-                         next_ids, k_new, v_new)
+                         next_ids)
         self._in_flight = None if samples else launch
         metrics.count("gen.steps")
         metrics.count("gen.steps_ahead", int(ahead is not None))
@@ -1149,13 +1132,14 @@ class ContinuousBatchingEngine:
     def _retire(self, launch: _Launch, step_span: RecordEvent = None):
         """Read a decode step's results and book them: the ids (the
         logits too, whole, where a row of it samples: a download costs the
-        link a round trip, hardly its bytes), the two KV columns to the
-        pages, the tokens to their sequences; finished rows resolve their
-        futures and free their slots.  A row whose slot has meanwhile
+        link a round trip, hardly its bytes), a column to each row's page
+        table (the step wrote it on the device), the tokens to their
+        sequences; finished rows resolve their futures and free their
+        slots.  A row whose slot has meanwhile
         finished, was cancelled or belongs to another request is skipped:
-        the step computed it past its sequence's end, its column goes to
-        no page, its id to no sequence, and its state and dense-view
-        column lie in a slot the next prefill overwrites.  A launch with
+        the step computed it past its sequence's end, its column is booked
+        to no table, its id goes to no sequence, and its state and column
+        lie in a slot the next prefill overwrites.  A launch with
         no row left is dropped unread.  What the step counted becomes
         fields of the first `engine/step` span to open after the step's
         own — `step_span` where that is the one it is read under, else the
@@ -1166,17 +1150,10 @@ class ContinuousBatchingEngine:
         if not pairs:
             return
         S = self.max_slots
-        on_device = launch.k_new is None    # the step wrote its own columns
         with RecordEvent("engine/fetch") as fetch:
-            picked, *got = self._download(
+            picked, *step_logits = self._download(
                 fetch, launch.next_ids,
-                *([] if on_device else [launch.k_new, launch.v_new]),
                 *([launch.logits] if launch.logits is not None else []))
-            if not on_device:
-                k_col, v_col, *got = got
-                k_col = k_col[:, :, :, 0].astype(np.float32)  # [L,S,H,Dh]
-                v_col = v_col[:, :, :, 0].astype(np.float32)
-            step_logits = got
         if self._steps.counters:
             self._step_counts = self._count_step(picked[S:])
             if step_span is not None:
@@ -1187,15 +1164,9 @@ class ContinuousBatchingEngine:
         metrics.count("gen.sampled_on_device", greedy)
         metrics.count("gen.logits_rows_fetched", S if step_logits else 0)
         retired = []
-        with RecordEvent("engine/kv_append") as append:
+        with RecordEvent("engine/kv_append", bytes=0):
             for i, s in pairs:
-                if on_device:
-                    self._pool.account_column(s.table)
-                else:
-                    self._pool.append_column(s.table, k_col[:, i],
-                                             v_col[:, i])
-            append.set(bytes=0 if on_device
-                       else len(pairs) * 2 * k_col[:, 0].nbytes)
+                self._pool.account_column(s.table)
         with RecordEvent("engine/sample"):
             for i, s in pairs:
                 s.tokens.append(s.next_id)

@@ -72,20 +72,18 @@ holds whatever its length — a recurrent layer's state).  A model with a
 ``reserve`` hands out a state slot with the page reservation (on
 ``table.state_slot``) and ``close_sequence`` gives both back, so every
 retire / cancel / failure path that returns pages returns the state.
-The recurrent state never crosses the host link.  Where the model's
-``kv`` group states a ``dense_dtype``, the same slots also hold the decode
-step's dense KV view of the live sequences, so that a step uploads no KV;
-the pages stay the record.
+The recurrent state never crosses the host link.
 
-SEVERAL ``kv`` GROUPS, EACH WITH ITS OWN RETENTION.  A description may
-state more than one ``kv`` group where every one says what it retains:
-``"retain": "all"`` (every column, up to the context served) or a window
-in columns (the last W tokens, a RING written at ``position mod W``).
-Such a description keeps its KV ON THE DEVICE ONLY: per group two arrays
-``[layers, slots, kv heads, columns, head dim]`` (``device_kv_arrays``)
-ride the ``StateSlots`` beside the recurrent state, the compiled steps
-are given them whole and write the new column in place, and no KV byte
-crosses the host link.  The pool then allocates NO host slabs
+KV ON THE DEVICE ONLY, A GROUP PER KIND OF RETENTION.  A description whose
+``kv`` groups (one or several) each say what they retain — ``"retain":
+"all"`` (every column, up to the context served) or a window in columns
+(the last W tokens, a RING written at ``position mod W``) — keeps its KV
+in the slots too: per group two arrays ``[layers, slots, kv heads,
+columns, head dim]`` (``device_kv_arrays``) ride the ``StateSlots``
+before the recurrent state, the compiled steps are given them whole and
+write the new column in place, and no KV byte crosses the host link.
+Every model on the engine's compiled route is described so
+(``serving/step_program.py``).  The pool then allocates NO host slabs
 (``device_only``): its page tables do admission and accounting — a
 sequence's pages count the tokens its ``"all"`` group holds
 (``account_prompt`` / ``account_column``), a window's ring is a constant
@@ -130,7 +128,8 @@ def cache_spec_of(config) -> List[Dict]:
 def retained_kv_groups(spec) -> List[Dict]:
     """The ``kv`` groups that state what they retain (``"all"`` or a
     window in columns): all of the description's ``kv`` groups, or none —
-    a description with them keeps its KV on the device only."""
+    a description with them keeps its KV on the device only, one without
+    in the host pages (the eager route)."""
     kv = [g for g in spec if g["kind"] == "kv"]
     said = [g for g in kv if g.get("retain") is not None]
     if said and len(said) != len(kv):
@@ -176,7 +175,7 @@ def device_kv_arrays(spec, max_context: int) -> List[Dict]:
         columns = window or _next_pow2(int(max_context))
         for name in ("k", "v"):
             out.append({"name": f"{name}{g}", "layers": int(group["layers"]),
-                        "dtype": group.get("dense_dtype", "float32"),
+                        "dtype": group.get("dtype", "float32"),
                         "shape": [int(group["kv_heads"]), columns,
                                   int(group["head_dim"])],
                         "window": window})
@@ -251,26 +250,18 @@ class StateSlots:
     shows its last owner's state (``serving.gen.state_resets`` counts
     those overwrites).
 
-    ``dense_kv = (layers, kv heads, context, head dim, dtype)`` adds the
-    decode step's DENSE KV VIEW of the live sequences, ``k_dense`` /
-    ``v_dense`` ``[layers, slots, kv heads, context, head dim]``: what
-    ``PagedKVPool.gather`` would build from each sequence's pages, kept
-    where the step reads it instead of being gathered in numpy and
-    uploaded every step (67-268 MB a step for 16 rows; docs/serving.md).
-    The pool's pages stay the record — admission, sharing, accounting —
-    and receive every column too; the view is the step's workspace, which
-    ``page_budget`` prices per slot.
-
     ``device_kv`` (``device_kv_arrays``) puts a description's device-only
     KV arrays FIRST among ``arrays``, before the state's, in the order the
-    step contract takes them: they are the cache itself, not a view —
-    donated through the decode step, which writes each row's new column
-    where the array lies, and written by ``install`` from a prefill's
-    result (a window group's ring whole; a group that keeps every column
-    up to the prompt's bucket).  Mutated on the engine's decode thread
+    step contract takes them: they are the cache itself — donated through
+    the decode step, which writes each row's new column where the array
+    lies, and written by ``install`` from a prefill's result (a window
+    group's ring whole; a group that keeps every column up to the prompt's
+    bucket: columns past it keep what the slot's last owner left, which no
+    row reads — a row sees its own ``length`` columns, each written by its
+    own prompt or step first).  Mutated on the engine's decode thread
     only."""
 
-    def __init__(self, groups: Sequence[Dict], slots: int, dense_kv=None,
+    def __init__(self, groups: Sequence[Dict], slots: int,
                  device_kv: Sequence[Dict] = ()):
         import jax
         import jax.numpy as jnp
@@ -297,13 +288,6 @@ class StateSlots:
                     np_dtype(a["dtype"]))
         self.slot_bytes = sum(v.nbytes for v in self.arrays.values()) \
             // self.slots
-        self.dense: Dict[str, "jax.Array"] = {}
-        if dense_kv is not None:
-            layers, heads, context, head_dim, dtype = dense_kv
-            for name in ("k_dense", "v_dense"):
-                self.dense[name] = jnp.zeros(
-                    (int(layers), self.slots, int(heads), int(context),
-                     int(head_dim)), np_dtype(dtype))
         self._free: List[int] = list(range(self.slots - 1, -1, -1))
         self._written = set()       # slots that have held a sequence
         # one slot's entry (a prefill's result, shorter along the context
@@ -313,18 +297,22 @@ class StateSlots:
                 slab, new.astype(slab.dtype),
                 (slot * 0, slot) + (slot * 0,) * (slab.ndim - 2)),
             donate_argnums=0)
-        # one new column a row at each row's own position, in place
-        self._append = jax.jit(
-            lambda slab, cols, pos: jax.vmap(
-                lambda row, col, p: jax.lax.dynamic_update_slice(
-                    row, col.astype(row.dtype), (p * 0, p * 0, p, p * 0)),
-                in_axes=(1, 1, 0), out_axes=1)(slab, cols, pos),
-            donate_argnums=0)
         self._publish()
 
     @property
     def names(self) -> List[str]:
         return list(self.arrays)
+
+    def built_for(self, spec) -> bool:
+        """Whether these slots hold what the cache description `spec`
+        states: its state groups, and a K, V pair for each of its kv
+        groups' layers and retention (their columns follow the context
+        served)."""
+        want = [(int(g["layers"]),
+                 0 if g["retain"] == "all" else int(g["retain"]))
+                for g in retained_kv_groups(spec)]
+        return self.groups == state_groups(spec) and want == [
+            (a["layers"], a["window"]) for a in self.device_kv[::2]]
 
     @property
     def used(self) -> int:
@@ -352,32 +340,19 @@ class StateSlots:
         self._publish()
 
     def install(self, slot: int, **new):
-        """Write one sequence's state — arrays ``[layers, 1, *shape]`` by
-        name, as a prefill returns them (``k_dense`` / ``v_dense``: its
-        prompt's KV columns) — into `slot`, on the device."""
-        if sorted(new) != sorted([*self.arrays, *self.dense]):
+        """Write one sequence's cache — arrays ``[layers, 1, *shape]`` by
+        name, as a prefill returns them (a KV group that keeps every
+        column: the prompt's bucket of them) — into `slot`, on the
+        device."""
+        if sorted(new) != sorted(self.arrays):
             raise ValueError(
-                f"install needs {sorted([*self.arrays, *self.dense])}, "
-                f"got {sorted(new)}")
+                f"install needs {sorted(self.arrays)}, got {sorted(new)}")
         for name, value in new.items():
-            where = self.dense if name in self.dense else self.arrays
-            where[name] = self._write(where[name], value, np.int32(slot))
+            self.arrays[name] = self._write(self.arrays[name], value,
+                                            np.int32(slot))
         if slot in self._written:
             metrics.count("gen.state_resets")
         self._written.add(slot)
-
-    def kv_view(self, columns: int):
-        """The dense KV view's first `columns` columns: (k, v), each
-        ``[layers, slots, kv heads, columns, head dim]``, on the device."""
-        return tuple(a[:, :, :, :columns] for a in self.dense.values())
-
-    def append_kv(self, k_cols, v_cols, positions):
-        """A decode step's new columns ``[layers, slots, kv heads, 1, head
-        dim]`` into the dense view, row ``i`` at ``positions[i]`` (an idle
-        row's lands in a column its next prefill overwrites)."""
-        pos = np.asarray(positions, np.int32)
-        for name, cols in (("k_dense", k_cols), ("v_dense", v_cols)):
-            self.dense[name] = self._append(self.dense[name], cols, pos)
 
     def rebind(self, **new):
         """Take a decode step's updated arrays in place of the old, which
@@ -401,10 +376,9 @@ class StateSlots:
         """After a failed step or install: zeroed arrays in place of the
         ones a donation left dead."""
         import jax.numpy as jnp
-        for where in (self.arrays, self.dense):
-            for name, a in where.items():
-                if a.is_deleted():
-                    where[name] = jnp.zeros(a.shape, a.dtype)
+        for name, a in self.arrays.items():
+            if a.is_deleted():
+                self.arrays[name] = jnp.zeros(a.shape, a.dtype)
 
     def row(self, slot: int) -> Dict[str, np.ndarray]:
         """Host copies of one slot's state (tests and debugging: this is
@@ -496,21 +470,16 @@ class PagedKVPool:
         ``StateSlots`` — ``max_slots`` of them — allocated here."""
         spec = plan.get("cache") or []
         groups = state_groups(spec)
-        dense, device_kv = None, []
-        if retained_kv_groups(spec):    # the KV itself, on the device only
-            device_kv = device_kv_arrays(spec, int(plan["max_context"]))
-        elif groups:    # the step's dense KV view rides the state slots
-            kv = [g for g in spec if g["kind"] == "kv"][0]
-            dense = (kv["layers"], kv["kv_heads"],
-                     _next_pow2(int(plan["max_context"])),
-                     kv["head_dim"], kv.get("dense_dtype", "float32"))
+        # the KV itself, on the device only, where the groups say `retain`
+        device_kv = device_kv_arrays(spec, int(plan["max_context"])) \
+            if retained_kv_groups(spec) else []
         return cls(num_layers=int(plan["num_layers"]),
                    num_heads=int(plan["num_heads"]),
                    head_dim=int(plan["head_dim"]),
                    page_tokens=int(plan["page_tokens"]),
                    num_pages=int(plan["pages"]),
                    dtype=plan.get("kv_dtype", dtype), plan=plan,
-                   state=StateSlots(groups, int(plan["max_slots"]), dense,
+                   state=StateSlots(groups, int(plan["max_slots"]),
                                     device_kv)
                    if groups or device_kv else None)
 
@@ -1081,10 +1050,12 @@ def budget_drift(pool: PagedKVPool, model=None) -> List[str]:
             f"{want_dtype.name} — the carve assumed "
             f"{want_dtype.itemsize}-byte pages")
     have = pool.state.slot_bytes if pool.state is not None else 0
-    if int(fresh.get("state_slot_bytes", 0)) != have:
+    want = int(fresh.get("state_slot_bytes", 0)) \
+        + int(fresh.get("kv_slot_bytes", 0))
+    if want != have:
         drift.append(
-            f"state_slot_bytes: pool holds {have} B a slot, page_budget "
-            f"derives {fresh.get('state_slot_bytes', 0)}")
+            f"state_slot_bytes: pool holds {have} B a slot (device KV and "
+            f"state), page_budget derives {want}")
     if pool.state is not None and pool.state.slots != int(
             fresh["max_slots"]):
         drift.append(

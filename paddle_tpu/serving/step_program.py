@@ -1,21 +1,40 @@
 """The engine's step contract, compiled: one program per (phase, bucket).
 
-A model that serves through this route has two cache-aware methods of
-flat tensor arguments and a tuple result:
+A model that serves through this route keeps what a sequence holds between
+steps ON THE DEVICE, a row a slot — a K and a V array per `kv` group of its
+cache description (`kv_pool.device_kv_arrays`: every group states what it
+retains, a window's ring or `"all"`), then its recurrent state's arrays —
+and has two cache-aware methods of flat tensor arguments and a tuple
+result:
 
     prefill_step(ids [1, T], lengths [1], last [1])
-        -> (logits [1, V], K, V [La, 1, Hkv, T, D], *state [Ls, 1, ...])
+        -> (logits [1, V], *kv [Lg, 1, Hkv, T | window, D], *state)
     decode_step(ids [S, 1], cache_lengths [S], active [S],
-                k_cache, v_cache [La, S, Hkv, L, D], *state [Ls, S, ...])
-        -> (logits [S, V], K, V columns [La, S, Hkv, 1, D], *state)
+                *kv [Lg, S, Hkv, columns, D], *state [Ls, S, ...],
+                columns=None)
+        -> (logits [S, V], *kv, *state)
 
-ids, per-row lengths and the caches go in; the ONE logits row a sequence's
-sampling needs, the new KV columns and the updated state come out.  Each
-is wrapped in `paddle_tpu.jit.to_static`'s `StaticFunction`: traced once
-per argument signature — the engine's power-of-two buckets — into a
+ids and per-row lengths go in; the ONE logits row a sequence's sampling
+needs and the caches come out.  A prefill returns each group's K, V of the
+prompt (a window group's RING as it stands after the prompt's last valid
+token) and the state after it; the decode step takes the arrays WHOLE,
+writes each row's new column where the array lies (a ring at ``length mod
+window``) and each layer's new state over the old, and returns them.  Each
+method is wrapped in `paddle_tpu.jit.to_static`'s `StaticFunction`: traced
+once per argument signature — the engine's power-of-two buckets — into a
 Program (from shapes alone, `abstract_trace`: nothing runs eagerly) and
 run as one jitted XLA computation, under `eval()` and `no_grad`, so no
 tape is kept.
+
+`columns`, THE BOUND A DECODE PROGRAM IS TRACED FOR, is static (a Python
+int, not a tensor): a program a value.  None: a row reads what it holds,
+block by block to a trip count read from the data — ONE decode program
+whatever the contexts, what the engine asks of a description with a ring.
+n: every row's earlier tokens lie in the first n columns of the arrays
+and the program reads those, in turns fixed at trace time — what the
+engine asks of a description without a ring, n its power-of-two bucket
+over the longest live row, so short contexts do not pay for the arrays'
+length.  Either way the arrays go in and come out whole.
 
 WHAT `StepPrograms` RETURNS, AND THE FORM IT TAKES THE IDS IN.  Picking a
 token is the engine's business, and the greedy pick is made here, inside
@@ -26,10 +45,10 @@ logits, and behind the ids whatever the step counted (`C` =
 `len(StepPrograms.counters)`, 0 for most models):
 
     prefill(ids [1, T], lengths [1], last [1])
-        -> (logits [1, V], next_id  [1 + C], K, V, *state)
-    decode(ids [S + C], cache_lengths [S], active [S], k_cache, v_cache,
-           *state)
-        -> (logits [S, V], next_ids [S + C], K, V columns, *state)
+        -> (logits [1, V], next_id  [1 + C], *kv, *state)
+    decode(ids [S + C], cache_lengths [S], active [S], *kv, *state,
+           columns=None)
+        -> (logits [S, V], next_ids [S + C], *kv, *state)
 
 `decode` TAKES ITS IDS IN THE FORM IT RETURNS THEM: int32 `[S + C]`, row
 `i`'s token at `i`, the tail ignored; the reshape to the model's `[S, 1]`
@@ -59,51 +78,31 @@ size) and the tuple keeps its positions.  `StepPrograms.counters` has the
 names and `model.step_metrics(counts)` says which `serving.*` counters
 and gauges the engine makes of them.
 
-THE DECODE STEP OWNS THE STATE ARRAYS while it runs: the contract's
-`*state` arguments of `decode_step` — every position from `DECODE_STATE_AT`
-on — are donated through the compiled program, whose `*state` results
-have their shapes and dtypes, so XLA writes each layer's new state where
-the old one lies and no second copy of the state exists.  After `decode`
-returns (or raises) the arrays that went in are dead: the caller takes the
-results in their place (`StateSlots.rebind`).  Nothing else is donated:
-not the ids, which the engine still has to read where they are the step
-before's result, nor the lengths or the KV view's slices.
-
-A MODEL WHOSE CACHE DESCRIPTION STATES `retain` (several `kv` groups, a
-window's ring among them; `kv_pool.retained_kv_groups`) keeps its KV on
-the device only, and its contract carries the groups' arrays in place of
-the one K, V and the columns:
-
-    prefill_step(ids [1, T], lengths [1], last [1])
-        -> (logits [1, V], *kv [Lg, 1, Hkv, T | window, D], *state)
-    decode_step(ids [S, 1], cache_lengths [S], active [S],
-                *kv [Lg, S, Hkv, columns, D], *state)
-        -> (logits [S, V], *kv, *state)
-
-`*kv`: a K and a V per group in the description's order
-(`kv_pool.device_kv_arrays`).  A prefill returns a window group's RING as
-it stands after the prompt's last valid token; the decode step takes the
-arrays whole — every position from `DECODE_CACHE_AT` on is donated, the KV
-like the state — writes each row's new column where the array lies (a ring
-at ``length mod window``) and returns them.  The greedy pick, the ids as
-picked, the counts behind the ids and the launch in flight are the same.
+THE DECODE STEP OWNS THE CACHE ARRAYS while it runs: the contract's `*kv`
+and `*state` arguments of `decode_step` — every position from
+`DECODE_CACHE_AT` on — are donated through the compiled program, whose
+results of the same shapes and dtypes XLA writes where the old arrays lie,
+so ONE copy of the KV and of the state exists.  After `decode` returns (or
+raises) the arrays that went in are dead: the caller takes the results in
+their place (`StateSlots.rebind`).  Nothing else is donated: not the ids,
+which the engine still has to read where they are the step before's
+result, nor the lengths.
 
 `GPTModel` is not on this route yet (ROADMAP S2b): its eager forward has
 no such methods.
 """
 from __future__ import annotations
 
+import functools
+
 from ..dygraph.base import no_grad
 from ..dygraph.tensor import Tensor
 from ..tensor.manipulation import concat, reshape, slice as slice_
 from ..tensor.search import argmax
 
-__all__ = ["StepPrograms", "DECODE_STATE_AT", "DECODE_CACHE_AT"]
+__all__ = ["StepPrograms", "DECODE_CACHE_AT"]
 
-# decode_step(ids, cache_lengths, active, k_cache, v_cache, *state)
-DECODE_STATE_AT = 5
-# decode_step(ids, cache_lengths, active, *kv, *state) of a description
-# with `retain`: the KV arrays are donated too
+# decode_step(ids, cache_lengths, active, *kv, *state): the arrays donated
 DECODE_CACHE_AT = 3
 
 
@@ -118,6 +117,7 @@ class StepPrograms:
                     f"{type(model).__name__} has no {name}(): the compiled "
                     "step route needs the model's cache-aware entry points")
         model.eval()
+        self._model = model
         self.counters = tuple(getattr(model, "step_counters", ()))
         # traced from shapes: the steps' Python never reads a tensor's
         # value, and an eager pass of a 3 B-parameter model a bucket would
@@ -126,36 +126,56 @@ class StepPrograms:
             _with_greedy(model.prefill_step, bool(self.counters)),
             layer=model, abstract_trace=True)
         spec = cache_spec_of(model.config)
-        n_state = sum(len(g["arrays"]) for g in state_groups(spec))
         n_kv = 2 * len(retained_kv_groups(spec))
-        first = DECODE_CACHE_AT if n_kv else DECODE_STATE_AT
-        self._decode = StaticFunction(
-            _with_greedy(model.decode_step, bool(self.counters),
-                         ids_as_picked=True),
-            layer=model, abstract_trace=True,
-            donate_args=range(first, first + n_kv + n_state))
+        if not n_kv:
+            raise TypeError(
+                f"{type(model).__name__}'s cache description states no "
+                "`retain` on its kv groups: the step contract carries the "
+                "KV as device arrays a group")
+        n_state = sum(len(g["arrays"]) for g in state_groups(spec))
+        self._donated = range(DECODE_CACHE_AT,
+                              DECODE_CACHE_AT + n_kv + n_state)
+        self._decode = {}       # `columns` -> its StaticFunction
+
+    def decode_program(self, columns=None):
+        """The decode step's `StaticFunction` at the static bound
+        `columns` (None: the one that reads what its rows hold)."""
+        if columns not in self._decode:
+            from ..jit import StaticFunction
+            step = self._model.decode_step if columns is None else \
+                functools.partial(self._model.decode_step,
+                                  columns=int(columns))
+            self._decode[columns] = StaticFunction(
+                _with_greedy(step, bool(self.counters), ids_as_picked=True),
+                layer=self._model, abstract_trace=True,
+                donate_args=self._donated)
+        return self._decode[columns]
+
+    def _decode_traces(self) -> list:
+        """Every traced decode program (`ConcreteProgram`), all bounds."""
+        return [cp for fn in self._decode.values()
+                for cp in fn._cache.values()]
 
     @property
     def programs(self) -> int:
         """Traced signatures so far: growth after warm-up is a retrace."""
-        return len(self._prefill._cache) + len(self._decode._cache)
+        return len(self._prefill._cache) + len(self._decode_traces())
 
     def prefill(self, ids, lengths, last):
-        """ids [1, T] -> (logits [1, V], next_id [1 + C], K, V, *state)."""
+        """ids [1, T] -> (logits [1, V], next_id [1 + C], *kv, *state)."""
         with no_grad():
             return self._prefill(ids, lengths, last)
 
-    def decode(self, ids, cache_lengths, active, *cache):
+    def decode(self, ids, cache_lengths, active, *cache, columns=None):
         """One decode step, ids [S + C] -> (logits [S, V], next_ids
-        [S + C], K, V columns, *state).  `cache` = k_cache, v_cache (the
-        dense view's slices, tensors) then the state's raw device arrays,
-        DONATED — dead when this returns; the `*state` results replace
-        them.  For a description with `retain`, `cache` = the device KV
-        arrays then the state's, all raw and all donated, and the result
-        is (logits, next_ids, *kv, *state)."""
+        [S + C], *kv, *state).  `cache` = the device KV arrays then the
+        state's, raw, all DONATED — dead when this returns; the results
+        replace them.  `columns`: the static bound the program reads the
+        arrays to (module docstring)."""
         with no_grad():
-            return self._decode(ids, cache_lengths, active, *[
-                c if isinstance(c, Tensor) else Tensor(c) for c in cache])
+            return self.decode_program(columns)(
+                ids, cache_lengths, active,
+                *[c if isinstance(c, Tensor) else Tensor(c) for c in cache])
 
 
 def _with_greedy(step, counted=False, ids_as_picked=False):
@@ -164,7 +184,7 @@ def _with_greedy(step, counted=False, ids_as_picked=False):
     vector taken off the end and appended to the ids.  `ids_as_picked`:
     the ids arrive as this wrapper returns them, `[S + C]`, and are given
     to `step` as `[S, 1]`.  The arguments keep their positions
-    (`DECODE_STATE_AT`, the donation)."""
+    (`DECODE_CACHE_AT`, the donation)."""
     def step_and_pick(ids, *args):
         if ids_as_picked:
             rows = args[0].shape[0]             # cache_lengths [S]

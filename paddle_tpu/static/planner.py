@@ -1406,6 +1406,14 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
     for both.  The plan records the raw fp32 parameter bytes as
     ``weight_bytes_fp32`` so ``budget_drift`` can re-derive.
 
+    A cache description whose ``kv`` groups state their ``retain`` (every
+    model on the engine's compiled route; a description with a ``state``
+    group must) keeps its KV on the device, a slot a sequence: there is
+    no host pool to carve and no gather view to rent, ``max_slots`` slots
+    of KV + state (``kv_slot_bytes`` + ``state_slot_bytes``) take at most
+    half of what the weights leave, and the pages only account
+    (``_device_kv_slot``).
+
     Returns the plan dict ``PagedKVPool.from_plan`` consumes; every
     input is recorded in it so ``serving.kv_pool.budget_drift`` can
     re-derive the numbers and flag hand-edits, V504-style.
@@ -1414,26 +1422,27 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
     from .memory_analysis import hbm_budget_bytes
     from ..core.dtype import np_dtype
     from ..serving.kv_pool import (kv_geometry, retained_kv_groups,
-                                   state_slot_bytes)
+                                   state_groups)
     cfg = _model_config(model, config)
     # pool geometry from the model's cache description: the kv group's
-    # layers x kv heads x head dim, and what a sequence's recurrent state
-    # holds whatever its length (0 without a `state` group); `on_device`:
-    # its kv groups state their `retain`, so the KV itself is per-slot
-    # device arrays (`_device_kv_slot`) and the pages only account
+    # layers x kv heads x head dim; `on_device`: its kv groups state their
+    # `retain`, so the KV itself is per-slot device arrays beside whatever
+    # recurrent state a sequence holds (`_device_kv_slot`) and the pages
+    # only account
     L, H, Dh = kv_geometry(cfg["cache"])
-    state_slot = state_slot_bytes(cfg["cache"])
     on_device = bool(retained_kv_groups(cfg["cache"]))
-    if (state_slot or on_device) and (
+    if state_groups(cfg["cache"]) and not on_device:
+        raise NotImplementedError(
+            "page_budget: a description with a `state` group serves through "
+            "the compiled steps, whose KV lives on the device beside the "
+            "state: its kv groups state their `retain`")
+    if on_device and (
             int(tp_degree or 1) > 1 or draft_layers
             or str(weight_dtype) != "float32" or str(kv_dtype) != "float32"):
         raise NotImplementedError(
             "page_budget: a description with `retain` is sized at tp 1, its "
             "own KV and weight dtypes and no draft — sharded or quantized "
-            "device KV and a ring's rollback are not built" if on_device else
-            "page_budget: a model with recurrent state is sized at tp 1, "
-            "float32 pages, its own weight dtype and no draft — sharded "
-            "state, quantized pages and state rollback are not built")
+            "device KV and state, and their rollback, are not built")
     T = int(page_tokens)
     if T < 1:
         raise ValueError(f"page_tokens must be >= 1, got {page_tokens}")
@@ -1453,7 +1462,7 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
             weight_bytes = int(sum(
                 int(np.prod(p.shape)) * np_dtype(p.dtype).itemsize
                 for p in getattr(model, "gpt", model).parameters()))
-        elif state_slot or on_device:
+        elif on_device:
             raise ValueError(
                 "page_budget: give weight_bytes (or the model) for a "
                 "config the GPT closed form does not describe")
@@ -1461,7 +1470,7 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
             weight_bytes = _decode_weight_bytes(cfg)
     weight_bytes = int(weight_bytes)
     weight_bytes_fp32 = weight_bytes
-    shardable = 0 if state_slot or on_device else \
+    shardable = 0 if on_device else \
         min(weight_bytes, _decode_shardable_bytes(cfg))
     weight_dtype = str(weight_dtype)
     if weight_dtype not in ("float32", "int8"):
@@ -1531,25 +1540,25 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
         # gigabytes); all of a slot is resident, so the pages are every
         # slot's worst case and `kv_bytes` the device arrays whole
         per = _device_kv_slot(cfg, ctx, T)
-        ws_slot, state_slot = per["ws_slot"], per["slot"]
-        if usable // 2 < state_slot + ws_slot:
+        ws_slot, slot = per["ws_slot"], per["slot"]
+        state_slot = slot - per["kv_slot"]      # the recurrent state alone
+        if usable // 2 < slot + ws_slot:
             raise ValueError(
                 f"page_budget: {budget} B HBM/chip leaves {usable} B after "
                 f"{weight_bytes} B of weights — not enough for one slot of "
-                f"{state_slot} B of device KV and state at context {ctx} "
+                f"{slot} B of device KV and state at context {ctx} "
                 "beside a prefill's workspace")
-        max_slots = int(max(1, min(
-            cap, (usable // 2) // (state_slot + ws_slot))))
+        max_slots = int(max(1, min(cap, (usable // 2) // (slot + ws_slot))))
         pages = max_slots * per["slot_pages"]
         page_bytes = page_bytes_pc = per["page_bytes"]
         kv_bytes = max_slots * per["kv_slot"]
         wm_low, wm_high = 1, 2
-        on_device_keys = {"kv_slot_bytes": per["kv_slot"],
-                          "kv_on_device": True}
+        on_device_keys = {"kv_slot_bytes": per["kv_slot"]}
         source = ("static.page_budget (device-only KV: per-slot arrays of "
                   "each kv group's retention + parameter persistable walk)")
     else:
-        if usable < page_bytes_pc + ws_col_pc * _next_pow2(ctx) + state_slot:
+        state_slot = 0      # host pages: a description without `state`
+        if usable < page_bytes_pc + ws_col_pc * _next_pow2(ctx):
             raise ValueError(
                 f"page_budget: {budget} B HBM/chip leaves {usable} B after "
                 f"{weight_bytes_pc} B of per-chip weights"
@@ -1565,18 +1574,11 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
         # and the draft model's per-slot dense KV when speculating
         ws_slot = ws_col_pc * _next_pow2(ctx) \
             + cfg["vocab_size"] * 4 + draft_kv_slot_pc
-        # what a slot holds for good comes off the budget before pages are
-        # cut: its recurrent state, resident from engine start — ONE copy:
-        # the decode step is given the state arrays to write into (they
-        # are donated through the compiled program,
-        # `serving/step_program.py`), so no second copy of a row's state
-        # exists while a step runs
-        slot_bytes = ws_slot + state_slot
-        max_slots = max(1, min(cap, int(usable * 0.35) // slot_bytes))
-        pages = (usable - max_slots * slot_bytes) // page_bytes_pc
+        max_slots = max(1, min(cap, int(usable * 0.35) // ws_slot))
+        pages = (usable - max_slots * ws_slot) // page_bytes_pc
         while pages < 1 and max_slots > 1:  # tiny budgets: trade slots back
             max_slots -= 1
-            pages = (usable - max_slots * slot_bytes) // page_bytes_pc
+            pages = (usable - max_slots * ws_slot) // page_bytes_pc
         if pages < 1:
             raise ValueError(
                 f"page_budget: workspace for one slot leaves no room for "

@@ -333,9 +333,9 @@ def test_from_published_reads_the_published_keys():
         == [(4096, 50000.0)] * 3 + [(None, None)]
     assert cfg.cache_spec() == [
         {"kind": "kv", "layers": 3, "kv_heads": 8, "head_dim": 128,
-         "dense_dtype": "bfloat16", "retain": 4096},
+         "dtype": "bfloat16", "retain": 4096},
         {"kind": "kv", "layers": 1, "kv_heads": 8, "head_dim": 128,
-         "dense_dtype": "bfloat16", "retain": "all"}]
+         "dtype": "bfloat16", "retain": "all"}]
     whole = Cohere2MoeConfig.from_published(_catalog_row())
     assert len(whole.blocks) == 32 and whole.vocab_size == 262144
     assert round(whole.param_count() / 1e9, 1) == 218.3
@@ -362,10 +362,65 @@ def test_config_refuses_layer_kinds_and_shares_it_cannot_describe():
     from paddle_tpu.models.hybrid_decoder import (AttentionSpec,
                                                   HybridDecoderConfig)
     bad = granite_hybrid_tiny().config
-    bad.attention_specs = {bad.layers_of("attention")[0]:
+    bad.attention_specs = {bad.layers_of("mamba")[0]:
                            AttentionSpec(window=8)}
-    with pytest.raises(NotImplementedError, match="kv_on_device"):
+    with pytest.raises(ValueError, match="no attention to describe"):
         HybridDecoderConfig._check(bad)
+
+
+def test_a_window_beside_a_recurrent_state_is_one_more_description():
+    """No option picks the KV's path: whatever a description's attention
+    layers state — here granite's tiny layers with a window of 8 and rotary
+    positions given to its one attention layer — its cache groups say
+    `retain`, the ring rides the step contract before the Mamba state, and
+    the engine serves it through ONE decode program (a ring: no bound on
+    the columns), token-equal to the model's own full forward on both
+    sides of the window and past two wraps."""
+    from paddle_tpu.models import GraniteHybridModel
+    from paddle_tpu.models.hybrid_decoder import AttentionSpec
+    reset_serving_stats()
+    with dg.guard():
+        paddle_tpu.seed(21)
+        cfg = granite_hybrid_tiny().config
+        assert not cfg.kv_ring
+        cfg.attention_specs = {2: AttentionSpec(window=W, rotary=10000.0)}
+        assert cfg.kv_ring and cfg.kv_groups() == [(W, [2])]
+        assert cfg.cache_spec()[0] == {
+            "kind": "kv", "layers": 1, "kv_heads": 2, "head_dim": 16,
+            "dtype": "float32", "retain": W}
+        m = GraniteHybridModel(cfg)
+        plan = static.page_budget(m, page_tokens=4, max_context=64,
+                                  hbm_bytes=16 << 20, max_slots_cap=2)
+        eng = ContinuousBatchingEngine(m, kv_pool=plan).start()
+        state = eng.kv_pool.state
+        assert state.names == ["k0", "v0", "ssm", "conv"]
+        assert state.arrays["k0"].shape == (1, 2, 2, W, 16)
+        rng = np.random.default_rng(3)
+        prompts = [rng.integers(0, 126, n) for n in (5, 19, 11)]
+        news = (22, 6, 9)
+        outs = [f.result(timeout=900) for f in
+                [eng.submit(p, max_length=n) for p, n in zip(prompts, news)]]
+        for prompt, n, out in zip(prompts, news, outs):
+            ids = list(prompt)
+            for _ in range(n):
+                buf = np.zeros((1, 64), np.int32)
+                buf[0, :len(ids)] = ids
+                with dg.no_grad():
+                    ids.append(int(m(_t(buf)).numpy()[0, len(ids) - 1]
+                                   .argmax()))
+            assert list(out) == ids
+        (dec,) = eng._steps._decode_traces()             # ONE decode program
+        ops = [op for op in dec.program.global_block().ops
+               if op.type == "cached_decode_attention"]
+        assert [(op.attrs["window"], op.attrs["columns"]) for op in ops] \
+            == [(W, 0)]
+        eng.stop()
+        eng.kv_pool.assert_drained()
+        assert budget_drift(eng.kv_pool, m) == []
+    stats = serving_stats()
+    assert stats["serving.gen.state_in_place"] == stats["serving.gen.steps"]
+    assert stats["serving.kv.ring_wraps"] >= 2 + 2
+    assert stats["serving.gen.kv_buckets"] == eng._steps.programs
 
 
 def test_built_model_has_exactly_the_shapes_the_config_states():
@@ -460,8 +515,11 @@ def test_a_description_states_retain_on_every_kv_group_or_none():
         ("k0", 3, [2, W, 16], W), ("v0", 3, [2, W, 16], W),
         ("k1", 1, [2, 64, 16], 0), ("v1", 1, [2, 64, 16], 0)]
     assert state_slot_bytes(spec, 40) == 2 * 2 * 16 * 4 * (3 * W + 64)
-    assert retained_kv_groups(granite_hybrid_tiny().config.cache_spec()) \
-        == []
+    # every description of the hybrid decoder states it; a GPT's does not
+    assert [g["retain"] for g in retained_kv_groups(
+        granite_hybrid_tiny().config.cache_spec())] == ["all"]
+    assert retained_kv_groups([{"kind": "kv", "layers": 2, "kv_heads": 2,
+                                "head_dim": 8}]) == []
     mixed = [dict(spec[0]), {k: v for k, v in spec[1].items()
                              if k != "retain"}]
     with pytest.raises(ValueError, match="every kv group or on none"):
@@ -479,8 +537,9 @@ def test_page_budget_prices_the_ring_and_the_columns_a_slot():
         plan = static.page_budget(m, page_tokens=4, max_context=40,
                                   hbm_bytes=8 << 20, max_slots_cap=4)
         slot = 2 * 2 * 16 * 4 * (3 * W + 64)
-        assert plan["kv_on_device"] and plan["max_slots"] == 4
-        assert plan["kv_slot_bytes"] == plan["state_slot_bytes"] == slot
+        assert plan["max_slots"] == 4
+        assert plan["kv_slot_bytes"] == slot      # and no recurrent state
+        assert plan["state_slot_bytes"] == plan["state_bytes"] == 0
         assert plan["kv_bytes"] == 4 * slot
         assert plan["pages"] == 4 * (64 // 4 + 1)
         assert plan["page_bytes"] == 2 * 1 * 2 * 16 * 4 * 4
@@ -542,30 +601,38 @@ def _step_programs(m):
     pre = steps._prefill.concrete_program(
         _t(np.zeros((1, 16), np.int32)), _t([9], np.int32),
         _t([8], np.int32))
-    view = [Tensor(a) for a in state.kv_view(32)] if state.dense else []
-    dec = steps._decode.concrete_program(
+    # the engine's own choice: a ring's description takes no bound
+    bound = None if m.config.kv_ring else 32
+    dec = steps.decode_program(bound).concrete_program(
         _t(np.zeros(s + c, np.int32)), _t(np.zeros(s, np.int32)),
-        _t(np.ones(s, np.int32)), *view,
+        _t(np.ones(s, np.int32)),
         *[Tensor(a) for a in state.arrays.values()])
     return pre, dec, state
 
 
 @pytest.mark.parametrize("family,want", [
-    (granite_hybrid_tiny, ((108, "b489e6600310c111"),
-                           (123, "451fffc9f4f2a96e"))),
-    (nemotron_h_tiny, ((109, "55ed3a55583ac91e"),
-                       (124, "a3c1d211bfacd7e9")))],
+    (granite_hybrid_tiny, ((108, "669d0a323a557dc9"),
+                           (118, "2d160e58eafa7c1c"))),
+    (nemotron_h_tiny, ((109, "70337780e784aaf2"),
+                       (119, "11a37cd67e3f8c30")))],
     ids=["granite", "nemotron"])
 def test_the_other_families_step_programs_do_not_change_by_one_op(
         family, want):
-    """The traced prefill and decode Programs of the descriptions that were
-    there: op types, attrs and slot arities in order, as PR 32's tree
-    records them (the digests are that tree's, taken by this function)."""
+    """The traced prefill and decode (bound 32) Programs of the other
+    descriptions: op types, attrs and slot arities in order, as PR 34's
+    tree records them (the digests are that tree's, taken by this
+    function).  Against PR 32's: a prefill's ops are the same 108 / 109
+    (the K / V stacks now come before the head); a decode step has 5 ops
+    fewer — the view's `unstack`s and `cast`s and the columns' `stack`s
+    gone, `cached_decode_attention` in `gqa_attention`'s place."""
     with dg.guard():
         paddle_tpu.seed(1)
         pre, dec, _ = _step_programs(family())
         assert (_program_digest(pre.program),
                 _program_digest(dec.program)) == want
+        ops = [op.type for op in dec.program.global_block().ops]
+        assert ops.count("cached_decode_attention") == 1
+        assert not {"gqa_attention", "unstack", "stack"} & set(ops)
 
 
 def test_decode_program_donates_every_kv_array_and_writes_in_place():

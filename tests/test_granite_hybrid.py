@@ -245,7 +245,11 @@ def test_parameter_count_at_published_widths_is_the_issues_sum():
     assert (kv["layers"], kv["kv_heads"], kv["head_dim"]) == (4, 8, 64)
     assert state["layers"] == 36
     from paddle_tpu.serving.kv_pool import state_slot_bytes
-    assert state_slot_bytes(cfg.cache_spec()) == 75_497_472 + 940_032
+    assert kv["retain"] == "all" and kv["dtype"] == "bfloat16"
+    assert state_slot_bytes([state]) == 75_497_472 + 940_032
+    # with its KV at the context the cell serves: 4 x 8 x 1,024 x 64, K + V
+    assert state_slot_bytes(cfg.cache_spec(), 1024) \
+        == 75_497_472 + 940_032 + 2 * 4 * 8 * 1024 * 64 * 2
 
 
 def test_built_model_has_exactly_the_shapes_the_config_states():
@@ -268,42 +272,68 @@ def test_full_forward_matches_the_reference():
 
 
 def test_prefill_then_decode_through_the_cache_matches_the_full_pass():
-    """Prefill of p tokens (padded to a bucket of 16) in batch row 1 of 3,
-    then n decode steps through the dense KV cache and the state arrays,
-    against the reference's full forward of p + n; row 2 is idle and holds
-    a made-up state, which every step must hand back bit for bit."""
+    """Prefill of p tokens (padded to a bucket of 16) into row 1 of 3, then
+    n decode steps over the KV and state arrays given whole, against the
+    reference's full forward of p + n: every step at the engine's bound on
+    the columns (the power of two over the row, floor 16: 13..15 columns
+    read to 16, 16 itself too, 17.. to 32, so the steps cross a bucket)
+    and, beside it, with no bound (what a row holds, block by block); row
+    2 is idle and holds a made-up state and made-up columns, which every
+    step must hand back bit for bit but for the one column it names."""
+    from paddle_tpu.core.compile_cache import next_pow2
     p, n, rows, cache_len = 13, 8, 3, 32
     with dg.guard():
         m = _model(3)
         c = m.config
+        assert not c.kv_ring and c.cache_spec()[0] == {
+            "kind": "kv", "layers": 1, "kv_heads": 2, "head_dim": 16,
+            "dtype": "float32", "retain": "all"}
         ids = np.random.default_rng(1).integers(0, 127, p + n)
         want = _reference_logits(m, ids)
         logits, k, v, ssm, conv = _prefill(m, ids[:p], 16)
         _assert_logits(logits[0], want[p - 1], want.std())
         kc = np.zeros((1, rows, 2, cache_len, 16), np.float32)
         vc = np.zeros_like(kc)
-        kc[:, 1, :, :p], vc[:, 1, :, :p] = k[:, 0, :, :p], v[:, 0, :, :p]
+        kc[:, 1, :, :16], vc[:, 1, :, :16] = k[:, 0], v[:, 0]   # pads too
+        kc[:, 2], vc[:, 2] = 7.0, -7.0
         ssm_s = np.zeros((3, rows) + ssm.shape[2:], np.float32)
         conv_s = np.zeros((3, rows) + conv.shape[2:], np.float32)
         ssm_s[:, 1], conv_s[:, 1] = ssm[:, 0], conv[:, 0]
         ssm_s[:, 2], conv_s[:, 2] = 0.5, 0.25
+        bounds = []
         for t in range(n):
             step_ids = np.zeros((rows, 1), np.int32)
             step_ids[1, 0] = ids[p + t]
-            with dg.no_grad():
-                out = m.decode_step(
-                    _t(step_ids), _t([0, p + t, 0], np.int32),
+            args = [_t(step_ids), _t([0, p + t, 5], np.int32),
                     _t([0, 1, 0], np.int32), _t(kc), _t(vc), _t(ssm_s),
-                    _t(conv_s))
+                    _t(conv_s)]
+            bounds.append(next_pow2(p + t, 16))
+            with dg.no_grad():
+                out = m.decode_step(*args, columns=bounds[-1])
+                free = m.decode_step(*args)
             logits, kn, vn, ssm_n, conv_n = (np.asarray(o.numpy())
                                              for o in out)
             _assert_logits(logits[1], want[p + t], want.std())
+            _assert_logits(free[0].numpy()[1], want[p + t], want.std())
+            for a, b in zip(out[1:3], free[1:3]):   # K, V: the same bits
+                np.testing.assert_array_equal(a.numpy(), b.numpy())
+            for a, b in zip(out[3:], free[3:]):     # behind the attention
+                _assert_state(a.numpy(), b.numpy())
             for new, old in ((ssm_n, ssm_s), (conv_n, conv_s)):
                 np.testing.assert_array_equal(new[:, 2], old[:, 2])
                 np.testing.assert_array_equal(new[:, 0], old[:, 0])
-            kc[:, 1, :, p + t], vc[:, 1, :, p + t] = kn[:, 1, :, 0], \
-                vn[:, 1, :, 0]
-            ssm_s, conv_s = ssm_n, conv_n
+            # the arrays come back whole: the live row's new column at its
+            # own position, an idle row's at the position it names (5, a
+            # slot without a sequence 0), and nothing else touched
+            for new, old in ((kn, kc), (vn, vc)):
+                assert new.shape == old.shape
+                changed = set(map(tuple, np.argwhere(
+                    (new != old).any(axis=(0, 2, 4)))))
+                named = {(0, 0), (1, p + t), (2, 5)}
+                # (an idle row writes the same column again every step)
+                assert changed == (named if t == 0 else {(1, p + t)})
+            kc, vc, ssm_s, conv_s = kn, vn, ssm_n, conv_n
+        assert bounds == [16] * 4 + [32] * 4
     assert c.layers_of("mamba") == [0, 1, 3]
 
 
@@ -363,36 +393,52 @@ def test_state_slots_reserve_install_release():
     assert stats["serving.state.bytes"] == slots.nbytes == 2 * slots.slot_bytes
 
 
-def test_state_slots_keep_the_steps_dense_kv_view_on_the_device():
-    """(layers 2, kv heads 1, context 8, head dim 4): a prefill's prompt
-    columns go in with its state, a step's new columns land at each row's
-    own position, and a view is the first columns of every row."""
+def test_state_slots_hold_the_kv_arrays_before_the_state_on_the_device():
+    """A description of one attention layer (1 kv head x 4) beside one
+    Mamba layer, 3 slots, 16 columns: the KV arrays come first among the
+    slots' arrays, in the step contract's order; a prefill's prompt columns
+    go in with its state, into its slot alone and as far as its bucket
+    reaches; a slot's bytes count the KV; nothing of it is a second copy."""
+    from paddle_tpu.serving.kv_pool import (device_kv_arrays, state_groups,
+                                            state_slot_bytes)
+    reset_serving_stats()
     spec = GraniteHybridConfig(
-        vocab_size=8, hidden_size=8, layer_types=["mamba"],
-        num_attention_heads=1, num_key_value_heads=1,
+        vocab_size=8, hidden_size=8, layer_types=["mamba", "attention"],
+        num_attention_heads=2, num_key_value_heads=1,
         shared_intermediate_size=8, mamba_n_heads=2, mamba_d_head=8,
-        mamba_d_state=4).cache_spec()
-    slots = StateSlots(spec, 3, dense_kv=(2, 1, 8, 4, "float32"))
-    state = {n: np.zeros((v.shape[0], 1) + v.shape[2:], np.float32)
-             for n, v in slots.arrays.items()}
-    prompt = np.arange(2 * 1 * 1 * 5 * 4, dtype=np.float32).reshape(
-        2, 1, 1, 5, 4)
-    with pytest.raises(ValueError):
+        mamba_d_state=4, dtype="float32").cache_spec()
+    kv = device_kv_arrays(spec, 12)         # the power of two over it
+    assert [(a["name"], a["layers"], a["shape"], a["window"]) for a in kv] \
+        == [("k0", 1, [1, 16, 4], 0), ("v0", 1, [1, 16, 4], 0)]
+    slots = StateSlots(state_groups(spec), 3, device_kv=kv)
+    assert slots.names == ["k0", "v0", "ssm", "conv"]
+    assert slots.arrays["k0"].shape == (1, 3, 1, 16, 4)
+    state = {n: np.ones((v.shape[0], 1) + v.shape[2:], np.float32)
+             for n, v in slots.arrays.items() if n in ("ssm", "conv")}
+    prompt = np.arange(1, 1 * 1 * 1 * 5 * 4 + 1, dtype=np.float32).reshape(
+        1, 1, 1, 5, 4)
+    with pytest.raises(ValueError, match="install needs"):
         slots.install(1, **state)               # the prompt's KV is missing
-    slots.install(1, **state, k_dense=prompt, v_dense=-prompt)
-    cols = np.full((2, 3, 1, 1, 4), 9.0, np.float32)
-    slots.append_kv(cols, 2 * cols, [0, 5, 0])
-    k, v = (np.asarray(a) for a in slots.kv_view(8))
+    slots.install(1, **state, k0=prompt, v0=-prompt)
+    k, v = (np.asarray(slots.arrays[n]) for n in ("k0", "v0"))
     np.testing.assert_array_equal(k[:, 1, :, :5], prompt[:, 0])
     np.testing.assert_array_equal(v[:, 1, :, :5], -prompt[:, 0])
-    assert (k[:, 1, :, 5] == 9).all() and (v[:, 1, :, 5] == 18).all()
-    assert not k[:, 1, :, 6:].any()
-    # an idle row's column lands at its position 0, which the row's next
-    # prefill overwrites; nothing else of the row is touched
-    assert (k[:, 2, :, 0] == 9).all() and not k[:, 2, :, 1:].any()
-    assert slots.kv_view(4)[0].shape == (2, 3, 1, 4, 4)
+    assert not k[:, 1, :, 5:].any() and not k[:, [0, 2]].any()
+    # a shorter prompt into the same slot: its bucket's columns are
+    # overwritten, the last owner's later columns stay (no row reads past
+    # its own length, and each of its columns is written before it is read)
+    slots.install(1, **state, k0=9 * prompt[:, :, :, :2],
+                  v0=prompt[:, :, :, :2])
+    k = np.asarray(slots.arrays["k0"])
+    np.testing.assert_array_equal(k[:, 1, :, :2], 9 * prompt[:, 0, :, :2])
+    np.testing.assert_array_equal(k[:, 1, :, 2:5], prompt[:, 0, :, 2:5])
+    assert slots.kv_slot_bytes == 2 * 1 * 16 * 4 * 4
     assert slots.slot_bytes == sum(
-        a.nbytes for a in slots.arrays.values()) // 3     # state only
+        a.nbytes for a in slots.arrays.values()) // 3 \
+        == state_slot_bytes(spec, 12)
+    stats = serving_stats()
+    assert stats["serving.kv.device_bytes"] == 3 * slots.kv_slot_bytes
+    assert stats["serving.gen.state_resets"] == 1
 
 
 def test_page_budget_sizes_from_the_cache_description():
@@ -403,28 +449,38 @@ def test_page_budget_sizes_from_the_cache_description():
         assert (plan["num_layers"], plan["num_heads"], plan["head_dim"]) \
             == (1, 2, 16)                     # the ONE attention layer, GQA
         assert plan["page_bytes"] == 2 * 1 * 2 * 16 * 4 * 4
+        # a slot holds its KV (every column of the context served) beside
+        # its state, ONCE: the decode step writes both where they lie
+        kv_slot = 2 * 1 * 2 * 64 * 16 * 4
+        assert plan["kv_slot_bytes"] == kv_slot
         assert plan["state_slot_bytes"] == 3 * (8 * 16 * 16 + 3 * 160) * 4
         assert plan["state_bytes"] == 3 * plan["state_slot_bytes"]
-        # the state comes off the budget before pages are cut, ONCE: the
-        # decode step writes the state where it lies (no second copy)
-        stateless = dict(plan["config"], cache=[plan["cache"][0]])
-        more = static.page_budget(
-            config=stateless, page_tokens=4, max_context=64,
-            hbm_bytes=8 << 20, max_slots_cap=3,
-            weight_bytes=plan["weight_bytes"])
-        assert more["max_slots"] == plan["max_slots"] == 3
-        assert more["workspace_bytes"] == plan["workspace_bytes"]
-        given_up = (more["pages"] - plan["pages"]) * plan["page_bytes"]
-        assert plan["state_bytes"] - plan["page_bytes"] <= given_up \
-            <= plan["state_bytes"] + plan["page_bytes"]
+        assert plan["kv_bytes"] == 3 * kv_slot
+        assert plan["workspace_bytes"] == 3 * 128 * 4      # a logits row
+        # the pages only account: every slot's worst case, nothing carved
+        assert plan["max_slots"] == 3
+        assert plan["pages"] == 3 * (64 // 4 + 1)
+        assert "kv_on_device" not in plan
         pool = PagedKVPool.from_plan(plan)
+        assert pool.device_only and pool.k is None and pool.v is None
+        assert pool.state.names == ["k0", "v0", "ssm", "conv"]
         assert pool.state.slots == 3 and budget_drift(pool, m) == []
         pool.state = StateSlots(plan["cache"][1:], 2)
-        assert any("state slots" in d for d in budget_drift(pool, m))
+        drift = budget_drift(pool, m)
+        assert any("state slots" in d for d in drift)
+        assert any("state_slot_bytes" in d for d in drift)  # its KV is gone
         for bad in (dict(tp_degree=2), dict(draft_layers=1),
                     dict(kv_dtype="int8")):
             with pytest.raises(NotImplementedError):
                 static.page_budget(m, hbm_bytes=8 << 20, **bad)
+        # a recurrent state beside KV in host pages is no route's: the
+        # description must say what its kv groups retain
+        no_retain = [{k: v for k, v in plan["cache"][0].items()
+                      if k != "retain"}, plan["cache"][1]]
+        with pytest.raises(NotImplementedError, match="retain"):
+            static.page_budget(
+                config=dict(plan["config"], cache=no_retain),
+                hbm_bytes=8 << 20, weight_bytes=plan["weight_bytes"])
         # a GPT states one kv group of layers x heads: as before
         g = static.page_budget(GPTModel(GPTConfig(
             vocab_size=50, hidden_size=16, num_layers=2, num_heads=2,
@@ -432,29 +488,37 @@ def test_page_budget_sizes_from_the_cache_description():
         assert (g["num_layers"], g["num_heads"], g["head_dim"]) == (2, 2, 8)
         assert g["state_slot_bytes"] == 0 and g["cache"] == [
             {"kind": "kv", "layers": 2, "kv_heads": 2, "head_dim": 8}]
+        assert not PagedKVPool.from_plan(g).device_only
 
 
-def test_page_budget_charges_a_slots_state_once_at_the_published_widths():
+def test_page_budget_sizes_granite_h_micro_by_slots_at_the_published_widths():
     """`granite-4.0-h-micro` on a v5e's 16.9 GB, shapes only: 16 slots as
-    before, and the 1.22 GB that paid for a second copy of the state while
-    a step ran (24,615 pages, PR 27) goes to pages."""
+    before, each of its 76.4 MB of state and 8.4 MB of bf16 KV (4 layers x
+    8 heads x 1,024 columns x 64), 134 MB of KV in all; no host pool is
+    carved and no gather view rented (PR 28: 29,281 float32 pages and a
+    1.07 GB view), the pages account for 16 worst cases."""
     cfg = GraniteHybridConfig(dtype="bfloat16")
     sizing = dict(config=cfg, page_tokens=16, max_context=1024,
                   max_slots_cap=16, weight_bytes=cfg.param_count() * 2)
     plan = static.page_budget(hbm_bytes=16_909_336_064, **sizing)
-    slot = plan["state_slot_bytes"]
-    assert (plan["max_slots"], slot) == (16, 76_437_504)
-    assert plan["state_bytes"] == 16 * slot == 1_223_000_064
-    dense_view = 2 * 4 * 8 * 1024 * 64 * 4      # K + V, 4 layers x 8 heads
-    assert plan["workspace_bytes"] == 16 * (dense_view + 100_352 * 4)
-    assert plan["pages"] == 29_281 >= 24_615 + slot * 16 // plan["page_bytes"]
-    # one slot's state, its workspace and a page are enough to start ...
-    least = int((plan["weight_bytes"] + slot + dense_view
-                 + plan["page_bytes"]) / (1.0 - plan["headroom"])) + 1
-    assert least + slot > int(least * 1.001)    # ... a second copy is not
-    tight = static.page_budget(hbm_bytes=int(least * 1.001), **sizing)
-    assert tight["max_slots"] == 1 and tight["pages"] >= 1
-    with pytest.raises(ValueError, match="not enough for one decode slot"):
+    kv_slot = 2 * 4 * 8 * 1024 * 64 * 2
+    assert (plan["max_slots"], plan["state_slot_bytes"],
+            plan["kv_slot_bytes"]) == (16, 76_437_504, kv_slot)
+    assert plan["state_bytes"] == 16 * 76_437_504 == 1_223_000_064
+    slot = 76_437_504 + kv_slot
+    assert plan["kv_bytes"] == 16 * kv_slot == 134_217_728
+    assert plan["workspace_bytes"] == 16 * 100_352 * 4
+    assert plan["pages"] == 16 * (1024 // 16 + 1)
+    assert plan["page_bytes"] == 2 * 4 * 8 * 64 * 2 * 16
+    # the slots may take half of what the weights leave (the other half
+    # is a prefill's workspace): one slot and its logits row are enough
+    # to start, a little less is not
+    ws = 100_352 * 4
+    least = int((plan["weight_bytes"] + 2 * (slot + ws))
+                / (1.0 - plan["headroom"])) + 2
+    tight = static.page_budget(hbm_bytes=least, **sizing)
+    assert tight["max_slots"] == 1 and tight["pages"] == 1024 // 16 + 1
+    with pytest.raises(ValueError, match="not enough for one slot"):
         static.page_budget(hbm_bytes=least - slot // 2, **sizing)
 
 
@@ -489,55 +553,72 @@ def _pool_and_steps(m, slots=3):
     return PagedKVPool.from_plan(plan).state, StepPrograms(m)
 
 
-def _decode_args(state, ids, lengths, active, columns=32):
-    """`StepPrograms.decode`'s arguments before the state: `ids` [S] int32
-    as a step returns them (or such a result itself, on the device)."""
+def _decode_args(ids, lengths, active):
+    """`StepPrograms.decode`'s arguments before the cache arrays: `ids` [S]
+    int32 as a step returns them (or such a result itself, on the
+    device)."""
     ids = ids if isinstance(ids, dg.Tensor) else _t(ids, np.int32)
-    return [ids, _t(lengths, np.int32), _t(active, np.int32),
-            *[dg.to_variable(a) for a in state.kv_view(columns)]]
+    return [ids, _t(lengths, np.int32), _t(active, np.int32)]
 
 
-def test_decode_program_aliases_both_state_feeds_to_its_results():
-    """Read from the lowered module, not from a timing: the two `*state`
-    feeds of the step contract (positions 5 and 6) are donated and each is
-    aliased to a result; ids, lengths, `active` and the KV view are not,
-    and the prefill program donates nothing."""
+def _install(state, slot, made):
+    """A prefill's `*kv, *state` results into `slot`."""
+    state.install(slot, **{n: t._value for n, t in zip(state.names, made)})
+
+
+def test_decode_program_aliases_every_cache_feed_to_its_result():
+    """Read from the lowered module, not from a timing: the four cache
+    feeds of the step contract (`DECODE_CACHE_AT`..: K, V, ssm, conv) are
+    donated and each is aliased to a result; ids, lengths and `active` are
+    not, and the prefill program donates nothing.  A bound on the columns
+    is a static of the trace: a program a bound, the same feeds."""
     import re
     import jax.numpy as jnp
-    from paddle_tpu.serving.step_program import DECODE_STATE_AT
+    from paddle_tpu.serving import step_program
     with dg.guard():
         m = _model(11)
         state, steps = _pool_and_steps(m)
-        args = _decode_args(state, np.zeros(3), [0, 0, 0], [0, 0, 0]) \
+        args = _decode_args(np.zeros(3), [0, 0, 0], [0, 0, 0]) \
             + [dg.to_variable(a) for a in state.arrays.values()]
         with dg.no_grad():
-            cp = steps._decode.concrete_program(*args)
+            cp = steps.decode_program(32).concrete_program(*args)
             pre = steps._prefill.concrete_program(
                 _t(np.zeros((1, 16)), np.int32), _t([3], np.int32),
                 _t([2], np.int32))
-    assert DECODE_STATE_AT == 5 and cp.donated == (5, 6)
+            assert steps.programs == 2
+            steps.decode_program(16).concrete_program(*args)
+            steps.decode_program(None).concrete_program(*args)
+        assert steps.programs == 4 and len(steps._decode_traces()) == 3
+    assert step_program.DECODE_CACHE_AT == 3 and cp.donated == (3, 4, 5, 6)
     assert pre.donated == ()
     kept, donated = cp.split_feeds([a._value for a in args])
-    assert (len(kept), len(donated)) == (5, 2)
+    assert (len(kept), len(donated)) == (3, 4)
     text = cp.composed().lower(
         jnp.uint32(0), tuple(t._value for t in cp.params.values()), kept,
         True, donated).as_text()
     aliased = re.findall(r"%arg\d+: tensor<([0-9x]+)x[a-z0-9]+> "
                          r"\{[^}]*tf.aliasing_output = (\d+)", text)
     shapes = ["x".join(map(str, a.shape)) for a in state.arrays.values()]
-    assert [a[0] for a in aliased] == shapes        # ssm, conv: nothing else
-    # (logits, next_ids, K, V, *state)
-    assert [int(a[1]) for a in aliased] == [4, 5]
+    assert [a[0] for a in aliased] == shapes    # k0, v0, ssm, conv: no other
+    # (logits, next_ids, K, V, ssm, conv)
+    assert [int(a[1]) for a in aliased] == [2, 3, 4, 5]
+    bounds = sorted(op.attrs["columns"] for t in steps._decode_traces()
+                    for op in t.program.global_block().ops
+                    if op.type == "cached_decode_attention")
+    assert bounds == [0, 16, 32]
 
 
 @pytest.mark.parametrize("tiles", [{}, SLAB_TILES], ids=["jnp", "slab"])
-def test_decode_consumes_the_state_it_is_given_and_rebind_takes_its_result(
+def test_decode_consumes_the_cache_it_is_given_and_rebind_takes_its_result(
         tiles):
     """20 decode steps through `StepPrograms.decode` and `StateSlots`, as
-    the engine makes them: after every step the arrays that went in are
-    dead and the rebound ones carry the recurrence on (logits match the
-    reference's full forward at every step); an idle row's made-up state
-    and tail come back bit for bit; both counters count."""
+    the engine makes them, the bound doubling on the way (16 -> 32: 13..32
+    columns): after every step the arrays that went in — the KV among them
+    — are dead and the rebound ones carry the sequence on (logits match
+    the reference's full forward at every step); an idle row's made-up
+    state and tail come back bit for bit, and of its made-up KV all but
+    the one column the step names; both counters count."""
+    from paddle_tpu.core.compile_cache import next_pow2
     from paddle_tpu.ops.kernels import ssm as ssm_kernels
     p, n = 13, 20
     reset_serving_stats()
@@ -552,32 +633,36 @@ def test_decode_consumes_the_state_it_is_given_and_rebind_takes_its_result(
         want = _reference_logits(m, ids)
         padded = np.zeros((1, 16), np.int32)
         padded[0, :p] = ids[:p]
-        logits, _, k, v, ssm, conv = steps.prefill(
+        logits, _, *made = steps.prefill(
             _t(padded), _t([p], np.int32), _t([p - 1], np.int32))
         _assert_logits(logits.numpy()[0], want[p - 1], want.std())
-        state.install(1, ssm=ssm._value, conv=conv._value,
-                      k_dense=k._value, v_dense=v._value)
+        _install(state, 1, made)
         made_up = {name: np.full((a.shape[0], 1) + a.shape[2:], fill,
                                  np.float32)
                    for (name, a), fill in zip(state.arrays.items(),
-                                              (0.5, 0.25))}
-        state.install(2, **made_up, k_dense=k._value * 0, v_dense=v._value)
+                                              (3.0, -3.0, 0.5, 0.25))}
+        state.install(2, **made_up)
         for t in range(n):
             step_ids = np.zeros(3, np.int32)
             step_ids[1] = ids[p + t]
-            lengths = [0, p + t, 0]
             old = list(state.arrays.values())
-            logits, _, kn, vn, *new = steps.decode(
-                *_decode_args(state, step_ids, lengths, [0, 1, 0]), *old)
+            logits, _, *new = steps.decode(
+                *_decode_args(step_ids, [0, p + t, 40], [0, 1, 0]), *old,
+                columns=next_pow2(p + t, 16))
             assert all(a.is_deleted() for a in old)
             state.rebind(**{name: tensor._value
                             for name, tensor in zip(state.names, new)})
-            state.append_kv(kn._value, vn._value, lengths)
             assert not any(a.is_deleted() for a in state.arrays.values())
             _assert_logits(logits.numpy()[1], want[p + t], want.std())
+        assert steps.programs == 1 + 2          # bounds 16 and 32
         for name, a in state.row(2).items():
-            np.testing.assert_array_equal(a, made_up[name][:, 0])
+            if name in ("ssm", "conv"):
+                np.testing.assert_array_equal(a, made_up[name][:, 0])
+            else:       # a column of its own named, the rest as it was
+                same = (a == made_up[name][:, 0]).all(axis=(0, 1, 3))
+                assert list(np.flatnonzero(~same)) == [40]
         assert not state.row(0)["ssm"].any()
+        assert not state.row(0)["k0"][:, :, 1:].any()
     stats = serving_stats()
     assert stats["serving.gen.state_in_place"] == n
     assert stats.get("serving.gen.state_copied", 0) == 0
@@ -621,30 +706,28 @@ def test_step_programs_pick_the_first_argmax_of_the_logits_they_return(
         for slot, p in enumerate((13, 9, 16, 3, 11)):
             padded = np.zeros((1, 16), np.int32)
             padded[0, :p] = rng.integers(0, vocab, p)
-            logits, nxt, k, v, ssm, conv = steps.prefill(
+            logits, nxt, *made = steps.prefill(
                 _t(padded), _t([p], np.int32), _t([p - 1], np.int32))
             assert nxt.numpy().dtype == np.int32 and nxt.shape == [1]
             picks.append(nxt.numpy())
             rows.append(logits.numpy())
             if slot < 2:
-                state.install(slot, ssm=ssm._value, conv=conv._value,
-                              k_dense=k._value, v_dense=v._value)
+                _install(state, slot, made)
         lengths = np.asarray([13, 9, 0], np.int32)
         pending = np.concatenate(picks[:2] + [[0]]).astype(np.int32)
         for _ in range(20 * (phase == "decode")):
-            logits, nxt, kn, vn, *new = steps.decode(
-                *_decode_args(state, pending, lengths, [1, 1, 0]),
-                *state.arrays.values())
+            logits, nxt, *new = steps.decode(
+                *_decode_args(pending, lengths, [1, 1, 0]),
+                *state.arrays.values(), columns=64)
             state.rebind(**{name: tensor._value
                             for name, tensor in zip(state.names, new)})
-            state.append_kv(kn._value, vn._value, lengths)
             assert nxt.numpy().dtype == np.int32 and nxt.shape == [3]
             picks.append(nxt.numpy()[:2])       # the idle row's is ignored
             rows.append(logits.numpy()[:2])
             pending = nxt
             lengths[:2] += 1
         if phase == "decode":
-            (traced,) = steps._decode._cache.values()
+            (traced,) = steps._decode_traces()
             assert traced.composed()._cache_size() == 1
     picks, rows = np.concatenate(picks), np.concatenate(rows)
     assert len(picks) == (45 if phase == "decode" else 5)
@@ -708,8 +791,8 @@ def test_a_step_that_raises_leaves_zeroed_state_and_a_live_engine(fails_at):
         state, real = eng.kv_pool.state, eng._steps.decode
         calls, in_flight = [], []
 
-        def failing(*args):
-            calls.append(real(*args))
+        def failing(*args, **bound):
+            calls.append(real(*args, **bound))
             if len(calls) == fails_at:
                 in_flight.append(eng._in_flight)
                 raise RuntimeError("the device fell over")
@@ -722,9 +805,8 @@ def test_a_step_that_raises_leaves_zeroed_state_and_a_live_engine(fails_at):
         eng._steps.decode = real
         assert len(calls) == fails_at and eng._in_flight is None
         assert (in_flight[0] is not None) == (fails_at == 2)
-        for a in state.arrays.values():     # the step had donated these
-            assert not a.is_deleted() and not np.asarray(a).any()
-        assert not any(a.is_deleted() for a in state.dense.values())
+        for a in state.arrays.values():     # the step had donated these,
+            assert not a.is_deleted() and not np.asarray(a).any()   # KV too
         out = eng.submit(prompt, max_length=6).result(timeout=600)
         assert list(out) == _greedy(m, prompt, 6)
         eng.stop()
@@ -779,16 +861,29 @@ def test_engine_serves_the_hybrid_model_token_equal_to_one_sequence():
         eng.kv_pool.assert_drained()
         assert budget_drift(eng.kv_pool, m) == []
     stats = serving_stats()
-    # the step reads the dense view kept on the device: nothing is
-    # gathered from the pages, which hold every column all the same
+    # ONE cache, on the device: nothing is gathered from pages or appended
+    # to them (there are none: the tables account), no KV byte is fetched,
+    # every step's arrays — the KV among them — were written in place, and
+    # the engine's buckets are the programs traced
     assert stats.get("serving.kv.gather_bytes", 0) == 0
-    assert stats["serving.kv.append_bytes"] > 0
+    assert stats.get("serving.kv.append_bytes", 0) == 0
+    assert eng.kv_pool.device_only and eng.kv_pool.k is None
+    assert stats["serving.gen.state_in_place"] == stats["serving.gen.steps"]
+    assert stats.get("serving.gen.state_copied", 0) == 0
+    assert stats["serving.gen.kv_buckets"] == programs
+    assert stats["serving.kv.device_bytes"] \
+        == 2 * eng.kv_pool.state.arrays["k0"].nbytes
     assert stats["serving.gen.state_resets"] >= 2    # 5 sequences, 2 slots
     assert stats["serving.state.slots_used"] == 0
-    ops = {op.type for cp in eng._steps._decode._cache.values()
-           for op in cp.program.global_block().ops}
-    assert {"mamba2_state_update", "causal_conv1d", "gqa_attention",
-            "rms_norm", "gated_rms_norm"} <= ops
+    prompt_ops = {op.type for cp in eng._steps._prefill._cache.values()
+                  for op in cp.program.global_block().ops}
+    step_ops = {op.type for cp in eng._steps._decode_traces()
+                for op in cp.program.global_block().ops}
+    assert {"mamba2_chunk_scan", "gqa_attention", "rms_norm"} <= prompt_ops
+    assert {"mamba2_state_update", "causal_conv1d", "gated_rms_norm",
+            "cached_decode_attention"} <= step_ops
+    assert "gqa_attention" not in step_ops
+    assert "windowed_prefill_attention" not in prompt_ops
 
 
 def test_one_launch_in_flight_finds_eos_a_step_late_and_books_no_column():
@@ -837,7 +932,7 @@ def test_one_launch_in_flight_finds_eos_a_step_late_and_books_no_column():
         assert list(again) == list(outs[1])
         assert eng._steps.programs == programs
         assert {cp.composed()._cache_size() for cp in
-                eng._steps._decode._cache.values()} == {1}
+                eng._steps._decode_traces()} == {1}
         eng.stop()
         eng.kv_pool.assert_drained()
         assert budget_drift(eng.kv_pool, m) == []
@@ -950,9 +1045,11 @@ def test_sampling_rows_beside_greedy_rows_keep_their_seeded_tokens():
     assert fetched >= sampled and (fetched - prefills) % 3 == 0
 
 
-def test_a_greedy_step_fetches_ids_and_kv_columns_and_no_logits():
+def test_a_greedy_step_fetches_ids_and_no_kv_column_and_no_logits():
     """Under a profiler session: the `engine/fetch` span of a step of
-    greedy rows carries the bytes of `next_ids` and the two KV columns,
+    greedy rows carries the bytes of `next_ids` and nothing else (the KV
+    columns stay where the step wrote them: `engine/kv_install` and
+    `engine/kv_append` carry `bytes` 0),
     and `serving.gen.logits_rows_fetched` stays 0 while
     `serving.gen.sampled_on_device` grows by the step's active rows; a
     row that samples adds the whole logits to the step's bytes and every
@@ -965,9 +1062,7 @@ def test_a_greedy_step_fetches_ids_and_kv_columns_and_no_logits():
         plan = static.page_budget(m, page_tokens=4, max_context=128,
                                   hbm_bytes=8 << 20, max_slots_cap=slots)
         eng = ContinuousBatchingEngine(m, kv_pool=plan).start()
-        state = eng.kv_pool.state
-        columns = sum(a.nbytes // 16 for a in state.kv_view(16))
-        greedy_step = 4 * slots + columns
+        greedy_step, one_row = 4 * slots, 4 * vocab
         rng = np.random.default_rng(5)
         prompts = [rng.integers(0, 126, n) for n in (5, 7)]
 
@@ -991,10 +1086,12 @@ def test_a_greedy_step_fetches_ids_and_kv_columns_and_no_logits():
             # prefill's own fetch (an id, or one logits row) by its size
             fetched = [e.fields["bytes"] for e in events
                        if e.name == "engine/fetch"]
-            assert greedy_step > 4 * vocab
-            fetches = [b for b in fetched if b >= greedy_step]
-            prefills = [b for b in fetched if b < greedy_step]
+            fetches = [b for b in fetched if b not in (4, one_row)]
+            prefills = [b for b in fetched if b in (4, one_row)]
             assert len(fetches) == len(active) and len(prefills) == 2
+            moved = [e.fields["bytes"] for e in events
+                     if e.name in ("engine/kv_install", "engine/kv_append")]
+            assert len(moved) == 2 + len(active) and not any(moved)
             tokens = [len(o) - len(p) for o, p in zip(outs, prompts)]
             return active, fetches, prefills, tokens, serving_stats()
 
@@ -1006,7 +1103,6 @@ def test_a_greedy_step_fetches_ids_and_kv_columns_and_no_logits():
 
         active, fetches, prefills, tokens, stats = serve(
             decode_strategy="sampling", seed=2)
-        one_row = 4 * vocab
         # the steps that held the sampling row (one a token after its
         # prefill's, whenever its neighbour was admitted) brought the
         # logits of all the slots down, the others none

@@ -444,10 +444,13 @@ def _prefill(m, ids, bucket):
 
 
 def test_prefill_then_decode_through_the_caches_matches_the_full_pass():
-    """A padded prefill, then decode steps over three rows (one idle) on
-    the carried state and a dense KV cache: every step's logits equal the
-    reference's full pass, and the counts are a numpy count of the
-    reference's picks."""
+    """A padded prefill, then decode steps over three rows (one live) on
+    the KV and state arrays given whole, each at the engine's bound on the
+    columns (11..18 columns: 16, then 32 from 17 on): every step's logits
+    equal the reference's full pass, the counts are a numpy count of the
+    reference's picks, the arrays come back with the live row's column
+    written at its own position, and an idle row routes nothing."""
+    from paddle_tpu.core.compile_cache import next_pow2
     with dg.guard():
         m = _model(4, held_experts=4, first_held=4)
         c = m.config
@@ -473,7 +476,7 @@ def test_prefill_then_decode_through_the_caches_matches_the_full_pass():
         kc = np.zeros((1, rows, c.num_key_value_heads, lpad, c.head_dim),
                       np.float32)
         vc = np.zeros_like(kc)
-        kc[:, 1, :, :p], vc[:, 1, :, :p] = k[:, 0, :, :p], v[:, 0, :, :p]
+        kc[:, 1, :, :16], vc[:, 1, :, :16] = k[:, 0], v[:, 0]   # pads too
         s = np.zeros((3, rows) + ssm.shape[2:], np.float32)
         t = np.zeros((3, rows) + conv.shape[2:], np.float32)
         s[:, 1], t[:, 1] = ssm[:, 0], conv[:, 0]
@@ -483,13 +486,19 @@ def test_prefill_then_decode_through_the_caches_matches_the_full_pass():
             with dg.no_grad():
                 out = m.decode_step(
                     _t(step), _t([0, p + i, 0], np.int32),
-                    _t([0, 1, 0], np.int32), _t(kc), _t(vc), _t(s), _t(t))
+                    _t([0, 1, 0], np.int32), _t(kc), _t(vc), _t(s), _t(t),
+                    columns=next_pow2(p + i, 16))
             logits, kn, vn, s, t, counts = (np.asarray(o.numpy())
                                             for o in out)
             _assert_logits(logits[1], want[p + i], want.std())
             np.testing.assert_array_equal(counts, counted([p + i]))
-            kc[:, 1, :, p + i], vc[:, 1, :, p + i] = kn[:, 1, :, 0], \
-                vn[:, 1, :, 0]
+            assert kn.shape == kc.shape
+            np.testing.assert_array_equal(kn[:, 1, :, :p + i],
+                                          kc[:, 1, :, :p + i])
+            assert kn[:, 1, :, p + i].any()     # past it: the prompt's pads
+            assert not kn[:, 1, :, max(16, p + i + 1):].any()
+            assert not kn[:, [0, 2], :, 1:].any()   # idle: their column 0
+            kc, vc = kn, vn
             assert not s[:, [0, 2]].any()       # idle rows: state untouched
 
 
@@ -591,20 +600,30 @@ def test_engine_serves_the_share_token_equal_and_counts_what_it_routed():
     assert any(step.fields["active"] >= 2 and step.fields["ahead"]
                for step in steps)
     # no further download: ids (+ the 4 counts, 16 bytes in the same
-    # array) and the two KV columns a step - read under the next step's
-    # span or under a prefill's - and 4 + 16 bytes a prefill
-    state = eng.kv_pool.state
-    columns = sum(a.nbytes // 16 for a in state.kv_view(16))
+    # array) a step - read under the next step's span or under a prefill's
+    # - and 4 + 16 bytes a prefill; no KV byte moves: the one attention
+    # layer's columns are written where the device arrays lie
     fetches = [e.fields["bytes"] for e in events if e.name == "engine/fetch"]
-    assert set(fetches) == {4 * (slots + 4) + columns, 4 * (1 + 4)}
+    assert set(fetches) == {4 * (slots + 4), 4 * (1 + 4)}
     assert fetches.count(4 * (1 + 4)) == 10
+    assert not any(e.fields["bytes"] for e in events
+                   if e.name in ("engine/kv_install", "engine/kv_append"))
     assert stats.get("serving.gen.logits_rows_fetched", 0) == 0
-    ops = {op.type for cp in eng._steps._decode._cache.values()
+    assert stats["serving.gen.state_in_place"] == stats["serving.gen.steps"]
+    assert stats.get("serving.gen.state_copied", 0) == 0
+    assert stats["serving.gen.kv_buckets"] == eng._steps.programs
+    state = eng.kv_pool.state
+    assert state.names == ["k0", "v0", "ssm", "conv"]
+    assert stats["serving.kv.device_bytes"] == 2 * state.arrays["k0"].nbytes
+    ops = {op.type for cp in eng._steps._decode_traces()
            for op in cp.program.global_block().ops}
     assert {"moe_router_topk", "moe_grouped_experts", "mamba2_state_update",
-            "gqa_attention", "gated_rms_norm"} <= ops
-    groups = {op.attrs.get("groups") for cp in
-              eng._steps._decode._cache.values()
+            "cached_decode_attention", "gated_rms_norm"} <= ops
+    bounds = {op.attrs["columns"] for cp in eng._steps._decode_traces()
+              for op in cp.program.global_block().ops
+              if op.type == "cached_decode_attention"}
+    assert 0 not in bounds and len(bounds) == len(eng._steps._decode_traces())
+    groups = {op.attrs.get("groups") for cp in eng._steps._decode_traces()
               for op in cp.program.global_block().ops
               if op.type == "gated_rms_norm"}
     assert groups == {2}
@@ -641,7 +660,7 @@ def test_eos_is_found_a_step_late_beside_the_counts():
         assert list(again) == list(outs[1])
         assert eng._steps.programs == programs
         assert {cp.composed()._cache_size() for cp in
-                eng._steps._decode._cache.values()} == {1}
+                eng._steps._decode_traces()} == {1}
         eng.stop()
         eng.kv_pool.assert_drained()
     stats = serving_stats()

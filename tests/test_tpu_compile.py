@@ -220,7 +220,7 @@ def command_a_plus_programs():
             got["prefill"] = steps._prefill.concrete_program(
                 zeros((1, 8192), jnp.int32), zeros((1,), jnp.int32),
                 zeros((1,), jnp.int32))
-            got["decode"] = steps._decode.concrete_program(
+            got["decode"] = steps.decode_program(None).concrete_program(
                 zeros((slots + len(steps.counters),), jnp.int32),
                 zeros((slots,), jnp.int32), zeros((slots,), jnp.int32),
                 *[zeros((a["layers"], slots) + tuple(a["shape"]),
@@ -292,3 +292,114 @@ def test_command_a_plus_step_programs_compile_inside_hbm(
             shape = f"bf16\\[{a['layers']},{slots}," + ",".join(
                 str(n) for n in a["shape"]) + "\\]"
             assert not re.findall(rf"= {shape}\S* copy\(", text)
+
+
+def _hybrid_decode_programs(family, bounds):
+    """The traced decode Programs of a published hybrid configuration at
+    the cell's own slots, one a bound on the columns, recorded from shapes
+    alone (the model is built inside `jax.eval_shape`): ({bound: program},
+    config, [(shape, dtype)] of the cache arrays in the contract's order)."""
+    import json
+    import sys
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.dygraph as dg
+    from paddle_tpu.core.dtype import np_dtype
+    from paddle_tpu.dygraph.tensor import Tensor
+    from paddle_tpu.serving.kv_pool import device_kv_arrays, state_groups
+    from paddle_tpu.serving.step_program import StepPrograms
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, here)
+    if family == "granite":
+        from paddle_tpu.models import GraniteHybridConfig
+        from paddle_tpu.models import GraniteHybridModel as Model
+        with open(os.path.join(here, "benchmark", "configs",
+                               "granite-4.0-h-micro.json")) as f:
+            file = json.load(f)
+        cfg = GraniteHybridConfig.from_published(
+            file, eos_id=file["eos_token_id"], bos_id=file["eos_token_id"],
+            dtype=file["engine"]["dtype"])
+    else:
+        from benchmark import serving_moe_hybrid
+        from paddle_tpu.models import NemotronHModel as Model
+        with open(os.path.join(here, "benchmark", "configs",
+                               "nemotron-3-super-120b-a12b.json")) as f:
+            file = json.load(f)
+        cfg = serving_moe_hybrid.model_config(file, file["engine"])
+    slots = file["engine"]["max_slots_cap"]
+    spec = cfg.cache_spec()
+    arrays = [((a["layers"], slots) + tuple(a["shape"]), np_dtype(a["dtype"]))
+              for a in device_kv_arrays(spec, file["engine"]["max_context"])]
+    arrays += [((g["layers"], slots) + tuple(a["shape"]),
+                np_dtype(a["dtype"]))
+               for g in state_groups(spec) for a in g["arrays"]]
+    got = {}
+
+    def build():
+        def zeros(shape, dtype):
+            return Tensor(jnp.zeros(shape, dtype))
+
+        with dg.guard():
+            steps = StepPrograms(Model(cfg))
+            for bound in bounds:
+                got[bound] = steps.decode_program(bound).concrete_program(
+                    zeros((slots + len(steps.counters),), jnp.int32),
+                    zeros((slots,), jnp.int32), zeros((slots,), jnp.int32),
+                    *[zeros(shape, dtype) for shape, dtype in arrays])
+        return 0
+
+    with jax.enable_x64(False):
+        jax.eval_shape(build)
+    return got, cfg, arrays
+
+
+@pytest.mark.parametrize("family,bounds,kv_bytes,cache_bytes", [
+    ("granite", (32, 1024), 134_217_728, 1_357_217_792),
+    ("nemotron", (1024,), 67_108_864, 1_428_946_944)])
+def test_hybrid_decode_programs_write_the_kv_where_it_lies(
+        one_chip, no_compile_cache, monkeypatch, family, bounds, kv_bytes,
+        cache_bytes):
+    """The 16-row decode program of `granite-4.0-h-micro` (at the
+    smallest bound, which reads one lane tile, and the largest, two blocks
+    of 512) and the 64-row one of the nemotron share, whole, for XLA:TPU +
+    Mosaic: every cache argument — K, V of the attention layers as well as
+    the Mamba state — is aliased to its result, no copy of a KV array is
+    left (the appends of the dense view copied it whole four times a
+    step), and the temporaries stay two orders under the cache."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.kernels import moe, ssm, window_attention
+    for module in (moe, ssm, window_attention):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    got, cfg, arrays = _hybrid_decode_programs(family, bounds)
+    assert not cfg.kv_ring
+    assert sum(2 * int(np.prod(shape)) for shape, _ in arrays[:2]) \
+        == kv_bytes                             # K + V, bfloat16
+    assert sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
+               for shape, dtype in arrays) == cache_bytes
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    for bound, cp in got.items():
+        assert cp.donated == (3, 4, 5, 6)
+        block = cp.program.global_block()
+        assert {op.attrs["columns"] for op in block.ops
+                if op.type == "cached_decode_attention"} == {bound}
+        feeds = [sds(block.var(n).shape, block.var(n).dtype)
+                 for n in cp.feed_names]
+        kept = tuple(f for i, f in enumerate(feeds) if i not in cp.donated)
+        donated = tuple(feeds[i] for i in cp.donated)
+        params = tuple(sds(t.shape, t._value.dtype)
+                       for t in cp.params.values())
+        with jax.enable_x64(False):
+            compiled = cp.composed().lower(
+                sds((), jnp.uint32), params, kept, True, donated).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == cache_bytes
+        assert mem.temp_size_in_bytes < 64 << 20
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes \
+            < 16_909_336_064
+        shape = "bf16\\[" + ",".join(str(n) for n in arrays[0][0]) + "\\]"
+        assert not re.findall(rf"= {shape}\S* copy\(", compiled.as_text())
