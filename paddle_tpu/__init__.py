@@ -5,6 +5,10 @@ The public API mirrors paddle 2.0 (`paddle.*`) plus the fluid static-graph
 API (`paddle_tpu.static`, analog of `paddle.fluid`).  See SURVEY.md for the
 capability inventory this package implements.
 """
+import time as _time
+
+_t_import = _time.perf_counter()     # `import/paddle_tpu` starts here
+
 from .core.dtype import DataType as dtype  # noqa: F401
 from .core.place import (  # noqa: F401
     CPUPlace, XLAPlace, TPUPlace, CUDAPlace, CUDAPinnedPlace,
@@ -102,3 +106,9 @@ def set_cuda_rng_state(state):
     from .core.generator import seed as _set_seed
     if state:
         _set_seed(int(state[0]))
+
+
+# the import as a kept phase, first line to last (kernel registry,
+# `_setup_api`'s subpackages): docs/observability.md §6
+from .profiler import record_phase as _record_phase  # noqa: E402
+_record_phase("import/paddle_tpu", _t_import, _time.perf_counter())

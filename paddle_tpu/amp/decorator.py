@@ -18,6 +18,7 @@ from ..static import layers
 from ..static.layer_helper import LayerHelper
 from .fp16_lists import AutoMixedPrecisionLists
 from .fp16_utils import rewrite_program
+from ..profiler import Phase
 
 __all__ = ["decorate", "OptimizerWithMixedPrecision"]
 
@@ -66,7 +67,8 @@ class OptimizerWithMixedPrecision:
         from ..core.program import program_guard
         with program_guard(program, startup_program
                            or default_startup_program()):
-            rewrite_program(program, self._amp_lists, self._dest_dtype)
+            with Phase("amp/rewrite"):
+                rewrite_program(program, self._amp_lists, self._dest_dtype)
             # loss may now be low precision; bring it to fp32 for scaling
             if loss.dtype != "float32":
                 loss = layers.cast(loss, "float32")

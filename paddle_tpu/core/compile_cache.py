@@ -80,6 +80,14 @@ def initialize(cache_dir: Optional[str] = None, *,
     """
     if _state["initialized"] and not force:
         return _state["dir"]
+    from ..profiler import Phase
+    with Phase("compile_cache/initialize") as phase:
+        _initialize(cache_dir, min_compile_time_s)
+        phase.set(entries=persistent_entries())
+    return _state["dir"]
+
+
+def _initialize(cache_dir, min_compile_time_s):
     import jax
     if _state["floor"] is None:
         _state["floor"] = \
@@ -112,7 +120,6 @@ def initialize(cache_dir: Optional[str] = None, *,
         else min_compile_time_s)
     _state["initialized"] = True
     _state["dir"] = cache_dir
-    return cache_dir
 
 
 def cache_dir() -> Optional[str]:

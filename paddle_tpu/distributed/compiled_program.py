@@ -28,7 +28,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.program import Program, OpRole, unique_name
 from ..ops.registry import get_op_info, OpContext
-from ..profiler import RecordEvent
+from ..profiler import Phase, RecordEvent
 
 __all__ = ["CompiledProgram", "BuildStrategy", "ExecutionStrategy",
            "insert_grad_allreduce"]
@@ -444,8 +444,10 @@ class CompiledProgram:
             fn = self._cache.get(key)
         if fn is None:
             fingerprint = str(key[0])[:16]
-            with RecordEvent("executor/trace_compile", mode="compiled",
-                             fingerprint=fingerprint):
+            executor._unsettled[key] = {"mode": "compiled",
+                                        "fingerprint": fingerprint}
+            with Phase("executor/trace_compile", mode="compiled",
+                       fingerprint=fingerprint):
                 # env-gated IR verification rides the (already slow)
                 # first compile of each program (PADDLE_TPU_VERIFY,
                 # verifier.py)
@@ -483,8 +485,10 @@ class CompiledProgram:
                     executor._elastic_steps // micro_k) % (2 ** 31)
         else:
             seed = executor._seed_for_step(program)
-        with RecordEvent("executor/launch"):
+        first_launch = executor._first_launch(key)
+        with RecordEvent("executor/launch"), first_launch:
             fetches, new_state = fn(state, feed_vals, jnp.uint32(seed))
+        executor._settle(key, first_launch)
         self._dispatches += 1
         executor._step += 1
         if elastic is not None:
@@ -715,8 +719,10 @@ class CompiledProgram:
         if fn is None:
             mode = "compiled_steps_hoisted" if hoist else "compiled_steps"
             fingerprint = str(key[2])[:16]
-            with RecordEvent("executor/trace_compile", mode=mode,
-                             fingerprint=fingerprint):
+            executor._unsettled[key] = {"mode": mode,
+                                        "fingerprint": fingerprint}
+            with Phase("executor/trace_compile", mode=mode,
+                       fingerprint=fingerprint):
                 from ..static.verifier import verify_first_compile
                 verify_first_compile(program, fetch_list=fetch_names)
                 _ccache.record_miss()
@@ -754,8 +760,10 @@ class CompiledProgram:
             seeds = jnp.asarray(
                 [(executor._seed_for_step(program) + i) % (2 ** 31)
                  for i in range(k)], jnp.uint32)
-        with RecordEvent("executor/launch"):
+        first_launch = executor._first_launch(key)
+        with RecordEvent("executor/launch"), first_launch:
             fetches, new_state = fn(state, feed_vals, seeds)
+        executor._settle(key, first_launch)
         self._dispatches += 1
         executor._step += k
         if elastic is not None:

@@ -25,7 +25,19 @@ from ..static.param_attr import ParamAttr
 from .base import in_dygraph_mode
 from .tensor import Tensor
 
-__all__ = ["Layer", "Sequential", "LayerList", "ParameterList", "ParamBase"]
+__all__ = ["Layer", "Sequential", "LayerList", "ParameterList", "ParamBase",
+           "parameter_footprint"]
+
+
+def parameter_footprint(layer: "Layer") -> Dict[str, int]:
+    """{"params": elements, "bytes": shape x dtype} over a layer's
+    parameters, as `p.numpy().nbytes` would sum, without bringing one
+    across the host link to count it."""
+    from ..core.dtype import np_dtype
+    sizes = [(int(np.prod(p.shape)), np_dtype(p.dtype).itemsize)
+             for p in layer.parameters()]
+    return {"params": sum(n for n, _ in sizes),
+            "bytes": sum(n * item for n, item in sizes)}
 
 
 class ParamBase(Tensor):

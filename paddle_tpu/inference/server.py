@@ -52,7 +52,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 import numpy as np
-from ..profiler import RecordEvent
+from ..profiler import RecordEvent, record_phase
 
 __all__ = ["InferenceServer"]
 
@@ -254,6 +254,7 @@ class InferenceServer:
                  gen_tp_degree: Optional[int] = None):
         from . import Config, create_predictor
         from ..serving import DynamicBatcher
+        self._t_created = time.perf_counter()   # `server/start` begins
         self._status = "loading"
         self._base = create_predictor(Config(model_dir))
         self._run_lock = threading.Lock()
@@ -318,7 +319,10 @@ class InferenceServer:
     def stats(self) -> dict:
         """The /stats payload: serving namespace + predictor exe cache."""
         from ..serving.metrics import serving_stats
-        out = {"status": self._status, "serving": serving_stats()}
+        from ..core.monitor import monitor_snapshot
+        out = {"status": self._status, "serving": serving_stats(),
+               # what start-up went on: `phase.<name>.us` / `.calls`
+               "phases": monitor_snapshot("phase.")}
         exe = getattr(self._base, "_exe", None)
         if exe is not None and hasattr(exe, "cache_stats"):
             out["predictor_cache"] = exe.cache_stats()
@@ -424,6 +428,10 @@ class InferenceServer:
         t.start()
         self._serve_thread = t
         self._status = "ok"
+        # once a replica: the constructor's first line (predictor, engine,
+        # pool) to the engine and HTTP threads up — the cold-start time an
+        # autoscaler waits for, in `phase.server/start.us`
+        record_phase("server/start", self._t_created, time.perf_counter())
         return t
 
     def stop(self, drain_timeout_s: float = 30.0):

@@ -36,6 +36,7 @@ from ..ops.registry import OpContext
 from ..dygraph import tracer as dytracer
 from ..dygraph.tensor import Tensor
 from ..dygraph.layers import Layer
+from ..profiler import Phase
 
 __all__ = ["to_static", "declarative", "save", "load", "TranslatedLayer",
            "ProgramTranslator", "InputSpec", "StaticFunction",
@@ -189,7 +190,7 @@ class StaticFunction:
 
     def __init__(self, fn, input_spec=None, layer: Optional[Layer] = None,
                  abstract_trace: bool = False,
-                 donate_args: Sequence[int] = ()):
+                 donate_args: Sequence[int] = (), describe=None):
         """``donate_args``: positions of the call's Tensor arguments whose
         device buffers the compiled run may write its results into (XLA
         aliases an output of the same shape and dtype to each).  After
@@ -203,7 +204,13 @@ class StaticFunction:
         executable is compiled for a result the compiled run recomputes
         anyway.  For functions whose Python never reads a tensor's value
         (a 3 B-parameter decode step: seconds instead of minutes, and none
-        of the trace's activations held on the device)."""
+        of the trace's activations held on the device).
+
+        ``describe``: what the caller knows of a program this function is
+        about to obtain, as fields of its `jit/program` phase
+        (docs/observability.md §6): called with the arguments of the call
+        that found no traced entry, returns a dict."""
+        self._describe = describe
         self._fn = self._maybe_ast_transform(fn)
         self._input_spec = input_spec
         self._layer = layer
@@ -257,10 +264,15 @@ class StaticFunction:
 
     def concrete_program(self, *args) -> ConcreteProgram:
         args = self._to_tensors(args)
-        key = self._sig(args)
-        if key not in self._cache:
-            self._cache[key] = self._trace(args)
-        return self._cache[key]
+        return self._cache.get(self._sig(args)) or self._record(args)
+
+    def _record(self, args) -> ConcreteProgram:
+        """The miss path: `_trace` the arguments' signature into its
+        entry, as the kept phase `jit/record`."""
+        with Phase("jit/record") as phase:
+            cp = self._cache[self._sig(args)] = self._trace(args)
+            phase.set(ops=len(cp.program.global_block().ops))
+        return cp
 
     def _trace(self, args) -> ConcreteProgram:
         program = Program()
@@ -365,7 +377,19 @@ class StaticFunction:
             raise TypeError("to_static functions take positional Tensor "
                             "arguments only (trace-time contract)")
         args = self._to_tensors(args)
-        cp = self.concrete_program(*args)
+        cp = self._cache.get(self._sig(args))
+        if cp is not None:
+            return self._run(cp, args)
+        # a program obtained: recorded, then composed, lowered, compiled
+        # (or loaded) and run once, all under one kept phase
+        fields = self._describe(*args) if self._describe else {}
+        with Phase("jit/program", fn=self.__name__, **fields) as phase:
+            cp = self._record(args)
+            phase.set(ops=len(cp.program.global_block().ops))
+            return self._run(cp, args)
+
+    def _run(self, cp: ConcreteProgram, args):
+        """One call of the traced entry `cp` on tensor arguments `args`."""
         input_raws, donated_raws = cp.split_feeds(
             [a._value for a in args if isinstance(a, Tensor)])
         param_ts = [cp.params[n] for n in cp.params]

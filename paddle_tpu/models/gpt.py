@@ -20,7 +20,8 @@ import numpy as np
 
 import paddle_tpu
 from .. import nn
-from ..dygraph.layers import Layer
+from ..dygraph.layers import Layer, parameter_footprint
+from ..profiler import Phase
 
 __all__ = ["GPTConfig", "GPTModel", "GPTForGeneration", "gpt_small"]
 
@@ -78,10 +79,14 @@ class GPTModel(Layer):
         super().__init__()
         self.config = cfg or GPTConfig(**kw)
         c = self.config
-        self.wte = nn.Embedding(c.vocab_size, c.hidden_size)
-        self.wpe = nn.Embedding(c.max_position, c.hidden_size)
-        self.blocks = nn.LayerList([_Block(c) for _ in range(c.num_layers)])
-        self.ln_f = nn.LayerNorm(c.hidden_size)
+        # once a model: the weights drawn from the seed and placed
+        with Phase("model/build") as phase:
+            self.wte = nn.Embedding(c.vocab_size, c.hidden_size)
+            self.wpe = nn.Embedding(c.max_position, c.hidden_size)
+            self.blocks = nn.LayerList(
+                [_Block(c) for _ in range(c.num_layers)])
+            self.ln_f = nn.LayerNorm(c.hidden_size)
+            phase.set(**parameter_footprint(self))
         self._mask_cache = {}
 
     def _mask(self, seq):

@@ -43,9 +43,10 @@ import math
 import numpy as np
 
 from ..core.generator import global_seed
-from ..dygraph.layers import Layer, ParamBase
+from ..dygraph.layers import Layer, ParamBase, parameter_footprint
 from ..nn import functional as F
 from ..nn.initializer import Constant, Normal, Uniform
+from ..profiler import Phase
 from ..tensor._dispatch import dispatch
 from ..tensor.linalg import matmul
 from ..tensor.manipulation import (cast, gather, reshape, split, squeeze,
@@ -607,7 +608,13 @@ class HybridDecoder(Layer):
 
     def __init__(self, cfg):
         super().__init__(dtype=cfg.dtype)
-        self.config = c = cfg
+        self.config = cfg
+        # once a model: the weights drawn from the seed and placed
+        with Phase("model/build") as phase:
+            self._build(cfg)
+            phase.set(**parameter_footprint(self))
+
+    def _build(self, c):
         # seeded weights: the table is drawn so that the scaled embedding
         # has RMS `embed_init_rms` — small beside what the mixers and
         # feed-forwards add to the residual.  At the Embedding layer's

@@ -21,6 +21,7 @@ is the static counterpart the ERNIE-style pretrain configs use.)
 """
 from __future__ import annotations
 
+from ..profiler import Phase
 from ..static import layers
 
 __all__ = ["build_transformer_lm", "build_bert_base"]
@@ -99,6 +100,19 @@ def build_transformer_lm(vocab_size, hidden, num_layers, num_heads, seq_len,
 
 def build_bert_base(vocab=30522, seq=512, hidden=768, layers_n=12, heads=12,
                     batch=8, use_amp=True, use_ring=False):
+    # once a model: the trainer's Program IR, its rewrites and its backward
+    # (children `amp/rewrite`, `static/head_loss_rewrite`,
+    # `static/backward`) as the kept phase `program/build`
+    with Phase("program/build") as phase:
+        main, startup, loss = _bert_base_programs(
+            vocab, seq, hidden, layers_n, heads, use_amp, use_ring)
+        block = main.global_block()
+        phase.set(ops=len(block.ops), vars=len(block.vars))
+    return main, startup, loss
+
+
+def _bert_base_programs(vocab, seq, hidden, layers_n, heads, use_amp,
+                        use_ring):
     import paddle_tpu.static as static
     from paddle_tpu.static import layers, nets
     from paddle_tpu import amp

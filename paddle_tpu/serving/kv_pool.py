@@ -99,7 +99,7 @@ import numpy as np
 
 from . import metrics
 from ..core.compile_cache import next_pow2 as _next_pow2
-from ..profiler import RecordEvent
+from ..profiler import NO_PHASE, Phase, RecordEvent
 
 __all__ = ["PagedKVPool", "PageTable", "PagePoolExhaustedError",
            "StateSlots", "budget_drift", "cache_spec_of", "device_kv_arrays",
@@ -273,23 +273,28 @@ class StateSlots:
             raise ValueError("StateSlots needs >= 1 slot and a state group "
                              "or device-only KV arrays")
         self.arrays: Dict[str, "jax.Array"] = {}
-        for a in self.device_kv:
-            self.arrays[a["name"]] = jnp.zeros(
-                (a["layers"], self.slots) + tuple(a["shape"]),
-                np_dtype(a["dtype"]))
-        self.kv_slot_bytes = sum(v.nbytes for v in self.arrays.values()) \
-            // self.slots
-        for g in self.groups:
-            for a in g["arrays"]:
-                if a["name"] in self.arrays:
-                    raise ValueError(f"two state arrays named {a['name']!r}")
+        # once an engine: the device arrays, allocated here and never again
+        with Phase("kv_pool/allocate", slots=self.slots, pages=0) as phase:
+            for a in self.device_kv:
                 self.arrays[a["name"]] = jnp.zeros(
-                    (int(g["layers"]), self.slots) + tuple(a["shape"]),
+                    (a["layers"], self.slots) + tuple(a["shape"]),
                     np_dtype(a["dtype"]))
-        self.slot_bytes = sum(v.nbytes for v in self.arrays.values()) \
-            // self.slots
+            self.kv_slot_bytes = sum(
+                v.nbytes for v in self.arrays.values()) // self.slots
+            for g in self.groups:
+                for a in g["arrays"]:
+                    if a["name"] in self.arrays:
+                        raise ValueError(
+                            f"two state arrays named {a['name']!r}")
+                    self.arrays[a["name"]] = jnp.zeros(
+                        (int(g["layers"]), self.slots) + tuple(a["shape"]),
+                        np_dtype(a["dtype"]))
+            self.slot_bytes = sum(
+                v.nbytes for v in self.arrays.values()) // self.slots
+            phase.set(bytes=self.slot_bytes * self.slots)
         self._free: List[int] = list(range(self.slots - 1, -1, -1))
         self._written = set()       # slots that have held a sequence
+        self._write_shapes = set()  # `install`s whose writes are compiled
         # one slot's entry (a prefill's result, shorter along the context
         # where it is a prompt's KV) into its row, in place
         self._write = jax.jit(
@@ -347,9 +352,20 @@ class StateSlots:
         if sorted(new) != sorted(self.arrays):
             raise ValueError(
                 f"install needs {sorted(self.arrays)}, got {sorted(new)}")
-        for name, value in new.items():
-            self.arrays[name] = self._write(self.arrays[name], value,
-                                            np.int32(slot))
+        shapes = tuple(v.shape for v in new.values())
+        obtained = NO_PHASE
+        if shapes not in self._write_shapes:
+            # a program obtained: `_write`'s executables for a prefill's
+            # results of these shapes (one set a prompt bucket)
+            self._write_shapes.add(shapes)
+            obtained = Phase("jit/program", fn="StateSlots._write",
+                             kind="install", rows=1, bucket=(
+                                 new[self.device_kv[0]["name"]].shape[3]
+                                 if self.device_kv else 0))
+        with obtained:
+            for name, value in new.items():
+                self.arrays[name] = self._write(self.arrays[name], value,
+                                                np.int32(slot))
         if slot in self._written:
             metrics.count("gen.state_resets")
         self._written.add(slot)
@@ -433,8 +449,12 @@ class PagedKVPool:
         self.state = state
         shape = (self.num_layers, self.num_pages, self.num_heads,
                  self.page_tokens, self.head_dim)
-        self.k = None if self.device_only else np.zeros(shape, self.dtype)
-        self.v = None if self.device_only else np.zeros(shape, self.dtype)
+        self.k = self.v = None
+        if not self.device_only:
+            with Phase("kv_pool/allocate", slots=0, pages=self.num_pages,
+                       bytes=2 * int(np.prod(shape)) * self.dtype.itemsize):
+                self.k = np.zeros(shape, self.dtype)
+                self.v = np.zeros(shape, self.dtype)
         if self.is_quantized:
             # per-(layer, page, head) fp32 dequant scale: x ≈ q * scale
             sshape = (self.num_layers, self.num_pages, self.num_heads)

@@ -124,7 +124,10 @@ class StepPrograms:
         # compile hundreds of per-op programs to throw their results away
         self._prefill = StaticFunction(
             _with_greedy(model.prefill_step, bool(self.counters)),
-            layer=model, abstract_trace=True)
+            layer=model, abstract_trace=True,
+            describe=lambda ids, *_: {
+                "kind": "prefill", "bucket": ids.shape[1],
+                "rows": ids.shape[0]})
         spec = cache_spec_of(model.config)
         n_kv = 2 * len(retained_kv_groups(spec))
         if not n_kv:
@@ -148,7 +151,10 @@ class StepPrograms:
             self._decode[columns] = StaticFunction(
                 _with_greedy(step, bool(self.counters), ids_as_picked=True),
                 layer=self._model, abstract_trace=True,
-                donate_args=self._donated)
+                donate_args=self._donated,
+                describe=lambda ids, lengths, *_: {
+                    "kind": "decode", "rows": lengths.shape[0],
+                    "columns": -1 if columns is None else int(columns)})
         return self._decode[columns]
 
     def _decode_traces(self) -> list:

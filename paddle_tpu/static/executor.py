@@ -29,7 +29,7 @@ from ..core.place import CPUPlace, XLAPlace, Place, _current_expected_place
 from ..core.dtype import np_dtype
 from ..core import compile_cache as _ccache
 from ..ops.registry import get_op_info, OpContext
-from ..profiler import RecordEvent
+from ..profiler import NO_PHASE, Phase, RecordEvent
 from ..testing import chaos as _chaos
 
 __all__ = ["Executor", "Scope", "global_scope", "scope_guard",
@@ -295,6 +295,10 @@ class Executor:
         self.bucket_policy = flag("feed_bucketing", "existing")
         self._stats = {"hits": 0, "misses": 0, "traces": 0,
                        "bucket_hits": 0}
+        # cache entries (this executor's and its CompiledPrograms') whose
+        # launches may still obtain an executable: key -> the fields of
+        # their `executor/first_launch` phase
+        self._unsettled = {}
         self._step = 0
         # chaos fault-injection step index (testing/chaos.py): counts
         # TRAINING run()/run_steps() calls only (_chaos_step gates on
@@ -361,7 +365,11 @@ class Executor:
                        for f in (fetch_list or [])]
 
         if self._program_is_startup(program):
-            self._run_eager(program, scope, feed, fetch_names)
+            # once a model: a static model's weights come from the seed here
+            with Phase("executor/first_launch", mode="startup",
+                       fingerprint=str(program.fingerprint())[:16],
+                       startup=1):
+                self._run_eager(program, scope, feed, fetch_names)
             return [] if not fetch_names else [
                 as_numpy(scope.get(n)) if return_numpy else scope.get(n)
                 for n in fetch_names]
@@ -715,6 +723,7 @@ class Executor:
         `cache_stats()` still reports the session."""
         self._cache.clear()
         self._bucket_map.clear()
+        self._unsettled.clear()
 
     # -- eager interpreter (startup / debug) --------------------------------
     def _program_is_startup(self, program: Program) -> bool:
@@ -771,8 +780,10 @@ class Executor:
                     fn = self._cache.get(key)
         if fn is None:
             fingerprint = str(key[0])[:16]
-            with RecordEvent("executor/trace_compile", mode="run",
-                             fingerprint=fingerprint):
+            self._unsettled[key] = {"mode": "run",
+                                    "fingerprint": fingerprint}
+            with Phase("executor/trace_compile", mode="run",
+                       fingerprint=fingerprint):
                 # env-gated IR verification on the first compile of each
                 # program (PADDLE_TPU_VERIFY — static/verifier.py): the
                 # IR walk rides the already-slow trace path only
@@ -788,9 +799,11 @@ class Executor:
             self._record("hit", bucketed=bucket is not None)
 
         state = {n: scope.get(n) for n in state_names}
-        with RecordEvent("executor/launch"):
+        first_launch = self._first_launch(key)
+        with RecordEvent("executor/launch"), first_launch:
             seed = self._seed_for_step(program)
             fetches, new_state = fn(state, feed_vals, jnp.uint32(seed))
+        self._settle(key, first_launch)
         self._step += 1
         for n, v in new_state.items():
             scope.set(n, v)
@@ -802,6 +815,26 @@ class Executor:
             with RecordEvent("executor/fetch"):
                 return [np.asarray(f) for f in fetches]
         return list(fetches)
+
+    # -- a new entry's first launches ------------------------------------------
+    def _first_launch(self, key):
+        """The kept phase `executor/first_launch` of a launch of cache entry
+        `key` that may still obtain an executable — inside its
+        `executor/launch` span, which stays where it is for hits and misses
+        alike: a new entry's launches until one obtains none (`jax.jit` is
+        lazy, so the first traces, lowers and compiles or loads; under a
+        mesh the second does so again, for state that now lives sharded).
+        `NO_PHASE` for every other: with everything warm, one test of an
+        empty dict."""
+        if not self._unsettled or key not in self._unsettled:
+            return NO_PHASE
+        return Phase("executor/first_launch", **self._unsettled[key],
+                     startup=0)
+
+    def _settle(self, key, first_launch):
+        if first_launch is not NO_PHASE and \
+                not first_launch.fields.get("executables"):
+            del self._unsettled[key]
 
     # -- shape bucketing -----------------------------------------------------
     def _record(self, kind, bucketed=False):
@@ -1126,8 +1159,10 @@ class Executor:
                                             return_numpy)
         if fn is None:
             fingerprint = str(key[1])[:16]
-            with RecordEvent("executor/trace_compile", mode="run_steps",
-                             fingerprint=fingerprint):
+            self._unsettled[key] = {"mode": "run_steps",
+                                    "fingerprint": fingerprint}
+            with Phase("executor/trace_compile", mode="run_steps",
+                       fingerprint=fingerprint):
                 from .verifier import verify_first_compile
                 verify_first_compile(program, fetch_list=fetch_names)
                 self._record("miss")
@@ -1150,11 +1185,13 @@ class Executor:
         from ..core.monitor import stat_add
         stat_add("executor_run_times")
         state = {n: scope.get(n) for n in state_names}
-        with RecordEvent("executor/launch"):
+        first_launch = self._first_launch(key)
+        with RecordEvent("executor/launch"), first_launch:
             seeds = jnp.asarray(
                 [self._seed_for_step(program) + i for i in range(k)],
                 jnp.uint32)
             fetches, new_state = fn(state, feed_vals, seeds)
+        self._settle(key, first_launch)
         self._step += k
         for n, v in new_state.items():
             scope.set(n, v)

@@ -18,6 +18,7 @@ from ..core.program import (Program, VarDesc, OpRole, default_main_program,
                             default_startup_program, unique_name)
 from .backward import append_backward
 from .head_loss_rewrite import fuse_head_loss
+from ..profiler import Phase
 from .layer_helper import LayerHelper
 from .initializer import Constant
 from . import layers
@@ -199,9 +200,12 @@ class Optimizer:
         # the forward program is final here (AMP has inserted its casts):
         # an LM head and its loss become one op before the backward is
         # derived, so it gets one grad op (static/head_loss_rewrite.py)
-        fuse_head_loss(loss.block.program)
-        return append_backward(loss, parameter_list or self._parameter_list,
-                               no_grad_set, callbacks)
+        with Phase("static/head_loss_rewrite"):
+            fuse_head_loss(loss.block.program)
+        with Phase("static/backward"):
+            return append_backward(
+                loss, parameter_list or self._parameter_list, no_grad_set,
+                callbacks)
 
     def apply_gradients(self, params_grads):
         """fluid optimizer.py:802 — clip, regularize, then per-param op.
@@ -816,10 +820,12 @@ class RecomputeOptimizer(Optimizer):
                  no_grad_set=None, callbacks=None):
         assert self._checkpoints is not None, \
             "call _set_checkpoints before minimize (fluid contract)"
-        fuse_head_loss(loss.block.program, keep=[
-            getattr(c, "name", c) for c in self._checkpoints])
-        return append_backward(loss, parameter_list, no_grad_set,
-                               checkpoints=self._checkpoints)
+        with Phase("static/head_loss_rewrite"):
+            fuse_head_loss(loss.block.program, keep=[
+                getattr(c, "name", c) for c in self._checkpoints])
+        with Phase("static/backward"):
+            return append_backward(loss, parameter_list, no_grad_set,
+                                   checkpoints=self._checkpoints)
 
     def apply_gradients(self, params_grads):
         return self._optimizer.apply_gradients(params_grads)

@@ -1420,7 +1420,6 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
     """
     import numpy as np
     from .memory_analysis import hbm_budget_bytes
-    from ..core.dtype import np_dtype
     from ..serving.kv_pool import (kv_geometry, retained_kv_groups,
                                    state_groups)
     cfg = _model_config(model, config)
@@ -1457,11 +1456,9 @@ def page_budget(model=None, config=None, *, page_tokens: int = 16,
     budget = int(hbm_bytes) if hbm_bytes else hbm_budget_bytes()
     if weight_bytes is None:
         if model is not None:
-            # shape x dtype, as `p.numpy().nbytes` gave it, without
-            # bringing every parameter across the host link to count it
-            weight_bytes = int(sum(
-                int(np.prod(p.shape)) * np_dtype(p.dtype).itemsize
-                for p in getattr(model, "gpt", model).parameters()))
+            from ..dygraph.layers import parameter_footprint
+            weight_bytes = parameter_footprint(
+                getattr(model, "gpt", model))["bytes"]
         elif on_device:
             raise ValueError(
                 "page_budget: give weight_bytes (or the model) for a "

@@ -372,6 +372,11 @@ def test_executor_spans_nest_under_the_parity_names(capsys, tmp_path):
                 "executor/hooks"}
     assert by_parent["Executor::Run"] == children
     assert by_parent["Executor::RunSteps"] == children
+    # both calls are misses: each launch holds the kept phase
+    # `executor/first_launch`, with JAX's stages of obtaining the program
+    assert by_parent["executor/launch"] == {"executor/first_launch"}
+    assert {"jax/lower", "jax/compile"} <= \
+        by_parent["executor/first_launch"]
     compiled = [e.fields for e in events
                 if e.name == "executor/trace_compile"]
     assert [f["mode"] for f in compiled] == ["run", "run_steps"]

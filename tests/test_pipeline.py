@@ -83,8 +83,10 @@ def test_pipeline_trains_and_matches_plain():
     assert np.isfinite(pp_loss).all()
     np.testing.assert_allclose(float(pp_loss), float(plain_loss),
                                rtol=1e-4, atol=1e-5)
-    for (n1, w1), (n2, w2) in zip(sorted(plain_w.items()),
-                                  sorted(pp_w.items())):
+    # in creation order: the two builds' names differ by the process's
+    # name counter, and sorted they pair up wrong across a digit boundary
+    # (`fc_9` / `fc_10`)
+    for (n1, w1), (n2, w2) in zip(plain_w.items(), pp_w.items()):
         np.testing.assert_allclose(w1, w2, rtol=1e-4, atol=1e-5)
 
 
