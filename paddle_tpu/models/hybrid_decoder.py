@@ -380,11 +380,11 @@ class _Experts(Layer):
 class _Attention(Layer):
     """Grouped-query attention with the config's own score multiplier;
     of its layer's description, a `window` and rotary positions (`rotary`:
-    theta), each or neither.  `forward(x)` runs whole sequences from
-    empty caches — through the blocked kernel where the description has a
-    ring (`kv_ring`), else `gqa_attention` — and returns (out, (k, v)), k,
-    v the tokens' [B, Hkv, T, D]; `step` one token a row over the cache
-    group's arrays."""
+    theta), each or neither.  `forward(x, lengths)` runs whole sequences
+    from empty caches — through the blocked kernel where the description
+    has a ring (`kv_ring`; given `lengths` it leaves blocks of pads out),
+    else `gqa_attention` — and returns (out, (k, v)), k, v the tokens' [B,
+    Hkv, T, D]; `step` one token a row over the cache group's arrays."""
 
     def __init__(self, cfg, spec=AttentionSpec()):
         super().__init__(dtype=cfg.dtype)
@@ -423,13 +423,15 @@ class _Attention(Layer):
                       [b, t, c.num_attention_heads * c.head_dim])
         return matmul(ctx, self.wo)
 
-    def forward(self, x):
+    def forward(self, x, lengths=None):
         c = self.cfg
         q, k, v = self._heads(x)
         attrs = {"scale": c.attention_multiplier}
         if c.kv_ring:       # blocks of queries, the window's key blocks
-            ctx = dispatch("windowed_prefill_attention",
-                           {"Q": q, "K": k, "V": v},
+            ins = {"Q": q, "K": k, "V": v}
+            if lengths is not None:     # blocks of pads are not computed
+                ins["Lengths"] = lengths
+            ctx = dispatch("windowed_prefill_attention", ins,
                            dict(attrs, window=int(self.window or 0)))
         else:
             ctx = dispatch("gqa_attention", {"Q": q, "K": k, "V": v}, attrs)
@@ -696,11 +698,9 @@ class HybridDecoder(Layer):
         h = self._embed(ids)
         ks, vs, ssm, conv, routed = [], [], [], [], []
         for blk in list(self.layers)[:upto]:
-            if blk.kind == "mamba":
-                mix = lambda m, x: m.scan(x, lengths)      # noqa: E731
-            else:
-                mix = lambda m, x: m(x)                    # noqa: E731
-            h, made, expert = blk(h, mix, lengths)
+            scan = blk.kind == "mamba"
+            h, made, expert = blk(
+                h, lambda m, x: (m.scan if scan else m)(x, lengths), lengths)
             if blk.kind == "attention":
                 ks.append(made[0])
                 vs.append(made[1])

@@ -23,6 +23,7 @@ given (bfloat16 when served).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -35,8 +36,10 @@ from ..registry import register_op
 
 _F32 = jnp.float32
 # rows of queries a grid step takes per kv head (times the heads that share
-# it) and the columns of keys a turn of its loop reads
-_BLOCK_Q, _BLOCK_K = 64, 512
+# it), the columns of keys a turn of its loop reads, and the rows of the
+# merged [heads x queries] axis a turn folds at a time (timed on the v5e at
+# [1, 8, 16, T, 128], PERF.md PR 36: 128 x 512 x 256)
+_BLOCK_Q, _BLOCK_K, _FOLD_ROWS = 128, 512, 256
 # columns of the cache a turn of the decode step's loop reads, and the
 # fewest a bounded read takes: a lane tile (an array of 64-wide heads lies
 # with its columns along the lanes; for a narrower slice XLA:TPU turns the
@@ -104,93 +107,203 @@ def rotary_embedding(ins, attrs, ctx):
     return {"Out": lax.optimization_barrier(out)}
 
 
-def _prefill_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, window,
-                    scale):
+def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                    acc_ref, *, block_q, block_k, heads, window, scale):
     """One block of `block_q` queries of the `G` heads that share a kv head
-    against the key blocks it can see: online softmax over them."""
+    against the key blocks it can see: online softmax over them.
+
+    A block of queries that begins at or past the row's length (`len_ref`,
+    prefetched) is not computed: zeros.
+
+    Only the key blocks that can hide a key are masked, and they come
+    first: the DIAGONAL blocks (those that hold a key some query of the
+    block may not see yet), after which every row has met its own key, so
+    that its running maximum is finite from there on, then the window's far
+    EDGE blocks.  The INTERIOR blocks, every key of which every query sees,
+    take no mask at all.
+
+    A turn of a loop folds the block's `G * block_q` rows `heads` heads at
+    a time, the statistics (`m_ref` the running maximum, `l_ref` the
+    running sum kept as one partial sum a lane, `acc_ref`) in scratch
+    between turns: the scores of all the rows at once ([2,048, 512] float32
+    at the served shapes) are many times the vector registers, and a loop
+    over them is bound by their spills on the one store slot a bundle has,
+    not by the matmuls or the masks (PERF.md, PR 36)."""
     groups, d = q_ref.shape[2], q_ref.shape[4]
-    rows = groups * block_q
+    lanes = m_ref.shape[1]
+    rows = heads * block_q
     first = pl.program_id(2) * block_q
-    q = q_ref[0, 0].reshape(rows, d)
-    # a row of the merged [G * block_q] axis is query first + row % block_q
-    qpos = first + lax.rem(
-        lax.broadcasted_iota(jnp.int32, (rows, block_k), 0),
-        jnp.int32(block_q))
-    col = lax.broadcasted_iota(jnp.int32, (rows, block_k), 1)
+    last = first + (block_q - 1)
+    valid = first < len_ref[pl.program_id(0)]
 
-    def body(kb, carry):
-        m, l, acc = carry
-        ks = k_ref[0, 0, pl.ds(kb * block_k, block_k), :]
-        vs = v_ref[0, 0, pl.ds(kb * block_k, block_k), :]
-        s = lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
-                            preferred_element_type=_F32) * scale
-        kpos = kb * block_k + col
-        seen = kpos <= qpos
+    def folds(fold):
+        """`fold(c)` for each of the block's folds.  Written out, so that
+        one fold's matmuls overlap the next one's softmax (rolled, a turn
+        has 40% more bundles), but traced once."""
+        lax.fori_loop(0, groups // heads, lambda c, _: fold(c), None,
+                      unroll=True)
+
+    @pl.when(jnp.logical_not(valid))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(valid)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, _F32)
+        l_ref[...] = jnp.zeros(l_ref.shape, _F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
+        # a row of the merged [heads * block_q] axis is query first + row %
+        # block_q; a mask compares a column of rows with a row of columns
+        qpos = first + lax.rem(
+            lax.broadcasted_iota(jnp.int32, (rows, 1), 0),
+            jnp.int32(block_q))
+        col = lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+
+        def block(j, masked):
+            at = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+            ks, vs = k_ref[0, 0, at, :], v_ref[0, 0, at, :]
+
+            def fold(c):
+                part = pl.ds(pl.multiple_of(c * rows, rows), rows)
+                q = q_ref[0, 0, pl.ds(c * heads, heads)].reshape(rows, d)
+                s = lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=_F32) * scale
+                if masked:
+                    rel = qpos - j * block_k        # the row's own column
+                    seen = col <= rel
+                    if window:
+                        seen &= col > rel - window
+                    s = jnp.where(seen, s, -jnp.inf)
+                m = m_ref[part, :]
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                # a row none of whose keys lay in the blocks so far:
+                # exp(-inf + inf); only before its own key's block
+                safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0) \
+                    if masked else m_new
+                p = jnp.exp(s - jnp.tile(safe, (1, block_k // lanes)))
+                alpha = jnp.exp(m - safe)
+                partial = p[:, :lanes]
+                for i in range(1, block_k // lanes):
+                    partial = partial + p[:, i * lanes:(i + 1) * lanes]
+                m_ref[part, :] = m_new
+                l_ref[part, :] = alpha * l_ref[part, :] + partial
+                acc_ref[part, :] = (
+                    jnp.tile(alpha, (1, d // lanes)) * acc_ref[part, :]
+                    + lax.dot_general(
+                        p.astype(vs.dtype), vs, (((1,), (0,)), ((), ())),
+                        preferred_element_type=_F32))
+            folds(fold)
+
+        # whole blocks: [lo, edge) hold a key outside SOME query's window
+        # (from the first with a key inside the first query's), [edge,
+        # diag) neither edge, [diag, hi) a key after the first query
+        diag, hi = (first + 1) // block_k, last // block_k + 1
         if window:
-            seen &= qpos - kpos < window
-        s = jnp.where(seen, s, -jnp.inf)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        # a row none of whose keys lies in this block yet: exp(-inf + inf)
-        safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - safe)
-        alpha = jnp.exp(m - safe)
-        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        acc = alpha * acc + lax.dot_general(
-            p.astype(vs.dtype), vs, (((1,), (0,)), ((), ())),
-            preferred_element_type=_F32)
-        return m_new, l, acc
+            lo = jnp.maximum(first - window + 1, 0) // block_k
+            edge = jnp.clip(jnp.maximum(last - window + block_k, 0)
+                            // block_k, lo, diag)
+        else:
+            lo = edge = 0
+        # ONE loop over the masked blocks, diagonal then edge (a loop is a
+        # trace of the folds, and a program holds a kernel a layer)
+        lax.fori_loop(
+            0, hi - diag + edge - lo,
+            lambda i, _: block(jnp.where(i < hi - diag, diag + i,
+                                         lo + i - (hi - diag)), True), None)
+        lax.fori_loop(edge, diag, lambda j, _: block(j, False), None)
 
-    # key blocks from the first that holds a key inside the FIRST query's
-    # window to the one that holds the LAST query's own key
-    lo = jnp.maximum(first - window + 1, 0) // block_k if window else 0
-    hi = (first + block_q - 1) // block_k + 1
-    m, l, acc = lax.fori_loop(
-        lo, hi, body, (jnp.full((rows, 1), -jnp.inf, _F32),
-                       jnp.zeros((rows, 1), _F32),
-                       jnp.zeros((rows, d), _F32)))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).reshape(
-        groups, block_q, d).astype(o_ref.dtype)
+        def normalize(c):
+            part = pl.ds(pl.multiple_of(c * rows, rows), rows)
+            l = jnp.sum(l_ref[part, :], axis=-1, keepdims=True)
+            o_ref[0, 0, pl.ds(c * heads, heads)] = (
+                acc_ref[part, :] / l).reshape(heads, block_q, d).astype(
+                    o_ref.dtype)
+        folds(normalize)
 
 
-@register_op("windowed_prefill_attention", inputs=["Q", "K", "V"],
-             outputs=["Out"], grad=None)
+@functools.partial(jax.jit, static_argnames=(
+    "window", "scale", "block_q", "block_k", "fold_rows", "interpret"))
+def _prefill_attention(q, k, v, lengths, *, window, scale, block_q, block_k,
+                       fold_rows, interpret):
+    """Q [B, Hkv, G, T, D] against K, V [B, Hkv, T, D], T whole blocks.  A
+    function of its own under the program's trace (as `_write_columns`):
+    the layers of one kind, and every pass that traces a program, share
+    ONE trace and ONE lowering of the kernel a bucket."""
+    b, hkv, groups, t, d = q.shape
+    # the heads of a block a turn of the key loop folds at a time
+    heads = max(h for h in range(1, groups + 1)
+                if groups % h == 0 and (h == 1 or h * block_q <= fold_rows))
+    kernel = functools.partial(_prefill_kernel, block_q=block_q,
+                               block_k=block_k, heads=heads, window=window,
+                               scale=scale)
+    whole = pl.BlockSpec((1, 1, t, d), lambda bi, hi, qi, _: (bi, hi, 0, 0))
+    # a skipped block's queries are not fetched: the last valid block's
+    # tile stays where it is
+    queries = pl.BlockSpec(
+        (1, 1, groups, block_q, d), lambda bi, hi, qi, lens: (
+            bi, hi, 0, jnp.minimum(qi, jnp.maximum(lens[bi] - 1, 0)
+                                   // block_q), 0))
+    out = pl.BlockSpec((1, 1, groups, block_q, d),
+                       lambda bi, hi, qi, _: (bi, hi, 0, qi, 0))
+    # one value a row is kept across `lanes` lanes: a whole register's at
+    # the served shapes, so that no turn broadcasts or reduces across them
+    lanes = math.gcd(128, block_k, d)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, hkv, t // block_q),
+            in_specs=[queries, whole, whole], out_specs=out,
+            scratch_shapes=[pltpu.VMEM((groups * block_q, lanes), _F32),
+                            pltpu.VMEM((groups * block_q, lanes), _F32),
+                            pltpu.VMEM((groups * block_q, d), _F32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name="windowed_prefill_attention",
+    )(lengths, q, k, v)
+
+
+@register_op("windowed_prefill_attention",
+             inputs=["Q", "K", "V", "Lengths?!"], outputs=["Out"], grad=None)
 def windowed_prefill_attention(ins, attrs, ctx):
     """Causal grouped-query attention of a whole prompt among its own
     tokens, over blocks of queries: `[T, T]` scores never exist for all
     heads, and a window layer reads only the key blocks inside the window,
     so its work is ``sum_i min(i, W)``, not ``T^2 / 2``.
 
-    Q [B, Hq, T, D]; K, V [B, Hkv, T, D] (positions already applied).
+    Q [B, Hq, T, D]; K, V [B, Hkv, T, D] (positions already applied);
+    Lengths [B] (optional) the valid positions a row, the pads after them.
     attrs ``scale`` (default ``D^-0.5``), ``window`` (0: every earlier key
-    is seen).  Pads need no lengths: they lie after every valid token and
-    causality hides them; their own rows are nobody's to read (the op
-    itself pads T to whole sublanes that way).  Forward only."""
+    is seen).  A pad's KEY needs no lengths: it lies after every valid
+    token and causality hides it.  A pad's own ROW is nobody's to read —
+    the ring a window layer keeps (`kv_ring_pack`) and the decode step
+    read by length, the head takes the last valid row, the experts route
+    valid positions only, and no valid query sees a pad — so with Lengths
+    a block of queries that lies wholly past its row's length is not
+    computed and comes back as zeros (the block the length falls in is
+    computed whole).  Without Lengths every position is valid and every
+    row is computed (the op itself pads T to whole sublanes with rows it
+    then drops).  Forward only."""
     q, k, v = lax.optimization_barrier((ins["Q"], ins["K"], ins["V"]))
     b, hq, t, d = q.shape
     hkv = k.shape[1]
     groups = hq // hkv
     window = int(attrs.get("window", 0) or 0)
     scale = float(attrs.get("scale", d ** -0.5))
+    lengths = ins.get("Lengths")
+    lengths = jnp.full((b,), t, jnp.int32) if lengths is None \
+        else lengths.astype(jnp.int32).reshape(b)
     real, pad = t, -t % 8
     if pad:     # whole sublanes: trailing rows, which causality hides
         q, k, v = (jnp.pad(y, ((0, 0), (0, 0), (0, pad), (0, 0)))
                    for y in (q, k, v))
         t += pad
     block_q, block_k = _fit_block(t, _BLOCK_Q), _fit_block(t, _BLOCK_K)
-    kernel = functools.partial(_prefill_kernel, block_q=block_q,
-                               block_k=block_k, window=window, scale=scale)
-    whole = pl.BlockSpec((1, 1, t, d), lambda bi, hi, qi: (bi, hi, 0, 0))
-    tile = pl.BlockSpec((1, 1, groups, block_q, d),
-                        lambda bi, hi, qi: (bi, hi, 0, qi, 0))
-    out = pl.pallas_call(
-        kernel, grid=(b, hkv, t // block_q), in_specs=[tile, whole, whole],
-        out_specs=tile,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, groups, t, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=_interpret(), name="windowed_prefill_attention",
-    )(q.reshape(b, hkv, groups, t, d), k, v)
+    out = _prefill_attention(
+        q.reshape(b, hkv, groups, t, d), k, v, lengths, window=window,
+        scale=scale, block_q=block_q, block_k=block_k, fold_rows=_FOLD_ROWS,
+        interpret=_interpret())
     out = out.reshape(b, hq, t, d)[:, :, :real]
     return {"Out": lax.optimization_barrier(out)}
 

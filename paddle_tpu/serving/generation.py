@@ -987,6 +987,7 @@ class ContinuousBatchingEngine:
         if counted:
             span.set(**self._count_step(got[0][1:]))
         metrics.count("gen.prefill_tokens", p)
+        metrics.count("gen.prefill_pad_tokens", pp - p)
         metrics.count("gen.logits_rows_fetched" if samples
                       else "gen.sampled_on_device")
         with RecordEvent("engine/sample"):

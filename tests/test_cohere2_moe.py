@@ -147,17 +147,83 @@ def test_rotary_turns_interleaved_pairs_by_the_position(t):
     np.testing.assert_allclose(got[:, :, 0], x[:, :, 0], atol=1e-6)
 
 
-@pytest.mark.parametrize("window", [0, W, 3])
-@pytest.mark.parametrize("t", [8, 21, 64])
-def test_prompt_attention_is_the_masked_softmax_window_or_none(t, window):
+def _prompt_attention(q, k, v, window, lengths=None):
+    """`lengths`: one for the only row, or one a row."""
+    ins = {"Q": q, "K": k, "V": v}
+    if lengths is not None:
+        ins["Lengths"] = np.asarray(lengths, np.int32).reshape(-1)
+    return np.asarray(run_kernel("windowed_prefill_attention", ins,
+                                 {"window": window}, CTX)["Out"])
+
+
+def _assert_prompt_attention(got, q, k, v, window, length, block_q):
+    """Rows of the blocks of queries that begin before `length` are the
+    plain attention's (the block the length falls in whole), rows of the
+    blocks past it zeros; every row is finite."""
+    t = q.shape[2]
+    computed = t if length is None else min(-(-length // block_q) * block_q,
+                                            t)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(
+        got[:, :, :computed], _plain_attention(q, k, v, window)[
+            :, :, :computed], rtol=0, atol=2e-6)
+    assert not got[:, :, computed:].any()
+
+
+@pytest.mark.parametrize("window", [0, W, 3, 512])
+@pytest.mark.parametrize("t,length", [
+    (8, None), (21, None), (64, None), (384, None),     # every row valid
+    # 384 tokens are three blocks of 128 queries: a length inside the
+    # first, on a block's boundary, one past it, and the whole bucket
+    (384, 5), (384, 128), (384, 129), (384, 256), (384, 384), (21, 9)])
+def test_prompt_attention_is_the_masked_softmax_window_or_none(
+        t, length, window):
+    """window 0 (none), shorter than the prompt, and at least as long;
+    with `Lengths`, blocks of queries past the length are left out."""
+    from paddle_tpu.ops.kernels import window_attention
     rng = np.random.default_rng(t + window)
     q = rng.normal(size=(1, 4, t, 16)).astype(np.float32)
     k = rng.normal(size=(1, 2, t, 16)).astype(np.float32)
     v = rng.normal(size=(1, 2, t, 16)).astype(np.float32)
-    got = np.asarray(run_kernel("windowed_prefill_attention", {
-        "Q": q, "K": k, "V": v}, {"window": window}, CTX)["Out"])
-    np.testing.assert_allclose(got, _plain_attention(q, k, v, window),
-                               rtol=0, atol=2e-6)
+    got = _prompt_attention(q, k, v, window, length)
+    block_q = window_attention._fit_block(t + -t % 8,
+                                          window_attention._BLOCK_Q)
+    assert block_q == min(128, t + -t % 8)
+    _assert_prompt_attention(got, q, k, v, window, length, block_q)
+    if length is not None:      # without Lengths every row is computed
+        np.testing.assert_array_equal(
+            got[:, :, :length],
+            _prompt_attention(q, k, v, window)[:, :, :length])
+
+
+@pytest.mark.parametrize("fold_rows", [16, 8])
+@pytest.mark.parametrize("length", [None, 100, 41])
+@pytest.mark.parametrize("window", [0, 48, 40, 12])
+def test_prompt_attention_drives_every_phase_of_its_key_loop(
+        window, length, fold_rows, monkeypatch):
+    """Blocks of 8 queries against blocks of 16 keys over 128 tokens: a
+    late block of queries of a window-48 layer reads a far-edge block
+    (masked by the window alone), two interior blocks (no mask) and its
+    diagonal block (causal); a window of 40 is not whole blocks (two edge
+    blocks), one of 12 reaches into the diagonal block (both masks there,
+    no interior); the full layer has interior blocks and the diagonal.
+    The two heads of a kv head are folded together or (`fold_rows` 8) one
+    at a time.  All equal the plain attention, with lengths (on no
+    boundary) and without."""
+    from paddle_tpu.ops.kernels import window_attention
+    monkeypatch.setattr(window_attention, "_BLOCK_Q", 8)
+    monkeypatch.setattr(window_attention, "_BLOCK_K", 16)
+    monkeypatch.setattr(window_attention, "_FOLD_ROWS", fold_rows)
+    t = 128
+    rng = np.random.default_rng(window + 7)
+    q = rng.normal(size=(2, 4, t, 16)).astype(np.float32)
+    k = rng.normal(size=(2, 2, t, 16)).astype(np.float32)
+    v = rng.normal(size=(2, 2, t, 16)).astype(np.float32)
+    lengths = length and [length, length // 2]  # the second shorter still
+    got = _prompt_attention(q, k, v, window, lengths)
+    for row, n in enumerate(lengths or [None, None]):
+        at = slice(row, row + 1)
+        _assert_prompt_attention(got[at], q[at], k[at], v[at], window, n, 8)
 
 
 @pytest.mark.parametrize("p", [1, 5, 8, 9, 13, 27, 32])
@@ -503,6 +569,35 @@ def test_prefill_then_decode_through_the_cache_matches_the_full_pass(p):
                                           before[name][:, [0, 2], :, 1:])
 
 
+@pytest.mark.parametrize("p", [5, 13, 27])
+def test_a_prompts_pads_are_nobodys_to_read(p, monkeypatch):
+    """`prefill_step` on a prompt padded to twice its bucket, blocks of 8
+    queries, so that the attention layers leave whole blocks of pads out
+    (zeros where the smaller bucket computed rows: the op's own test):
+    the logits row, the rings, the full layer's valid columns and the
+    experts' counts are those of the prompt padded to its own bucket."""
+    from paddle_tpu.ops.kernels import window_attention
+    monkeypatch.setattr(window_attention, "_BLOCK_Q", 8)
+    monkeypatch.setattr(window_attention, "_BLOCK_K", 16)
+    with dg.guard():
+        m = _model(4, held_experts=4, first_held=4)
+        ids = np.random.default_rng(5).integers(0, 126, 40)[:p]
+        bucket = 16 if p <= 16 else 32
+        near = _prefill(m, ids, bucket)
+        far = _prefill(m, ids, 2 * bucket)
+        spread = float(near[0].std())
+        _assert_logits(far[0], near[0], spread)
+        for a, b in zip(near[1:3], far[1:3]):           # the rings
+            at = slice(0, min(p, W))
+            np.testing.assert_allclose(b[..., at, :], a[..., at, :],
+                                       rtol=0, atol=1e-5)
+        for a, b in zip(near[3:5], far[3:5]):           # every column
+            assert b.shape[3] == 2 * a.shape[3]
+            np.testing.assert_allclose(b[..., :p, :], a[..., :p, :],
+                                       rtol=0, atol=1e-5)
+        np.testing.assert_array_equal(far[5], near[5])
+
+
 # -- the cache description: several kv groups ---------------------------------
 def test_a_description_states_retain_on_every_kv_group_or_none():
     spec = cohere2_moe_tiny().config.cache_spec()
@@ -652,6 +747,13 @@ def test_decode_program_donates_every_kv_array_and_writes_in_place():
         assert windows == [W, W, W, 0] and entries == [0, 1, 2, 0]
         ops = [op.type for op in pre.program.global_block().ops]
         assert ops.count("windowed_prefill_attention") == 4
+        # each is given the prompt's length: the feed the scan, the rings
+        # and the experts read
+        lengths = {tuple(op.inputs["Lengths"])
+                   for op in pre.program.global_block().ops
+                   if op.type in ("windowed_prefill_attention",
+                                  "kv_ring_pack", "moe_grouped_experts")}
+        assert len(lengths) == 1 and len(lengths.pop()) == 1
         assert ops.count("kv_ring_pack") == 2 * 3
         assert ops.count("layer_norm") == 5 and ops.count("moe_grouped_"
                                                           "experts") == 4
@@ -740,6 +842,10 @@ def test_engine_serves_through_compiled_steps_with_the_kv_on_the_device():
     assert stats.get("serving.gen.logits_rows_fetched", 0) == 0
     prefills = [e for e in events if e.name == "engine/prefill"]
     assert sorted(e.fields["prompt"] for e in prefills) == sorted(lengths)
+    # the rows of the prompt programs that were pads, counted
+    assert stats["serving.gen.prefill_tokens"] == sum(lengths)
+    assert stats["serving.gen.prefill_pad_tokens"] == sum(
+        e.fields["bucket"] - e.fields["prompt"] for e in prefills) > 0
     assert {e.fields["bucket"] for e in prefills} == {16, 32}
     assert all("moe_pairs" in e.fields for e in prefills)
 
