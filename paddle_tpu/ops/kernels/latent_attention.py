@@ -19,7 +19,8 @@ caller):
 - `latent_prefill_attention`: a prompt's causal attention in the expanded
   form, queries and keys ``nope + rope`` wide, values ``v`` wide: the
   blocked Pallas kernel of `window_attention` (``window`` 0), its V width
-  its own, blocks of queries past the prompt's length not computed.
+  its own, blocks of queries past the prompt's length not computed, at the
+  block shape `prompt_blocks` gives one query head a kv head.
 - `latent_decode_attention`: one token a row in the absorbed form over the
   cache group's whole array: the new row written where the array lies,
   then ONE Pallas kernel a layer, grid (rows, column blocks), in which the
@@ -45,8 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..attention import _fit_block, _interpret
 from ..registry import register_op
-from .window_attention import (_BLOCK_K, _BLOCK_Q, _FOLD_ROWS, _VMEM_LIMIT,
-                               _prefill_attention)
+from .window_attention import _VMEM_LIMIT, _prefill_attention, prompt_blocks
 
 __all__ = ["mla", "latent_prefill_attention", "latent_decode_attention",
            "decode_block", "mla_attrs", "DECODE_BLOCK"]
@@ -175,11 +175,11 @@ def latent_prefill_attention(ins, attrs, ctx):
         q, k, v = (jnp.pad(y, ((0, 0), (0, 0), (0, pad), (0, 0)))
                    for y in (q, k, v))
         t += pad
+    block_q, block_k, fold_rows = prompt_blocks(t, 1)
     out = _prefill_attention(
         q.reshape(b, h, 1, t, d), k, v, lengths, window=0, scale=scale,
-        block_q=_fit_block(t, _BLOCK_Q), block_k=_fit_block(t, _BLOCK_K),
-        fold_rows=_FOLD_ROWS, interpret=_interpret(),
-        name="latent_prefill_attention")
+        block_q=block_q, block_k=block_k, fold_rows=fold_rows,
+        interpret=_interpret(), name="latent_prefill_attention")
     out = out.reshape(b, h, t, dv)[:, :, :real]
     return {"Out": lax.optimization_barrier(out)}
 

@@ -40,6 +40,11 @@ _F32 = jnp.float32
 # merged [heads x queries] axis a turn folds at a time (timed on the v5e at
 # [1, 8, 16, T, 128], PERF.md PR 36: 128 x 512 x 256)
 _BLOCK_Q, _BLOCK_K, _FOLD_ROWS = 128, 512, 256
+# rows of queries a grid step takes where ONE query head reads a kv head
+# (latent attention's expanded form): a turn of 128 rows loads a key tile
+# into the MXU for too few rows, so a block is 512 queries of the head, two
+# folds of 256 (timed at [1, 128, T, 192 | 128], PERF.md PR 39)
+_HEAD_BLOCK_Q = 512
 # columns of the cache a turn of the decode step's loop reads, and the
 # fewest a bounded read takes: a lane tile (an array of 64-wide heads lies
 # with its columns along the lanes; for a narrower slice XLA:TPU turns the
@@ -108,7 +113,7 @@ def rotary_embedding(ins, attrs, ctx):
 
 
 def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                    acc_ref, *, block_q, block_k, heads, window, scale):
+                    acc_ref, *, block_q, block_k, rows, window, scale):
     """One block of `block_q` queries of the `G` heads that share a kv head
     against the key blocks it can see: online softmax over them.
 
@@ -122,16 +127,17 @@ def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     EDGE blocks.  The INTERIOR blocks, every key of which every query sees,
     take no mask at all.
 
-    A turn of a loop folds the block's `G * block_q` rows `heads` heads at
-    a time, the statistics (`m_ref` the running maximum, `l_ref` the
-    running sum kept as one partial sum a lane, `acc_ref`) in scratch
-    between turns: the scores of all the rows at once ([2,048, 512] float32
-    at the served shapes) are many times the vector registers, and a loop
-    over them is bound by their spills on the one store slot a bundle has,
-    not by the matmuls or the masks (PERF.md, PR 36)."""
+    A turn of a loop folds the block's `G * block_q` rows `rows` at a time,
+    the statistics (`m_ref` the running maximum, `l_ref` the running sum
+    kept as one partial sum a lane, `acc_ref`) in scratch between turns:
+    the scores of all the rows at once ([2,048, 512] float32 at the served
+    shapes) are many times the vector registers, and a loop over them is
+    bound by their spills on the one store slot a bundle has, not by the
+    matmuls or the masks (PERF.md, PR 36).  A fold is whole heads (`rows` a
+    multiple of `block_q`) or a slice of one head's queries."""
     groups, d, dv = q_ref.shape[2], q_ref.shape[4], v_ref.shape[3]
     lanes = m_ref.shape[1]
-    rows = heads * block_q
+    heads = rows // block_q             # 0: a fold is a slice of one head
     first = pl.program_id(2) * block_q
     last = first + (block_q - 1)
     valid = first < len_ref[pl.program_id(0)]
@@ -140,8 +146,17 @@ def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         """`fold(c)` for each of the block's folds.  Written out, so that
         one fold's matmuls overlap the next one's softmax (rolled, a turn
         has 40% more bundles), but traced once."""
-        lax.fori_loop(0, groups // heads, lambda c, _: fold(c), None,
-                      unroll=True)
+        lax.fori_loop(0, groups * block_q // rows, lambda c, _: fold(c),
+                      None, unroll=True)
+
+    def rows_of(c):
+        """Where fold `c`'s rows lie in the tiles of queries and outputs,
+        and the offset of its first query in the block."""
+        if heads:
+            return (pl.ds(c * heads, heads),), 0
+        start = c * rows
+        offset = pl.multiple_of(lax.rem(start, block_q), rows)
+        return (lax.div(start, block_q), pl.ds(offset, rows)), offset
 
     @pl.when(jnp.logical_not(valid))
     def _():
@@ -152,8 +167,9 @@ def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, _F32)
         l_ref[...] = jnp.zeros(l_ref.shape, _F32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
-        # a row of the merged [heads * block_q] axis is query first + row %
-        # block_q; a mask compares a column of rows with a row of columns
+        # a row of the merged [G * block_q] axis is query first + row %
+        # block_q (a fold that is a slice of one head adds its offset); a
+        # mask compares a column of rows with a row of columns
         qpos = first + lax.rem(
             lax.broadcasted_iota(jnp.int32, (rows, 1), 0),
             jnp.int32(block_q))
@@ -165,11 +181,13 @@ def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
             def fold(c):
                 part = pl.ds(pl.multiple_of(c * rows, rows), rows)
-                q = q_ref[0, 0, pl.ds(c * heads, heads)].reshape(rows, d)
+                tile, offset = rows_of(c)
+                q = q_ref[(0, 0) + tile].reshape(rows, d)
                 s = lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
                                     preferred_element_type=_F32) * scale
                 if masked:
-                    rel = qpos - j * block_k        # the row's own column
+                    # the row's own column
+                    rel = (qpos if heads else qpos + offset) - j * block_k
                     seen = col <= rel
                     if window:
                         seen &= col > rel - window
@@ -212,13 +230,22 @@ def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                                          lo + i - (hi - diag)), True), None)
         lax.fori_loop(edge, diag, lambda j, _: block(j, False), None)
 
+        shape = (heads, block_q, dv) if heads else (rows, dv)
+
         def normalize(c):
             part = pl.ds(pl.multiple_of(c * rows, rows), rows)
             l = jnp.sum(l_ref[part, :], axis=-1, keepdims=True)
-            o_ref[0, 0, pl.ds(c * heads, heads)] = (
-                acc_ref[part, :] / l).reshape(heads, block_q, dv).astype(
-                    o_ref.dtype)
+            o_ref[(0, 0) + rows_of(c)[0]] = (acc_ref[part, :] / l).reshape(
+                shape).astype(o_ref.dtype)
         folds(normalize)
+
+
+def prompt_blocks(t, groups):
+    """The prompt kernel's `(block_q, block_k, fold_rows)` over `t`
+    positions (whole sublanes) for `groups` query heads a kv head: a key
+    tile serves the block's `groups * block_q` rows."""
+    block_q = _HEAD_BLOCK_Q if groups == 1 else _BLOCK_Q
+    return _fit_block(t, block_q), _fit_block(t, _BLOCK_K), _FOLD_ROWS
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -235,11 +262,13 @@ def _prefill_attention(q, k, v, lengths, *, window, scale, block_q, block_k,
     a bucket."""
     b, hkv, groups, t, d = q.shape
     dv = v.shape[-1]
-    # the heads of a block a turn of the key loop folds at a time
-    heads = max(h for h in range(1, groups + 1)
-                if groups % h == 0 and (h == 1 or h * block_q <= fold_rows))
+    # the rows of a block a turn of the key loop folds at a time: whole
+    # heads, or a slice of one head's queries where those are more
+    rows = _fit_block(block_q, fold_rows) if block_q > fold_rows \
+        else block_q * max(h for h in range(1, groups + 1) if groups % h == 0
+                           and (h == 1 or h * block_q <= fold_rows))
     kernel = functools.partial(_prefill_kernel, block_q=block_q,
-                               block_k=block_k, heads=heads, window=window,
+                               block_k=block_k, rows=rows, window=window,
                                scale=scale)
     whole, whole_v = (pl.BlockSpec((1, 1, t, n), lambda bi, hi, qi, _: (
         bi, hi, 0, 0)) for n in (d, dv))
@@ -305,10 +334,10 @@ def windowed_prefill_attention(ins, attrs, ctx):
         q, k, v = (jnp.pad(y, ((0, 0), (0, 0), (0, pad), (0, 0)))
                    for y in (q, k, v))
         t += pad
-    block_q, block_k = _fit_block(t, _BLOCK_Q), _fit_block(t, _BLOCK_K)
+    block_q, block_k, fold_rows = prompt_blocks(t, groups)
     out = _prefill_attention(
         q.reshape(b, hkv, groups, t, d), k, v, lengths, window=window,
-        scale=scale, block_q=block_q, block_k=block_k, fold_rows=_FOLD_ROWS,
+        scale=scale, block_q=block_q, block_k=block_k, fold_rows=fold_rows,
         interpret=_interpret())
     out = out.reshape(b, hq, t, d)[:, :, :real]
     return {"Out": lax.optimization_barrier(out)}

@@ -239,26 +239,53 @@ def test_the_mla_ops_absorbed_query_scores_as_the_expanded_keys_do():
                                rtol=1e-6, atol=1e-6)
 
 
-def test_latent_prefill_attention_with_lengths_is_a_masked_softmax():
-    """q / k 24 wide, v 16: the kernel (interpreted) against a dense causal
-    softmax, rows inside the length; blocks of queries past it are zeros."""
-    rng = np.random.default_rng(3)
-    b, h, t, d, dv = 1, 2, 256, 24, 16
+@pytest.mark.parametrize("t,length", [
+    (256, 100),             # one block of 256 queries, one fold
+    # blocks of 512 queries, two folds of 256 of the one head each: every
+    # row valid, a length inside a block's first fold, inside its second,
+    # and on a block's edge
+    (1024, None), (1024, 300), (1024, 700), (1024, 512)])
+def test_latent_prefill_attention_with_lengths_is_a_masked_softmax(t, length):
+    """q / k 24 wide, v 16: the kernel (interpreted, at the op's own block
+    shape) against a dense causal softmax, rows inside the length; blocks
+    of queries past it are zeros (the block the length falls in is
+    computed whole)."""
+    from paddle_tpu.ops.kernels.window_attention import prompt_blocks
+    rng = np.random.default_rng(t + (length or 0))
+    b, h, d, dv = 1, 2, 24, 16
     q, k = (rng.normal(size=(b, h, t, d)).astype(np.float32)
             for _ in range(2))
     v = rng.normal(size=(b, h, t, dv)).astype(np.float32)
+    ins = {"Q": q, "K": k, "V": v}
+    if length is not None:
+        ins["Lengths"] = np.asarray([length], np.int32)
     kernel = get_op_info("latent_prefill_attention").kernel
-    got = np.asarray(kernel({"Q": q, "K": k, "V": v,
-                             "Lengths": np.asarray([100], np.int32)},
-                            {"scale": d ** -0.5}, OpContext(seed=0))["Out"])
+    got = np.asarray(kernel(ins, {"scale": d ** -0.5},
+                            OpContext(seed=0))["Out"])
     assert got.shape == (b, h, t, dv)
     s = np.einsum("bhid,bhjd->bhij", q, k) * d ** -0.5
     s = np.where(np.tril(np.ones((t, t), bool)), s, -np.inf)
     p = np.exp(s - s.max(-1, keepdims=True))
     want = np.einsum("bhij,bhjd->bhid", p / p.sum(-1, keepdims=True), v)
-    np.testing.assert_allclose(got[:, :, :100], want[:, :, :100],
+    block_q = prompt_blocks(t, 1)[0]
+    computed = t if length is None else -(-length // block_q) * block_q
+    np.testing.assert_allclose(got[:, :, :computed], want[:, :, :computed],
                                rtol=1e-4, atol=1e-5)
-    assert not got[:, :, 128:].any()    # the block past the length
+    assert not got[:, :, computed:].any()    # the blocks past the length
+
+
+@pytest.mark.parametrize("t,groups,want", [
+    # grouped-query attention (command-a-plus: 16 query heads a kv head)
+    # keeps 128 queries a head against 512 keys, 256 rows a fold
+    (8192, 16, (128, 512, 256)), (1024, 16, (128, 512, 256)),
+    (64, 16, (64, 64, 256)),
+    # one query head a kv head: 512 queries, two folds of 256 a key tile
+    (16384, 1, (512, 512, 256)), (1024, 1, (512, 512, 256)),
+    (256, 1, (256, 256, 256))])
+def test_the_prompt_kernels_block_shape_follows_the_heads_a_kv_head(
+        t, groups, want):
+    from paddle_tpu.ops.kernels.window_attention import prompt_blocks
+    assert prompt_blocks(t, groups) == want
 
 
 # -- the expert share ------------------------------------------------------------

@@ -481,3 +481,40 @@ def test_pangu_decode_program_reads_and_writes_the_latent_cache_in_place(
     assert len([ln for ln in text.splitlines()
                 if 'custom_call_target="tpu_custom_call"' in ln
                 and "latent_decode_attention" in ln]) == 5
+
+
+@pytest.mark.parametrize("t", [1024, 16384])
+def test_latent_prompt_kernel_compiles_at_its_own_block_shape(
+        one_chip, no_compile_cache, monkeypatch, t):
+    """`latent_prefill_attention` over one layer of the published share's
+    prompt, [1, 128, T, 192 | 128] bfloat16, for XLA:TPU + Mosaic at the
+    block shape one query head a kv head takes (512 queries of the head a
+    grid step, two folds of 256 against each 512-key tile; each head's
+    whole K and V in VMEM at 16k): ONE kernel, no scores in HBM."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.kernels import latent_attention, window_attention
+    from paddle_tpu.ops.registry import OpContext
+    monkeypatch.setattr(latent_attention, "_interpret", lambda: False)
+    assert window_attention.prompt_blocks(t, 1) == (512, 512, 256)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attend(q, k, v, lengths):
+        return latent_attention.latent_prefill_attention(
+            {"Q": q, "K": k, "V": v, "Lengths": lengths},
+            {"scale": 192 ** -0.5}, OpContext(seed=0))["Out"]
+
+    with jax.enable_x64(False):
+        compiled = jax.jit(attend).lower(
+            sds((1, 128, t, 192)), sds((1, 128, t, 192)),
+            sds((1, 128, t, 128)), sds((1,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len([ln for ln in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in ln
+                and "latent_prefill_attention" in ln]) == 1
+    # at most the operands again, re-tiled for the kernel: scores of 128
+    # heads at [T, T] would be 137 GB at 16k
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= mem.argument_size_in_bytes
