@@ -25,3 +25,6 @@ from .cohere2_moe import (  # noqa: F401
 from .pangu_ultra_moe import (  # noqa: F401
     PanguUltraMoeConfig, PanguUltraMoeModel, pangu_ultra_moe_tiny,
 )
+from .lfm2_moe import (  # noqa: F401
+    Lfm2MoeConfig, Lfm2MoeModel, lfm2_moe_tiny,
+)

@@ -1,17 +1,19 @@
 """A hybrid decoder from a per-layer block description: each layer has a
-mixer (Mamba-2 state-space, grouped-query attention, multi-head latent
-attention, or none) and a feed-forward part (a gated MLP, routed experts,
-or none), each applied as ``h = h + residual_multiplier * f(norm(h))`` one
-after the other, or, in the `parallel` block form, both from ONE norm of
-the same `h`, or, in the `sandwich` form, each sublayer's output normed
-again before it is added; the norm is RMSNorm or a mean-subtracting
-LayerNorm (scale only); an attention layer may state a `window` and
-rotary positions (`AttentionSpec`); a latent layer states its ranks and
-head dims (`LatentSpec`, `config.latent_spec`); the experts are
-`latent_relu2` (a latent space, ``relu^2``, a selection bias, one shared
-expert) or `gated_silu` (full width, ``silu(g) * u``, `n_shared` shared
-experts averaged, a selection bias where `router_bias` says); an
-embedding multiplier, a logits divisor, and a tied or untied head.  `cache_spec`,
+mixer (Mamba-2 state-space, a gated short convolution, grouped-query
+attention, multi-head latent attention, or none) and a feed-forward part
+(a gated MLP, routed experts, or none), each applied as ``h = h +
+residual_multiplier * f(norm(h))`` one after the other, or, in the
+`parallel` block form, both from ONE norm of the same `h`, or, in the
+`sandwich` form, each sublayer's output normed again before it is added;
+the norm is RMSNorm or a mean-subtracting LayerNorm (scale only); an
+attention layer may state a `window`, rotary positions in interleaved
+pairs or in halves, and a norm on each head's q and k (`AttentionSpec`); a
+latent layer states its ranks and head dims (`LatentSpec`,
+`config.latent_spec`); the experts are `latent_relu2` (a latent space,
+``relu^2``, a selection bias, one shared expert) or `gated_silu` (full
+width, ``silu(g) * u``, `n_shared` shared experts averaged — none where
+it is 0 — a selection bias where `router_bias` says); an embedding
+multiplier, a logits divisor, and a tied or untied head.  `cache_spec`,
 `param_shapes`, the full `forward` and the two steps of the serving
 contract are emitted ONCE from that description; a model family is a
 config class that gives the description (`models/granite_hybrid.py`,
@@ -23,7 +25,9 @@ not ask for gradients and no tape is ever kept.  The model states its
 cache (`cache_spec`): one `kv` group per kind of retention of its attention
 layers (`retain`: a window's columns in a ring, or `"all"`), one `latent`
 group for the latent attention layers (one row a token and layer, shared
-by the heads) and one `state` group for the Mamba layers — `serving.PagedKVPool`, `static.page_budget`
+by the heads) and a `state` group each for the Mamba layers and for the
+short-convolution layers (their last inputs) — `serving.PagedKVPool`,
+`static.page_budget`
 and the engine size themselves from it.  ALL of it lives on the device, a
 row a slot, and rides the step contract: two cache-aware entry points
 (ids, per-row lengths, cache in; last-row logits, cache out),
@@ -31,13 +35,15 @@ row a slot, and rides the step contract: two cache-aware entry points
 result, so `jit.to_static` turns each into one compiled program per shape
 bucket (`serving.step_program`); the decode step is given the KV and state
 arrays whole and every layer writes its own entry where the array lies.
-The one fork is read from the description (`kv_ring`): with a window layer
-the attention ops are the blocked ones (`ops/kernels/window_attention.py`)
-and the decode step one program that reads what its rows hold; without,
-a prompt goes through `gqa_attention` and the decode step reads its
-caller's static bound on the columns (`decode_step(columns=)`).  A model
-with routed experts also counts what its steps routed (`step_counters`):
-one int32 vector a call, after the state in the result tuple.
+Two forks are read from the description and the shapes.  `kv_ring`: with
+a window layer the decode step is one program that reads what its rows
+hold; without, it reads its caller's static bound on the columns
+(`decode_step(columns=)`).  `blocked_prompt(T)` (a window layer, or a
+prompt bucket past `GQA_PROMPT_MAX` tokens): its attention is the blocked
+kernel (`ops/kernels/window_attention.py`); else `gqa_attention`, whose
+`[T, T]` scores a short prompt affords.  A model with routed experts also
+counts what its steps routed (`step_counters`): one int32 vector a call,
+after the state in the result tuple.
 """
 from __future__ import annotations
 
@@ -61,21 +67,24 @@ from ..tensor.math import add, multiply, scale
 __all__ = ["LayerSpec", "AttentionSpec", "LatentSpec", "HybridDecoderConfig",
            "HybridDecoder", "MOE_COUNTERS"]
 
-# one layer: `mixer` "mamba" | "attention" | "latent" | None; `ffn` "mlp" |
-# "experts" | None
+# one layer: `mixer` "mamba" | "short_conv" | "attention" | "latent" |
+# None; `ffn` "mlp" | "experts" | None
 LayerSpec = collections.namedtuple("LayerSpec", "mixer ffn")
 # what an attention mixer may state beside (`config.attention_specs`, by
-# layer): `window` (None: every earlier token is seen) and `rotary` (None:
-# no positions, else theta)
-AttentionSpec = collections.namedtuple("AttentionSpec", "window rotary",
-                                       defaults=(None, None))
+# layer): `window` (None: every earlier token is seen), `rotary` (None: no
+# positions, else theta), `rotary_form` ("interleaved": pairs (2i, 2i + 1);
+# "half": dim i with i + D/2) and `qk_norm` (an RMSNorm over each head's
+# dims of q and of k, before positions)
+AttentionSpec = collections.namedtuple(
+    "AttentionSpec", "window rotary rotary_form qk_norm",
+    defaults=(None, None, "interleaved", False))
 # what every latent layer states (`config.latent_spec`): the query's and the
 # keys' low ranks, a head's dims without and with positions, a value's, and
 # the rotary theta of the positional dims
 LatentSpec = collections.namedtuple(
     "LatentSpec", "q_lora_rank kv_lora_rank qk_nope_head_dim "
     "qk_rope_head_dim v_head_dim rope_theta")
-MIXERS, FFNS = ("mamba", "attention", "latent", None), \
+MIXERS, FFNS = ("mamba", "short_conv", "attention", "latent", None), \
     ("mlp", "experts", None)
 # what `moe_grouped_experts` counts a call (ops/kernels/moe.py `STATS`),
 # summed over a step's expert layers
@@ -83,6 +92,9 @@ MOE_COUNTERS = ("moe_routed", "moe_pairs", "moe_touched", "moe_max_load")
 BLOCK_FORMS, NORM_KINDS = ("sequential", "parallel", "sandwich"), \
     ("rms", "layer")
 EXPERT_FORMS = ("latent_relu2", "gated_silu")
+# the longest prompt bucket whose attention is `gqa_attention` where no
+# layer keeps a ring: [B, heads, T, T] float32 scores, 33.5 MB at 32 heads
+GQA_PROMPT_MAX = 512
 
 
 class HybridDecoderConfig:
@@ -91,6 +103,7 @@ class HybridDecoderConfig:
     (a `LayerSpec` a layer), `num_attention_heads`, `num_key_value_heads`,
     `head_dim`, `attention_multiplier` (the score scale), the `mamba_*`
     sizes with `mamba_norm_groups` (groups of the mixer's output norm),
+    `short_conv_width` (the short convolution's taps, its `L_cache`),
     `shared_intermediate_size` (the gated MLP), the `moe_*` /
     `n_routed_experts` / `held_experts` / `first_held` sizes of an expert
     layer, `embedding_multiplier`, `residual_multiplier`, `logits_scaling`,
@@ -107,10 +120,12 @@ class HybridDecoderConfig:
     expert_form = "latent_relu2"    # | "gated_silu"
     router_bias = False             # gated_silu: a selection bias (the
                                     # latent_relu2 router always has one)
-    n_shared_experts = 1            # averaged where there are several
+    n_shared_experts = 1            # averaged where there are several;
+                                    # gated_silu: 0, none
     # a family without Mamba layers states no `mamba_*` sizes
     mamba_n_heads = mamba_d_head = mamba_d_state = mamba_n_groups = 0
     mamba_expand = mamba_d_conv = 0
+    short_conv_width = 0
     shared_intermediate_size = 0
 
     def _check(self):
@@ -122,6 +137,10 @@ class HybridDecoderConfig:
                 raise ValueError(f"layer {i} has no attention to describe")
         if self.layers_of("latent") and self.latent_spec is None:
             raise ValueError("a latent layer needs the config's latent_spec")
+        if self.layers_of("short_conv") and self.short_conv_width < 2:
+            raise ValueError("a short_conv layer needs short_conv_width >= 2")
+        if self.expert_form == "latent_relu2" and self.n_shared_experts < 1:
+            raise ValueError("latent_relu2 experts have one shared expert")
         if self.block_form not in BLOCK_FORMS \
                 or self.norm_kind not in NORM_KINDS \
                 or self.expert_form not in EXPERT_FORMS:
@@ -183,6 +202,12 @@ class HybridDecoderConfig:
         a bound on the columns)."""
         return any(window for window, _ in self.kv_groups())
 
+    def blocked_prompt(self, tokens):
+        """Whether a prompt of `tokens` (its static bucket) goes through the
+        blocked kernel — with a ring, or past `GQA_PROMPT_MAX` — rather
+        than `gqa_attention`."""
+        return self.kv_ring or tokens > GQA_PROMPT_MAX
+
     @property
     def expert_layers(self):
         return len(self.layers_of("experts"))
@@ -200,7 +225,9 @@ class HybridDecoderConfig:
         the latent layers' `latent` group — ONE array [layers, slots,
         columns, kv_lora_rank + qk_rope_head_dim], a row a token shared by
         every head, read `read_block` rows at a time by the decode kernel —
-        and the Mamba layers' `state` group, of fixed size."""
+        and, of fixed size, the Mamba layers' `state` group and the short
+        convolution layers' (each one's last `short_conv_width - 1`
+        inputs)."""
         groups = [{"kind": "kv", "layers": len(layers),
                    "kv_heads": self.num_key_value_heads,
                    "head_dim": self.head_dim, "dtype": self.dtype,
@@ -221,6 +248,12 @@ class HybridDecoderConfig:
                                self.mamba_d_state]},
                     {"name": "conv", "dtype": self.dtype,
                      "shape": [self.mamba_d_conv - 1, self.conv_dim]}]})
+        if self.layers_of("short_conv"):
+            groups.append({
+                "kind": "state", "layers": len(self.layers_of("short_conv")),
+                "arrays": [{"name": "short_conv", "dtype": self.dtype,
+                            "shape": [self.short_conv_width - 1,
+                                      self.hidden_size]}]})
         return groups
 
     def param_shapes(self):
@@ -256,6 +289,13 @@ class HybridDecoderConfig:
                 out[p + "mixer.wq"] = (h, q)
                 out[p + "mixer.wo"] = (q, h)
                 out[p + "mixer.wk"] = out[p + "mixer.wv"] = (h, kv)
+                if self.attention_spec(i).qk_norm:
+                    out[p + "mixer.q_norm"] = out[p + "mixer.k_norm"] = \
+                        (self.head_dim,)
+            elif spec.mixer == "short_conv":
+                out[p + "mixer.in_proj"] = (h, 3 * h)
+                out[p + "mixer.conv_w"] = (h, self.short_conv_width)
+                out[p + "mixer.out_proj"] = (h, h)
             elif spec.mixer == "mamba":
                 out[p + "mixer.w_in"] = (h, inner + self.conv_dim + heads)
                 out[p + "mixer.w_out"] = (inner, h)
@@ -283,7 +323,8 @@ class HybridDecoderConfig:
                 if self.expert_form == "gated_silu":
                     out[p + "experts.w1"] = (self.held_experts, h, 2 * f)
                     out[p + "experts.w2"] = (self.held_experts, f, h)
-                    out[p + "experts.shared_in"] = (h, 2 * sf)
+                    if sf:
+                        out[p + "experts.shared_in"] = (h, 2 * sf)
                 else:
                     lat = self.moe_latent_size
                     out[p + "experts.w_down"] = (h, lat)
@@ -291,7 +332,8 @@ class HybridDecoderConfig:
                     out[p + "experts.w1"] = (self.held_experts, lat, f)
                     out[p + "experts.w2"] = (self.held_experts, f, lat)
                     out[p + "experts.shared_in"] = (h, sf)
-                out[p + "experts.shared_out"] = (sf, h)
+                if sf:
+                    out[p + "experts.shared_out"] = (sf, h)
         return out
 
     def param_count(self):
@@ -347,7 +389,7 @@ class _Experts(Layer):
     `w1` = [G | U]; `n_shared_experts` shared experts of the same form,
     AVERAGED — kept side by side as one wide expert (`shared_in` = [G_1 ..
     G_n | U_1 .. U_n], `shared_out` the D_j stacked) whose output is
-    divided by n.
+    divided by n — or none, no shared projections at all, where n is 0.
 
     `forward(x, lengths)` -> (out, the op's counts [4] int32, the picks
     [B, T, k])."""
@@ -383,16 +425,16 @@ class _Experts(Layer):
             default_initializer=Uniform(-bound, bound))
         self.w2 = self.create_parameter(
             [held, f, width], default_initializer=Uniform(-bound, bound))
-        if self.gated:      # each shared expert's matrix as its own
+        if not self.gated:
+            self.shared_in = self.create_parameter([h, sf])
+            self.shared_out = self.create_parameter([sf, h])
+        elif sf:            # each shared expert's matrix as its own
             one = cfg.moe_shared_expert_intermediate_size
             bound = math.sqrt(6.0 / (h + one))
             self.shared_in = self.create_parameter(
                 [h, 2 * sf], default_initializer=Uniform(-bound, bound))
             self.shared_out = self.create_parameter(
                 [sf, h], default_initializer=Uniform(-bound, bound))
-        else:
-            self.shared_in = self.create_parameter([h, sf])
-            self.shared_out = self.create_parameter([sf, h])
 
     def forward(self, x, lengths):
         c = self.cfg
@@ -416,6 +458,8 @@ class _Experts(Layer):
             attrs["activation"] = "silu_gated"
         routed, stats = dispatch("moe_grouped_experts", ins, attrs,
                                  ["Out", "Stats"])
+        if self.gated and not c.n_shared_experts:
+            return cast(routed, c.dtype), stats, experts
         if self.gated:
             out = cast(routed, c.dtype)
             g, u = split(matmul(x, self.shared_in), 2, axis=-1)
@@ -430,17 +474,19 @@ class _Experts(Layer):
 
 class _Attention(Layer):
     """Grouped-query attention with the config's own score multiplier;
-    of its layer's description, a `window` and rotary positions (`rotary`:
-    theta), each or neither.  `forward(x, lengths)` runs whole sequences
-    from empty caches — through the blocked kernel where the description
-    has a ring (`kv_ring`; given `lengths` it leaves blocks of pads out),
-    else `gqa_attention` — and returns (out, (k, v)), k, v the tokens' [B,
-    Hkv, T, D]; `step` one token a row over the cache group's arrays."""
+    of its layer's description, a `window`, rotary positions (`rotary`:
+    theta; `rotary_form`) and a norm on each head's q and k (`qk_norm`),
+    each or none.  `forward(x, lengths)` runs whole sequences from empty
+    caches — through the blocked kernel where `blocked_prompt` says so for
+    the prompt's bucket (given `lengths` it leaves blocks of pads out), else
+    `gqa_attention` — and returns (out, (k, v)), k, v the tokens' [B, Hkv,
+    T, D]; `step` one token a row over the cache group's arrays."""
 
     def __init__(self, cfg, spec=AttentionSpec()):
         super().__init__(dtype=cfg.dtype)
         self.cfg = cfg
         self.window, self.rotary = spec.window, spec.rotary
+        self.rotary_form = spec.rotary_form
         h = cfg.hidden_size
         q, kv = (n * cfg.head_dim for n in (cfg.num_attention_heads,
                                             cfg.num_key_value_heads))
@@ -448,6 +494,13 @@ class _Attention(Layer):
         self.wk = self.create_parameter([h, kv])
         self.wv = self.create_parameter([h, kv])
         self.wo = self.create_parameter([q, h])
+        self.qk_norm = bool(spec.qk_norm)
+        if self.qk_norm:
+            ones = Constant(1.0)
+            self.q_norm = self.create_parameter([cfg.head_dim],
+                                                default_initializer=ones)
+            self.k_norm = self.create_parameter([cfg.head_dim],
+                                                default_initializer=ones)
 
     def _heads(self, x, positions=None):
         """q, k, v [B, heads, T, D] of x [B, T, hidden], q and k turned to
@@ -461,10 +514,16 @@ class _Attention(Layer):
         q = heads(matmul(x, self.wq), c.num_attention_heads)
         k = heads(matmul(x, self.wk), c.num_key_value_heads)
         v = heads(matmul(x, self.wv), c.num_key_value_heads)
+        if self.qk_norm:
+            q = _rms_norm(q, self.q_norm, c.rms_norm_eps)
+            k = _rms_norm(k, self.k_norm, c.rms_norm_eps)
         if self.rotary:
             ins = {} if positions is None else {"Positions": positions}
-            q, k = (dispatch("rotary_embedding", dict(ins, X=y),
-                             {"theta": float(self.rotary)}) for y in (q, k))
+            attrs = {"theta": float(self.rotary)}
+            if self.rotary_form != "interleaved":
+                attrs["form"] = self.rotary_form
+            q, k = (dispatch("rotary_embedding", dict(ins, X=y), attrs)
+                    for y in (q, k))
         return q, k, v
 
     def _out(self, ctx):
@@ -478,7 +537,7 @@ class _Attention(Layer):
         c = self.cfg
         q, k, v = self._heads(x)
         attrs = {"scale": c.attention_multiplier}
-        if c.kv_ring:       # blocks of queries, the window's key blocks
+        if c.blocked_prompt(x.shape[1]):    # blocks of queries
             ins = {"Q": q, "K": k, "V": v}
             if lengths is not None:     # blocks of pads are not computed
                 ins["Lengths"] = lengths
@@ -576,6 +635,40 @@ class _LatentAttention(Layer):
                                "Wo": self.wo},
                        self._attrs("out", "absorbed"))
         return out, cache
+
+
+class _ShortConv(Layer):
+    """The gated short-convolution mixer (`short_conv`: ``[B | C | x] = x
+    W_in``, a depthwise causal conv of ``B * x`` over `short_conv_width`
+    taps, ``(C * conv) W_out``; no biases).  `scan(x, lengths)` runs a
+    whole (padded) prompt from an empty tail; `update(x, lengths, tails,
+    index)` one token on the carried tail, entry `index` of the state
+    array `tails` [Lc, B, K-1, hidden] replaced where it lies.  Both return
+    (out, the tail: the last K-1 inputs of the conv)."""
+
+    def __init__(self, cfg):
+        super().__init__(dtype=cfg.dtype)
+        h, k = cfg.hidden_size, cfg.short_conv_width
+        self.in_proj = self.create_parameter([h, 3 * h])
+        # torch's conv1d default, as the Mamba conv's
+        bound = 1.0 / math.sqrt(k)
+        self.conv_w = self.create_parameter(
+            [h, k], default_initializer=Uniform(-bound, bound))
+        self.out_proj = self.create_parameter([h, h])
+
+    def _run(self, x, lengths, tails=None, index=-1):
+        ins = {"X": x, "WIn": self.in_proj, "Weight": self.conv_w,
+               "WOut": self.out_proj, "Lengths": lengths}
+        if tails is not None:
+            ins["Tail"] = tails
+        return dispatch("short_conv", ins, {"slab_index": index},
+                        ["Out", "NewTail"])
+
+    def scan(self, x, lengths):
+        return self._run(x, lengths)
+
+    def update(self, x, lengths, tails, index):
+        return self._run(x, lengths, tails, index)
 
 
 class _Mamba(Layer):
@@ -696,6 +789,8 @@ class _Block(Layer):
                 self.mixer = _Attention(cfg, cfg.attention_spec(index))
             elif self.kind == "latent":
                 self.mixer = _LatentAttention(cfg)
+            elif self.kind == "short_conv":
+                self.mixer = _ShortConv(cfg)
             else:
                 self.mixer = _Mamba(cfg, index)
             if self.sandwich:
@@ -822,25 +917,29 @@ class HybridDecoder(Layer):
                         "out_dtype": "float32"})
         return _scaled(out, 1.0 / c.logits_scaling)
 
-    def _counted(self, routed):
+    def _counted(self, routed, picks=False):
         """The steps' trailing result: the expert layers' counts summed
-        ([4] int32), nothing for a model without them."""
+        ([4] int32), nothing for a model without them; `picks`: then the
+        experts each expert layer picked, stacked [Le, rows, T, k]."""
         if not routed:
             return ()
         total = routed[0][0]
         for counts, _ in routed[1:]:
             total = add(total, counts)
+        if picks:
+            return total, stack([p for _, p in routed])
         return (total,)
 
     def _scan_layers(self, ids, lengths, upto=None):
         """The whole (padded) sequences from empty caches: (h, new K, new
         V per attention layer, ssm state and conv tail per Mamba layer,
-        (counts, picks) per expert layer, cache rows per latent layer);
-        `upto`: the first layers only."""
+        (counts, picks) per expert layer, cache rows per latent layer, the
+        tail per short-convolution layer); `upto`: the first layers
+        only."""
         h = self._embed(ids)
-        ks, vs, ssm, conv, routed, lat = [], [], [], [], [], []
+        ks, vs, ssm, conv, routed, lat, tails = [], [], [], [], [], [], []
         for blk in list(self.layers)[:upto]:
-            scan = blk.kind == "mamba"
+            scan = blk.kind in ("mamba", "short_conv")
             h, made, expert = blk(
                 h, lambda m, x: (m.scan if scan else m)(x, lengths), lengths)
             if blk.kind == "attention":
@@ -851,9 +950,11 @@ class HybridDecoder(Layer):
                 conv.append(made[1])
             elif blk.kind == "latent":
                 lat.append(made)
+            elif blk.kind == "short_conv":
+                tails.append(made)
             if expert is not None:
                 routed.append(expert)
-        return h, ks, vs, ssm, conv, routed, lat
+        return h, ks, vs, ssm, conv, routed, lat, tails
 
     def forward(self, ids):
         """Logits [B, T, V] of whole sequences (every position valid)."""
@@ -876,7 +977,7 @@ class HybridDecoder(Layer):
                           last)
 
     # -- the step contract ----------------------------------------------------
-    def prefill_step(self, ids, lengths, last):
+    def prefill_step(self, ids, lengths, last, picks=False):
         """A prompt padded to its bucket, from empty caches.
 
         ids [B, T]; lengths [B] valid tokens a row; last [B] = lengths - 1
@@ -887,9 +988,14 @@ class HybridDecoder(Layer):
         token, [Lg, B, Hkv, window, D] (`kv_ring_pack`); the latent
         group's rows [Ll, B, T, width] where the model has latent layers;
         ssm [Lm, B, H, P, N] float32 and conv [Lm, B, K-1, C] where it has
-        Mamba layers: the state after each row's last VALID token; then,
-        for a model with expert layers, the counts of `step_counters`)."""
-        h, ks, vs, ssm, conv, routed, lat = self._scan_layers(ids, lengths)
+        Mamba layers, the tails [Lc, B, K-1, hidden] where it has short
+        convolution layers: the state after each row's last VALID token;
+        then, for a model with expert layers, the counts of
+        `step_counters`, and where `picks` (static) says so the experts
+        every position picked, [Le, B, T, k]: what a comparison with a
+        reference reads, never the engine)."""
+        h, ks, vs, ssm, conv, routed, lat, tails = self._scan_layers(
+            ids, lengths)
         rows = _take_rows(h, last)
         at = {i: n for n, i in enumerate(
             self.config.layers_of("attention"))}
@@ -906,9 +1012,13 @@ class HybridDecoder(Layer):
         if lat:
             kv.append(stack(lat))
         state = [stack(ssm), stack(conv)] if ssm else []
-        return (self._logits(rows), *kv, *state, *self._counted(routed))
+        if tails:
+            state.append(stack(tails))
+        return (self._logits(rows), *kv, *state,
+                *self._counted(routed, picks))
 
-    def decode_step(self, ids, cache_lengths, active, *cache, columns=None):
+    def decode_step(self, ids, cache_lengths, active, *cache, columns=None,
+                    picks=False):
         """One token a row on the carried caches.
 
         ids [S, 1]; cache_lengths [S] valid columns of each row's KV cache;
@@ -917,13 +1027,15 @@ class HybridDecoder(Layer):
         `cache` = a K, V per cache group of `cache_spec`, [Lg, S, Hkv,
         columns, D], the latent group's array [Ll, S, columns, width]
         where the model has latent layers, then ssm [Lm, S, H, P, N] and
-        conv [Lm, S, K-1, C] where it has Mamba layers.  They come back
-        WHOLE in the result, each attention or latent layer's new column
-        written into its entry (`_Attention.step`, `_LatentAttention.step`)
-        and each Mamba layer's state replaced in its: the caller donates
-        them.  Returns (logits [S, V] float32, *cache;
-        then, for a model with expert layers, the counts of
-        `step_counters`).
+        conv [Lm, S, K-1, C] where it has Mamba layers, then the tails
+        [Lc, S, K-1, hidden] where it has short-convolution layers.  They
+        come back WHOLE in the result, each attention or latent layer's
+        new column written into its entry (`_Attention.step`,
+        `_LatentAttention.step`) and each Mamba or short-convolution
+        layer's state replaced in its: the caller donates them.  Returns
+        (logits [S, V] float32, *cache; then, for a model with expert
+        layers, the counts of `step_counters`, and where `picks` (static)
+        says so the experts each row picked, [Le, S, 1, k]).
 
         `columns` (static, not a tensor): None, a row reads what it holds,
         block by block up to the longest row's — what a description with a
@@ -940,9 +1052,11 @@ class HybridDecoder(Layer):
         where.update({i: (n_kv, n) for n, i in
                       enumerate(c.layers_of("latent"))})
         n_kv += 1 if c.layers_of("latent") else 0
-        ssm, conv = cache[n_kv:] if c.layers_of("mamba") else (None, None)
+        state = cache[n_kv:]
+        ssm, conv = state[:2] if c.layers_of("mamba") else (None, None)
+        tails = state[-1] if c.layers_of("short_conv") else None
         h = self._embed(ids)
-        routed, n_mamba = [], 0
+        routed, n_mamba, n_conv = [], 0, 0
         for i, blk in enumerate(self.layers):
             if blk.kind == "attention":
                 at, entry = where[i]
@@ -962,13 +1076,19 @@ class HybridDecoder(Layer):
                 h, (ssm, conv), expert = blk(h, lambda m, x: m.update(
                     x, active, ssm, conv, n_mamba), active)
                 n_mamba += 1
+            elif blk.kind == "short_conv":
+                h, tails, expert = blk(h, lambda m, x: m.update(
+                    x, active, tails, n_conv), active)
+                n_conv += 1
             else:
                 h, _, expert = blk(h, None, active)
             if expert is not None:
                 routed.append(expert)
         state = [ssm, conv] if ssm is not None else []
+        if tails is not None:
+            state.append(tails)
         return (self._logits(squeeze(h, 1)), *cache[:n_kv], *state,
-                *self._counted(routed))
+                *self._counted(routed, picks))
 
 
 def _take_rows(h, index):

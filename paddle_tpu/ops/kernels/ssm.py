@@ -1,7 +1,7 @@
 """State-space (Mamba-2) and hybrid-decoder ops: RMS norms, a causal
-depthwise conv with a carried tail, the chunked state-space scan (SSD
-form, Dao & Gu 2024) and its one-token update, and grouped-query
-attention over an optional dense cache.
+depthwise conv with a carried tail, the chunked state-space scan (SSD,
+Dao & Gu 2024) and its one-token update, grouped-query attention over a
+dense cache, and the gated short-convolution mixer (last in this file).
 
 All are FORWARD ONLY (``grad=None``): the serving path is their one
 caller; gradients and the chunked scan's backward are what is left of
@@ -414,3 +414,32 @@ def gqa_attention(ins, attrs, ctx):
                      jnp.concatenate(values, 2),
                      preferred_element_type=_F32)
     return {"Out": out.reshape(b, hq, t, d).astype(q.dtype)}
+
+
+@register_op("short_conv",
+             inputs=["X", "WIn", "Weight", "WOut", "Tail?", "Lengths?!"],
+             outputs=["Out", "NewTail"], grad=None)
+def short_conv(ins, attrs, ctx):
+    """The gated short-convolution mixer whole (LFM2's), one op so that
+    its device time has one scope: ``[B | C | x] = X WIn`` (three equal
+    parts), ``u = B * x``, ``c`` = `causal_conv1d` of ``u`` over its
+    carried tail (no bias, no activation), ``Out = (C * c) WOut``.
+
+    X [B, T, C]; WIn [C, 3C]; Weight [C, K]; WOut [C, C]; Tail, Lengths
+    and attr ``slab_index`` as `causal_conv1d`'s: NewTail is the last K-1
+    ``u`` rows up to each row's length (a row of length 0 keeps its tail),
+    written into the state array's entry where it lies.  The matmuls take
+    their operands as given and accumulate in float32; ``u`` and the gate
+    are products in X's dtype.  Forward only."""
+    x, w_in, w_out, tail = lax.optimization_barrier(
+        (ins["X"], ins["WIn"], ins["WOut"], ins.get("Tail")))
+    bcx = jnp.matmul(x, w_in, preferred_element_type=_F32).astype(x.dtype)
+    gate_b, gate_c, xs = jnp.split(bcx, 3, axis=-1)
+    conv = causal_conv1d(
+        {"X": gate_b * xs, "Weight": ins["Weight"], "Tail": tail,
+         "Lengths": ins.get("Lengths")},
+        {"activation": "", "slab_index": attrs.get("slab_index", -1)}, ctx)
+    out = jnp.matmul(gate_c * conv["Out"], w_out,
+                     preferred_element_type=_F32).astype(x.dtype)
+    out, new_tail = lax.optimization_barrier((out, conv["NewTail"]))
+    return {"Out": out, "NewTail": new_tail}

@@ -76,16 +76,16 @@ def _rotary_kernel(x_ref, cos_ref, sin_ref, o_ref):
 @register_op("rotary_embedding", inputs=["X", "Positions?!"],
              outputs=["Out"], grad=None)
 def rotary_embedding(ins, attrs, ctx):
-    """Rotary positions, interleaved pairs (the GPT-J form): dims ``(2i,
-    2i + 1)`` of every head turn by ``position * theta^(-2i / D)``, all
-    ``D`` dims.
+    """Rotary positions, all ``D`` dims of every head: interleaved pairs
+    ``(2i, 2i + 1)`` (the GPT-J form) turn by ``position * theta^(-2i /
+    D)``; attr ``form`` "half": `_rotary_half`'s, the rotate_half form.
 
     X [B, H, T, D]; Positions [B, T] int32 (default ``0..T-1``: a prompt;
-    a decode row's position is its cache length).  Angles, cos and sin are
-    float32 from the int32 positions; Out in X's dtype.  attr ``theta``.
-    A prompt goes through one pass of a kernel (a block of positions of
-    one head a grid step); a single position a row is a few elementwise
-    ops.  Forward only."""
+    a decode row's position is its cache length); attr ``theta``.  Angles
+    float32, Out in X's dtype.  A prompt goes through one pass of a kernel;
+    a single position a row is a few elementwise ops.  Forward only."""
+    if attrs.get("form", "interleaved") != "interleaved":
+        return _rotary_half(ins, attrs)
     x, pos = lax.optimization_barrier((ins["X"], ins.get("Positions")))
     b, h, t, d = x.shape
     if pos is None:
@@ -491,3 +491,22 @@ def cached_decode_attention(ins, attrs, ctx):
     out = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype).reshape(s, hq, 1, d)
     out, kc, vc = lax.optimization_barrier((out, kc, vc))
     return {"Out": out, "NewKCache": kc, "NewVCache": vc}
+
+
+def _rotary_half(ins, attrs):
+    """`rotary_embedding` in the ``rotate_half`` form (attr ``form``
+    "half"): dims ``(i, i + D/2)`` turn by ``position * theta^(-2i / D)``,
+    ``x cos + rotate_half(x) sin`` with ``rotate_half(x) = [-x2 | x1]``
+    (LFM2's, Llama's).  A few elementwise ops XLA fuses, prompt or step."""
+    if attrs["form"] != "half":
+        raise ValueError(f"rotary_embedding: form {attrs['form']!r}")
+    x, pos = lax.optimization_barrier((ins["X"], ins.get("Positions")))
+    b, h, t, d = x.shape
+    if pos is None:
+        pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
+    inv = float(attrs["theta"]) ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
+    angle = jnp.tile(pos.astype(_F32)[:, :, None] * inv, 2)[:, None]
+    x32 = x.astype(_F32)
+    half = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
+    out = x32 * jnp.cos(angle) + half * jnp.sin(angle)
+    return {"Out": lax.optimization_barrier(out.astype(x.dtype))}

@@ -468,6 +468,10 @@ class StateSlots:
         metrics.gauge("state.slots_total", self.slots)
         metrics.gauge("state.slots_used", self.used)
         metrics.gauge("state.bytes", self.nbytes)
+        conv = sum(a.nbytes for n, a in self.arrays.items()
+                   if n.endswith("conv"))
+        if conv:        # the conv tails (Mamba's, a short convolution's)
+            metrics.gauge("state.conv_bytes", conv)
         if self.device_kv:
             metrics.gauge("kv.device_bytes", self.kv_slot_bytes * self.slots)
 

@@ -518,3 +518,123 @@ def test_latent_prompt_kernel_compiles_at_its_own_block_shape(
     # heads at [T, T] would be 137 GB at 16k
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes <= mem.argument_size_in_bytes
+
+
+def _float_ops(text):
+    """The floating-point instructions of an optimized HLO module, as a
+    multiset of (type, opcode, attributes without names, metadata or the
+    backend's placement): what decides a program's arithmetic, whatever
+    moves, copies or int results beside it."""
+    import collections
+    got = collections.Counter()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = (\S+) (\S+)\(.*?\)(.*)", line)
+        if not m or not m.group(1).startswith(("f32", "bf16")) \
+                or m.group(2) in ("parameter", "bitcast", "copy",
+                                  "copy-start", "copy-done", "tuple",
+                                  "get-tuple-element"):
+            continue
+        attrs = re.sub(r"backend_config=.*$", "",
+                       re.sub(r"metadata=\{.*?\}", "", m.group(3)))
+        got[(m.group(1).split("{")[0], m.group(2),
+             re.sub(r"[\w.-]+\.\d+", "_", attrs).strip())] += 1
+    return got
+
+
+@pytest.mark.parametrize("phase,size", [("prefill", 2048), ("decode", 1024)])
+def test_lfm2_twins_compile_to_the_engine_programs_arithmetic(
+        one_chip, no_compile_cache, monkeypatch, phase, size):
+    """The LFM2 cell's check gives its reference the experts picked by
+    TWINS of the engine's step programs (`serving_lfm2_moe._with_picks`:
+    the same step with the picks as one result more) and needs the twins'
+    logits to be the engine programs' to the bit.  Compiled whole for
+    XLA:TPU + Mosaic at the published stage (a 2,048-token prompt bucket;
+    64 rows at a 1,024-column bound), every floating-point instruction of
+    the engine program is in the twin's and the twin has no other: the
+    picks add int32 results and copies only."""
+    import functools
+    import json
+    import sys
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.dygraph as dg
+    from paddle_tpu.core.dtype import np_dtype
+    from paddle_tpu.dygraph.tensor import Tensor
+    from paddle_tpu.jit import StaticFunction
+    from paddle_tpu.models import Lfm2MoeModel
+    from paddle_tpu.ops.kernels import moe, ssm, window_attention
+    from paddle_tpu.serving.kv_pool import device_kv_arrays, state_groups
+    from paddle_tpu.serving.step_program import DECODE_CACHE_AT, StepPrograms
+    for module in (moe, ssm, window_attention):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, here)
+    from benchmark import serving_lfm2_moe
+    with open(os.path.join(here, "benchmark", "configs",
+                           "lfm2-8b-a1b.json")) as f:
+        file = json.load(f)
+    cfg = serving_lfm2_moe.model_config(file, file["engine"])
+    slots = file["engine"]["max_slots_cap"]
+    spec = cfg.cache_spec()
+    arrays = [((a["layers"], slots) + tuple(a["shape"]), np_dtype(a["dtype"]))
+              for a in device_kv_arrays(spec, file["engine"]["max_context"])]
+    arrays += [((g["layers"], slots) + tuple(a["shape"]),
+                np_dtype(a["dtype"]))
+               for g in state_groups(spec) for a in g["arrays"]]
+    got = {}
+
+    def build():
+        def zeros(shape, dtype):
+            return Tensor(jnp.zeros(shape, dtype))
+
+        with dg.guard():
+            model = Lfm2MoeModel(cfg)
+            steps = StepPrograms(model)
+            if phase == "prefill":
+                args = [zeros((1, size), jnp.int32)] \
+                    + [zeros((1,), jnp.int32)] * 2
+                engine = steps._prefill
+                twin = StaticFunction(
+                    serving_lfm2_moe._with_picks(model.prefill_step, False),
+                    layer=model, abstract_trace=True)
+            else:
+                args = [zeros((slots + len(steps.counters),), jnp.int32)] \
+                    + [zeros((slots,), jnp.int32)] * 2 \
+                    + [zeros(shape, dtype) for shape, dtype in arrays]
+                engine = steps.decode_program(size)
+                twin = StaticFunction(
+                    serving_lfm2_moe._with_picks(functools.partial(
+                        model.decode_step, columns=size), True),
+                    layer=model, abstract_trace=True,
+                    donate_args=range(DECODE_CACHE_AT,
+                                      DECODE_CACHE_AT + len(arrays)))
+            got["engine"] = engine.concrete_program(*args)
+            got["twin"] = twin.concrete_program(*args)
+        return 0
+
+    with jax.enable_x64(False):
+        jax.eval_shape(build)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    texts = {}
+    for name, cp in got.items():
+        block = cp.program.global_block()
+        feeds = [sds(block.var(n).shape, block.var(n).dtype)
+                 for n in cp.feed_names]
+        kept = tuple(f for i, f in enumerate(feeds) if i not in cp.donated)
+        donated = tuple(feeds[i] for i in cp.donated)
+        params = tuple(sds(t.shape, t._value.dtype)
+                       for t in cp.params.values())
+        with jax.enable_x64(False):
+            texts[name] = cp.composed().lower(
+                sds((), jnp.uint32), params, kept, True,
+                *((donated,) if donated else ())).compile().as_text()
+    engine, twin = (_float_ops(texts[n]) for n in ("engine", "twin"))
+    assert sum(engine.values()) > 2000
+    assert engine == twin
+    # the picks: 10 expert layers, top 4, one more int32 result
+    rows = "1,2048" if phase == "prefill" else f"{slots},1"
+    assert re.search(rf"s32\[10,{rows},4\]", texts["twin"])
+    assert not re.search(rf"s32\[10,{rows},4\]", texts["engine"])
