@@ -19,10 +19,28 @@ grouped product over the sorted rows (a Pallas grouped matmul, the
 held, non-empty groups, so a pair of an absent expert costs no FLOPs and
 an untouched expert's weights are never read).
 
+Tiling (`grouped_tiling`, from the operand shapes alone): megablox walks
+a grid of (n tiles, active row tiles, k tiles), k innermost, and fetches
+the weight block ``(expert, k_i, n_i)`` again whenever its index changes.
+With two or more k tiles it changes on every grid step, and an expert
+that holds hundreds of a prompt's rows has its weights read again for
+every 128 of them.  So the k tile is the WHOLE contraction wherever the blocks fit
+`_VMEM_BYTES` with an n tile of at least 512 lanes (the widest that fits,
+narrowed before the whole contraction is given up): the weight block's
+index then stays put across one expert's consecutive row tiles and Pallas
+skips the copy, each expert's ``[K, tn]`` read once per n tile.  Where
+nothing fits (openPangu's W1, K 7,680) the tiles are `_tile(K)` and
+`_tile(N)`.  Where an expert's rows fit one tile (every decode step) the
+bytes read are the same and the grid is shorter.  The row tile stays 128:
+a row tile that two groups share computes both groups' rows for each, so
+a taller one wastes MXU rows at every group boundary.  A trace counts
+``ops.moe.gmm_whole_k`` (matmuls traced with the whole contraction).
+
 Numerics: router logits, sigmoid, top-k and the weights are float32 at
 ``Precision.HIGHEST`` (the published code computes its gate in float32);
 the expert matmuls take their operands as given (bfloat16 when served)
-and accumulate in float32.
+and accumulate in float32 (a whole-contraction tile adds the same float32
+partial products in another order).
 """
 from __future__ import annotations
 
@@ -31,6 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental.pallas.ops.tpu.megablox import gmm
 
+from ...core.monitor import stat_add
 from ..attention import _interpret
 from .ssm import _valid
 from ..registry import register_op
@@ -38,9 +57,16 @@ from ..registry import register_op
 _F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
 # rows of the sorted pairs a grid step takes (a group's rows are masked
-# inside a tile that straddles two groups), and the widest k / n tile:
-# [1024, 896] bfloat16 is 1.8 MB, twice buffered well inside VMEM
+# inside a tile that straddles two groups), and the widest n tile (and k
+# tile, where the whole contraction does not fit)
 _TILE_M, _TILE_KN = 128, 1024
+# the narrowest n tile a whole-contraction k tile is taken with
+_TILE_N_MIN = 512
+# VMEM the blocks of a whole-contraction tiling may take (`_vmem_bytes`):
+# megablox asks for the default scoped VMEM, 16 MiB on the v5e, and the
+# chip-free compile for the v5e takes 15.9 MB by this count (K 4,096, tn
+# 768) and refuses 16.3 MB (K 7,680, tn 384) and 20.4 MB (K 4,096, tn 1,024)
+_VMEM_BYTES = 14 << 20
 # a call with more token-expert pairs than this (a prompt of thousands of
 # tokens) sorts the HELD pairs to the front and computes them in blocks of
 # this many rows, so the pairs of absent experts are never gathered to full
@@ -77,15 +103,40 @@ def moe_router_topk(ins, attrs, ctx):
     return {"Experts": pick.astype(jnp.int32), "Weights": w}
 
 
+def _tiles(size, cap=_TILE_KN):
+    """The tiles of `size` no wider than `cap`, widest first: the whole
+    axis where it is no wider, then the whole-lane (multiple of 128)
+    divisors."""
+    whole = [size] if size <= cap else []
+    return whole + [t for t in range(cap - cap % 128, 0, -128)
+                    if t < size and size % t == 0]
+
+
 def _tile(size, cap=_TILE_KN):
     """The widest tile <= cap that divides `size` into whole lanes (a
     multiple of 128), else the whole axis."""
-    if size <= cap:
-        return size
-    for t in range(cap - cap % 128, 0, -128):
-        if size % t == 0:
-            return t
-    return size
+    return next(iter(_tiles(size, cap)), size)
+
+
+def _vmem_bytes(tm, tk, tn, itemsize):
+    """VMEM of a grouped matmul's blocks: the weight [tk, tn] and the rows
+    [tm, tk], each twice buffered, the float32 output [tm, tn] twice and
+    its accumulator once."""
+    return 2 * (tk * tn + tm * tk) * itemsize + 3 * tm * tn * 4
+
+
+def grouped_tiling(m, k, n, itemsize=2):
+    """``(tm, tk, tn)`` of `_grouped` over [m, k] rows and [held, k, n]
+    weights of `itemsize` bytes: tk the whole contraction with the widest
+    n tile of at least `_TILE_N_MIN` lanes (or the whole of a narrower n)
+    whose blocks fit `_VMEM_BYTES`; where none does, `_tile(k)` and
+    `_tile(n)`."""
+    tm = min(_TILE_M, m)
+    for tn in _tiles(n):
+        if tn >= min(_TILE_N_MIN, n) and \
+                _vmem_bytes(tm, k, tn, itemsize) <= _VMEM_BYTES:
+            return tm, k, tn
+    return tm, _tile(k), _tile(n)
 
 
 def _grouped(rows, w, sizes, first):
@@ -93,8 +144,11 @@ def _grouped(rows, w, sizes, first):
     elsewhere, float32.  rows [M, K] sorted by group; w [held, K, N];
     sizes [E] rows a group."""
     k, n = w.shape[1], w.shape[2]
-    return gmm(rows, w, sizes, preferred_element_type=_F32,
-               tiling=(min(_TILE_M, rows.shape[0]), _tile(k), _tile(n)),
+    tiling = grouped_tiling(rows.shape[0], k, n,
+                            max(rows.dtype.itemsize, w.dtype.itemsize))
+    if tiling[1] == k:
+        stat_add("ops.moe.gmm_whole_k")
+    return gmm(rows, w, sizes, preferred_element_type=_F32, tiling=tiling,
                group_offset=jnp.int32(first), interpret=_interpret())
 
 

@@ -183,6 +183,60 @@ def test_grouped_experts_at_published_widths_compile_to_two_kernels(
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * hidden_bytes
 
 
+@pytest.mark.parametrize("tokens,k,d,f,held,total,activation,tilings", [
+    (4096, 4, 2048, 1792, 32, 32, "silu_gated",
+     [(128, 2048, 896), (128, 1792, 1024)]),
+    (8192, 8, 4096, 4096, 16, 128, "silu_gated",
+     [(128, 4096, 512), (128, 4096, 512)]),
+    (1024, 22, 1024, 2688, 128, 512, "relu2",
+     [(128, 1024, 896), (128, 2688, 1024)]),
+], ids=["lfm2-prompt4096", "command-a-plus-block16384",
+        "nemotron-prompt1024"])
+def test_grouped_experts_compile_inside_vmem_with_the_whole_contraction(
+        one_chip, no_compile_cache, monkeypatch, tokens, k, d, f, held,
+        total, activation, tilings):
+    """A prompt's expert layer at the published widths, each matmul's k
+    tile the whole contraction: LFM2-8B-A1B's 4,096-token bucket (all 32
+    experts held, 16,384 pairs on the plain path), command-a-plus's
+    8,192-token bucket (16 of 128 held, held pairs first in blocks of
+    16,384 rows) and nemotron's 1,024-token bucket in its latent width
+    (128 of 512 held; W2's blocks take 13.96 MB of the 14 MiB budget).
+    Mosaic refuses a block set past the scoped VMEM
+    (`RESOURCE_EXHAUSTED`), so a tile the rule picks too wide fails here."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.core import monitor
+    from paddle_tpu.ops.kernels import moe
+    from paddle_tpu.ops.registry import OpContext
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    width = 2 * f if activation == "silu_gated" else f
+    m = min(tokens * k, moe._BLOCK_ROWS)
+    assert [moe.grouped_tiling(m, d, width),
+            moe.grouped_tiling(m, f, d)] == tilings
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def experts(x, picked, weights, w1, w2, lengths):
+        out = moe.moe_grouped_experts(
+            {"X": x, "Experts": picked, "Weights": weights, "W1": w1,
+             "W2": w2, "Lengths": lengths},
+            {"n_experts": total, "first_held": 0, "held": held,
+             "activation": activation}, OpContext(seed=0, is_test=True))
+        return out["Out"], out["Stats"]
+
+    monitor.stat_reset("ops.moe.gmm_whole_k")
+    with jax.enable_x64(False):
+        compiled = jax.jit(experts).lower(
+            sds((1, tokens, d), jnp.bfloat16),
+            sds((1, tokens, k), jnp.int32), sds((1, tokens, k), jnp.float32),
+            sds((held, d, width), jnp.bfloat16),
+            sds((held, f, d), jnp.bfloat16), sds((1,), jnp.int32)).compile()
+    assert monitor.stat_get("ops.moe.gmm_whole_k") == 2
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          compiled.as_text())) == 2
+
+
 @pytest.fixture(scope="module")
 def command_a_plus_programs():
     """The traced prefill (8,192-token bucket) and decode (32 rows) step
